@@ -182,8 +182,10 @@ def fit(args, network, data_loader):
                      args.load_epoch)
 
     epoch_size = max(1, args.num_examples // args.batch_size)
+    # the current context: the chip when one is attached, else the host
     mod = mx.mod.Module(network, context=mx.current_context(),
                         compute_dtype=_compute_dtype(args))
+    logging.info("training on %s", mx.current_context())
     batch_end = [mx.callback.Speedometer(args.batch_size,
                                          args.disp_batches)]
     epoch_end = []

@@ -71,8 +71,11 @@ def _synth_iter(vocab_size, seq_len, batch_size, batches):
 def benchmark(args, net):
     """Synthetic-token steady-state throughput via the fused Module step."""
     it = _synth_iter(args.vocab_size, args.seq_len, args.batch_size, 1)
+    # the current context: the chip when one is attached, else the host
     mod = mx.mod.Module(net, label_names=("softmax_label",),
+                        context=mx.current_context(),
                         compute_dtype=args.dtype)
+    logging.info("benchmark on %s", mx.current_context())
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label,
              for_training=True)
     mod.init_params(initializer=mx.init.Xavier(factor_type="in",
@@ -124,7 +127,9 @@ def main():
     it = _corpus_iter(args.data_train, args.vocab_size, args.seq_len,
                       args.batch_size)
     mod = mx.mod.Module(net, label_names=("softmax_label",),
+                        context=mx.current_context(),
                         compute_dtype=args.dtype)
+    logging.info("training on %s", mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer=args.optimizer,
             optimizer_params={"learning_rate": args.lr},
             eval_metric=mx.metric.Perplexity(ignore_label=None),

@@ -236,6 +236,17 @@ def _signature(args) -> dict:
     return {"tree": str(treedef), "leaves": sig}
 
 
+def _arg_devices(args) -> list:
+    """Sorted (platform, id) of every device the concrete arguments live
+    on — an executable is compiled FOR its devices, so the same graph on
+    cpu(0) and tpu(0), or on two replicas' chips, is two entries."""
+    import jax
+
+    return sorted({(d.platform, d.id)
+                   for leaf in jax.tree_util.tree_leaves(args)
+                   if isinstance(leaf, jax.Array) for d in leaf.devices()})
+
+
 _digest = digest_of
 
 
@@ -306,8 +317,13 @@ def _load(digest: str):
                 continue  # stale-version entry: a miss, not an error
             from jax.experimental import serialize_executable as se
 
+            # onto the devices the entry was compiled for: left to its
+            # default the loader spreads the executable over EVERY local
+            # device, which fails on any host with more than one
             t0 = time.perf_counter()
-            loaded = se.deserialize_and_load(*pickle.loads(payload))
+            loaded = se.deserialize_and_load(
+                *pickle.loads(payload), backend=meta["devices"]["platform"],
+                execution_devices=_entry_devices(meta))
             ms = (time.perf_counter() - t0) * 1e3
             _metrics()["deserialize_ms"].observe(ms)
             with _lock:
@@ -323,6 +339,14 @@ def _load(digest: str):
                 raise
             continue
     return None
+
+
+def _entry_devices(meta: dict) -> list:
+    """The live ``jax.Device`` objects an entry's meta names."""
+    import jax
+
+    by_id = {d.id: d for d in jax.devices(meta["devices"]["platform"])}
+    return [by_id[i] for i in meta["devices"]["ids"]]
 
 
 def _store(digest: str, compiled, meta: dict, compile_ms: float) -> Optional[str]:
@@ -425,6 +449,7 @@ class CachedFunction:
             "group2ctx": sorted(
                 (g, str(c)) for g, c in ex._group2ctx.items()),
             "sig": _signature(args),
+            "devices": _arg_devices(args),
         }
         # tuned and untuned executables must never collide: when the
         # autotuner is active its DB-state fingerprint joins the key (a
@@ -492,14 +517,15 @@ class CachedFunction:
             return self._register(sig, self._fn, "bypass")
         _metrics()["compile_ms"].observe(compile_ms)
         cost = _cost_of(compiled)
-        meta = self._build_meta(digest, compile_ms, cost)
+        meta = self._build_meta(digest, compile_ms, cost, compiled)
         with _lock:
             _mem[digest] = (compiled, meta)
         _store(digest, compiled, meta, compile_ms)
         return self._register(sig, compiled, "miss", digest, meta, compiled)
 
-    def _build_meta(self, digest, compile_ms, cost) -> dict:
+    def _build_meta(self, digest, compile_ms, cost, compiled) -> dict:
         ex = self._executor
+        devs = compiled.runtime_executable().local_devices()
         mesh_axes = None
         if ex._shard_mesh is not None:
             mesh = ex._shard_mesh
@@ -509,6 +535,9 @@ class CachedFunction:
             "kind": self._kind,
             "env": env_fingerprint(),
             "mesh_axes": mesh_axes,
+            # what _load hands deserialize_and_load as execution_devices
+            "devices": {"platform": devs[0].platform,
+                        "ids": [d.id for d in devs]},
             "created": round(time.time(), 3),
             "compile_ms": round(compile_ms, 1),
             "cost": cost,

@@ -6,11 +6,20 @@ training scripts (``--gpus 0,1``) run unchanged.  ``cpu_pinned`` maps to host
 memory.  A Context is hashable, usable as a ``with``-scope (current-context
 stack, parity with python/mxnet/context.py), and resolves lazily to a concrete
 ``jax.Device`` so contexts can be constructed before backends initialise.
+
+Device rule (docs/how_to/deviations.md "Default context"): ``tpu(i)`` /
+``gpu(i)`` is the i-th attached accelerator or an error — never a host
+device, never a wrapped index — unless the platform was explicitly forced
+to the host (``JAX_PLATFORMS=cpu``, as the tests do), where the host's
+devices stand in for chips.  The default context is ``tpu(0)`` when an
+accelerator is attached and ``cpu(0)`` otherwise.
 """
 from __future__ import annotations
 
 import threading
 from typing import List, Optional
+
+from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
 
@@ -56,27 +65,32 @@ class Context:
     def jax_device(self):
         """Resolve to a concrete jax.Device.
 
-        ``gpu``/``tpu`` resolve to accelerator devices (whatever platform JAX
-        exposes — TPU in production, host CPU devices in tests running under
-        ``--xla_force_host_platform_device_count``); ``cpu``/``cpu_pinned``
-        prefer the CPU backend when present.
+        ``cpu``/``cpu_pinned`` resolve to a host device (ids wrap: the
+        reference gives cpu ids no meaning).  ``gpu``/``tpu`` resolve to
+        the ``device_id``-th accelerator or raise — see the module
+        docstring for the one exception (platform forced to the host).
         """
         import jax
 
         if self.device_type in ("cpu", "cpu_pinned"):
-            try:
-                devs = jax.local_devices(backend="cpu")
-            except RuntimeError:
-                devs = jax.local_devices()
-        else:
-            devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+            devs = jax.local_devices(backend="cpu")
+            return devs[self.device_id % len(devs)]
+        devs = _accelerators()
+        if not devs:
+            raise MXNetError(
+                "%s: no accelerator is attached (jax.local_devices() = %s). "
+                "Use mx.cpu(), or set JAX_PLATFORMS=cpu to let host devices "
+                "stand in for chips." % (self, jax.local_devices()))
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s: device_id out of range, %d %s device(s) attached"
+                % (self, len(devs), devs[0].platform))
+        return devs[self.device_id]
 
     # -- with-scope --------------------------------------------------------
     def __enter__(self):
-        if not hasattr(Context._default_ctx, "value"):
-            Context._default_ctx.value = Context("cpu", 0)
-        self._old_ctx = Context._default_ctx.value
+        # None = no scope active on this thread: the process default
+        self._old_ctx = getattr(Context._default_ctx, "value", None)
         Context._default_ctx.value = self
         return self
 
@@ -89,9 +103,17 @@ class Context:
 
     @classmethod
     def default_ctx(cls) -> "Context":
-        if not hasattr(cls._default_ctx, "value"):
-            cls._default_ctx.value = Context("cpu", 0)
-        return cls._default_ctx.value
+        """Innermost ``with`` scope of this thread, else the process
+        default: the first accelerator when one is attached, the host
+        otherwise."""
+        scoped = getattr(cls._default_ctx, "value", None)
+        if scoped is not None:
+            return scoped
+        import jax
+
+        if any(d.platform != "cpu" for d in jax.local_devices()):
+            return Context("tpu", 0)
+        return Context("cpu", 0)
 
 
 def cpu(device_id: int = 0) -> Context:
@@ -116,11 +138,25 @@ def num_gpus() -> int:
 
 
 def num_tpus() -> int:
+    """Attached accelerators (same rule as ``tpu(i)``: host devices count
+    only where the platform was forced to the host)."""
+    return len(_accelerators())
+
+
+def _accelerators() -> List:
     import jax
 
-    try:
-        return len([d for d in jax.local_devices() if d.platform != "cpu"]) or len(
-            jax.local_devices()
-        )
-    except RuntimeError:
-        return 0
+    devs = jax.local_devices()
+    chips = [d for d in devs if d.platform != "cpu"]
+    if chips:
+        return chips
+    return devs if _forced_to_host() else []
+
+
+def _forced_to_host() -> bool:
+    """The platform was explicitly forced to the host (``JAX_PLATFORMS=cpu``
+    or the equivalent config): a choice, not a fallback."""
+    import jax
+
+    return (jax.config.jax_platforms or "").split(",")[0].strip().lower() \
+        == "cpu"

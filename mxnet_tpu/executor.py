@@ -217,22 +217,6 @@ class Executor:
         # NaiveEngine parity: MXNET_ENGINE_TYPE=NaiveEngine disables jit and
         # synchronizes after every call (threaded_engine.h:329-337 debugging).
         self._naive = env("MXNET_ENGINE_TYPE") == "NaiveEngine"
-        # graphs with Python-callback ops need host send/recv inside jit;
-        # on backends without it (some tunneled TPU platforms) fall back to
-        # eager execution so the graph still runs
-        if not self._naive and any(
-                n.op is not None and n.op.name in ("Custom", "_Native",
-                                                   "_NDArray")
-                for n in plan.nodes):
-            from .operator import host_callbacks_supported
-
-            if not host_callbacks_supported():
-                import logging
-
-                logging.warning(
-                    "graph contains Python-callback ops but backend lacks "
-                    "host-callback support under jit; executor runs eagerly")
-                self._naive = True
         # model parallelism: ctx-group → device placement compiled into the
         # step (group2ctx was previously accepted but silently ignored)
         self._placement = plan.placement_map(self._group2ctx)
@@ -240,6 +224,9 @@ class Executor:
         # XLA partitions every compiled step from the committed input
         # shardings — tensor parallelism needs no graph changes here.
         self._shard_mesh = None
+        # (mesh, batch axis) of a data-parallel bind: Pallas kernels run
+        # per batch shard (set by DataParallelExecutorGroup)
+        self._kernel_mesh = (None, None)
         self._shard_specs: Dict[str, Any] = {}
         self._shard_fingerprint = None
 
@@ -282,6 +269,15 @@ class Executor:
 
         return cast
 
+    def _bound(self, fn):
+        """``fn`` with its Pallas kernels bound to this executor's device:
+        compiled on a tpu context, interpreted (and saying so) on a cpu
+        one — whatever the process's default backend — and, on a device
+        mesh, run per batch shard (ops/interpret.py)."""
+        from .ops.interpret import bind
+
+        return bind(fn, self._ctx.jax_device().platform, *self._kernel_mesh)
+
     def _get_fwd(self, is_train: bool, internals: bool = False):
         import jax
 
@@ -297,6 +293,7 @@ class Executor:
                 return plan.run(cast(args), aux, rng, is_train,
                                 want_internals=internals, placement=placement)
 
+            fn = self._bound(fn)
             if self._naive:
                 self._jit_cache[key] = fn
             else:
@@ -337,6 +334,7 @@ class Executor:
                     grads[name] = grads[name] + old_grads[name]
                 return list(outs), new_aux, grads
 
+            fn = self._bound(fn)
             if self._naive:
                 self._jit_cache[key] = fn
             else:
@@ -539,7 +537,7 @@ class Executor:
                     return (list(outs), new_aux, new_params, new_states,
                             (ok, gnorm))
 
-                return fn
+                return self._bound(fn)
 
             if self._naive:
                 self._jit_cache[key] = make_fn(env_remat)
@@ -689,8 +687,13 @@ class Executor:
             # abstract arg signature of the fused call: the autotuner
             # lowers candidate variants against it, and perf_probe reuses
             # it (via _fused_introspect) to lower the exact same program
+            # (committed arrays keep their sharding: on a device mesh the
+            # partitioning of the program comes from nothing else)
             abstract_args = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype,
+                    sharding=x.sharding if getattr(x, "committed", False)
+                    else None),
                 (diff_args, states, aux, other_args, rng, sc, opt_rng))
         fn = self._get_fused_step(key, tuple(infos), optimizer.pure_update,
                                   optimizer.needs_rng, shardings,

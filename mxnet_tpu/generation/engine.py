@@ -371,7 +371,7 @@ class DecodeEngine:
         from ..models.transformer import (get_transformer_lm_catchup,
                                           get_transformer_lm_decode,
                                           get_transformer_lm_prefill)
-        from ..predictor import Predictor
+        from ..predictor import Predictor, on_ctx
 
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
@@ -380,7 +380,11 @@ class DecodeEngine:
         self.max_seq_len = int(max_seq_len)
         self.head_dim = self.hidden // self.num_heads
         self.eos_id = eos_id
-        self._ctx = ctx
+        # None: the current context — the chip when one is attached
+        # (docs/how_to/deviations.md "Default context")
+        from ..context import current_context
+
+        self._ctx = ctx = ctx or current_context()
         self._dtype = np.dtype(dtype)
         # unset knobs consult the autotuner before the env defaults:
         # explicit constructor args always pin, tuned winners beat the
@@ -446,15 +450,17 @@ class DecodeEngine:
                 dparams = nd.load(dparams)
             if dparams is None:
                 raise MXNetError("draft spec needs 'params'")
-            self._draft_params = dict(dparams)
+            self._draft_params = {k: on_ctx(v, ctx)
+                                  for k, v in dparams.items()}
             self._verify_width = k + 1
         self._accept_ewma: Optional[float] = None
 
         if isinstance(params, str):
             params = nd.load(params)
-        # one shared copy of the weights: Predictor passes live NDArrays
-        # through rebinds, so every bucket executor binds the same arrays
-        self._params = dict(params)
+        # one shared copy of the weights ON the engine's device: Predictor
+        # passes live NDArrays of its own context through, so every
+        # bucket executor binds the same arrays
+        self._params = {k: on_ctx(v, ctx) for k, v in params.items()}
 
         self.pool = PagedKVPool(self.num_pages, self.page_size,
                                 self.num_layers, self.num_heads,
@@ -879,6 +885,21 @@ class DecodeEngine:
     def active_lanes(self) -> int:
         with self._cv:
             return len(self._active)
+
+    def devices(self) -> Dict[str, List[str]]:
+        """Where this engine's arrays live, as ``str(jax.Device)`` lists:
+        ``weights`` (the shared parameter copy) and ``prefill`` /
+        ``decode`` (output buffers of every executable that has run —
+        all of them after :meth:`warmup`)."""
+        def of(arrays):
+            return sorted({str(d) for a in arrays
+                           for d in a._data.devices()})
+
+        prefill = [o for bp in self._prefill.values()
+                   for p in bp._preds.values() for o in p.get_outputs()]
+        decode = [o for p in self._decode.values() for o in p.get_outputs()]
+        return {"weights": of(self._params.values()),
+                "prefill": of(prefill), "decode": of(decode)}
 
     def snapshot(self) -> dict:
         with self._cv:

@@ -23,26 +23,43 @@ __all__ = ["peak_flops", "hbm_bytes_per_s", "cost_analysis",
 
 register_env("MXNET_TELEMETRY_HBM_GBS", 0.0, float,
              "HBM bandwidth (GB/s) for the roofline bytes term; "
-             "0 uses the TPU v5e figure (819 GB/s).")
+             "0 looks the attached device's kind up in DEVICE_PEAKS.")
 
-# TPU v5e: 197 bf16 TFLOP/s, 819 GB/s HBM — the chip every PERF.md
-# number was measured on; both overridable for other parts
-_V5E_PEAK_FLOPS = 197e12
-_V5E_HBM_BYTES_S = 819e9
+# Published per-chip peaks keyed by jax ``device_kind`` — the one table
+# every MFU / roofline denominator reads (bench.py included).  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
+# A kind that is not listed has no peak: MFU is None, not the v5e's.
+DEVICE_PEAKS = {
+    # what jax reports for a v5e chip (chip_smoke.py run, PR 22)
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+# the chip the autotuner's off-chip roofline proxy models when the
+# attached device is not in the table (roofline_ms ranks, never reports)
+ROOFLINE_PROXY_KIND = "TPU v5 lite"
 
 
-def peak_flops() -> float:
+def _peak(field, device_kind):
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind, {}).get(field)
+
+
+def peak_flops(device_kind=None) -> Optional[float]:
     """MFU denominator: MXNET_TELEMETRY_PEAK_FLOPS override, else the
-    TPU v5e bf16 peak used by bench.py/perf_probe (197 TFLOP/s)."""
+    published bf16 peak of ``device_kind`` (default: the attached
+    device's); None for a kind DEVICE_PEAKS does not list."""
     v = env("MXNET_TELEMETRY_PEAK_FLOPS", 0.0, float)
-    return float(v) if v else _V5E_PEAK_FLOPS
+    return float(v) if v else _peak("flops", device_kind)
 
 
-def hbm_bytes_per_s() -> float:
+def hbm_bytes_per_s(device_kind=None) -> Optional[float]:
     """Roofline bytes denominator: MXNET_TELEMETRY_HBM_GBS override,
-    else TPU v5e HBM bandwidth (819 GB/s)."""
+    else the published HBM bandwidth of ``device_kind``; None when
+    unlisted."""
     v = env("MXNET_TELEMETRY_HBM_GBS", 0.0, float)
-    return float(v) * 1e9 if v else _V5E_HBM_BYTES_S
+    return float(v) * 1e9 if v else _peak("hbm_bytes_per_s", device_kind)
 
 
 def cost_analysis(compiled) -> Optional[dict]:
@@ -82,7 +99,9 @@ def roofline_ms(info) -> Optional[float]:
     nbytes = float(info.get("bytes_accessed") or 0.0)
     if flops <= 0 and nbytes <= 0:
         return None
-    return max(flops / peak_flops(), nbytes / hbm_bytes_per_s()) * 1e3
+    peak = peak_flops() or peak_flops(ROOFLINE_PROXY_KIND)
+    bw = hbm_bytes_per_s() or hbm_bytes_per_s(ROOFLINE_PROXY_KIND)
+    return max(flops / peak, nbytes / bw) * 1e3
 
 
 def hlo_op_counts(hlo_text, interesting=None) -> dict:
@@ -97,7 +116,7 @@ def hlo_op_counts(hlo_text, interesting=None) -> dict:
 
 
 def bn_fusion_analysis(hlo_text) -> dict:
-    """Does BN's scale/shift ride the conv epilogue? (VERDICT r4 ask.)
+    """Does BN's scale/shift ride the conv epilogue?
 
     Classifies every convolution by actual dataflow, not substring
     presence: a conv counts as epilogue-fused only when its RESULT name

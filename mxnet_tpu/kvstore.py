@@ -134,6 +134,16 @@ class KVStore:
                     acc = acc + v._data
                 merged = NDArray(acc, vlist[0].context)
             if self._updater is not None:
+                # the store lives where it was initialised: a value pushed
+                # from elsewhere (a gradient replicated over a device
+                # mesh) is brought to it, as pull brings it back
+                # (reference CopyFromTo(merged, &local))
+                local = self._store[k]._data.sharding
+                if merged._data.sharding != local:
+                    import jax
+
+                    merged = NDArray(jax.device_put(merged._data, local),
+                                     self._store[k].context)
                 self._updater(k, merged, self._store[k])
             else:
                 # no updater: the store holds the merged sum of this push

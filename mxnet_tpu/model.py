@@ -38,11 +38,14 @@ def _create_kvstore(kvstore, num_device, arg_params):
             kv = None
         else:
             kv = kvs.create(kvstore)
-            if kvstore == "local":
-                max_size = max(int(__import__("numpy").prod(p.shape))
-                               for p in arg_params.values()) if arg_params else 0
-                if max_size < 1024 * 1024 * 16:
-                    update_on_kvstore = False
+            if "dist" not in kvstore:
+                # one mesh executor spans the devices, so gradients leave
+                # the compiled step already summed (psum) whatever their
+                # size.  The reference's 16M-element rule chose where N
+                # per-device copies were merged; here it only pushed
+                # every realistic model (one parameter above 16M
+                # elements) off the fused step onto the eager per-key loop
+                update_on_kvstore = False
     else:
         raise TypeError("kvstore must be KVStore, str or None")
     if kv is None:

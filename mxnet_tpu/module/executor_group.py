@@ -241,6 +241,8 @@ class DataParallelExecutorGroup:
                             compute_dtype=self.compute_dtype,
                             cast_exclude=self.label_names)
         self.execs = [executor]
+        if self._mesh is not None:
+            executor._kernel_mesh = (self._mesh, self._data_axis)
         if self._rules is not None:
             self._apply_rule_shardings(
                 executor,

@@ -245,22 +245,37 @@ class NDArray:
         return NDArray(self._data[key], self._ctx)
 
     def __setitem__(self, key, value):
+        import jax
         import jax.numpy as jnp
 
         if isinstance(value, NDArray):
             value = value._data
+            if isinstance(value, jax.Array) and \
+                    not value.is_fully_addressable and \
+                    value.is_fully_replicated:
+                # replicated over other processes too: read the local copy
+                # (as asnumpy does)
+                value = value.addressable_shards[0].data
         elif isinstance(value, np.ndarray):
             value = jnp.asarray(value, self.dtype)
         if isinstance(key, NDArray):
             key = key._data
         if isinstance(key, _pyslice) and key == _pyslice(None):
             if np.isscalar(value):
-                self._set(jnp.full(self.shape, value, self.dtype))
+                new = jnp.full(self.shape, value, self.dtype)
             else:
                 value = jnp.asarray(value, self.dtype)
-                self._set(jnp.broadcast_to(value, self.shape))
+                new = jnp.broadcast_to(value, self.shape)
         else:
-            self._set(self._data.at[key].set(value))
+            new = self._data.at[key].set(value)
+        # a write never moves the array: the new value is committed where
+        # the old one lived (jnp.* alone lands on the default device,
+        # whatever this array's context says).  Arrays spanning other
+        # processes are placed by their owners (executor_group).
+        if isinstance(self._data, jax.Array) and \
+                self._data.is_fully_addressable:
+            new = jax.device_put(new, self._data.sharding)
+        self._set(new)
 
     # -- arithmetic --------------------------------------------------------
     def _binary(self, other, op, scalar_op, reverse=False):

@@ -16,8 +16,9 @@ from (Q, K, logsumexp):
 Both run O(s²) time in O(s) memory — sequence length is bounded by HBM,
 not VMEM, so ≥16k-token training steps fit on one chip.
 
-On non-TPU backends the same kernels run in Pallas interpret mode, so the
-CPU test mesh exercises the real kernel logic. Registered through the
+On a non-TPU device the same kernels run in Pallas interpret mode (chosen
+from where the operands live — ops/interpret.py), so the CPU test mesh
+exercises the real kernel logic. Registered through the
 public ``mx.register_pallas_op`` mechanism (its first user) as
 ``_contrib_FlashAttention`` (inputs [b, s, h, d]); also usable
 functionally and as ``ulysses_attention(attn_fn=flash_attention)``.
@@ -27,6 +28,8 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from .interpret import interpret_for, over_batch_shards
 
 _NEG = -1e30
 # exp2-based softmax: fold log2(e) into the QK scale so the kernel's
@@ -173,14 +176,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     # lse carries a singleton middle dim so its block's trailing dims
     # (1, bq) satisfy the Mosaic tiling rule (second-to-last equals the
     # array dim, last divisible by 128); squeezed before returning
-    try:
-        vma = jax.typeof(qt).vma
-        out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
-                     jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32,
-                                          vma=vma)]
-    except (AttributeError, TypeError):
-        out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-                     jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32)]
+    vma = jax.typeof(qt).vma
+    out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
+                 jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma)]
     o, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // bq, nk),
@@ -352,10 +350,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         return pltpu.VMEM(shape, jnp.float32)
 
     def sds(shape, dtype):
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(qt).vma)
-        except (AttributeError, TypeError):
-            return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(qt).vma)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, nk=nk, scale=scale,
@@ -428,7 +423,7 @@ def _autotune_blocks(seq_q, seq_k, head_dim, dtype, causal):
     def build(cand):
         import jax
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_for("flash_attention")
         scale = 1.0 / np.sqrt(head_dim)
 
         def fwd(q, k, v):
@@ -485,7 +480,7 @@ def resolve_blocks(block_q, block_k, seq_q, seq_k, head_dim=128,
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, interpret=None):
     """Exact fused attention, Pallas fwd+bwd. q, k, v: [b, seq, heads, d].
 
     Default 512 blocks: measured on v5e (d=128, s=8k), 512-wide tiles run
@@ -493,7 +488,9 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     softmax amortizes); blocks are clamped to the sequence length for
     short inputs.  Passing None (the default) consults the autotuner
     (``MXNET_AUTOTUNE``) for this shape family's winner before falling
-    back to 512; explicit block sizes are always respected."""
+    back to 512; explicit block sizes are always respected.
+    ``interpret`` None picks compiled-vs-interpreted from the device the
+    operands live on (ops/interpret.py)."""
     import jax
 
     if scale is None:
@@ -501,7 +498,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     block_q, block_k = resolve_blocks(block_q, block_k, q.shape[1],
                                       k.shape[1], q.shape[-1], q.dtype,
                                       causal)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_for("flash_attention", (q, k, v), interpret)
 
     @jax.custom_vjp
     def run(q, k, v):
@@ -544,42 +541,46 @@ def _attrs_config(attrs, q, k):
 
 
 def _fa_fn(attrs, query, key, value):
-    import jax
-
     causal, scale, bq, bk = _attrs_config(attrs, query, key)
-    interpret = jax.default_backend() != "tpu"
-    o, _ = _flash_forward(query, key, value, causal, scale, bq, bk,
-                          interpret)
-    return o
+    interpret = interpret_for("_contrib_FlashAttention", (query, key, value))
+
+    def kernel(q, k, v):
+        return _flash_forward(q, k, v, causal, scale, bq, bk, interpret)[0]
+
+    return over_batch_shards(kernel)(query, key, value)
 
 
 def _fa_fwd(attrs, query, key, value):
-    import jax
-
     causal, scale, bq, bk = _attrs_config(attrs, query, key)
-    interpret = jax.default_backend() != "tpu"
-    o, lse = _flash_forward(query, key, value, causal, scale, bq, bk,
-                            interpret)
+    interpret = interpret_for("_contrib_FlashAttention", (query, key, value))
+
+    def kernel(q, k, v):
+        return _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
+
+    o, lse = over_batch_shards(kernel)(query, key, value)
     return o, (query, key, value, o, lse)
 
 
 def _fa_bwd(attrs, res, ct):
-    import jax
-
     q, k, v, o, lse = res
     causal, scale, bq, bk = _attrs_config(attrs, q, k)
-    interpret = jax.default_backend() != "tpu"
-    return _flash_backward(q, k, v, o, lse, ct, causal, scale, bq, bk,
-                           interpret)
+    interpret = interpret_for("_contrib_FlashAttention", (q, k, v))
+
+    def kernel(q, k, v, o, lse, ct):
+        return _flash_backward(q, k, v, o, lse, ct, causal, scale, bq, bk,
+                               interpret)
+
+    return over_batch_shards(kernel)(q, k, v, o, lse, ct)
 
 
-def splash_attention(q, k, v, causal: bool = True, scale=None):
+def splash_attention(q, k, v, causal: bool = True, scale=None,
+                     interpret=None):
     """Upstream splash-attention backend (jax.experimental.pallas.ops.tpu)
     behind this framework's [b, seq, heads, d] layout — the mature,
     internally-pipelined TPU kernel, offered as an alternative attention
     implementation for A/B against the in-tree flash kernels (PERF.md's
-    ceiling reference). Interpret mode off-TPU, so CPU tests exercise the
-    real wrapper. Splash applies no logit scaling itself; q is pre-scaled
+    ceiling reference). Interpret mode off a TPU device (ops/interpret.py),
+    so CPU tests exercise the real wrapper. Splash applies no logit scaling itself; q is pre-scaled
     here, and gradients flow through splash's own custom vjp."""
     import jax
 
@@ -591,7 +592,7 @@ def splash_attention(q, k, v, causal: bool = True, scale=None):
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_for("splash_attention", (q, k, v), interpret)
     mk_one = (_mk.CausalMask((s, s)) if causal
               else _mk.FullMask((s, s)))
     if s % 128:

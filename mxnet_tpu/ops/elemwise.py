@@ -26,14 +26,9 @@ def _round_away(x):
 
 
 def _gamma(x):
-    try:
-        from jax.scipy.special import gamma as _g
+    from jax.scipy.special import gamma as _g
 
-        return _g(x)
-    except ImportError:  # pragma: no cover
-        from jax.scipy.special import gammaln
-
-        return jnp.exp(gammaln(x))
+    return _g(x)
 
 
 _UNARY = {

@@ -71,7 +71,7 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis: str = "expert", top_k: int = 1,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     act = act or jax.nn.gelu
     n = mesh.shape[axis]

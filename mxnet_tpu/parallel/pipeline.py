@@ -24,11 +24,10 @@ def _pipeline_inner(params, xs, *, axis, n_stages, n_micro, stage_fn):
     micro_shape = xs.shape[1:]
     # initial carries must be typed varying over the pipe axis (shard_map
     # VMA typing — the loop outputs depend on stage-varying params)
-    from ._compat import pcast_varying
-
-    state0 = pcast_varying(jnp.zeros(micro_shape, xs.dtype), (axis,))
-    out0 = pcast_varying(jnp.zeros((n_micro,) + micro_shape, xs.dtype),
-                         (axis,))
+    state0 = lax.pcast(jnp.zeros(micro_shape, xs.dtype), (axis,),
+                       to="varying")
+    out0 = lax.pcast(jnp.zeros((n_micro,) + micro_shape, xs.dtype), (axis,),
+                     to="varying")
     fwd_perm = [(j, j + 1) for j in range(n_stages - 1)]
 
     def step(carry, t):
@@ -73,7 +72,7 @@ def pipeline_spmd(stage_fn, stage_params, x, mesh, axis: str = "pipe",
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     n_stages = mesh.shape[axis]
     if n_microbatches is None:

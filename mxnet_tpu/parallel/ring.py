@@ -27,6 +27,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..ops.interpret import interpret_for
+
 _NEG = -1e30
 
 
@@ -60,10 +62,8 @@ def _ring_inner(q, k, v, *, axis, vary_axes, n_shards, causal, scale):
 
     # initial accumulators must carry the same varying-axis type as the
     # loop outputs (shard_map VMA typing)
-    from ._compat import pcast_varying
-
     def _vary(x):
-        return pcast_varying(x, vary_axes)
+        return lax.pcast(x, vary_axes, to="varying")
 
     o0 = _vary(jnp.zeros((b, sq, h, d), jnp.float32))
     m0 = _vary(jnp.full((b, h, sq), _NEG, jnp.float32))
@@ -107,7 +107,7 @@ def ring_attention(q, k, v, mesh, axis: str = "seq",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
@@ -147,10 +147,8 @@ def _ring_flash_fwd(q, k, v, *, axis, vary_axes, n_shards, causal, scale,
     b, sq, h, d = q.shape
     perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
 
-    from ._compat import pcast_varying
-
     def _vary(x):
-        return pcast_varying(x, vary_axes)
+        return lax.pcast(x, vary_axes, to="varying")
 
     o0 = _vary(jnp.zeros((b, sq, h, d), jnp.float32))
     lse0 = _vary(jnp.full((b * h, sq), _NEG, jnp.float32))
@@ -201,10 +199,8 @@ def _ring_flash_bwd(q, k, v, o, lse, do, *, axis, vary_axes, n_shards,
     b, sq, h, d = q.shape
     perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
 
-    from ._compat import pcast_varying
-
     def _vary(x):
-        return pcast_varying(x, vary_axes)
+        return lax.pcast(x, vary_axes, to="varying")
 
     dq0 = _vary(jnp.zeros((b, sq, h, d), jnp.float32))
     dkv0 = _vary(jnp.zeros((b, sq, h, d), jnp.float32))
@@ -270,12 +266,14 @@ def ring_flash_attention(q, k, v, mesh, axis: str = "seq",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     n_shards = mesh.shape[axis]
-    interpret = jax.default_backend() != "tpu"
+    # the mesh names the devices the kernels run on
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+    interpret = interpret_for("ring_flash_attention", interpret=not on_tpu)
     spec = P(batch_axis, axis, None, None)
     vary_axes = (axis,) + ((batch_axis,) if batch_axis else ())
     kw = dict(axis=axis, vary_axes=vary_axes, n_shards=n_shards,
@@ -295,9 +293,10 @@ def ring_flash_attention(q, k, v, mesh, axis: str = "seq",
         return _ring_flash_bwd(*res, g, **kw)
 
     rf.defvjp(fwd, bwd)
-    check_vma = jax.default_backend() == "tpu"
+    # interpret-mode Pallas trips the varying-axis checker (see
+    # ulysses_attention); compiled kernels keep it on
     fn = shard_map(rf, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=check_vma)
+                   out_specs=spec, check_vma=on_tpu)
     return fn(q, k, v)
 
 
@@ -325,7 +324,7 @@ def ulysses_attention(q, k, v, mesh, axis: str = "seq",
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     n_shards = mesh.shape[axis]
     if q.shape[2] % n_shards:
@@ -341,7 +340,7 @@ def ulysses_attention(q, k, v, mesh, axis: str = "seq",
                               causal=causal, scale=scale, attn_fn=attn_fn)
     # pallas interpret-mode (non-TPU) dynamic_slice inside shard_map trips
     # the varying-axis checker (jax 0.9); keep the checker on for TPU
-    check_vma = jax.default_backend() == "tpu"
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
     fn = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=check_vma)
+                   out_specs=spec, check_vma=on_tpu)
     return fn(q, k, v)

@@ -23,11 +23,21 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from .base import MXNetError
-from .context import Context, cpu
+from .context import Context, current_context
 
 __all__ = ["Predictor", "load_exported"]
 
 _EXPORT_MAGIC = b"MXTPUEXP1"
+
+
+def on_ctx(value, ctx):
+    """``value`` as an NDArray living on ``ctx``: the identity for one
+    already there (live device arrays are shared, not copied), a copy for
+    one elsewhere, an upload for host data."""
+    from . import ndarray as nd
+
+    return value.as_in_context(ctx) if isinstance(value, nd.NDArray) \
+        else nd.array(value, ctx)
 
 
 class Predictor:
@@ -67,7 +77,9 @@ class Predictor:
             else:
                 arg_params[k] = v
 
-        self._ctx = ctx or cpu()
+        # None: the current context (docs/how_to/deviations.md "Default
+        # context") — the chip when one is attached, like Module
+        self._ctx = ctx or current_context()
         self._symbol = symbol
         self._input_shapes = {k: tuple(v) for k, v in input_shapes.items()}
         self._dtype = np.dtype(dtype)
@@ -88,10 +100,8 @@ class Predictor:
                     raise MXNetError(
                         "param %s shape %s does not match inferred %s"
                         % (name, arg_params[name].shape, shape))
-                p = arg_params[name]
                 # reshape() passes live device NDArrays: share, don't copy
-                args[name] = p if isinstance(p, nd.NDArray) else \
-                    nd.array(p, self._ctx)
+                args[name] = on_ctx(arg_params[name], self._ctx)
             else:
                 # reference MXPredCreate allocates missing args without
                 # initializing them (c_predict_api.cc:190-195); we
@@ -103,9 +113,7 @@ class Predictor:
         for name, shape in zip(aux_names, aux_shapes):
             if name not in aux_params:
                 raise MXNetError("missing auxiliary state %r" % name)
-            a = aux_params[name]
-            aux[name] = a if isinstance(a, nd.NDArray) else \
-                nd.array(a, self._ctx)
+            aux[name] = on_ctx(aux_params[name], self._ctx)
 
         self._exec = symbol.bind(self._ctx, args, args_grad=None,
                                  grad_req="null", aux_states=aux)
@@ -182,7 +190,8 @@ class Predictor:
 
         abstract = [jax.ShapeDtypeStruct(self._input_shapes[n], self._dtype)
                     for n in input_names]
-        exported = jexport.export(jax.jit(serve))(*abstract)
+        exported = jexport.export(
+            jax.jit(self._exec._bound(serve)))(*abstract)
         blob = exported.serialize()
         meta = json.dumps({
             "inputs": [[n, list(self._input_shapes[n]), str(self._dtype)]

@@ -23,6 +23,7 @@ import textwrap
 from typing import List, Sequence, Tuple
 
 from .base import MXNetError
+from .ops.interpret import interpret_for
 
 __all__ = ["MXRtc"]
 
@@ -70,9 +71,9 @@ class MXRtc:
                     "(preferably named 'kernel')")
             fn = fns[0]
         self._kernel = fn
-        self._compiled = None
+        self._compiled = {}  # interpret flag -> jitted call
 
-    def _build(self):
+    def _build(self, interpret):
         import jax
         from jax.experimental import pallas as pl
 
@@ -81,9 +82,9 @@ class MXRtc:
         call = pl.pallas_call(
             self._kernel,
             out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret,
             **self._pallas_kwargs)
-        self._compiled = jax.jit(lambda *a: call(*a))
+        return jax.jit(lambda *a: call(*a))
 
     def push(self, ins, outs, grid_dims=None, block_dims=None):
         """Run the kernel (reference MXRtc.push signature; the launch dims
@@ -91,8 +92,6 @@ class MXRtc:
         one was supplied at construction)."""
         from . import ndarray as nd
 
-        if self._compiled is None:
-            self._build()
         if len(ins) != len(self._in_protos):
             raise MXNetError(
                 "rtc %r expects %d inputs, got %d"
@@ -112,7 +111,12 @@ class MXRtc:
                     "rtc %r output %s shape %s does not match prototype %s"
                     % (self.name, pname, tuple(out.shape), shape))
         vals = [a._data if isinstance(a, nd.NDArray) else a for a in ins]
-        result = self._compiled(*vals)
+        # compiled on a tpu device, interpreted elsewhere: decided by where
+        # the operands live (ops/interpret.py)
+        interpret = interpret_for("rtc:%s" % self.name, vals)
+        if interpret not in self._compiled:
+            self._compiled[interpret] = self._build(interpret)
+        result = self._compiled[interpret](*vals)
         if not isinstance(result, (list, tuple)):
             result = [result]
         for out, res in zip(outs, result):

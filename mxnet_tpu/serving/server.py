@@ -84,7 +84,9 @@ class InferenceServer:
         and per-request inputs carry the remaining dims.
     ctx : Context | list of Context, optional
         One replica (bucket-predictor family + worker thread) is built
-        per context, all pulling from one shared queue.
+        per context, all pulling from one shared queue.  None is the
+        current context: the chip when one is attached
+        (docs/how_to/deviations.md "Default context").
     buckets : sequence of int, optional
         Allowed padded batch sizes; default ``pow2_buckets(max_batch)``.
     max_wait_us : int
@@ -238,6 +240,12 @@ class InferenceServer:
         self._generator = engine
         self._generator_spec = engine.spec()
         return self
+
+    @property
+    def generator(self):
+        """The attached :class:`~mxnet_tpu.generation.DecodeEngine`, or
+        None."""
+        return self._generator
 
     def submit_generate(self, prompt, max_new_tokens=None,
                         deadline_ms=None):

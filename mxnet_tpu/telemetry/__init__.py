@@ -74,8 +74,8 @@ register_env("MXNET_TELEMETRY_MFU", 1, int,
              "Run XLA cost analysis once per compiled fused step to "
              "derive achieved MFU (0 skips the per-compile analysis).")
 register_env("MXNET_TELEMETRY_PEAK_FLOPS", 0.0, float,
-             "MFU denominator in FLOP/s; 0 uses the TPU v5e bf16 peak "
-             "(197e12).")
+             "MFU denominator in FLOP/s; 0 looks the attached device's "
+             "kind up in hlo_analysis.DEVICE_PEAKS (no entry: no MFU).")
 
 # the single hot-path gate: plain module-global read, no locks
 _ENABLED = False

@@ -236,7 +236,10 @@ class StepMonitor:
         step_s = self.avg_step_s()
         if not step_s or not self._flops_per_step:
             return None
-        v = self._flops_per_step / step_s / peak_flops()
+        peak = peak_flops()
+        if not peak:
+            return None  # device kind not in hlo_analysis.DEVICE_PEAKS
+        v = self._flops_per_step / step_s / peak
         self.g_mfu.set(v)
         return v
 

@@ -1,0 +1,110 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+DESCRIBED v5e (no chip attached): flash forward, dQ and dK/dV at the grids
+``chip_smoke.py`` runs.  What Mosaic refuses here (unaligned slices, too
+much VMEM) it would refuse on the chip — interpret mode cannot see it.
+
+The topology is described inside the module-scoped fixture only (never at
+import): one process at a time may hold the TPU library, and under xdist
+every worker imports every test file.  Compiles run in this process, with
+JAX's persistent cache off (a described-device entry cannot be read back).
+"""
+import numpy as np
+import pytest
+
+# (batch, seq, heads, head_dim, block_q, block_k) — bf16, causal
+LM_TRAIN = (4, 4096, 16, 128, 512, 512)       # Module step, default blocks
+LM_TRAIN_BK1024 = (4, 4096, 16, 128, 512, 1024)  # the flash bench's grid
+LM_SCORE = (2, 1024, 16, 128, 512, 512)       # serving: /predict scoring
+GRIDS = {"lm_train": LM_TRAIN, "lm_train_bk1024": LM_TRAIN_BK1024,
+         "lm_score": LM_SCORE}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _qkv(grid, sharding):
+    import jax
+    import jax.numpy as jnp
+
+    b, s, h, d, _, _ = grid
+    return [jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16,
+                                 sharding=sharding)] * 3
+
+
+def _assert_mosaic(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(GRIDS))
+def test_flash_forward_compiles_for_v5e(one_chip, name):
+    import jax
+
+    from mxnet_tpu.ops import attention as att
+
+    grid = GRIDS[name]
+    d, bq, bk = grid[3], grid[4], grid[5]
+
+    def fwd(q, k, v):
+        return att._flash_forward(q, k, v, True, 1.0 / np.sqrt(d), bq, bk,
+                                  interpret=False)
+
+    _assert_mosaic(jax.jit(fwd).lower(*_qkv(grid, one_chip)).compile())
+
+
+@pytest.mark.parametrize("name", ["lm_train", "lm_train_bk1024"])
+def test_flash_backward_dq_dkv_compile_for_v5e(one_chip, name):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att
+
+    grid = GRIDS[name]
+    b, s, h, d, bq, bk = grid
+    q = _qkv(grid, one_chip)[0]
+    lse = jax.ShapeDtypeStruct((b * h, s), jnp.float32, sharding=one_chip)
+
+    def bwd(q, k, v, o, lse, do):
+        return att._flash_backward(q, k, v, o, lse, do, True,
+                                   1.0 / np.sqrt(d), bq, bk, interpret=False)
+
+    compiled = jax.jit(bwd).lower(q, q, q, q, lse, q).compile()
+    # two kernels: dQ, and dK/dV
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_flash_attention_vjp_through_the_public_call(one_chip):
+    """The user-facing ``flash_attention`` (custom_vjp, blocks resolved the
+    way the Module's op resolves them) differentiates into compiled
+    kernels when told its operands are on a chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(LM_TRAIN, one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3

@@ -203,7 +203,7 @@ def test_ring_flash_vma_typing(monkeypatch, causal):
 
     from mxnet_tpu.ops import attention as att
     from mxnet_tpu.parallel import ring
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     def dense_fwd(q, k, v, causal, scale, bq, bk, interpret):
         b, s, h, d = q.shape

@@ -1,5 +1,5 @@
 """perf_probe analysis units: the BN-epilogue classifier must answer by
-dataflow, not substring presence (VERDICT r4: settle whether BN scale/
+dataflow, not substring presence (settle whether BN scale/
 shift rides the conv epilogue in the committed HLO)."""
 import os
 import sys

@@ -48,7 +48,7 @@ def test_infer_shape_backward_deduction():
 
 
 def test_deep_chain_shape_convergence():
-    # VERDICT weak #6: deep chains must reach fixed point (not capped at 3)
+    # deep chains must reach fixed point (not capped at 3)
     net = sym.Variable("data")
     for i in range(10):
         net = sym.FullyConnected(data=net, num_hidden=8, name="fc%d" % i)

@@ -183,10 +183,12 @@ def test_step_monitor_counts_and_report():
     assert summ["step"]["steps"] == 5
 
 
-def test_step_monitor_mfu_matches_probe_path():
+def test_step_monitor_mfu_matches_probe_path(monkeypatch):
     """The monitor's flop count is the XLA cost analysis of the SAME
     compiled executable tools/perf_probe.py lowers — parity within 10%
     (exact, in practice) by construction."""
+    # the test CPU has no listed peak (hence no MFU): name one
+    monkeypatch.setenv("MXNET_TELEMETRY_PEAK_FLOPS", "1e12")
     telemetry.enable(trace=False)
     mod, _ = _fit_small()
     mon = telemetry.current_step_monitor()
@@ -202,8 +204,14 @@ def test_step_monitor_mfu_matches_probe_path():
     assert mfu == pytest.approx(expect, rel=0.10)
 
 
-def test_peak_flops_override(monkeypatch):
-    assert telemetry.peak_flops() == 197e12
+def test_peak_flops_table_and_override(monkeypatch):
+    # one table keyed by device_kind; an unlisted kind (the test CPU) has
+    # no peak and therefore no MFU — never the v5e's
+    assert telemetry.peak_flops("TPU v5 lite") == 197e12
+    assert telemetry.peak_flops() is None
+    from mxnet_tpu import hlo_analysis
+    assert hlo_analysis.hbm_bytes_per_s("TPU v5 lite") == 819e9
+    assert hlo_analysis.hbm_bytes_per_s() is None
     monkeypatch.setenv("MXNET_TELEMETRY_PEAK_FLOPS", "1e12")
     assert telemetry.peak_flops() == 1e12
 

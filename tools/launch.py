@@ -35,6 +35,42 @@ def _free_port():
     return port
 
 
+def local_chips():
+    """Chips attached to this host, counted from their device files: the
+    launcher never initialises a JAX backend (a parent that has touched
+    JAX holds every chip, and its workers then fail or hang)."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_env(env, slot, num_workers, chips):
+    """``env`` for local worker ``slot`` with ONE chip of its own (a chip
+    belongs to one process at a time; with identical environments worker 0
+    would take them all).  Left alone where there is nothing to assign:
+    no chip on the host, the platform forced to the host
+    (``JAX_PLATFORMS=cpu``), the caller assigned chips itself
+    (``TPU_VISIBLE_CHIPS`` already set), or a single worker (which keeps
+    every chip for its in-process device mesh)."""
+    forced = env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+    if (not chips or forced == "cpu" or "TPU_VISIBLE_CHIPS" in env
+            or num_workers == 1):
+        return env
+    if num_workers > len(chips):
+        raise SystemExit(
+            "launch.py: -n %d local workers but this host has %d chip(s) "
+            "(%s): a chip belongs to one process at a time. Use -n <= %d, "
+            "one worker with an in-process device mesh "
+            "(context=[mx.tpu(i) ...]), or JAX_PLATFORMS=cpu."
+            % (num_workers, len(chips), ", ".join(chips), len(chips)))
+    env = dict(env)
+    env.update({"TPU_VISIBLE_CHIPS": str(slot),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1"})
+    return env
+
+
 def parse_elastic(spec):
     """``MIN:MAX`` (or a bare ``MIN``, meaning MIN:MIN) -> (min, max).
     The job keeps running while at least MIN workers are live and
@@ -265,16 +301,21 @@ def main():
     worker_envs = []
     server_envs = []
     try:
+        chips = local_chips() if hosts is None else []
         for i in range(args.num_servers):
             env = dict(base_env)
             env["DMLC_ROLE"] = "server"
             env["DMLC_SERVER_ID"] = str(i)
+            if chips:
+                # parameter servers are host-side: keep them off the chips
+                env.setdefault("JAX_PLATFORMS", "cpu")
             server_envs.append(env)
             server_procs.append(spawn(env, i))
         for i in range(args.num_workers):
             env = dict(base_env)
             env["DMLC_ROLE"] = "worker"
             env["DMLC_WORKER_ID"] = str(i)
+            env = chip_env(env, i, args.num_workers, chips)
             worker_envs.append(env)
             procs.append(spawn(env, i))
         rc = 0

@@ -73,4 +73,5 @@ for layout in ("NCHW", "NHWC"):
     t, f = bench(layout)
     print(json.dumps({"layout": layout, "total_ms": round(t * 1e3, 2),
                       "tflops": round(f / t / 1e12, 1),
-                      "mfu": round(f / t / peak_flops(), 3)}))
+                      "mfu": round(f / t / peak_flops(), 3)
+                      if peak_flops() else None}))

@@ -127,9 +127,10 @@ def main():
         # measured MFU from XLA's own flop count, same denominator as the
         # live telemetry gauge (MXNET_TELEMETRY_PEAK_FLOPS-overridable)
         from mxnet_tpu import telemetry
+        peak = telemetry.peak_flops()  # None: device kind has no listed peak
         report["mfu_xla_flops"] = round(
-            report["xla_flops"] / (dt / cli.num_steps)
-            / telemetry.peak_flops(), 4)
+            report["xla_flops"] / (dt / cli.num_steps) / peak, 4) \
+            if peak else None
     write_json("perf_probe.json", report)
 
 
