@@ -109,7 +109,11 @@ class _GraphPlan:
             if placement and id(n) in placement:
                 dev = placement[id(n)]
                 ins = [jax.device_put(x, dev) for x in ins]
-            outs, aux_out = n.op.apply(opctx, n.attrs, ins, aux_in)
+            # every operation of this node, forward and (as
+            # ``transpose(jvp(<name>))``) backward, carries the node's name
+            # into the compiled program and from there into a device trace
+            with jax.named_scope(n.name):
+                outs, aux_out = n.op.apply(opctx, n.attrs, ins, aux_in)
             for i, o in enumerate(outs):
                 vals[(id(n), i)] = o
             for aname, a in zip(n.aux_names(), aux_out):
@@ -214,6 +218,10 @@ class Executor:
         # entries are both distinctly keyed and legible in
         # `compile_cache_admin.py ls`
         self._cache_kind = "fwd"
+        # name of this executor's jitted forward as a device trace's
+        # ``XLA Modules`` line shows it (``jit_<name>``); the generation
+        # engine names its programs ``decode_b<lanes>`` / ``prefill_L<len>``
+        self._program_name = "forward"
         # NaiveEngine parity: MXNET_ENGINE_TYPE=NaiveEngine disables jit and
         # synchronizes after every call (threaded_engine.h:329-337 debugging).
         self._naive = env("MXNET_ENGINE_TYPE") == "NaiveEngine"
@@ -293,6 +301,7 @@ class Executor:
                 return plan.run(cast(args), aux, rng, is_train,
                                 want_internals=internals, placement=placement)
 
+            fn.__name__ = self._program_name
             fn = self._bound(fn)
             if self._naive:
                 self._jit_cache[key] = fn
@@ -314,7 +323,8 @@ class Executor:
 
             cast = self._cast_fn()
 
-            def fn(diff_args, other_args, aux, rng, out_grads, old_grads):
+            def forward_backward(diff_args, other_args, aux, rng, out_grads,
+                                 old_grads):
                 def f(d):
                     merged = dict(other_args)
                     merged.update(d)
@@ -334,7 +344,7 @@ class Executor:
                     grads[name] = grads[name] + old_grads[name]
                 return list(outs), new_aux, grads
 
-            fn = self._bound(fn)
+            fn = self._bound(forward_backward)
             if self._naive:
                 self._jit_cache[key] = fn
             else:
@@ -475,7 +485,8 @@ class Executor:
             cast = self._cast_fn()
 
             def make_fn(remat):
-                def fn(diff_args, states, aux, other_args, rng, sc, opt_rng):
+                def fused_step(diff_args, states, aux, other_args, rng, sc,
+                               opt_rng):
                     lr0, wd0, t = sc
 
                     def f(d):
@@ -499,30 +510,32 @@ class Executor:
                                 in enumerate(update_infos)}
                     new_params = {}
                     new_states = {}
-                    for name, _idx, lmult, wmult in update_infos:
-                        w, s = pure_update(
-                            diff_args[name], grads[name], states[name],
-                            lr0 * lmult, wd0 * wmult, t, keys.get(name))
-                        new_params[name] = w
-                        new_states[name] = s
+                    with jax.named_scope("optimizer"):
+                        for name, _idx, lmult, wmult in update_infos:
+                            w, s = pure_update(
+                                diff_args[name], grads[name], states[name],
+                                lr0 * lmult, wd0 * wmult, t, keys.get(name))
+                            new_params[name] = w
+                            new_states[name] = s
                     if guard:
-                        ok = jnp.bool_(True)
-                        sq = jnp.float32(0)
-                        for name, _idx, _, _ in update_infos:
-                            g = grads[name]
-                            ok &= jnp.all(jnp.isfinite(g))
-                            sq += jnp.sum(jnp.square(
-                                g.astype(jnp.float32)))
-                        for o in outs:
-                            if jnp.issubdtype(o.dtype, jnp.floating):
-                                ok &= jnp.all(jnp.isfinite(o))
-                        gnorm = jnp.sqrt(sq)
-                        # the f32 norm overflowing is itself an anomaly:
-                        # a single exponent bit-flip lands ~1e38 in a
-                        # gradient, which is finite but squares to inf —
-                        # catch it here, not N steps later in the spike
-                        # detector
-                        ok &= jnp.isfinite(gnorm)
+                        with jax.named_scope("guard"):
+                            ok = jnp.bool_(True)
+                            sq = jnp.float32(0)
+                            for name, _idx, _, _ in update_infos:
+                                g = grads[name]
+                                ok &= jnp.all(jnp.isfinite(g))
+                                sq += jnp.sum(jnp.square(
+                                    g.astype(jnp.float32)))
+                            for o in outs:
+                                if jnp.issubdtype(o.dtype, jnp.floating):
+                                    ok &= jnp.all(jnp.isfinite(o))
+                            gnorm = jnp.sqrt(sq)
+                            # the f32 norm overflowing is itself an
+                            # anomaly: a single exponent bit-flip lands
+                            # ~1e38 in a gradient, which is finite but
+                            # squares to inf — catch it here, not N steps
+                            # later in the spike detector
+                            ok &= jnp.isfinite(gnorm)
                         # on-device skip: a poisoned batch leaves params,
                         # optimizer state and aux (BN stats) untouched
                         sel = lambda new, old: jnp.where(ok, new, old)
@@ -537,7 +550,7 @@ class Executor:
                     return (list(outs), new_aux, new_params, new_states,
                             (ok, gnorm))
 
-                return self._bound(fn)
+                return self._bound(fused_step)
 
             if self._naive:
                 self._jit_cache[key] = make_fn(env_remat)
@@ -592,9 +605,48 @@ class Executor:
         ``param_names`` gives the updater index space (position in list ==
         kvstore key, as Module wires idx2name).  Requires every param's
         grad_req to be 'write' or 'null' and an optimizer with
-        ``pure_update``."""
-        import numpy as _np
+        ``pure_update``.
+
+        Three spans, siblings on the caller's thread: ``:pack`` (building
+        the call), the dispatch itself, ``:rebind`` (the results back into
+        the bound arrays)."""
         from . import ndarray as nd
+
+        with _prof.Frame("Executor.fused_step:pack", "exec"):
+            fn, call_args, infos, guard, first_build = self._fused_pack(
+                optimizer, updater, param_names)
+        with _prof.Frame("Executor.fused_step", "exec"):
+            outs, new_aux, new_params, new_states, verdict = fn(*call_args)
+        # the on-device (ok, grad_norm) verdict: still device scalars —
+        # the guardian reads them where the step already syncs (metric
+        # update), so the guard adds no host round-trip of its own
+        self._guard_verdict = verdict if guard else None
+        if first_build and not self._naive:
+            # when the compile cache primed this executable, XLA's cost
+            # analysis rode along (entry meta on hits, read once from the
+            # fresh Compiled on misses) — StepMonitor consumes this instead
+            # of re-lowering+re-compiling the program
+            self._fused_cost_info = getattr(fn, "cost_info", None)
+
+        with _prof.Frame("Executor.fused_step:rebind", "exec"):
+            for name, idx, _, _ in infos:
+                self.arg_dict[name]._set(new_params[name])
+                updater.states[idx] = self._rewrap_state(
+                    updater.states[idx], new_states[name], self._ctx)
+            for k, v in new_aux.items():
+                self.aux_dict[k]._set(v)
+            self._output_arrays = [nd.NDArray(o, self._ctx) for o in outs]
+        if self._naive:
+            for o in self._output_arrays:
+                o.wait_to_read()
+        return self._output_arrays
+
+    def _fused_pack(self, optimizer, updater, param_names):
+        """The host's work before the fused step's dispatch: optimizer
+        bookkeeping, the argument trees, the program's cache lookup.
+        Returns (program, its arguments, update infos, guard, whether the
+        program was built by this call)."""
+        import numpy as _np
         from . import random as _random
 
         plan = self._plan
@@ -709,31 +761,18 @@ class Executor:
             # consumed by telemetry.StepMonitor (Module.update): one XLA
             # cost analysis per new executable, never per step
             self._fused_new_compile = True
-        with _prof.Frame("Executor.fused_step", "exec"):
-            outs, new_aux, new_params, new_states, verdict = fn(
-                diff_args, states, aux, other_args, rng, sc, opt_rng)
-        # the on-device (ok, grad_norm) verdict: still device scalars —
-        # the guardian reads them where the step already syncs (metric
-        # update), so the guard adds no host round-trip of its own
-        self._guard_verdict = verdict if guard else None
-        if first_build and not self._naive:
-            # when the compile cache primed this executable, XLA's cost
-            # analysis rode along (entry meta on hits, read once from the
-            # fresh Compiled on misses) — StepMonitor consumes this instead
-            # of re-lowering+re-compiling the program
-            self._fused_cost_info = getattr(fn, "cost_info", None)
+        return (fn, (diff_args, states, aux, other_args, rng, sc, opt_rng),
+                infos, guard, first_build)
 
-        for name, idx, _, _ in infos:
-            self.arg_dict[name]._set(new_params[name])
-            updater.states[idx] = self._rewrap_state(
-                updater.states[idx], new_states[name], self._ctx)
-        for k, v in new_aux.items():
-            self.aux_dict[k]._set(v)
-        self._output_arrays = [nd.NDArray(o, self._ctx) for o in outs]
-        if self._naive:
-            for o in self._output_arrays:
-                o.wait_to_read()
-        return self._output_arrays
+    def fused_op_scopes(self):
+        """{instruction name: scope path} of the compiled fused step: the
+        way from a device trace's ``fusion.674`` to the Symbol node (or
+        ``optimizer`` / ``guard``) it came from.  Compiles the program the
+        last ``fused_step`` ran once more (``_fused_introspect``)."""
+        from .hlo_analysis import op_scopes
+
+        fn, abstract = self._fused_introspect
+        return op_scopes(fn.lower(*abstract).compile().as_text())
 
     # ------------------------------------------------------------------
     # execution API

@@ -71,6 +71,7 @@ import numpy as np
 
 from .. import faults
 from .. import telemetry as _telemetry
+from ..profiler import Frame as _span
 from ..base import MXNetError, env, register_env
 from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
@@ -192,6 +193,9 @@ class GenStream:
     def __init__(self, prompt, max_new_tokens):
         self.prompt = list(prompt)
         self.max_new_tokens = int(max_new_tokens)
+        # the engine's id for this request, set at submit: what its spans
+        # (``gen:queued``, ``gen:step``'s ``sids``, ``serve:generate``) share
+        self.sid: Optional[int] = None
         self.tokens: List[int] = []
         self.ttft_ms: Optional[float] = None
         self.itl_ms: List[float] = []
@@ -487,6 +491,7 @@ class DecodeEngine:
                                    dtype=dtype)
             for pred in bp._preds.values():
                 pred._exec._cache_kind = "gen-prefill"
+                pred._exec._program_name = "prefill_L%d" % L
             self._prefill[L] = bp
 
         # decode: one fixed-lane Predictor per lane bucket (shared weights
@@ -512,8 +517,9 @@ class DecodeEngine:
             self._decode[b] = base.reshape(
                 {"data": (b,), "positions": (b,), "page_table": (b,
                  self.max_pages)})
-        for pred in self._decode.values():
+        for b, pred in self._decode.items():
             pred._exec._cache_kind = "gen-step"
+            pred._exec._program_name = "decode_b%d" % b
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -538,6 +544,7 @@ class DecodeEngine:
                                        ctx=ctx, dtype=dtype)
                 for pred in bp._preds.values():
                     pred._exec._cache_kind = "gen-draft-prefill"
+                    pred._exec._program_name = "draft_prefill_L%d" % L
                 self._draft_prefill[L] = bp
             with NameManager():
                 dd_symbol = get_transformer_lm_decode(
@@ -559,8 +566,9 @@ class DecodeEngine:
                 self._draft_decode[b] = d_base.reshape(
                     {"data": (b,), "positions": (b,),
                      "page_table": (b, self.max_pages)})
-            for pred in self._draft_decode.values():
+            for b, pred in self._draft_decode.items():
                 pred._exec._cache_kind = "gen-draft-step"
+                pred._exec._program_name = "draft_decode_b%d" % b
             # verification is teacher forcing too — the draft's K
             # proposals are known before the call — so the verify rig
             # uses the same windowed single-pass graph as catch-up
@@ -587,8 +595,9 @@ class DecodeEngine:
                     {"data": (b, self._verify_width),
                      "positions": (b, self._verify_width),
                      "page_table": (b, self.max_pages)})
-            for pred in self._verify.values():
+            for b, pred in self._verify.items():
                 pred._exec._cache_kind = "gen-verify"
+                pred._exec._program_name = "verify_b%d" % b
 
         # -- prefix-cache catch-up rig: a windowed teacher-forcing
         # executable that re-walks the KNOWN suffix of a partial prefix
@@ -626,8 +635,9 @@ class DecodeEngine:
                 self._catchup[b] = c_base.reshape(
                     {"data": (b, cw), "positions": (b, cw),
                      "page_table": (b, self.max_pages)})
-            for pred in self._catchup.values():
+            for b, pred in self._catchup.items():
                 pred._exec._cache_kind = "gen-catchup"
+                pred._exec._program_name = "catchup_b%d" % b
 
         # recompile-detector bookkeeping: lane buckets warmup compiled,
         # post-warmup steps that hit a novel (never-warmed) bucket
@@ -865,6 +875,7 @@ class DecodeEngine:
                 raise QueueFullError(
                     "generation queue full (%d pending); retry with "
                     "backoff" % len(self._pending))
+            stream.sid = self._sid
             self._pending.append(_Seq(self._sid, stream, deadline,
                                       self.eos_id))
             self._sid += 1
@@ -983,17 +994,19 @@ class DecodeEngine:
             self.metrics.g_pending.set(len(self._pending))
         if not batch:
             return
-        faults.fire("generation.engine.admit")
-        # group by prompt-length bucket, chunk to the prefill batch cap
-        by_bucket: Dict[int, List[_Seq]] = {}
-        for seq in batch:
-            by_bucket.setdefault(
-                self._prefill_bucket_for(len(seq.tokens)), []).append(seq)
-        for L, seqs in sorted(by_bucket.items()):
-            bp = self._prefill[L]
-            cap = bp.max_batch_size
-            for ofs in range(0, len(seqs), cap):
-                self._prefill_group(L, seqs[ofs:ofs + cap])
+        with _span("gen:admit", "gen", {"n": len(batch)}):
+            faults.fire("generation.engine.admit")
+            # group by prompt-length bucket, chunk to the prefill batch cap
+            by_bucket: Dict[int, List[_Seq]] = {}
+            for seq in batch:
+                by_bucket.setdefault(
+                    self._prefill_bucket_for(len(seq.tokens)),
+                    []).append(seq)
+            for L, seqs in sorted(by_bucket.items()):
+                bp = self._prefill[L]
+                cap = bp.max_batch_size
+                for ofs in range(0, len(seqs), cap):
+                    self._prefill_group(L, seqs[ofs:ofs + cap])
 
     def _prefill_group(self, L: int, seqs: List[_Seq]):
         admitted: List[_Seq] = []
@@ -1023,6 +1036,22 @@ class DecodeEngine:
             admitted.append(seq)
         if not admitted:
             return
+        misses = [s for s in admitted if s.next_pos == 0]
+        with _span("gen:prefill", "gen",
+                   {"bucket": L, "n": len(admitted),
+                    "tokens": sum(len(s.tokens) for s in misses)}):
+            start = time.monotonic()
+            for seq in admitted:
+                wait_ms = max(0.0, (start - seq.stream._t0) * 1e3)
+                with _span("gen:queued", "gen",
+                           {"sid": seq.sid, "wait_ms": round(wait_ms, 3)}):
+                    pass
+            self._prefill_admitted(L, admitted, misses)
+
+    def _prefill_admitted(self, L: int, admitted: List[_Seq],
+                          misses: List[_Seq]):
+        """Run the prefill executables for one admitted group and move it
+        into the decode lanes."""
         # the draft holds no prefix cache: prefill EVERY admitted
         # sequence through the draft model so proposals can start from
         # the first decode iteration
@@ -1041,7 +1070,6 @@ class DecodeEngine:
                         seq.sid, layer, outs[1 + 2 * layer],
                         outs[2 + 2 * layer], n)
                 seq.draft_pos = n
-        misses = [s for s in admitted if s.next_pos == 0]
         if misses:
             bp = self._prefill[L]
             items = []
@@ -1216,48 +1244,72 @@ class DecodeEngine:
         one token via the decode executable, or up to K+1 via the
         draft/verify speculative pass."""
         faults.fire("generation.engine.step")
+        lanes = len(self._active)
+        with _span("gen:step", "gen",
+                   {"lanes": lanes, "bucket": self._lane_bucket_for(lanes),
+                    "sids": "|".join(str(s.sid) for s in self._active)}):
+            self._grow_lanes()
+            active = list(self._active)
+            if not active:
+                return
+            if self._draft is not None:
+                self._spec_step(active)
+            else:
+                self._plain_step(active)
+
+    def _grow_lanes(self):
+        """Extend every lane's pages to the positions this iteration
+        writes, preempting the youngest other lane when the pool is out."""
         width = self._verify_width
-        for seq in list(self._active):
-            # an earlier lane's extend may have preempted this one already
-            while seq in self._active:
-                try:
-                    tgt = min(seq.next_pos + width, seq.limit,
-                              self.max_seq_len)
-                    self.pool.extend(seq.sid, tgt)
-                    if self.prefix_cache_pages:
-                        # the page under the cursor may be shared (cached
-                        # admission) or still prefix-indexed: split it
-                        # before this iteration writes K/V there
-                        self.pool.ensure_writable(seq.sid, seq.next_pos)
-                    if self._draft_pool is not None:
-                        self._draft_pool.extend(seq.sid, tgt)
-                    break
-                except KVPoolExhaustedError:
-                    if not self._preempt_one(exclude=seq):
-                        raise
-        active = list(self._active)
-        if not active:
-            return
-        if self._draft is not None:
-            self._spec_step(active)
-        else:
-            self._plain_step(active)
+        with _span("gen:grow", "gen") as span:
+            preempted = 0
+            for seq in list(self._active):
+                # an earlier lane's extend may have preempted this one
+                while seq in self._active:
+                    try:
+                        tgt = min(seq.next_pos + width, seq.limit,
+                                  self.max_seq_len)
+                        self.pool.extend(seq.sid, tgt)
+                        if self.prefix_cache_pages:
+                            # the page under the cursor may be shared
+                            # (cached admission) or still prefix-indexed:
+                            # split it before this iteration writes K/V
+                            self.pool.ensure_writable(seq.sid, seq.next_pos)
+                        if self._draft_pool is not None:
+                            self._draft_pool.extend(seq.sid, tgt)
+                        break
+                    except KVPoolExhaustedError:
+                        if not self._preempt_one(exclude=seq):
+                            raise
+                        preempted += 1
+            span.set(preempted=preempted)
 
     def _run_lanes(self, pred, n_layers, pool, data, positions, table):
         """Bind one lane-bucket executable, run it, write the pool
         planes back, return the raw outputs."""
-        pred.set_input("data", data)
-        pred.set_input("positions", positions)
-        pred.set_input("page_table", table)
-        for i in range(n_layers):
-            pred.set_input("layer%d_k_pool" % i, pool.k_pools[i])
-            pred.set_input("layer%d_v_pool" % i, pool.v_pools[i])
-        pred._exec.forward(is_train=False)
-        outs = [o.asnumpy() for o in pred.get_outputs()]
+        planes = sum(p.nbytes for p in pool.k_pools[:n_layers]) \
+            + sum(p.nbytes for p in pool.v_pools[:n_layers])
+        with _span("gen:pool_h2d", "gen",
+                   {"bytes": planes + data.nbytes + positions.nbytes
+                    + table.nbytes}):
+            pred.set_input("data", data)
+            pred.set_input("positions", positions)
+            pred.set_input("page_table", table)
+            for i in range(n_layers):
+                pred.set_input("layer%d_k_pool" % i, pool.k_pools[i])
+                pred.set_input("layer%d_v_pool" % i, pool.v_pools[i])
+        with _span("gen:forward", "gen"):
+            pred._exec.forward(is_train=False)
+        # the first read blocks until the device has run the step: this
+        # span holds the device's own work as well as the copy back
+        with _span("gen:pool_d2h", "gen") as span:
+            outs = [o.asnumpy() for o in pred.get_outputs()]
+            span.set(bytes=sum(o.nbytes for o in outs))
         n_logits = len(outs) - 2 * n_layers
-        for i in range(n_layers):
-            np.copyto(pool.k_pools[i], outs[n_logits + 2 * i])
-            np.copyto(pool.v_pools[i], outs[n_logits + 2 * i + 1])
+        with _span("gen:pool_copyback", "gen", {"bytes": planes}):
+            for i in range(n_layers):
+                np.copyto(pool.k_pools[i], outs[n_logits + 2 * i])
+                np.copyto(pool.v_pools[i], outs[n_logits + 2 * i + 1])
         return outs
 
     def _plain_step(self, active: List[_Seq]):
@@ -1269,28 +1321,34 @@ class DecodeEngine:
         b = self._lane_bucket_for(len(active))
         self._note_lane_bucket(b)
         pred = self._decode[b]
-        data = np.zeros((b,), self._dtype)
-        positions = np.zeros((b,), self._dtype)
-        table = np.zeros((b, self.max_pages), self._dtype)
-        for i, seq in enumerate(active):
-            data[i] = seq.tokens[seq.next_pos]
-            positions[i] = seq.next_pos  # slot the new K/V lands in
-            table[i] = self.pool.page_table_row(seq.sid, self.max_pages)
+        with _span("gen:feed", "gen"):
+            data = np.zeros((b,), self._dtype)
+            positions = np.zeros((b,), self._dtype)
+            table = np.zeros((b, self.max_pages), self._dtype)
+            for i, seq in enumerate(active):
+                data[i] = seq.tokens[seq.next_pos]
+                positions[i] = seq.next_pos  # slot the new K/V lands in
+                table[i] = self.pool.page_table_row(seq.sid,
+                                                    self.max_pages)
         outs = self._run_lanes(pred, self.num_layers, self.pool,
                                data, positions, table)
         logits = outs[0]
         self.metrics.steps.inc()
-        retired = []
-        for i, seq in enumerate(active):
-            seq.iters += 1
-            seq.next_pos += 1
-            if self.prefix_cache_pages:
-                self.pool.register_prefix(seq.sid,
-                                          seq.tokens[:seq.next_pos])
-            if seq.next_pos >= len(seq.tokens):
-                if self._emit(seq, int(np.argmax(logits[i]))):
-                    retired.append(seq)
-        self._drop_retired(retired)
+        with _span("gen:emit", "gen") as span:
+            retired = []
+            emitted = 0
+            for i, seq in enumerate(active):
+                seq.iters += 1
+                seq.next_pos += 1
+                if self.prefix_cache_pages:
+                    self.pool.register_prefix(seq.sid,
+                                              seq.tokens[:seq.next_pos])
+                if seq.next_pos >= len(seq.tokens):
+                    emitted += 1
+                    if self._emit(seq, int(np.argmax(logits[i]))):
+                        retired.append(seq)
+            self._drop_retired(retired)
+            span.set(emitted=emitted, retired=len(retired))
 
     def _spec_step(self, active: List[_Seq]):
         """One speculative iteration: draft K proposals per steady lane,
@@ -1315,7 +1373,8 @@ class DecodeEngine:
             self.metrics.spec_fallbacks.inc()
             self._plain_step(active)
             return
-        proposals = self._draft_propose(active, b)
+        with _span("gen:draft", "gen", {"k": width - 1}):
+            proposals = self._draft_propose(active, b)
         vpred = self._verify[b]
         data = np.zeros((b, width), self._dtype)
         # pad slots park at (token 0, position max_seq_len-1): with a
@@ -1343,8 +1402,9 @@ class DecodeEngine:
                 positions[i, w] = p
                 lw += 1
             lane_width[seq.sid] = lw
-        outs = self._run_lanes(vpred, self.num_layers, self.pool,
-                               data, positions, table)
+        with _span("gen:verify", "gen", {"width": width}):
+            outs = self._run_lanes(vpred, self.num_layers, self.pool,
+                                   data, positions, table)
         logits = outs[0].reshape(b, width, -1)
         self.metrics.steps.inc()
         retired = []

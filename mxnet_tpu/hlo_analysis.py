@@ -18,7 +18,7 @@ from typing import Optional
 from .base import env, register_env
 
 __all__ = ["peak_flops", "hbm_bytes_per_s", "cost_analysis",
-           "lower_and_analyze", "roofline_ms", "hlo_op_counts",
+           "lower_and_analyze", "roofline_ms", "hlo_op_counts", "op_scopes",
            "bn_fusion_analysis"]
 
 register_env("MXNET_TELEMETRY_HBM_GBS", 0.0, float,
@@ -113,6 +113,21 @@ def hlo_op_counts(hlo_text, interesting=None) -> dict:
     if interesting is None:
         return dict(ops)
     return {k: v for k, v in ops.most_common() if k in interesting}
+
+
+_SCOPED_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*?metadata=\{[^}\n]*?"
+    r'op_name="([^"]*)"', re.M)
+
+
+def op_scopes(hlo_text) -> dict:
+    """{instruction name: scope path} of a compiled module's text, from
+    each instruction's ``metadata={op_name="jit(fused_step)/fc1/dot"}``:
+    the ``jax.named_scope`` stack the operation was traced under (a Symbol
+    node's name, ``optimizer``, ``guard``, a kernel's name).  A fusion
+    carries the scope of its root.  Instructions of fused computations
+    are listed too; one without metadata is left out."""
+    return {name: scope for name, scope in _SCOPED_RE.findall(hlo_text)}
 
 
 def bn_fusion_analysis(hlo_text) -> dict:

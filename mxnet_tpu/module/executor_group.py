@@ -21,6 +21,7 @@ import numpy as np
 from ..base import MXNetError
 from .. import context as ctx_mod
 from .. import ndarray as nd
+from .. import profiler as _prof
 from ..executor import Executor
 from ..io import DataDesc
 
@@ -415,6 +416,10 @@ class DataParallelExecutorGroup:
     # ------------------------------------------------------------------
     def _load_batch(self, data_batch):
         """Place batch data onto the mesh (scatter ≈ _load_data :43)."""
+        with _prof.Frame("ExecGroup.load_batch", "module"):
+            self._place_batch(data_batch)
+
+    def _place_batch(self, data_batch):
         import jax
 
         executor = self.execs[0]

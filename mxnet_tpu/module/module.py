@@ -16,6 +16,7 @@ import numpy as np
 from .. import context as ctx_mod
 from .. import ndarray as nd
 from .. import optimizer as opt
+from .. import profiler as _prof
 from .. import telemetry as _telemetry
 from ..base import MXNetError
 from ..initializer import Uniform, InitDesc
@@ -509,17 +510,18 @@ class Module(BaseModule):
         forward, backward, AND the optimizer run as a single donated XLA
         program (see _decide_fused)."""
         assert self.binded and self.params_initialized
-        if _telemetry.enabled():
-            mon = self._telemetry_monitor()
-            mon.step_begin()
-            mon.note_batch(data_batch)  # recompile fingerprint
-        if self._fused_ok and self.optimizer_initialized:
-            self._fused_pending = data_batch
-            return
-        # this path does NOT go through self.forward(), so the async
-        # overlap window from the previous update() closes here
-        self._wait_async_comm()
-        self._exec_group.forward_backward(data_batch)
+        with _prof.Frame("Module.forward_backward", "module"):
+            if _telemetry.enabled():
+                mon = self._telemetry_monitor()
+                mon.step_begin()
+                mon.note_batch(data_batch)  # recompile fingerprint
+            if self._fused_ok and self.optimizer_initialized:
+                self._fused_pending = data_batch
+                return
+            # this path does NOT go through self.forward(), so the async
+            # overlap window from the previous update() closes here
+            self._wait_async_comm()
+            self._exec_group.forward_backward(data_batch)
 
     def _flush_fused_pending(self):
         """A caller wants grads/outputs before update(): fall back to the
@@ -535,6 +537,10 @@ class Module(BaseModule):
         push/pull/updater loop."""
         assert self.binded and self.params_initialized and \
             self.optimizer_initialized
+        with _prof.Frame("Module.update", "module"):
+            self._update()
+
+    def _update(self):
         self._params_dirty = True
         self._guardian_action = "ok"
         if self._fused_pending is not None:

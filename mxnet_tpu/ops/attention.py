@@ -201,6 +201,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         # stream is the sequential dim carrying the softmax state — the
         # semantics let Mosaic overlap the K/V block DMAs with compute
         interpret=interpret,
+        # the kernel's name in the compiled program and the device trace
+        name="flash_fwd",
         **_compiler_params(pltpu),
     )(qt, kt, vt)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse.reshape(b * h, sq)
@@ -368,6 +370,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         out_shape=sds((b * h, sq, d), q.dtype),
         scratch_shapes=[scratch((bq, d))],
         interpret=interpret,
+        name="flash_bwd_dq",
         **_compiler_params(pltpu),
     )(qt, kt, vt, dot, lse3, delta3)
 
@@ -391,6 +394,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                    sds((b * h, sk, d), v.dtype)],
         scratch_shapes=[scratch((bk, d)), scratch((bk, d))],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **_compiler_params(pltpu),
     )(qt, kt, vt, dot, lse3, delta3)
 

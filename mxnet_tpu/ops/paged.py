@@ -27,6 +27,7 @@ land harmlessly in the scratch page and never corrupt a live sequence.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from .param import Param
@@ -69,6 +70,7 @@ def _paged_infer(attrs, shapes):
           no_grad_inputs=("page_table", "positions"),
           output_names=lambda attrs: ["out", "k_pool_out", "v_pool_out"],
           hint="pagedattentionwindow")
+@jax.named_scope("paged_attention_window")
 def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
                             page_table, positions):
     """``width`` KNOWN tokens per lane in ONE causal pass over paged KV.
@@ -144,6 +146,7 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
           no_grad_inputs=("page_table", "positions"),
           output_names=lambda attrs: ["out", "k_pool_out", "v_pool_out"],
           hint="pagedattention")
+@jax.named_scope("paged_attention")
 def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
                      page_table, positions):
     """One decode step for ``lanes`` sequences at once.

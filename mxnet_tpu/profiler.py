@@ -16,6 +16,8 @@ import time
 import threading
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .base import env, register_env
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
@@ -113,22 +115,48 @@ def resume():
 
 
 class Frame:
-    """Context manager recording one named span into the chrome trace (the
-    python-level analogue of OprExecStat, profiler.h:20-42)."""
+    """Context manager recording one named span: THE span primitive.
+
+    Two sinks, one call site.  Whenever a ``jax.profiler`` session runs (the
+    benchmark's ``--trace 1``, ``profiler_set_state("run")``, an operator's
+    ``jax.profiler.start_trace``) the span is a host event of that
+    session's ``.xplane.pb``, on the clock of the device operations, with
+    ``args`` as its stats and the enclosing span of its thread as its
+    parent.  While the legacy profiler runs or the telemetry tracer is on,
+    it is also an event of the Chrome trace (the python-level analogue of
+    OprExecStat, profiler.h:20-42).  With neither, entering and leaving
+    read no clock and take no lock: what is left is the annotation's own
+    check that no session is active.
+
+    ``args`` is a flat dict of str/int/float.  A value never holds ``,`` or
+    ``#``: the trace format cuts a stat there.  The profiler session gets
+    the args as they are on entry; the Chrome trace reads them on exit, so
+    a caller may attach fields while the span is open."""
+
+    __slots__ = ("name", "category", "args", "_t0", "_ann")
 
     def __init__(self, name, category="python", args=None):
         self.name = name
         self.category = category
-        # optional chrome-trace args payload (e.g. the distributed trace
-        # id a kvstore RPC envelope carried); read at exit so callers may
-        # attach fields while the span is open
         self.args = args
 
+    def set(self, **fields):
+        """Attach fields known only once the span is open (a count of what
+        it did), to both sinks."""
+        self.args = dict(self.args or {}, **fields)
+        self._ann.set_metadata(**fields)
+
     def __enter__(self):
-        self._t0 = time.perf_counter_ns() // 1000
+        self._t0 = time.perf_counter_ns() // 1000 \
+            if _state["running"] or _sink is not None else None
+        self._ann = _TraceAnnotation(self.name, **(self.args or {}))
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._t0 is None:
+            return
         sink = _sink
         if _state["running"] or sink is not None:
             t1 = time.perf_counter_ns() // 1000

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import profiler
 from ..base import MXNetError, env, register_env
 from ..context import Context
 from .batcher import (BucketedPredictor, DeadlineExceededError, MicroBatcher,
@@ -673,6 +674,16 @@ class InferenceServer:
                         else 400
                     self._reply(code, json.dumps({"error": repr(exc)}))
                     return
+                # the request's life on this handler thread, tied to the
+                # engine thread's spans by the sid its submit was given
+                with profiler.Frame(
+                        "serve:generate", "serving",
+                        {"sid": stream.sid,
+                         "prompt_len": len(stream.prompt),
+                         "max_new": stream.max_new_tokens}):
+                    self._stream_tokens(stream)
+
+            def _stream_tokens(self, stream):
                 self.send_response(200)
                 self.send_header("Content-Type", "application/x-ndjson")
                 self.send_header("X-Accel-Buffering", "no")

@@ -7,10 +7,15 @@ Optionally dumps full HLO text and a jax.profiler trace.
 
 Usage:
   python tools/perf_probe.py [--batch-size 256] [--dump-hlo /tmp/hlo.txt]
+                             [--dump-scopes /tmp/scopes.json]
                              [--trace /tmp/jax-trace]
+
+``--dump-scopes`` writes {instruction name: scope path}: the way from a device
+trace's ``fusion.N`` to the Symbol node (or ``optimizer``) it came from.
 """
 import argparse
 import collections
+import json
 import os
 import re
 import sys
@@ -52,7 +57,7 @@ def build_module(batch):
 # re-exported for back-compat: the analysis now lives in the shared
 # mxnet_tpu.hlo_analysis module (the autotuner uses it too)
 from mxnet_tpu.hlo_analysis import bn_fusion_analysis  # noqa: E402,F401
-from mxnet_tpu.hlo_analysis import hlo_op_counts  # noqa: E402
+from mxnet_tpu.hlo_analysis import hlo_op_counts, op_scopes  # noqa: E402
 
 
 def main():
@@ -60,6 +65,7 @@ def main():
     ap.add_argument("--batch-size", type=int, default=256)
     ap.add_argument("--num-steps", type=int, default=20)
     ap.add_argument("--dump-hlo", default=None)
+    ap.add_argument("--dump-scopes", default=None)
     ap.add_argument("--trace", default=None)
     cli = ap.parse_args()
 
@@ -105,6 +111,9 @@ def main():
         if cli.dump_hlo:
             with open(cli.dump_hlo, "w") as f:
                 f.write(hlo)
+        if cli.dump_scopes:
+            with open(cli.dump_scopes, "w") as f:
+                json.dump(op_scopes(hlo), f, indent=1, sort_keys=True)
 
     # steady-state timing
     for _ in range(3):
