@@ -269,7 +269,8 @@ def server_phase(cfg, ctx, seed=0):
         eng = srv.generator
         where = eng.devices()
         check(all(v == [str(dev)] for v in where.values()),
-              "generator weights, prefill and decode outputs live on %s: %s"
+              "generator weights, KV pool, prefill and decode outputs live on "
+              "%s: %s"
               % (dev, where))
         host, port = srv.serve_http()
         rng = np.random.RandomState(seed + 1)

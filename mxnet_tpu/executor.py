@@ -222,6 +222,11 @@ class Executor:
         # ``XLA Modules`` line shows it (``jit_<name>``); the generation
         # engine names its programs ``decode_b<lanes>`` / ``prefill_L<len>``
         self._program_name = "forward"
+        # carried arguments (set_carried): argument name -> index of the
+        # output that is its next value
+        self._carried: Dict[str, int] = {}
+        # whether the forward last built donates them (None: none built)
+        self.carry_donated: Optional[bool] = None
         # NaiveEngine parity: MXNET_ENGINE_TYPE=NaiveEngine disables jit and
         # synchronizes after every call (threaded_engine.h:329-337 debugging).
         self._naive = env("MXNET_ENGINE_TYPE") == "NaiveEngine"
@@ -286,6 +291,22 @@ class Executor:
 
         return bind(fn, self._ctx.jax_device().platform, *self._kernel_mesh)
 
+    def set_carried(self, carried: Dict[str, int]):
+        """Declare arguments the forward carries: ``{argument name: index
+        of the output that is its next value}`` (a KV pool plane and the
+        ``k_pool_out`` the step makes of it).  Such a forward takes them
+        as an argument of their own, donated so that the program updates
+        them in place, and rebinds each to its output when it returns:
+        the bound NDArray then holds the new value, the old buffer is
+        dead, and ``outputs`` lists the bound NDArray itself at that
+        index.  Executors that bind the same NDArray share the value."""
+        outs = len(self._plan.output_entries)
+        for name, idx in carried.items():
+            if name not in self.arg_dict or not 0 <= int(idx) < outs:
+                raise MXNetError("set_carried: no argument %r or no output "
+                                 "%r" % (name, idx))
+        self._carried = {k: int(v) for k, v in carried.items()}
+
     def _get_fwd(self, is_train: bool, internals: bool = False):
         import jax
 
@@ -301,6 +322,12 @@ class Executor:
                 return plan.run(cast(args), aux, rng, is_train,
                                 want_internals=internals, placement=placement)
 
+            if self._carried:
+                run = fn
+
+                def fn(carried, args, aux, rng):  # noqa: F811
+                    return run({**args, **carried}, aux, rng)
+
             fn.__name__ = self._program_name
             fn = self._bound(fn)
             if self._naive:
@@ -308,8 +335,18 @@ class Executor:
             else:
                 from . import compile_cache as _cc
 
+                jit_kw, static_key = {}, key
+                if self._carried:
+                    # as the fused step: an executable that may be
+                    # serialized is built without donation (see
+                    # _get_fused_step), and donation, which changes the
+                    # compiled program, is part of the persistent key
+                    donate = () if _cc.active() else (0,)
+                    self.carry_donated = bool(donate)
+                    jit_kw = {"donate_argnums": donate}
+                    static_key = key + (("donate", donate),)
                 self._jit_cache[key] = _cc.maybe_cached(
-                    jax.jit(fn), kind, key, self)
+                    jax.jit(fn, **jit_kw), kind, static_key, self)
         return self._jit_cache[key]
 
     def _get_fwd_bwd(self, is_train: bool, diff_names: tuple, add_names: tuple):
@@ -842,6 +879,16 @@ class Executor:
         spec = self._shard_specs.get(name, PartitionSpec())
         target._set(_place(v, self._shard_mesh, spec))
 
+    def _forward_args(self, rng):
+        """The arguments of the forward program (``_get_fwd``): ``(args,
+        aux, rng)``, with the carried arguments split off in front when
+        there are any."""
+        args = {k: v._data for k, v in self.arg_dict.items()}
+        aux = {k: v._data for k, v in self.aux_dict.items()}
+        if not self._carried:
+            return args, aux, rng
+        return {k: args.pop(k) for k in self._carried}, args, aux, rng
+
     def forward(self, is_train: bool = False, **kwargs):
         from . import ndarray as nd
 
@@ -849,22 +896,24 @@ class Executor:
             if k not in self.arg_dict:
                 raise MXNetError("unknown forward argument %r" % k)
             self._write_arg(k, v)
-        args = {k: v._data for k, v in self.arg_dict.items()}
-        aux = {k: v._data for k, v in self.aux_dict.items()}
         rng = _random.next_key() if self._plan.stochastic_nodes else None
         self._last_rng = rng
+        call = self._forward_args(rng)
         with _prof.Frame("Executor.forward", "exec"):
             if self._monitor_callback is not None:
                 outs, new_aux, internals = self._get_fwd(is_train, True)(
-                    args, aux, rng)
+                    *call)
                 for name, arr in internals.items():
                     self._monitor_callback(name, nd.NDArray(arr, self._ctx))
             else:
-                outs, new_aux = self._get_fwd(is_train, False)(args, aux, rng)
+                outs, new_aux = self._get_fwd(is_train, False)(*call)
         if is_train:
             for k, v in new_aux.items():
                 self.aux_dict[k]._set(v)
         self._output_arrays = [nd.NDArray(o, self._ctx) for o in outs]
+        for name, idx in self._carried.items():
+            self.arg_dict[name]._set(outs[idx])
+            self._output_arrays[idx] = self.arg_dict[name]
         if self._naive:
             for o in self._output_arrays:
                 o.wait_to_read()

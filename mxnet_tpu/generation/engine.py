@@ -44,7 +44,10 @@ XLA discipline: every XLA-visible shape here is static.
 * Decode is a fixed-lane slotted program (``models.transformer.
   get_transformer_lm_decode``): ``lanes`` sequences advance one token
   through per-lane page tables into a shared paged KV pool
-  (:mod:`.kv_pool`), compiled ONCE per lane-count bucket and primed
+  (:mod:`.kv_pool`) whose planes stay on the device — every lane
+  program binds them as carried arguments and updates them in place;
+  a step sends ids, positions and page tables up and reads the logits
+  down — compiled ONCE per lane-count bucket and primed
   through the PR 10 compile cache (entry kinds ``gen-step`` /
   ``gen-prefill`` / ``gen-verify`` / ``gen-draft-step`` /
   ``gen-draft-prefill``), so AOT bundles restore a generate-ready
@@ -389,6 +392,7 @@ class DecodeEngine:
         from ..context import current_context
 
         self._ctx = ctx = ctx or current_context()
+        self._device = ctx.jax_device()
         self._dtype = np.dtype(dtype)
         # unset knobs consult the autotuner before the env defaults:
         # explicit constructor args always pin, tuned winners beat the
@@ -469,7 +473,8 @@ class DecodeEngine:
         self.pool = PagedKVPool(self.num_pages, self.page_size,
                                 self.num_layers, self.num_heads,
                                 self.head_dim, dtype=self._dtype,
-                                prefix_cache_pages=self.prefix_cache_pages)
+                                prefix_cache_pages=self.prefix_cache_pages,
+                                ctx=ctx)
         self.metrics = _GenMetrics()
 
         # prefill: one BucketedPredictor per prompt-length bucket.
@@ -480,46 +485,65 @@ class DecodeEngine:
         # digests the bundle was saved under.
         from ..name import NameManager
 
-        self._prefill: Dict[int, BucketedPredictor] = {}
-        for L in self.prefill_len_buckets:
-            with NameManager():
-                symbol = get_transformer_lm_prefill(
-                    self.vocab_size, self.num_layers, self.num_heads,
-                    self.hidden, seq_len=L, max_seq_len=self.max_seq_len)
-            bp = BucketedPredictor(symbol, self._params, {"data": (L,)},
-                                   self.prefill_batch_buckets, ctx=ctx,
-                                   dtype=dtype)
-            for pred in bp._preds.values():
-                pred._exec._cache_kind = "gen-prefill"
-                pred._exec._program_name = "prefill_L%d" % L
-            self._prefill[L] = bp
+        def prefill_rig(params, n_layers, n_heads, n_hidden, kind, name):
+            rig = {}
+            for L in self.prefill_len_buckets:
+                with NameManager():
+                    symbol = get_transformer_lm_prefill(
+                        self.vocab_size, n_layers, n_heads, n_hidden,
+                        seq_len=L, max_seq_len=self.max_seq_len)
+                bp = BucketedPredictor(symbol, params, {"data": (L,)},
+                                       self.prefill_batch_buckets, ctx=ctx,
+                                       dtype=dtype)
+                for pred in bp._preds.values():
+                    pred._exec._cache_kind = kind
+                    pred._exec._program_name = name % L
+                rig[L] = bp
+            return rig
 
-        # decode: one fixed-lane Predictor per lane bucket (shared weights
-        # via reshape; pool shapes are lane-independent)
-        with NameManager():
-            dec_symbol = get_transformer_lm_decode(
-                self.vocab_size, self.num_layers, self.num_heads,
-                self.hidden, max_seq_len=self.max_seq_len,
-                lanes=self.max_lanes, num_pages=self.num_pages,
-                page_size=self.page_size, max_pages=self.max_pages)
-        pool_shape = (self.num_pages, self.page_size, self.num_heads,
-                      self.head_dim)
-        shapes = {"data": (self.max_lanes,),
-                  "positions": (self.max_lanes,),
-                  "page_table": (self.max_lanes, self.max_pages)}
-        for i in range(self.num_layers):
-            shapes["layer%d_k_pool" % i] = pool_shape
-            shapes["layer%d_v_pool" % i] = pool_shape
-        base = Predictor(dec_symbol, self._params, shapes, ctx=ctx,
-                         dtype=dtype)
-        self._decode: Dict[int, Predictor] = {self.max_lanes: base}
-        for b in self.lane_buckets[:-1]:
-            self._decode[b] = base.reshape(
-                {"data": (b,), "positions": (b,), "page_table": (b,
-                 self.max_pages)})
-        for b, pred in self._decode.items():
-            pred._exec._cache_kind = "gen-step"
-            pred._exec._program_name = "decode_b%d" % b
+        def lane_rig(get_symbol, params, pool, n_heads, n_hidden, kind, name,
+                     width=None):
+            """One fixed-lane Predictor per lane bucket (shared weights via
+            reshape; pool shapes are lane-independent).  Every one binds
+            the pool's own planes, carried: the step updates them in
+            place and nothing uploads or reads them."""
+            kw = {} if width is None else {"width": width}
+            with NameManager():
+                symbol = get_symbol(
+                    self.vocab_size, pool.num_layers, n_heads, n_hidden,
+                    max_seq_len=self.max_seq_len, lanes=self.max_lanes,
+                    num_pages=self.num_pages, page_size=self.page_size,
+                    max_pages=self.max_pages, **kw)
+
+            def feeds(b):
+                shape = (b,) if width is None else (b, width)
+                return {"data": shape, "positions": shape,
+                        "page_table": (b, self.max_pages)}
+
+            # outputs: the logits, then the planes in the pool's order
+            names = ["layer%d_%s_pool" % (i, kv)
+                     for i in range(pool.num_layers) for kv in "kv"]
+            planes = dict(zip(names, pool.planes()))
+            carried = {name: 1 + i for i, name in enumerate(names)}
+            shapes = feeds(self.max_lanes)
+            shapes.update({k: v.shape for k, v in planes.items()})
+            base = Predictor(symbol, dict(params, **planes), shapes,
+                             ctx=ctx, dtype=dtype)
+            rig = {self.max_lanes: base}
+            for b in self.lane_buckets[:-1]:
+                rig[b] = base.reshape(feeds(b))
+            for b, pred in rig.items():
+                pred._exec._cache_kind = kind
+                pred._exec._program_name = name % b
+                pred._exec.set_carried(carried)
+            return rig
+
+        self._prefill = prefill_rig(self._params, self.num_layers,
+                                    self.num_heads, self.hidden,
+                                    "gen-prefill", "prefill_L%d")
+        self._decode = lane_rig(get_transformer_lm_decode, self._params,
+                                self.pool, self.num_heads, self.hidden,
+                                "gen-step", "decode_b%d")
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -530,74 +554,25 @@ class DecodeEngine:
             dl = self._draft["num_layers"]
             dh = self._draft["num_heads"]
             dhid = self._draft["hidden"]
-            dhd = dhid // dh
             self._draft_pool = PagedKVPool(self.num_pages, self.page_size,
-                                           dl, dh, dhd, dtype=self._dtype)
-            for L in self.prefill_len_buckets:
-                with NameManager():
-                    symbol = get_transformer_lm_prefill(
-                        self.vocab_size, dl, dh, dhid, seq_len=L,
-                        max_seq_len=self.max_seq_len)
-                bp = BucketedPredictor(symbol, self._draft_params,
-                                       {"data": (L,)},
-                                       self.prefill_batch_buckets,
-                                       ctx=ctx, dtype=dtype)
-                for pred in bp._preds.values():
-                    pred._exec._cache_kind = "gen-draft-prefill"
-                    pred._exec._program_name = "draft_prefill_L%d" % L
-                self._draft_prefill[L] = bp
-            with NameManager():
-                dd_symbol = get_transformer_lm_decode(
-                    self.vocab_size, dl, dh, dhid,
-                    max_seq_len=self.max_seq_len, lanes=self.max_lanes,
-                    num_pages=self.num_pages, page_size=self.page_size,
-                    max_pages=self.max_pages)
-            d_pool_shape = (self.num_pages, self.page_size, dh, dhd)
-            d_shapes = {"data": (self.max_lanes,),
-                        "positions": (self.max_lanes,),
-                        "page_table": (self.max_lanes, self.max_pages)}
-            for i in range(dl):
-                d_shapes["layer%d_k_pool" % i] = d_pool_shape
-                d_shapes["layer%d_v_pool" % i] = d_pool_shape
-            d_base = Predictor(dd_symbol, self._draft_params, d_shapes,
-                               ctx=ctx, dtype=dtype)
-            self._draft_decode = {self.max_lanes: d_base}
-            for b in self.lane_buckets[:-1]:
-                self._draft_decode[b] = d_base.reshape(
-                    {"data": (b,), "positions": (b,),
-                     "page_table": (b, self.max_pages)})
-            for b, pred in self._draft_decode.items():
-                pred._exec._cache_kind = "gen-draft-step"
-                pred._exec._program_name = "draft_decode_b%d" % b
+                                           dl, dh, dhid // dh,
+                                           dtype=self._dtype, ctx=ctx)
+            self._draft_prefill = prefill_rig(
+                self._draft_params, dl, dh, dhid, "gen-draft-prefill",
+                "draft_prefill_L%d")
+            self._draft_decode = lane_rig(
+                get_transformer_lm_decode, self._draft_params,
+                self._draft_pool, dh, dhid, "gen-draft-step",
+                "draft_decode_b%d")
             # verification is teacher forcing too — the draft's K
             # proposals are known before the call — so the verify rig
             # uses the same windowed single-pass graph as catch-up
             # rather than chaining K+1 literal decode blocks (whose
             # dispatch cost eats the speculation win on small models)
-            with NameManager():
-                v_symbol = get_transformer_lm_catchup(
-                    self.vocab_size, self.num_layers, self.num_heads,
-                    self.hidden, max_seq_len=self.max_seq_len,
-                    lanes=self.max_lanes, num_pages=self.num_pages,
-                    page_size=self.page_size, max_pages=self.max_pages,
-                    width=self._verify_width)
-            v_shapes = {"data": (self.max_lanes, self._verify_width),
-                        "positions": (self.max_lanes, self._verify_width),
-                        "page_table": (self.max_lanes, self.max_pages)}
-            for i in range(self.num_layers):
-                v_shapes["layer%d_k_pool" % i] = pool_shape
-                v_shapes["layer%d_v_pool" % i] = pool_shape
-            v_base = Predictor(v_symbol, self._params, v_shapes, ctx=ctx,
-                               dtype=dtype)
-            self._verify = {self.max_lanes: v_base}
-            for b in self.lane_buckets[:-1]:
-                self._verify[b] = v_base.reshape(
-                    {"data": (b, self._verify_width),
-                     "positions": (b, self._verify_width),
-                     "page_table": (b, self.max_pages)})
-            for b, pred in self._verify.items():
-                pred._exec._cache_kind = "gen-verify"
-                pred._exec._program_name = "verify_b%d" % b
+            self._verify = lane_rig(
+                get_transformer_lm_catchup, self._params, self.pool,
+                self.num_heads, self.hidden, "gen-verify", "verify_b%d",
+                width=self._verify_width)
 
         # -- prefix-cache catch-up rig: a windowed teacher-forcing
         # executable that re-walks the KNOWN suffix of a partial prefix
@@ -609,35 +584,25 @@ class DecodeEngine:
         self._catchup_width = 0
         if self.prefix_cache_pages:
             # wide enough to swallow a typical page-rounding suffix in
-            # one forward — every extra round pays a full pool
-            # host-roundtrip plus the executable's fixed dispatch cost;
-            # the windowed pass itself is compute-proportional, so a
-            # wider window costs only the pad slots it doesn't use
-            cw = max(2, min(32, self.max_seq_len - 1))
-            self._catchup_width = cw
-            with NameManager():
-                c_symbol = get_transformer_lm_catchup(
-                    self.vocab_size, self.num_layers, self.num_heads,
-                    self.hidden, max_seq_len=self.max_seq_len,
-                    lanes=self.max_lanes, num_pages=self.num_pages,
-                    page_size=self.page_size, max_pages=self.max_pages,
-                    width=cw)
-            c_shapes = {"data": (self.max_lanes, cw),
-                        "positions": (self.max_lanes, cw),
-                        "page_table": (self.max_lanes, self.max_pages)}
-            for i in range(self.num_layers):
-                c_shapes["layer%d_k_pool" % i] = pool_shape
-                c_shapes["layer%d_v_pool" % i] = pool_shape
-            c_base = Predictor(c_symbol, self._params, c_shapes, ctx=ctx,
-                               dtype=dtype)
-            self._catchup = {self.max_lanes: c_base}
-            for b in self.lane_buckets[:-1]:
-                self._catchup[b] = c_base.reshape(
-                    {"data": (b, cw), "positions": (b, cw),
-                     "page_table": (b, self.max_pages)})
-            for b, pred in self._catchup.items():
-                pred._exec._cache_kind = "gen-catchup"
-                pred._exec._program_name = "catchup_b%d" % b
+            # one forward — every extra round pays the executable's fixed
+            # dispatch cost; the windowed pass itself is
+            # compute-proportional, so a wider window costs only the pad
+            # slots it doesn't use
+            self._catchup_width = max(2, min(32, self.max_seq_len - 1))
+            self._catchup = lane_rig(
+                get_transformer_lm_catchup, self._params, self.pool,
+                self.num_heads, self.hidden, "gen-catchup", "catchup_b%d",
+                width=self._catchup_width)
+
+        import jax
+        import jax.numpy as jnp
+
+        def prefill_rows(logits, rows):
+            return jnp.take_along_axis(logits, rows[:, None, None],
+                                       axis=1)[:, 0]
+
+        # (batch, L, vocab) logits -> each prompt's last row, on the device
+        self._prefill_rows = jax.jit(prefill_rows)
 
         # recompile-detector bookkeeping: lane buckets warmup compiled,
         # post-warmup steps that hit a novel (never-warmed) bucket
@@ -687,47 +652,47 @@ class DecodeEngine:
         return cls("%s-%04d.params" % (prefix, int(epoch)), **spec)
 
     def warmup(self):
-        """Pre-compile every prefill (length x batch) bucket and every
-        decode/draft/verify lane bucket, priming through the compile
-        cache when it is enabled — post-warmup steady state performs
-        ZERO XLA compiles, and an attached AOT bundle makes warmup
+        """Pre-compile every prefill (length x batch) bucket with the
+        pool's scatter of its shape, and every decode/draft/verify lane
+        bucket, priming through the compile cache when it is enabled —
+        post-warmup steady state performs ZERO XLA compiles, and an
+        attached AOT bundle makes the executors' warmup
         deserialize-only."""
-        for bp in self._prefill.values():
+        for bp, pool in self._prefill_rigs():
             bp.warmup()
-        for bp in self._draft_prefill.values():
-            bp.warmup()
-        pool_shape = (self.num_pages, self.page_size, self.num_heads,
-                      self.head_dim)
-        zero_pool = np.zeros(pool_shape, self._dtype)
-        d_zero_pool = None
-        if self._draft is not None:
-            d_zero_pool = np.zeros(
-                (self.num_pages, self.page_size, self._draft["num_heads"],
-                 self._draft["hidden"] // self._draft["num_heads"]),
-                self._dtype)
+            # what a prefill does with the outputs, once per shape: the
+            # scatter into the planes (scratch page 0 here), and for the
+            # target the pick of each prompt's last logits row
+            for b, pred in bp._preds.items():
+                outs = pred.get_outputs()
+                slabs = [o._data for o in outs[1:]]
+                pool.write_slots(slabs, np.zeros(slabs[0].shape[:2],
+                                                 np.int32))
+                if pool is self.pool:
+                    np.asarray(self._prefill_rows(
+                        outs[0]._data, np.zeros((b,), np.int32)))
+        if self.prefix_cache_pages:
+            self.pool.copy_page(0, 0)  # the copy-on-write split's program
         for b in self.lane_buckets:
-            rigs = [(self._decode[b], (b,), self.num_layers, zero_pool)]
+            rigs = [(self._decode[b], (b,))]
             if self._draft is not None:
-                rigs.append((self._draft_decode[b], (b,),
-                             self._draft["num_layers"], d_zero_pool))
-                rigs.append((self._verify[b], (b, self._verify_width),
-                             self.num_layers, zero_pool))
+                rigs.append((self._draft_decode[b], (b,)))
+                rigs.append((self._verify[b], (b, self._verify_width)))
             if self._catchup:
-                rigs.append((self._catchup[b], (b, self._catchup_width),
-                             self.num_layers, zero_pool))
-            for pred, dshape, n_layers, zpool in rigs:
-                pred.set_input("data", np.zeros(dshape, self._dtype))
-                pred.set_input("positions", np.zeros(dshape, self._dtype))
-                pred.set_input("page_table",
-                               np.zeros((b, self.max_pages), self._dtype))
-                for i in range(n_layers):
-                    pred.set_input("layer%d_k_pool" % i, zpool)
-                    pred.set_input("layer%d_v_pool" % i, zpool)
-                pred._exec.forward(is_train=False)
-                for out in pred.get_outputs():
-                    out.asnumpy()  # block until compiled + ran
+                rigs.append((self._catchup[b], (b, self._catchup_width)))
+            for pred, dshape in rigs:
+                # all-zero feeds: every lane writes scratch page 0 of the
+                # pool's own planes, which the rig binds
+                self._run_lanes(pred, np.zeros(dshape, self._dtype),
+                                np.zeros(dshape, self._dtype),
+                                np.zeros((b, self.max_pages), self._dtype))
             self.warmed_lane_buckets.add(b)
         return self
+
+    def _prefill_rigs(self):
+        """(prefill family, the pool its K/V goes to), target then draft."""
+        return [(bp, self.pool) for bp in self._prefill.values()] + \
+            [(bp, self._draft_pool) for bp in self._draft_prefill.values()]
 
     def compiled_entries(self):
         """Primed compile-cache wrappers across prefill, decode, draft,
@@ -899,9 +864,9 @@ class DecodeEngine:
 
     def devices(self) -> Dict[str, List[str]]:
         """Where this engine's arrays live, as ``str(jax.Device)`` lists:
-        ``weights`` (the shared parameter copy) and ``prefill`` /
-        ``decode`` (output buffers of every executable that has run —
-        all of them after :meth:`warmup`)."""
+        ``weights`` (the shared parameter copy), ``pool`` (the K/V
+        planes) and ``prefill`` / ``decode`` (output buffers of every
+        executable that has run — all of them after :meth:`warmup`)."""
         def of(arrays):
             return sorted({str(d) for a in arrays
                            for d in a._data.devices()})
@@ -910,6 +875,7 @@ class DecodeEngine:
                    for p in bp._preds.values() for o in p.get_outputs()]
         decode = [o for p in self._decode.values() for o in p.get_outputs()]
         return {"weights": of(self._params.values()),
+                "pool": self.pool.devices(),
                 "prefill": of(prefill), "decode": of(decode)}
 
     def snapshot(self) -> dict:
@@ -919,6 +885,13 @@ class DecodeEngine:
                     "tokens_total": self.metrics.tokens.value,
                     "cold_decode_runs": self.cold_decode_runs(),
                     "prefix_cache_pages": self.prefix_cache_pages,
+                    # whether the step's program updates the planes in
+                    # place (None before it is built; False where the
+                    # executable may be serialized: executor.py)
+                    "step_donated": next(
+                        (p._exec.carry_donated
+                         for p in self._decode.values()
+                         if p._exec.carry_donated is not None), None),
                     "kv": self.pool.snapshot()}
             if self._draft is not None:
                 snap["draft"] = {
@@ -1055,42 +1028,37 @@ class DecodeEngine:
         # the draft holds no prefix cache: prefill EVERY admitted
         # sequence through the draft model so proposals can start from
         # the first decode iteration
+        def prefill(bp, pool, seqs):
+            """One prefill forward for ``seqs``; its K/V goes from the
+            program's outputs into the pool's planes on the device."""
+            items = []
+            for seq in seqs:
+                buf = np.zeros((L,), self._dtype)
+                buf[:len(seq.tokens)] = seq.tokens
+                items.append({"data": buf})
+            _, outs = bp.run_batch(items)
+            pool.write_prefill([s.sid for s in seqs],
+                               [o._data for o in outs[1:]],
+                               [len(s.tokens) for s in seqs])
+            return outs[0]._data  # logits (batch, L, vocab)
+
         if self._draft is not None:
-            dbp = self._draft_prefill[L]
-            items = []
+            prefill(self._draft_prefill[L], self._draft_pool, admitted)
             for seq in admitted:
-                buf = np.zeros((L,), self._dtype)
-                buf[:len(seq.tokens)] = seq.tokens
-                items.append({"data": buf})
-            _, results = dbp.forward_batch(items)
-            for seq, outs in zip(admitted, results):
-                n = len(seq.tokens)
-                for layer in range(self._draft["num_layers"]):
-                    self._draft_pool.write_prefill(
-                        seq.sid, layer, outs[1 + 2 * layer],
-                        outs[2 + 2 * layer], n)
-                seq.draft_pos = n
+                seq.draft_pos = len(seq.tokens)
         if misses:
-            bp = self._prefill[L]
-            items = []
-            for seq in misses:
-                buf = np.zeros((L,), self._dtype)
-                buf[:len(seq.tokens)] = seq.tokens
-                items.append({"data": buf})
-            _, results = bp.forward_batch(items)
-            for seq, outs in zip(misses, results):
+            logits = prefill(self._prefill[L], self.pool, misses)
+            rows = np.zeros((logits.shape[0],), np.int32)
+            rows[:len(misses)] = [len(s.tokens) - 1 for s in misses]
+            # the one read of a prefill: each prompt's last logits row
+            last = np.asarray(self._prefill_rows(logits, rows))
+            for i, seq in enumerate(misses):
                 n = len(seq.tokens)
-                logits = outs[0]  # (L, vocab)
-                for layer in range(self.num_layers):
-                    self.pool.write_prefill(seq.sid, layer,
-                                            outs[1 + 2 * layer],
-                                            outs[2 + 2 * layer], n)
                 seq.stream.prefill_tokens += n
                 seq.next_pos = n
                 if self.prefix_cache_pages:
                     self.pool.register_prefix(seq.sid, seq.tokens[:n])
-                tok = int(np.argmax(logits[n - 1]))
-                self._emit(seq, tok)
+                self._emit(seq, int(np.argmax(last[i])))
         if self.prefix_cache_pages:
             self._catchup_group([s for s in admitted if s not in misses])
         for seq in admitted:
@@ -1137,9 +1105,8 @@ class DecodeEngine:
                 table[i] = self.pool.page_table_row(seq.sid,
                                                     self.max_pages)
                 spans.append(span)
-            outs = self._run_lanes(pred, self.num_layers, self.pool,
-                                   data, positions, table)
-            logits = outs[0].reshape(b, W, -1)  # (lanes, width, vocab)
+            logits = self._run_lanes(pred, data, positions, table)
+            logits = logits.reshape(b, W, -1)  # (lanes, width, vocab)
             nxt = []
             for i, (seq, span) in enumerate(zip(pending, spans)):
                 seq.iters += 1
@@ -1284,33 +1251,27 @@ class DecodeEngine:
                         preempted += 1
             span.set(preempted=preempted)
 
-    def _run_lanes(self, pred, n_layers, pool, data, positions, table):
-        """Bind one lane-bucket executable, run it, write the pool
-        planes back, return the raw outputs."""
-        planes = sum(p.nbytes for p in pool.k_pools[:n_layers]) \
-            + sum(p.nbytes for p in pool.v_pools[:n_layers])
+    def _run_lanes(self, pred, data, positions, table):
+        """Run one lane-bucket executable and return its logits.  Ids,
+        positions and tables go up, the logits come down; the K/V planes
+        the executable binds are the pool's own and stay on the device
+        (``Executor.set_carried``)."""
+        import jax
+
+        args = pred._exec.arg_dict
         with _span("gen:pool_h2d", "gen",
-                   {"bytes": planes + data.nbytes + positions.nbytes
-                    + table.nbytes}):
-            pred.set_input("data", data)
-            pred.set_input("positions", positions)
-            pred.set_input("page_table", table)
-            for i in range(n_layers):
-                pred.set_input("layer%d_k_pool" % i, pool.k_pools[i])
-                pred.set_input("layer%d_v_pool" % i, pool.v_pools[i])
+                   {"bytes": data.nbytes + positions.nbytes + table.nbytes}):
+            feeds = jax.device_put((data, positions, table), self._device)
+            for name, fed in zip(("data", "positions", "page_table"), feeds):
+                args[name]._set(fed)
         with _span("gen:forward", "gen"):
             pred._exec.forward(is_train=False)
-        # the first read blocks until the device has run the step: this
-        # span holds the device's own work as well as the copy back
+        # the read blocks until the device has run the step: this span
+        # holds the device's own work as well as the logits' way down
         with _span("gen:pool_d2h", "gen") as span:
-            outs = [o.asnumpy() for o in pred.get_outputs()]
-            span.set(bytes=sum(o.nbytes for o in outs))
-        n_logits = len(outs) - 2 * n_layers
-        with _span("gen:pool_copyback", "gen", {"bytes": planes}):
-            for i in range(n_layers):
-                np.copyto(pool.k_pools[i], outs[n_logits + 2 * i])
-                np.copyto(pool.v_pools[i], outs[n_logits + 2 * i + 1])
-        return outs
+            logits = pred.get_output(0).asnumpy()
+            span.set(bytes=logits.nbytes)
+        return logits
 
     def _plain_step(self, active: List[_Seq]):
         """Advance every active lane one position through the decode
@@ -1330,9 +1291,7 @@ class DecodeEngine:
                 positions[i] = seq.next_pos  # slot the new K/V lands in
                 table[i] = self.pool.page_table_row(seq.sid,
                                                     self.max_pages)
-        outs = self._run_lanes(pred, self.num_layers, self.pool,
-                               data, positions, table)
-        logits = outs[0]
+        logits = self._run_lanes(pred, data, positions, table)
         self.metrics.steps.inc()
         with _span("gen:emit", "gen") as span:
             retired = []
@@ -1403,9 +1362,8 @@ class DecodeEngine:
                 lw += 1
             lane_width[seq.sid] = lw
         with _span("gen:verify", "gen", {"width": width}):
-            outs = self._run_lanes(vpred, self.num_layers, self.pool,
-                                   data, positions, table)
-        logits = outs[0].reshape(b, width, -1)
+            logits = self._run_lanes(vpred, data, positions, table)
+        logits = logits.reshape(b, width, -1)
         self.metrics.steps.inc()
         retired = []
         for i, seq in enumerate(active):
@@ -1455,7 +1413,6 @@ class DecodeEngine:
         for every steady lane.  Returns {sid: [d_1 .. d_K]}."""
         k = self._verify_width - 1
         pred = self._draft_decode[b]
-        dl = self._draft["num_layers"]
         rows = {s.sid: self._draft_pool.page_table_row(s.sid,
                                                        self.max_pages)
                 for s in active}
@@ -1471,8 +1428,7 @@ class DecodeEngine:
                     data[i] = seq.tokens[seq.draft_pos]
                     positions[i] = seq.draft_pos
                     table[i] = rows[seq.sid]
-            self._run_lanes(pred, dl, self._draft_pool,
-                            data, positions, table)
+            self._run_lanes(pred, data, positions, table)
             for seq in lag:
                 seq.draft_pos += 1
         proposals: Dict[object, List[int]] = {}
@@ -1500,9 +1456,7 @@ class DecodeEngine:
                 live.append((i, seq))
             if not live:
                 break
-            outs = self._run_lanes(pred, dl, self._draft_pool,
-                                   data, positions, table)
-            logits = outs[0]
+            logits = self._run_lanes(pred, data, positions, table)
             for i, seq in live:
                 d = int(np.argmax(logits[i]))
                 proposals[seq.sid].append(d)

@@ -9,9 +9,22 @@ PagedAttention layout): a sequence owns ``ceil(len / page_size)`` pages,
 listed in order in its page table, so live memory tracks live tokens and
 the pool admits as many sequences as actually fit.
 
+The K/V planes live on the pool's device and never visit the host: the
+decode step's programs take them as carried arguments and hand them back
+updated (``Executor.set_carried``), a prefill's K/V is scattered into
+them by one program (:meth:`PagedKVPool.write_prefill`), a copy-on-write
+split is one page copy over all layers.  Each of these donates the
+planes it is given (the step's does not where its executable may be
+serialized, executor.py), so the pool is their single owner: it holds
+them as NDArrays that every executor binds, and a finished program
+rebinds those NDArrays to its outputs before anything else can read
+them.  The host keeps the bookkeeping: free list, page tables,
+refcounts, prefix index.
+
 Page 0 is reserved as scratch: inactive decode lanes point their
 page-table rows at it so their masked-out writes land harmlessly
-(ops/paged.py).  Allocation is O(1) off a free list; exhaustion raises
+(ops/paged.py), and so do a prefill's padding rows.  Allocation is O(1)
+off a free list; exhaustion raises
 :class:`KVPoolExhaustedError` — the engine's admission backpressure and
 preemption signal, never a deadlock.
 
@@ -58,6 +71,35 @@ class KVPoolExhaustedError(MXNetError):
     """No free pages — backpressure: callers queue, shed, or preempt."""
 
 
+def _write_program(length: int):
+    """The scatter of one prefill length bucket: every plane takes its
+    slab's rows at ``slots`` (indices into the plane's flattened
+    ``num_pages * page_size`` token axis).  Donates the planes."""
+    import jax
+
+    def pool_write(planes, slabs, slots):
+        out = []
+        for plane, slab in zip(planes, slabs):
+            flat = plane.reshape((-1,) + plane.shape[2:])
+            rows = slab.reshape((-1,) + plane.shape[2:]).astype(flat.dtype)
+            out.append(flat.at[slots.reshape(-1)].set(rows)
+                       .reshape(plane.shape))
+        return out
+
+    pool_write.__name__ = "pool_write_L%d" % length
+    return jax.jit(pool_write, donate_argnums=(0,))
+
+
+def _copy_page_program():
+    """Page ``src`` onto page ``dst`` in every plane.  Donates the planes."""
+    import jax
+
+    def pool_copy_page(planes, src, dst):
+        return [p.at[dst].set(p[src]) for p in planes]
+
+    return jax.jit(pool_copy_page, donate_argnums=(0,))
+
+
 def _page_digest(prev: bytes, chunk) -> bytes:
     """Rolling content hash for one page worth of token ids: chains the
     previous page's digest so a digest identifies the ENTIRE prefix up
@@ -68,7 +110,8 @@ def _page_digest(prev: bytes, chunk) -> bytes:
 
 
 class PagedKVPool:
-    """Host-side paged K/V storage for ``num_layers`` attention layers.
+    """Paged K/V storage for ``num_layers`` attention layers: the planes
+    on the device, the bookkeeping on the host.
 
     Parameters
     ----------
@@ -79,15 +122,22 @@ class PagedKVPool:
         Tokens per page.
     num_layers, num_heads, head_dim : int
         K/V geometry; each layer holds one ``(num_pages, page_size,
-        num_heads, head_dim)`` K array and one V array.
+        num_heads, head_dim)`` K plane and one V plane (``k_pools`` /
+        ``v_pools``: NDArrays on ``ctx``).
     prefix_cache_pages : int, optional
         Upper bound on refcount-0 pages the prefix index retains after
         their last owner frees them (0, the default, disables prefix
         caching entirely — legacy alloc/free semantics).
+    ctx : Context, optional
+        Where the planes live (default: the current context).
     """
 
     def __init__(self, num_pages, page_size, num_layers, num_heads,
-                 head_dim, dtype=np.float32, prefix_cache_pages: int = 0):
+                 head_dim, dtype=np.float32, prefix_cache_pages: int = 0,
+                 ctx=None):
+        from .. import ndarray as nd
+        from ..context import current_context
+
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is reserved scratch)")
         if page_size < 1:
@@ -99,10 +149,13 @@ class PagedKVPool:
         self._dtype = np.dtype(dtype)
         shape = (self.num_pages, self.page_size, int(num_heads),
                  int(head_dim))
-        self.k_pools = [np.zeros(shape, self._dtype)
+        ctx = ctx or current_context()
+        self.k_pools = [nd.zeros(shape, ctx, dtype=self._dtype)
                         for _ in range(self.num_layers)]
-        self.v_pools = [np.zeros(shape, self._dtype)
+        self.v_pools = [nd.zeros(shape, ctx, dtype=self._dtype)
                         for _ in range(self.num_layers)]
+        self._writers: Dict[int, object] = {}  # prefill length -> program
+        self._copy_page = _copy_page_program()
         self._lock = threading.Lock()
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
         self._tables: Dict[object, List[int]] = {}
@@ -421,9 +474,7 @@ class PagedKVPool:
             self._reserve_locked(1)
             fresh = self._free.pop()
             self._ref[fresh] = 1
-            for layer in range(self.num_layers):
-                self.k_pools[layer][fresh] = self.k_pools[layer][page]
-                self.v_pools[layer][fresh] = self.v_pools[layer][page]
+            self.copy_page(page, fresh)
             pages[idx] = fresh
             self._release_page_locked(page)
             # the chain state survives a COW: digests are content-based
@@ -480,18 +531,64 @@ class PagedKVPool:
             row[:len(pages)] = pages
             return row
 
-    def write_prefill(self, seq_id, layer, k, v, length: int):
-        """Scatter a prefill pass's K/V (``(seq_len, heads, head_dim)``,
-        only the first ``length`` rows real) into the sequence's pages."""
-        with self._lock:
-            pages = self._tables[seq_id]
+    # -- the planes (device side) ----------------------------------------
+    def planes(self) -> list:
+        """The planes in the order the programs take and return them:
+        ``[k0, v0, k1, v1, ...]``."""
+        return [p for kv in zip(self.k_pools, self.v_pools) for p in kv]
+
+    def device_bytes(self) -> int:
+        return sum(int(np.prod(p.shape)) * p.dtype.itemsize
+                   for p in self.planes())
+
+    def devices(self) -> List[str]:
+        return sorted({str(d) for p in self.planes()
+                       for d in p._data.devices()})
+
+    def _run(self, program, *args):
+        """One donating program over the planes: the old buffers are dead
+        when it returns and the planes hold its outputs."""
+        planes = self.planes()
+        for plane, new in zip(planes,
+                              program([p._data for p in planes], *args)):
+            plane._set(new)
+
+    def write_slots(self, slabs, slots):
+        """Scatter ``slabs`` — ``[k0, v0, k1, v1, ...]``, each ``(batch,
+        length, heads, head_dim)``, on the device or the host — into the
+        planes on the device: row ``[b, t]`` of every slab goes to token
+        slot ``slots[b, t]`` (``page * page_size + offset``).  One program
+        a length bucket (``jit_pool_write_L<length>``)."""
+        length = int(slots.shape[1])
+        program = self._writers.get(length)
+        if program is None:
+            program = self._writers[length] = _write_program(length)
+        self._run(program, list(slabs), np.asarray(slots, np.int32))
+
+    def write_prefill(self, seq_ids, slabs, lengths):
+        """Scatter a prefill pass's K/V into its sequences' pages: batch
+        row ``b`` of the slabs (see :meth:`write_slots`) belongs to
+        ``seq_ids[b]``, of which only the first ``lengths[b]`` rows are
+        real.  Padding — the rest of a row, and batch rows beyond
+        ``seq_ids`` — lands in scratch page 0."""
         ps = self.page_size
-        kp, vp = self.k_pools[layer], self.v_pools[layer]
-        for start in range(0, int(length), ps):
-            page = pages[start // ps]
-            n = min(ps, int(length) - start)
-            kp[page, :n] = k[start:start + n]
-            vp[page, :n] = v[start:start + n]
+        slots = np.zeros(slabs[0].shape[:2], np.int32)
+        with self._lock:
+            for b, (seq_id, n) in enumerate(zip(seq_ids, lengths)):
+                pos = np.arange(int(n))
+                pages = np.asarray(self._tables[seq_id], np.int32)
+                slots[b, :int(n)] = pages[pos // ps] * ps + pos % ps
+        self.write_slots(slabs, slots)
+
+    def copy_page(self, src: int, dst: int):
+        """Page ``src`` onto page ``dst`` in every plane, one program."""
+        self._run(self._copy_page, np.int32(src), np.int32(dst))
+
+    def read_page(self, layer: int, page: int):
+        """``(k, v)`` of one page of one layer, read to the host: for
+        tests and debugging, nothing on the serving path reads a plane."""
+        return (self.k_pools[layer][int(page)].asnumpy(),
+                self.v_pools[layer][int(page)].asnumpy())
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -508,7 +605,8 @@ class PagedKVPool:
                     "prefix_misses": self._c_misses.value,
                     "prefix_evictions": self._c_evict.value,
                     "cow_copies": self._c_cow.value,
-                    "total_refcount": sum(self._ref.values())}
+                    "total_refcount": sum(self._ref.values()),
+                    "device_bytes": self.device_bytes()}
 
     def render_prometheus(self):
         """Collector hook for ``telemetry.render_prometheus()``."""

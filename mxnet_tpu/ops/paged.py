@@ -108,20 +108,29 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     pt = page_table.astype(jnp.int32)
     pos = positions.astype(jnp.int32)  # (lanes, width)
 
-    # -- write: the whole window's K/V into each lane's slots ------------
     flat_k = k_pool.reshape(num_pages * ps, heads, hd)
     flat_v = v_pool.reshape(num_pages * ps, heads, hd)
-    page_idx = jnp.take_along_axis(pt, pos // ps, axis=1)  # (lanes, width)
-    slot = (page_idx * ps + pos % ps).reshape(-1)
-    flat_k = flat_k.at[slot].set(k_new.astype(flat_k.dtype))
-    flat_v = flat_v.at[slot].set(v_new.astype(flat_v.dtype))
+    k_new = k_new.astype(flat_k.dtype)
+    v_new = v_new.astype(flat_v.dtype)
 
-    # -- gather ONCE: each lane's full history, in token order -----------
+    # -- gather ONCE: each lane's full history, in token order, from the
+    # pool as it came; the window's own K/V goes into the gathered copy
+    # at its positions.  The pool's update below is then a write of
+    # ``lanes * width`` rows that nothing in this step reads back.
     ctx_idx = (pt[:, :, None] * ps
                + jnp.arange(ps, dtype=jnp.int32)[None, None, :])
     ctx_idx = ctx_idx.reshape(lanes, max_pages * ps)
-    keys = flat_k[ctx_idx]    # (lanes, T, heads, hd)
-    vals = flat_v[ctx_idx]
+    lane = jnp.arange(lanes, dtype=jnp.int32)[:, None]
+    keys = flat_k[ctx_idx].at[lane, pos].set(   # (lanes, T, heads, hd)
+        k_new.reshape(lanes, width, heads, hd))
+    vals = flat_v[ctx_idx].at[lane, pos].set(
+        v_new.reshape(lanes, width, heads, hd))
+
+    # -- write: the whole window's K/V into each lane's slots ------------
+    page_idx = jnp.take_along_axis(pt, pos // ps, axis=1)  # (lanes, width)
+    slot = (page_idx * ps + pos % ps).reshape(-1)
+    flat_k = flat_k.at[slot].set(k_new)
+    flat_v = flat_v.at[slot].set(v_new)
 
     # -- causal masked attention, all width queries at once --------------
     qw = q.reshape(lanes, width, heads, hd)
@@ -158,7 +167,10 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
                         sequence order (float carrier, cast to int32 —
                         Predictor feeds every input as its bind dtype)
       positions       : (lanes,) this token's absolute position per lane
-    Returns (att_out, k_pool_out, v_pool_out).
+    Returns (att_out, k_pool_out, v_pool_out).  The engine carries the
+    pools through the step donated (``Executor.set_carried``), so the
+    ``.at[slot].set`` below updates them in place; undonated it copies
+    each pool once.
     """
     import jax.numpy as jnp
 
@@ -172,23 +184,30 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     pt = page_table.astype(jnp.int32)
     pos = positions.astype(jnp.int32)
 
-    # -- write: this step's K/V into each lane's current slot ------------
     flat_k = k_pool.reshape(num_pages * ps, heads, hd)
     flat_v = v_pool.reshape(num_pages * ps, heads, hd)
-    cur_page = jnp.take_along_axis(pt, (pos // ps)[:, None], axis=1)[:, 0]
-    slot = cur_page * ps + pos % ps  # (lanes,) — inactive lanes hit page 0
-    flat_k = flat_k.at[slot].set(k_new.astype(flat_k.dtype))
-    flat_v = flat_v.at[slot].set(v_new.astype(flat_v.dtype))
+    k_new = k_new.astype(flat_k.dtype)
+    v_new = v_new.astype(flat_v.dtype)
 
     # -- gather: each lane's full history, in token order ----------------
     # token t of a lane lives at page_table[lane, t // ps], offset t % ps,
     # so gathering the lane's pages in table order yields exactly tokens
-    # 0..max_pages*ps-1 at their flattened indices.
+    # 0..max_pages*ps-1 at their flattened indices.  The gather reads the
+    # pool as it came and this step's own K/V goes into the gathered copy
+    # at its position: the pool's update below is then a write of
+    # ``lanes`` rows that nothing in this step reads back.
     ctx_idx = (pt[:, :, None] * ps
                + jnp.arange(ps, dtype=jnp.int32)[None, None, :])
     ctx_idx = ctx_idx.reshape(lanes, max_pages * ps)
-    keys = flat_k[ctx_idx]    # (lanes, T, heads, hd)
-    vals = flat_v[ctx_idx]
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    keys = flat_k[ctx_idx].at[lane, pos].set(k_new)  # (lanes, T, heads, hd)
+    vals = flat_v[ctx_idx].at[lane, pos].set(v_new)
+
+    # -- write: this step's K/V into each lane's current slot ------------
+    cur_page = jnp.take_along_axis(pt, (pos // ps)[:, None], axis=1)[:, 0]
+    slot = cur_page * ps + pos % ps  # (lanes,) — inactive lanes hit page 0
+    flat_k = flat_k.at[slot].set(k_new)
+    flat_v = flat_v.at[slot].set(v_new)
 
     # -- masked softmax attention (local_attention numerics) -------------
     s = jnp.einsum("lhd,lthd->lht", q, keys).astype(jnp.float32) * scale

@@ -52,7 +52,9 @@ class Predictor:
         by ``save_checkpoint``) or a path to a ``.params`` file.
     input_shapes : dict
         ``{input_name: shape}`` — static shapes, like MXPredCreate's
-        input_keys/shape arrays.
+        input_keys/shape arrays.  An input that ``params`` also holds is
+        bound to that array and not to zeros of its own: state another
+        owner keeps on the device (the generation engine's KV pool).
     """
 
     def __init__(self, symbol, params, input_shapes: Dict[str, Sequence[int]],
@@ -93,7 +95,7 @@ class Predictor:
         args = {}
         self._synthesized = set()
         for name, shape in zip(arg_names, arg_shapes):
-            if name in self._input_shapes:
+            if name in self._input_shapes and name not in arg_params:
                 args[name] = nd.zeros(shape, self._ctx, dtype=self._dtype)
             elif name in arg_params:
                 if tuple(arg_params[name].shape) != tuple(shape):
@@ -115,6 +117,7 @@ class Predictor:
                 raise MXNetError("missing auxiliary state %r" % name)
             aux[name] = on_ctx(aux_params[name], self._ctx)
 
+        self._bound_inputs = set(self._input_shapes) & set(arg_params)
         self._exec = symbol.bind(self._ctx, args, args_grad=None,
                                  grad_req="null", aux_states=aux)
         self._input_names = list(self._input_shapes)
@@ -152,10 +155,12 @@ class Predictor:
         c_predict_api.cc:150-210).  Inputs not named keep their current
         shapes, matching the reference."""
         # synthesized (zero-filled) args are per-shape scratch, not model
-        # params: drop them so the new bind re-synthesizes at its shapes
+        # params: drop them so the new bind re-synthesizes at its shapes.
+        # Of the inputs only those bound to a given array go along.
         params = {("arg:%s" % k): v for k, v in self._exec.arg_dict.items()
-                  if k not in self._input_shapes
-                  and k not in self._synthesized}
+                  if k not in self._synthesized
+                  and (k not in self._input_shapes
+                       or k in self._bound_inputs)}
         params.update({("aux:%s" % k): v
                        for k, v in self._exec.aux_dict.items()})
         merged = dict(self._input_shapes)

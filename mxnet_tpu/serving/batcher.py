@@ -149,9 +149,10 @@ class BucketedPredictor:
                     out.append(fn)
         return out
 
-    def forward_batch(self, items: List[Dict[str, np.ndarray]]):
-        """Run one padded batch; returns per-item output lists (the batch
-        axis is stripped from every output that carries one)."""
+    def run_batch(self, items: List[Dict[str, np.ndarray]]):
+        """Pad ``items`` to their bucket and dispatch its executable;
+        returns ``(bucket, outputs)`` with the outputs still on the
+        device (batch axis and padding rows included)."""
         n = len(items)
         b = self.bucket_for(n)
         if b not in self.warmed_buckets:
@@ -165,9 +166,15 @@ class BucketedPredictor:
             pred.set_input(name, buf)
         pred._exec.forward(is_train=False)
         self.executor_calls += 1
-        outs = [o.asnumpy() for o in pred.get_outputs()]
+        return b, pred.get_outputs()
+
+    def forward_batch(self, items: List[Dict[str, np.ndarray]]):
+        """Run one padded batch; returns per-item output lists (the batch
+        axis is stripped from every output that carries one)."""
+        b, outs = self.run_batch(items)
+        outs = [o.asnumpy() for o in outs]
         per_item = []
-        for i in range(n):
+        for i in range(len(items)):
             per_item.append([o[i] if (o.ndim >= 1 and o.shape[0] == b) else o
                              for o in outs])
         return b, per_item

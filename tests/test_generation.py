@@ -544,7 +544,7 @@ def test_cow_isolation_never_mutates_shared_page():
     assert cached == 0  # cold index
     k = rng.randn(8, 2, 4).astype(np.float32)
     v = rng.randn(8, 2, 4).astype(np.float32)
-    pool.write_prefill("a", 0, k, v, 8)
+    pool.write_prefill(["a"], [k[None], v[None]], [8])
     assert pool.register_prefix("a", t) == 2  # both full pages published
     pool.free("a")  # refcount-0 pages retained as cache
 
@@ -552,20 +552,24 @@ def test_cow_isolation_never_mutates_shared_page():
     assert cached_b == 7  # capped at num_tokens - 1
     last = pages_b[1]
     assert pool.is_shared("b", 7)
-    before = pool.k_pools[0][last].copy()
+    before, _ = pool.read_page(0, last)
+    assert np.array_equal(before, k[4:8])  # what a's prefill put there
 
     assert pool.ensure_writable("b", 7)  # COW split
     row = pool.page_table_row("b", 4)
     assert int(row[1]) != last, "diverging seq still maps the shared page"
-    pool.k_pools[0][int(row[1])][3] = 99.0  # b writes its own copy
-    assert np.array_equal(pool.k_pools[0][last], before), \
+    own = int(row[1])
+    assert np.array_equal(pool.read_page(0, own)[0], before)  # the copy
+    pool.k_pools[0][own, 3] = 99.0  # b writes its own copy
+    assert np.all(pool.read_page(0, own)[0][3] == 99.0)
+    assert np.array_equal(pool.read_page(0, last)[0], before), \
         "COW leaked a write into the shared page"
     assert pool.snapshot()["cow_copies"] >= 1
 
     # a third request still hits the ORIGINAL bytes
     pages_c, cached_c = pool.alloc_prefix("c", 8, tokens=t)
     assert cached_c == 7 and pages_c[1] == last
-    assert np.array_equal(pool.k_pools[0][last], before)
+    assert np.array_equal(pool.read_page(0, last)[0], before)
     pool.free("b")
     pool.free("c")
     assert pool.total_refcount() == 0

@@ -1,7 +1,7 @@
 """Pool-level contract of the cross-request prefix cache: refcounted
 copy-on-write sharing, the bounded LRU index, content-addressed
-matching, and the fault hook that degrades lookups to misses.  Pure
-host-side data-structure tests — no XLA, so they run in milliseconds.
+matching, and the fault hook that degrades lookups to misses.  Tests of
+the host-side bookkeeping on a tiny pool, so they run in milliseconds.
 Engine-level behavior (zero prefill steps, TTFT, speculative parity)
 lives in test_generation.py."""
 import numpy as np
@@ -25,7 +25,7 @@ def _publish(pool, sid, tokens, seed=0):
     pool.alloc_prefix(sid, n, tokens=tokens)
     k = rng.randn(n, 2, 4).astype(np.float32)
     v = rng.randn(n, 2, 4).astype(np.float32)
-    pool.write_prefill(sid, 0, k, v, n)
+    pool.write_prefill([sid], [k[None], v[None]], [n])
     pool.register_prefix(sid, tokens)
     pool.free(sid)
 
