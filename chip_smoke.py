@@ -37,6 +37,11 @@ LM = dict(vocab=32000, hidden=2048, heads=16, layers=6)
 TRAIN = dict(LM, seq=4096, batch=4, steps=3, lr=3e-4)
 SERVE = dict(LM, max_seq=1024, lanes=8, page_size=16,
              prompt_lens=(128, 256, 512), new_tokens=16)
+# the decode cell's shapes (perfbench: cgpt13b-decode-closed), where the
+# paged-attention kernel is compared with the XLA formulation
+PAGED = dict(lanes=8, num_pages=176, page_size=16, heads=16, head_dim=128,
+             max_pages=128, positions=(63, 351))
+PAGED_TOL = 2e-2        # max|kernel - XLA| (XLA's products are one bf16 pass)
 LOSS0_BOUND = 1.0       # |step-0 loss - ln(vocab)|
 DP_LOSS_TOL = 0.05      # |dp4 loss - one-chip loss|, every step
 LOGITS_REL_TOL = 0.05   # max|tpu - cpu| prefill logits / max|cpu logits|
@@ -236,13 +241,59 @@ def _post_generate(host, port, prompt, max_new):
     return tokens, stamps, done
 
 
+def paged_kernel_check(shapes, ctx, seed=0):
+    """``_contrib_PagedAttention``'s kernel (compiled on a chip, interpreted
+    elsewhere) against the XLA formulation from the same pool: largest gap
+    of the attention's output, and the rows written."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import paged
+
+    dev = ctx.jax_device()
+    c = dict(shapes)
+    rng = np.random.RandomState(seed + 2)
+    at = rng.randint(c["positions"][0], c["positions"][1] + 1,
+                     size=c["lanes"])
+    free = list(rng.permutation(np.arange(1, c["num_pages"])))
+    table = np.zeros((c["lanes"], c["max_pages"]), np.int32)
+    for lane, pos in enumerate(at):
+        held = pos // c["page_size"] + 1
+        table[lane, :held] = [free.pop() for _ in range(held)]
+    row = (c["lanes"], c["heads"], c["head_dim"])
+    plane = (c["num_pages"], c["page_size"], c["heads"], c["head_dim"])
+    ops = [jax.device_put(rng.randn(*shape).astype(np.float32), dev)
+           for shape in (row, row, row, plane, plane)]
+    ops += [jax.device_put(table, dev),
+            jax.device_put(at.astype(np.int32), dev)]
+    scale = 1.0 / math.sqrt(c["head_dim"])
+    want = jax.jit(lambda *a: paged._gather_decode(*a, scale))(*ops)
+    got = jax.jit(lambda *a: paged._kernel_decode(
+        *a, scale, interpret=dev.platform != "tpu"))(*ops)
+    gap = float(jnp.abs(got[0] - want[0]).max())
+    check({d for g in got for d in g.devices()} == {dev},
+          "paged-attention kernel ran on %s" % dev)
+    check(gap <= PAGED_TOL and all(
+        bool(jnp.array_equal(g, w)) for g, w in zip(got[1:], want[1:])),
+        "paged-attention kernel vs the XLA formulation at %d lanes, %d pages "
+        "of %d, %d x %d, table width %d, positions %d-%d: max|diff| = %.2e "
+        "<= %.0e, written rows equal"
+        % (c["lanes"], c["num_pages"], c["page_size"], c["heads"],
+           c["head_dim"], c["max_pages"], at.min(), at.max(), gap,
+           PAGED_TOL))
+    return gap
+
+
 def server_phase(cfg, ctx, seed=0):
     """InferenceServer + generator on ``ctx`` behind its HTTP endpoint:
     >=4 ``POST /generate`` requests, two in flight at a time; the first
     prompt is sent twice.  Then the prefill logits of that prompt from a
-    ``Predictor`` on ``ctx`` against the same one bound on ``mx.cpu()``.
+    ``Predictor`` on ``ctx`` against the same one bound on ``mx.cpu()``, and
+    the paged-attention kernel against the XLA formulation at the decode
+    cell's shapes (``cfg["paged"]`` overrides them).
     Returns {"transcripts", "step_ms", "tokens_per_s", "logits_rel_diff",
-    "devices"}."""
+    "paged_kernel_gap", "devices"}."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -314,13 +365,14 @@ def server_phase(cfg, ctx, seed=0):
         gaps = sorted(b - a for _, st, _ in results
                       for a, b in zip(st, st[1:]))
         step_ms = gaps[len(gaps) // 2] * 1e3
-        say("decode: median inter-token gap %.1f ms (the whole KV pool "
-            "crosses the host boundary each step — ROADMAP A2), %.2f "
-            "tokens/s over %d tokens, %d steps, ttft_ms %s"
-            % (step_ms, total / wall, total, steps,
-               ["%.0f" % r[2]["ttft_ms"] for r in results]))
+        say("decode: median inter-token gap %.1f ms (the KV pool stays on "
+            "the device; paged attention: %s), %.2f tokens/s over %d tokens, "
+            "%d steps, ttft_ms %s"
+            % (step_ms, eng.snapshot()["paged_attention"], total / wall,
+               total, steps, ["%.0f" % r[2]["ttft_ms"] for r in results]))
     finally:
         srv.stop()
+    paged_gap = paged_kernel_check(cfg.get("paged", PAGED), ctx, seed)
 
     # prefill logits: ctx vs an explicit mx.cpu() bind — a named
     # comparison, not a fallback
@@ -346,7 +398,7 @@ def server_phase(cfg, ctx, seed=0):
           "%.2e <= %.0e" % (dev, rel, LOGITS_REL_TOL))
     return {"transcripts": transcripts, "step_ms": step_ms,
             "tokens_per_s": total / wall, "logits_rel_diff": rel,
-            "devices": where}
+            "paged_kernel_gap": paged_gap, "devices": where}
 
 
 # ---------------------------------------------------------------------------

@@ -323,10 +323,11 @@ class Executor:
                                 want_internals=internals, placement=placement)
 
             if self._carried:
-                run = fn
+                run, names = fn, self._carried_names()
 
                 def fn(carried, args, aux, rng):  # noqa: F811
-                    return run({**args, **carried}, aux, rng)
+                    return run({**args, **dict(zip(names, carried))}, aux,
+                               rng)
 
             fn.__name__ = self._program_name
             fn = self._bound(fn)
@@ -887,7 +888,17 @@ class Executor:
         aux = {k: v._data for k, v in self.aux_dict.items()}
         if not self._carried:
             return args, aux, rng
-        return {k: args.pop(k) for k in self._carried}, args, aux, rng
+        carried = tuple(args.pop(k) for k in self._carried_names())
+        return carried, args, aux, rng
+
+    def _carried_names(self):
+        """The carried arguments in the order of their outputs.  JAX pairs
+        a donated argument with the first unclaimed output of its shape
+        and dtype: handed over in another order (a dict's: ``layer10_``
+        sorts before ``layer1_``) equal-shaped planes are paired
+        crosswise, and XLA copies each one whole to put the result into
+        the buffer it was paired with."""
+        return sorted(self._carried, key=self._carried.get)
 
     def forward(self, is_train: bool = False, **kwargs):
         from . import ndarray as nd

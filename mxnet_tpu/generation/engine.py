@@ -79,6 +79,7 @@ from ..base import MXNetError, env, register_env
 from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
+from ..ops.paged import decode_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
 __all__ = ["DecodeEngine", "GenStream"]
@@ -892,6 +893,11 @@ class DecodeEngine:
                         (p._exec.carry_donated
                          for p in self._decode.values()
                          if p._exec.carry_donated is not None), None),
+                    # which formulation the lane program's attention runs
+                    # where this engine's planes live (ops/paged.py)
+                    "paged_attention": decode_formulation(
+                        self._device.platform, self.num_heads,
+                        self.hidden // self.num_heads, self._dtype),
                     "kv": self.pool.snapshot()}
             if self._draft is not None:
                 snap["draft"] = {
@@ -1214,11 +1220,16 @@ class DecodeEngine:
         lanes = len(self._active)
         with _span("gen:step", "gen",
                    {"lanes": lanes, "bucket": self._lane_bucket_for(lanes),
-                    "sids": "|".join(str(s.sid) for s in self._active)}):
+                    "sids": "|".join(str(s.sid) for s in self._active)}) \
+                as span:
             self._grow_lanes()
             active = list(self._active)
             if not active:
                 return
+            # the pages that hold the lanes' tokens up to this step's: what
+            # paged attention walks (times page_size: the live tokens)
+            span.set(pages=sum(seq.next_pos // self.page_size + 1
+                               for seq in active))
             if self._draft is not None:
                 self._spec_step(active)
             else:

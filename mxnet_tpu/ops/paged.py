@@ -1,7 +1,7 @@
 """Paged-KV attention ops — the decode-step kernels behind
 ``mxnet_tpu.generation`` (continuous batching + paged KV-cache).
 
-Two ops:
+Three ops:
 
 * ``_contrib_DenseAttention`` — plain dense softmax attention over
   ``[b, s, h, d]`` (the ``parallel.ring.local_attention`` oracle as a
@@ -14,18 +14,32 @@ Two ops:
   paged KV pool (the vLLM PagedAttention layout): each decode *lane*
   holds one live sequence whose K/V history lives in fixed-size pages of
   a shared pool, indirected through a per-lane page table.  The op
-  WRITES the lane's new K/V at ``positions[lane]`` into the pool, then
-  attends the lane's query against its own gathered history.  Because
+  attends the lane's query against its history as the pool holds it plus
+  this step's own K/V (taken from the projections, never read back), and
+  writes that K/V at ``positions[lane]`` into the pool: ``lanes`` rows.
+  Two formulations, one op (:func:`decode_formulation` picks by where the
+  operands live): on a TPU one Pallas kernel walks each lane's live pages
+  where they lie in the plane — ``positions[lane] // page_size`` page
+  reads a lane, not the table's whole width; anywhere else (every CPU
+  test, ``mx.cpu()`` serving) XLA gathers the whole table and masks,
+  which is also the kernel's oracle (tests/test_paged_kernel.py).  Because
   pools, page tables, and lane vectors are all fixed-shape, the whole
   decode step is ONE static XLA program per lane-count bucket — no
   per-sequence-length recompiles, which is the entire point
   (ISSUE 12 / Operator Fusion in XLA, arxiv 2301.13062).
+
+* ``_contrib_PagedAttentionWindow`` — ``width`` known tokens a lane in
+  one causal pass (prefix catch-up, re-admission, speculative verify).
+  It keeps the gather on every platform: ``width`` queries a lane want a
+  kernel of their own, and no benchmark cell runs it to judge one.
 
 Page 0 of the pool is reserved as a scratch page: inactive lanes carry
 an all-zero page-table row and position 0, so their (masked-out) writes
 land harmlessly in the scratch page and never corrupt a live sequence.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import numpy as np
@@ -85,6 +99,11 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     construction at a fraction of the gathers (2 per layer instead of
     2 per layer per token) and with every projection batched over
     ``lanes * width`` rows instead of ``lanes``.
+
+    This op gathers the table's whole width on a TPU too, where the
+    single-token op runs a kernel (:func:`decode_formulation`): a window
+    kernel is the follow-up once a benchmark cell with prefix reuse or
+    speculation exists to judge it.
 
     Shapes (all static):
       q, k_new, v_new : (lanes * width, heads, head_dim)
@@ -146,48 +165,31 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
             flat_v.reshape(num_pages, ps, heads, hd))
 
 
-@register("_contrib_PagedAttention",
-          inputs=("query", "key", "value", "k_pool", "v_pool",
-                  "page_table", "positions"),
-          params={"page_size": Param(int, required=True),
-                  "scale": Param("float-or-none", None)},
-          num_outputs=3, infer_shape=_paged_infer,
-          no_grad_inputs=("page_table", "positions"),
-          output_names=lambda attrs: ["out", "k_pool_out", "v_pool_out"],
-          hint="pagedattention")
-@jax.named_scope("paged_attention")
-def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
-                     page_table, positions):
-    """One decode step for ``lanes`` sequences at once.
+def decode_formulation(platform, heads, head_dim, dtype):
+    """Which formulation ``_contrib_PagedAttention`` runs: ``"pallas"`` —
+    the kernel that reads the live pages where they lie — where the
+    operands live on a TPU and a token's K (or V) is whole float32 tiles
+    (a page is then one contiguous block of the plane, the DMA's unit);
+    ``"xla"`` — the gather over the whole table — anywhere else.  An
+    observation of the operands, as ``interpret.interpret_for`` is for the
+    flash kernels: no attribute, environment variable or autotune entry
+    chooses."""
+    tiled = (np.dtype(dtype) == np.float32 and head_dim % 128 == 0
+             and heads % 8 == 0)
+    return "pallas" if platform == "tpu" and tiled else "xla"
 
-    Shapes (all static):
-      q, k_new, v_new : (lanes, heads, head_dim) — this step's projections
-      k_pool, v_pool  : (num_pages, page_size, heads, head_dim)
-      page_table      : (lanes, max_pages) pool-page ids per lane, in
-                        sequence order (float carrier, cast to int32 —
-                        Predictor feeds every input as its bind dtype)
-      positions       : (lanes,) this token's absolute position per lane
-    Returns (att_out, k_pool_out, v_pool_out).  The engine carries the
-    pools through the step donated (``Executor.set_carried``), so the
-    ``.at[slot].set`` below updates them in place; undonated it copies
-    each pool once.
-    """
+
+def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
+    """The XLA formulation, and the kernel's oracle: gather every lane's
+    whole table (``max_pages * page_size`` slots), put this step's own K/V
+    into the gathered copy at its position, masked softmax; the pool's
+    update is a scatter of ``lanes`` rows."""
     import jax.numpy as jnp
 
-    ps = int(attrs["page_size"])
-    lanes, heads, hd = q.shape
-    num_pages = k_pool.shape[0]
-    max_pages = page_table.shape[1]
-    scale = attrs.get("scale")
-    scale = (1.0 / np.sqrt(hd)) if scale is None else float(scale)
-
-    pt = page_table.astype(jnp.int32)
-    pos = positions.astype(jnp.int32)
-
+    num_pages, ps, heads, hd = k_pool.shape
+    lanes, max_pages = pt.shape
     flat_k = k_pool.reshape(num_pages * ps, heads, hd)
     flat_v = v_pool.reshape(num_pages * ps, heads, hd)
-    k_new = k_new.astype(flat_k.dtype)
-    v_new = v_new.astype(flat_v.dtype)
 
     # -- gather: each lane's full history, in token order ----------------
     # token t of a lane lives at page_table[lane, t // ps], offset t % ps,
@@ -217,6 +219,198 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     p = jnp.exp(s - s.max(-1, keepdims=True))
     p = p / p.sum(-1, keepdims=True)
     out = jnp.einsum("lht,lthd->lhd", p, vals).astype(q.dtype)
-    return (out,
-            flat_k.reshape(num_pages, ps, heads, hd),
-            flat_v.reshape(num_pages, ps, heads, hd))
+    return out, flat_k.reshape(k_pool.shape), flat_v.reshape(v_pool.shape)
+
+
+# page slots per plane in VMEM; one less is in flight.  At the cell's shapes
+# (a page of 131 KB) 7 x 2 pages cover the DMA's latency at the HBM's rate.
+_RING = 8
+
+
+def _decode_kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, k_in, v_in,
+                   o_ref, k_out, v_out, work, qs, m_ref, l_ref, acc, kbuf,
+                   vbuf, sems, row_sems, *, scale, max_pages):
+    """One call, all lanes.  Every lane's live pages, in lane then table
+    order, stream from the planes where they lie through a ring of VMEM page slots
+    while an online softmax (float32 on the VPU: exact products) folds
+    each page into its lane's running max, sum and weighted values.  The
+    planes come in and go out as the same buffers (``k_out`` aliases
+    ``k_in``): the kernel's only write to them is ``lanes`` rows."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = q_ref.shape[0]
+    ring, ps = kbuf.shape[0], kbuf.shape[1]
+
+    # this step's K/V rows into each lane's current slot (inactive lanes
+    # hit the scratch page).  No page read below uses that slot — it holds
+    # the token AT the lane's position, the reads stop before it — so the
+    # rows fly while the pages stream and are waited for at the end.
+    def rows(lane):
+        at = pos_ref[lane]
+        page = pt_ref[lane * max_pages + at // ps]
+        return (pltpu.make_async_copy(kn_ref.at[lane],
+                                      k_out.at[page, at % ps],
+                                      row_sems.at[0, lane]),
+                pltpu.make_async_copy(vn_ref.at[lane],
+                                      v_out.at[page, at % ps],
+                                      row_sems.at[1, lane]))
+
+    def each(copies, method):
+        def run(n, carry):
+            for copy in copies(n):
+                getattr(copy, method)()
+            return carry
+        return run
+
+    lax.fori_loop(0, lanes, each(rows, "start"), None)
+
+    # the work list: lane * max_pages + table column, once per page that
+    # holds a token before the lane's position.  A lane at position 0 (an
+    # inactive lane among them) has none: it attends its own token alone.
+    def lane_pages(lane, n):
+        def page(col, n):
+            work[n] = lane * max_pages + col
+            return n + 1
+        return lax.fori_loop(0, (pos_ref[lane] + ps - 1) // ps, page, n)
+
+    total = lax.fori_loop(0, lanes, lane_pages, 0)
+
+    def pages(n):
+        slot, page = n % ring, pt_ref[work[n]]
+        return (pltpu.make_async_copy(k_in.at[page], kbuf.at[slot],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_in.at[page], vbuf.at[slot],
+                                      sems.at[1, slot]))
+
+    lax.fori_loop(0, jnp.minimum(ring - 1, total), each(pages, "start"),
+                  None)
+
+    # this step's own token opens every lane's softmax: its K/V come from
+    # the projections, not from the pool
+    qs[...] = q_ref[...] * scale
+    m_ref[...] = jnp.sum(qs[...] * kn_ref[...], axis=-1, keepdims=True)
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc[...] = vn_ref[...]
+
+    def fold(n, carry):
+        @pl.when(n + ring - 1 < total)
+        def _():
+            each(pages, "start")(n + ring - 1, None)
+
+        lane, col = work[n] // max_pages, work[n] % max_pages
+        slot = n % ring
+        each(pages, "wait")(n, None)
+        s = jnp.sum(kbuf[slot] * qs[lane][None], axis=-1,
+                    keepdims=True)                        # (ps, heads, 1)
+        token = col * ps + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(token < pos_ref[lane], s, _NEG)
+        m_old = m_ref[lane]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new[None])  # a masked slot underflows to 0.0
+        m_ref[lane] = m_new
+        l_ref[lane] = alpha * l_ref[lane] + jnp.sum(p, axis=0)
+        acc[lane] = alpha * acc[lane] + jnp.sum(p * vbuf[slot], axis=0)
+        return carry
+
+    lax.fori_loop(0, total, fold, None)
+    o_ref[...] = acc[...] / l_ref[...]
+    lax.fori_loop(0, lanes, each(rows, "wait"), None)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _kernel_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale,
+                   interpret=False):
+    """The kernel's call: page table and positions as scalar-prefetch
+    operands (a page id is a DMA's source index), the planes left where
+    they lie, in the layout they have, and aliased to their outputs — a
+    donated plane then goes through the call in place, uncopied and
+    unstaged (an undonated one is copied once, as for the scatter) — and
+    everything else whole in VMEM.  The planes are NOT pinned to HBM
+    (``pltpu.HBM``): an undonated pool small enough for fast memory then
+    aborts the TPU compiler's memory-space assignment.  Jitted on its own
+    so that a lane program's 24 call sites trace and lower the kernel
+    once, not 24 times, at every start (the persistent cache's key needs
+    the lowered program)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, hd = q.shape
+    ps = k_pool.shape[1]
+    max_pages = pt.shape[1]
+    f32 = jnp.float32
+    whole = pl.BlockSpec((lanes, heads, hd), lambda i, *_: (0, 0, 0))
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    plane = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, max_pages=max_pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[whole, whole, whole, in_place, in_place],
+            out_specs=[whole, in_place, in_place],
+            scratch_shapes=[
+                pltpu.SMEM((lanes * max_pages,), jnp.int32),  # work list
+                pltpu.VMEM((lanes, heads, hd), f32),          # scaled q
+                pltpu.VMEM((lanes, heads, 1), f32),           # running max
+                pltpu.VMEM((lanes, heads, 1), f32),           # running sum
+                pltpu.VMEM((lanes, heads, hd), f32),          # weighted V
+                pltpu.VMEM((_RING, ps, heads, hd), f32),
+                pltpu.VMEM((_RING, ps, heads, hd), f32),
+                pltpu.SemaphoreType.DMA((2, _RING)),
+                pltpu.SemaphoreType.DMA((2, lanes))]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), plane, plane],
+        # operands count the two scalar-prefetch ones: the planes are 5, 6
+        input_output_aliases={5: 1, 6: 2},
+        name="paged_decode", interpret=interpret,
+    )(pt.reshape(-1), pos, q, k_new, v_new, k_pool, v_pool)
+
+
+@register("_contrib_PagedAttention",
+          inputs=("query", "key", "value", "k_pool", "v_pool",
+                  "page_table", "positions"),
+          params={"page_size": Param(int, required=True),
+                  "scale": Param("float-or-none", None)},
+          num_outputs=3, infer_shape=_paged_infer,
+          no_grad_inputs=("page_table", "positions"),
+          output_names=lambda attrs: ["out", "k_pool_out", "v_pool_out"],
+          hint="pagedattention")
+@jax.named_scope("paged_attention")
+def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
+                     page_table, positions):
+    """One decode step for ``lanes`` sequences at once.
+
+    Shapes (all static):
+      q, k_new, v_new : (lanes, heads, head_dim) — this step's projections
+      k_pool, v_pool  : (num_pages, page_size, heads, head_dim)
+      page_table      : (lanes, max_pages) pool-page ids per lane, in
+                        sequence order (float carrier, cast to int32 —
+                        Predictor feeds every input as its bind dtype)
+      positions       : (lanes,) this token's absolute position per lane
+    Returns (att_out, k_pool_out, v_pool_out).  The attention reads the
+    pool as it came and takes this step's own K/V from the projections; on
+    a TPU through the kernel, elsewhere through the gather
+    (:func:`decode_formulation`).  The engine carries the pools through
+    the step donated (``Executor.set_carried``), so the write of
+    ``lanes`` rows updates them in place; undonated it copies each pool
+    once.
+    """
+    import jax.numpy as jnp
+
+    from .interpret import platform_of
+
+    heads, hd = q.shape[-2:]
+    if int(attrs["page_size"]) != k_pool.shape[1]:
+        raise ValueError("page_size %s, but the pool's pages hold %d slots"
+                         % (attrs["page_size"], k_pool.shape[1]))
+    scale = attrs.get("scale")
+    scale = (1.0 / np.sqrt(hd)) if scale is None else float(scale)
+    decode = {"pallas": _kernel_decode, "xla": _gather_decode}[
+        decode_formulation(platform_of(q, k_pool, v_pool), heads, hd,
+                           k_pool.dtype)]
+    return decode(q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
+                  k_pool, v_pool, page_table.astype(jnp.int32),
+                  positions.astype(jnp.int32), scale)

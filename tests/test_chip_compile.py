@@ -118,3 +118,92 @@ def test_flash_attention_vjp_through_the_public_call(one_chip):
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert any(re.search(r"(?<![A-Za-z0-9])%s(?![A-Za-z0-9])" % kernel, n)
                    for n in names), (kernel, names)
+
+
+# the decode cell's shapes (perfbench: cgpt13b-decode-closed)
+DECODE = dict(lanes=8, heads=16, head_dim=128, page_size=16, num_pages=176,
+              max_pages=128)
+
+
+def test_paged_decode_kernel_compiles_for_v5e(one_chip):
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import paged
+
+    d = DECODE
+    f32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    row = f32(d["lanes"], d["heads"], d["head_dim"])
+    plane = f32(d["num_pages"], d["page_size"], d["heads"], d["head_dim"])
+
+    def step(q, k_new, v_new, k_pool, v_pool, table, at):
+        return paged._kernel_decode(q, k_new, v_new, k_pool, v_pool, table,
+                                    at, 1.0 / np.sqrt(d["head_dim"]))
+
+    text = jax.jit(step, donate_argnums=(3, 4)).lower(
+        row, row, row, plane, plane, i32(d["lanes"], d["max_pages"]),
+        i32(d["lanes"])).compile().as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert len(names) == 1 and names[0].startswith("paged_decode"), names
+    # donated, the planes go through the call in place: no copy of one
+    assert not [line for line in text.splitlines()
+                if "f32[176,16,16,128]" in line
+                and re.search(r" copy(-start)?\(", line)]
+
+
+@pytest.mark.parametrize("donated", [True, False])
+def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
+    """The whole decode step of a 12-layer model at the cell's widths, as
+    the engine's Executor builds it (planes carried), compiled for the
+    chip: 12 kernels and, donated, no copy of a plane, staged or plain.
+    Twelve layers because ``layer10`` sorts before ``layer1``: carried
+    arguments handed over in a dict's order are paired crosswise with the
+    outputs and XLA copies every plane.  Undonated (the rule under the
+    framework's compile cache) each plane is copied once and the program
+    still compiles: with the kernel's planes pinned to HBM this small pool
+    aborted the compiler's memory-space assignment."""
+    import re
+
+    import jax
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import compile_cache
+    from mxnet_tpu.models.transformer import get_transformer_lm_decode
+    from mxnet_tpu.ops.interpret import bind
+
+    # (earlier tests of this worker may have left a bundle attached)
+    monkeypatch.setattr(compile_cache, "active", lambda: not donated)
+    d = DECODE
+    layers, vocab, hidden = 12, 512, d["heads"] * d["head_dim"]
+    num_pages, max_pages = 24, 8
+    symbol = get_transformer_lm_decode(
+        vocab, layers, d["heads"], hidden, max_seq_len=128,
+        lanes=d["lanes"], num_pages=num_pages, page_size=d["page_size"],
+        max_pages=max_pages)
+    shapes = {"data": (d["lanes"],), "positions": (d["lanes"],),
+              "page_table": (d["lanes"], max_pages)}
+    planes = ["layer%d_%s_pool" % (i, kv) for i in range(layers)
+              for kv in "kv"]
+    shapes.update({name: (num_pages, d["page_size"], d["heads"],
+                          d["head_dim"]) for name in planes})
+    ex = symbol.simple_bind(mx.cpu(), grad_req="null", **shapes)
+    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
+    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
+    carried, args, aux, rng = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ex._forward_args(None))
+    assert ex._carried_names() == planes
+    fwd = ex._get_fwd(False)
+    assert ex.carry_donated == donated
+    text = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
+        .compile().as_text()
+    assert len(re.findall(r"= [^\n]*\"tpu_custom_call\"", text)) == layers
+    plane = "f32[%d,16,16,128]" % num_pages
+    copied = [line for line in text.splitlines() if plane in line
+              and re.search(r" copy(-start)?\(", line)]
+    assert not copied if donated else len(copied) >= 2 * layers

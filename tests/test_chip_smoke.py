@@ -75,7 +75,11 @@ def test_server_phase_toy_width_on_cpu():
 
     out = chip_smoke.server_phase(
         dict(TOY_LM, max_seq=64, lanes=4, page_size=8,
-             prompt_lens=(8, 16, 32), new_tokens=6), mx.tpu(0))
+             prompt_lens=(8, 16, 32), new_tokens=6,
+             paged=dict(lanes=2, num_pages=9, page_size=4, heads=2,
+                        head_dim=8, max_pages=4, positions=(3, 14))),
+        mx.tpu(0))
+    assert out["paged_kernel_gap"] <= 1e-5  # interpreted: float32 both
     assert len(out["transcripts"]) == 4
     assert out["transcripts"][0] == out["transcripts"][-1]
     assert out["logits_rel_diff"] <= chip_smoke.LOGITS_REL_TOL
