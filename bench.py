@@ -69,9 +69,6 @@ def _arg_parser():
     ap.add_argument("--lm-hidden", type=int, default=2048)
     ap.add_argument("--lm-layers", type=int, default=6)
     ap.add_argument("--lm-batch", type=int, default=4)
-    ap.add_argument("--lm-attn", default="flash",
-                    choices=["flash", "splash"],
-                    help="attention backend for the LM metric (A/B)")
     ap.add_argument("--in-process", action="store_true",
                     help="single-process mode (for callers already "
                          "holding the TPU); default CLI orchestrates "
@@ -180,9 +177,7 @@ def _lm_fields(record, cli):
     lm = transformer_lm_bench(seq_len=cli.lm_seq_len,
                               hidden=cli.lm_hidden,
                               num_layers=cli.lm_layers,
-                              batch_size=cli.lm_batch,
-                              attn_impl=cli.lm_attn)
-    record["transformer_lm_attn"] = cli.lm_attn
+                              batch_size=cli.lm_batch)
     record["transformer_lm_tokens_per_sec"] = round(
         lm["tokens_per_sec"], 1)
     record["transformer_lm_step_ms"] = round(lm["step_time_ms"], 1)
@@ -193,12 +188,9 @@ def _lm_fields(record, cli):
 
 
 def transformer_lm_bench(seq_len=4096, hidden=2048, num_layers=6,
-                         batch_size=4, num_steps=10, warmup=2,
-                         attn_impl="flash"):
+                         batch_size=4, num_steps=10, warmup=2):
     """Model-level transformer-LM train-step benchmark through the Module
-    fused path (in-process; the TPU is held by this process).
-    ``attn_impl``: "flash" (in-tree kernels) or "splash" (upstream) for
-    A/B at the model level."""
+    fused path (in-process; the TPU is held by this process)."""
     import argparse as _ap
 
     from examples.transformer import train_lm
@@ -214,8 +206,7 @@ def transformer_lm_bench(seq_len=4096, hidden=2048, num_layers=6,
 
     net = mx.models.get_transformer_lm(
         vocab_size=args.vocab_size, num_layers=args.num_layers,
-        num_heads=args.num_heads, hidden=args.hidden, seq_len=args.seq_len,
-        attn_impl=attn_impl)
+        num_heads=args.num_heads, hidden=args.hidden, seq_len=args.seq_len)
     return train_lm.benchmark(args, net)
 
 
@@ -364,8 +355,7 @@ def _run_phase(phase, cli, timeout):
                    "--lm-seq-len", str(cli.lm_seq_len),
                    "--lm-hidden", str(cli.lm_hidden),
                    "--lm-layers", str(cli.lm_layers),
-                   "--lm-batch", str(cli.lm_batch),
-                   "--lm-attn", cli.lm_attn]
+                   "--lm-batch", str(cli.lm_batch)]
     if cli.batch_size:
         passthrough += ["--batch-size", str(cli.batch_size)]
     if cli.skip_attention:

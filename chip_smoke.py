@@ -98,8 +98,7 @@ def trainer_phase(cfg, contexts, seed=0):
     platform = contexts[0].jax_device().platform
     net = mx.models.get_transformer_lm(
         vocab_size=cfg["vocab"], num_layers=cfg["layers"],
-        num_heads=cfg["heads"], hidden=cfg["hidden"], seq_len=cfg["seq"],
-        attn_impl="flash")
+        num_heads=cfg["heads"], hidden=cfg["hidden"], seq_len=cfg["seq"])
     rng = np.random.RandomState(seed)
     X = rng.randint(0, cfg["vocab"],
                     size=(cfg["batch"], cfg["seq"])).astype(np.float32)
@@ -196,8 +195,7 @@ def make_lm_params(cfg, ctx, seed=0):
 
     net = mx.models.get_transformer_lm(
         vocab_size=cfg["vocab"], num_layers=cfg["layers"],
-        num_heads=cfg["heads"], hidden=cfg["hidden"], seq_len=cfg["max_seq"],
-        attn_impl="flash")
+        num_heads=cfg["heads"], hidden=cfg["hidden"], seq_len=cfg["max_seq"])
     shapes, _, _ = net.infer_shape(data=(1, cfg["max_seq"]),
                                    softmax_label=(1, cfg["max_seq"]))
     rng = np.random.RandomState(seed)

@@ -2,7 +2,7 @@
 (model, topology), pay the tuning cost once per fleet.
 
 ROADMAP item 1 promoted the manual perf loop (a human sweeping
-``tools/flash_ab.py`` block configs by hand) into a framework
+flash-attention block configs by hand) into a framework
 subsystem, following the TVM autotuning loop (arXiv 1802.04799) with
 XLA cost analysis as the cheap proxy objective in the spirit of a
 learned TPU cost model (arXiv 2008.01040):

@@ -378,7 +378,8 @@ class DecodeEngine:
         from .. import ndarray as nd
         from ..models.transformer import (get_transformer_lm_catchup,
                                           get_transformer_lm_decode,
-                                          get_transformer_lm_prefill)
+                                          get_transformer_lm_prefill,
+                                          lane_plane_names)
         from ..predictor import Predictor, on_ctx
 
         self.vocab_size = int(vocab_size)
@@ -508,13 +509,10 @@ class DecodeEngine:
             reshape; pool shapes are lane-independent).  Every one binds
             the pool's own planes, carried: the step updates them in
             place and nothing uploads or reads them."""
-            kw = {} if width is None else {"width": width}
             with NameManager():
                 symbol = get_symbol(
                     self.vocab_size, pool.num_layers, n_heads, n_hidden,
-                    max_seq_len=self.max_seq_len, lanes=self.max_lanes,
-                    num_pages=self.num_pages, page_size=self.page_size,
-                    max_pages=self.max_pages, **kw)
+                    max_seq_len=self.max_seq_len, page_size=self.page_size)
 
             def feeds(b):
                 shape = (b,) if width is None else (b, width)
@@ -522,8 +520,7 @@ class DecodeEngine:
                         "page_table": (b, self.max_pages)}
 
             # outputs: the logits, then the planes in the pool's order
-            names = ["layer%d_%s_pool" % (i, kv)
-                     for i in range(pool.num_layers) for kv in "kv"]
+            names = lane_plane_names(pool.num_layers)
             planes = dict(zip(names, pool.planes()))
             carried = {name: 1 + i for i, name in enumerate(names)}
             shapes = feeds(self.max_lanes)

@@ -39,31 +39,12 @@ _NEG = -1e30
 # log (the ring/backward contract).
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
-
-
-def _use_exp2():
-    """MXTPU_FLASH_EXP2=0 reverts the softmax to natural-exp (A/B switch).
-    Read at TRACE time: an already-jitted step keeps the variant it was
-    traced with — rebuild the jit (as tools/flash_ab.py's harness does per
-    run) for a flip to take effect."""
-    import os
-
-    return os.environ.get("MXTPU_FLASH_EXP2", "1") == "1"
-
-
-def _compiler_params(pltpu):
-    """Grid semantics hint (bh/q-tile parallel, stream dim sequential),
-    OFF by default: measured on v5e (tools/flash_ab.py, s=8k d=128), the
-    hint made the train step ~40% slower and run-to-run erratic when
-    combined with the exp2 softmax (20.7 vs 34.3 TFLOP/s at bq=512
-    bk=1024); Mosaic's default sequential pipelining double-buffers the
-    streamed blocks fine on its own. MXTPU_FLASH_DIMSEM=1 re-enables."""
-    import os
-
-    if os.environ.get("MXTPU_FLASH_DIMSEM", "0") != "1":
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))}
+# The three pallas_calls below pass no ``dimension_semantics`` grid hint
+# (bh and q-tile parallel, the stream dim sequential), on a chip
+# measurement: on v5e at s=8k d=128 the hint made the train step ~40%
+# slower and erratic from run to run (20.7 against 34.3 TFLOP/s at bq=512
+# bk=1024); Mosaic's default sequential pipelining double-buffers the
+# streamed blocks on its own.
 
 
 def _reference_attention(q, k, v, causal, scale):
@@ -98,7 +79,7 @@ def _pick_block(block, seq):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, bq, bk, nk, scale, causal, exp2):
+                *, bq, bk, nk, scale, causal):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -127,16 +108,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         vblk = v_ref[0]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
-            * (scale * _LOG2E if exp2 else scale)
+            * (scale * _LOG2E)
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG)
-        expf = jnp.exp2 if exp2 else jnp.exp
         m = m_scr[...]
         m_new = jnp.maximum(m, s.max(axis=-1))
-        p = expf(s - m_new[:, None])
-        corr = expf(m - m_new)
+        p = jnp.exp2(s - m_new[:, None])
+        corr = jnp.exp2(m - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
         acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
             p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
@@ -149,10 +129,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lsafe = jnp.where(l > 0, l, 1.0)
         o_ref[0] = (acc_scr[...] / lsafe[:, None]).astype(o_ref.dtype)
         # back to natural log at the boundary (ring/backward contract)
-        if exp2:
-            lse_ref[0, 0] = (m_scr[...] + jnp.log2(lsafe)) * _LN2
-        else:
-            lse_ref[0, 0] = m_scr[...] + jnp.log(lsafe)
+        lse_ref[0, 0] = (m_scr[...] + jnp.log2(lsafe)) * _LN2
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -172,7 +149,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk,
-                               scale=scale, causal=causal, exp2=_use_exp2())
+                               scale=scale, causal=causal)
     # lse carries a singleton middle dim so its block's trailing dims
     # (1, bq) satisfy the Mosaic tiling rule (second-to-last equals the
     # array dim, last divisible by 128); squeezed before returning
@@ -197,13 +174,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        # bh and q-tile iterations are independent (parallel); the k
-        # stream is the sequential dim carrying the softmax state — the
-        # semantics let Mosaic overlap the K/V block DMAs with compute
         interpret=interpret,
         # the kernel's name in the compiled program and the device trace
         name="flash_fwd",
-        **_compiler_params(pltpu),
     )(qt, kt, vt)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse.reshape(b * h, sq)
 
@@ -214,7 +187,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_scr, *, bq, bk, nk, scale, causal, exp2):
+                   acc_scr, *, bq, bk, nk, scale, causal):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -238,15 +211,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
-            * (scale * _LOG2E if exp2 else scale)
+            * (scale * _LOG2E)
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG)
-        # p is the same probability either way; only the exponential's
-        # base changes (s and lse both carried in the base-2 domain)
-        p = (jnp.exp2(s - (lse * _LOG2E)[:, None]) if exp2
-             else jnp.exp(s - lse[:, None]))
+        # s and lse both carried in the base-2 domain
+        p = jnp.exp2(s - (lse * _LOG2E)[:, None])
         dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta[:, None]) * scale).astype(kblk.dtype)
@@ -261,7 +232,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, bq, bk, nq, scale,
-                    causal, exp2):
+                    causal):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -286,13 +257,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
-            * (scale * _LOG2E if exp2 else scale)
+            * (scale * _LOG2E)
         if causal:
             q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG)
-        p = (jnp.exp2(s - (lse * _LOG2E)[:, None]) if exp2
-             else jnp.exp(s - lse[:, None]))  # [bq, bk]
+        p = jnp.exp2(s - (lse * _LOG2E)[:, None])  # [bq, bk]
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)  # [bk, d]
@@ -339,7 +309,6 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
     nq, nk = sq // bq, sk // bk
-    exp2 = _use_exp2()  # one read: dq and dk/dv kernels share the variant
     if pre is None:
         pre = _flash_bwd_precompute(q, o, lse, do)
     qt, dot, lse3, delta3 = pre
@@ -356,7 +325,7 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, nk=nk, scale=scale,
-                          causal=causal, exp2=exp2),
+                          causal=causal),
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),   # q
@@ -371,12 +340,11 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         scratch_shapes=[scratch((bq, d))],
         interpret=interpret,
         name="flash_bwd_dq",
-        **_compiler_params(pltpu),
     )(qt, kt, vt, dot, lse3, delta3)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, nq=nq, scale=scale,
-                          causal=causal, exp2=exp2),
+                          causal=causal),
         grid=(b * h, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),   # q
@@ -395,7 +363,6 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         scratch_shapes=[scratch((bk, d)), scratch((bk, d))],
         interpret=interpret,
         name="flash_bwd_dkv",
-        **_compiler_params(pltpu),
     )(qt, kt, vt, dot, lse3, delta3)
 
     unflat = lambda t, s: t.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -577,53 +544,9 @@ def _fa_bwd(attrs, res, ct):
     return over_batch_shards(kernel)(q, k, v, o, lse, ct)
 
 
-def splash_attention(q, k, v, causal: bool = True, scale=None,
-                     interpret=None):
-    """Upstream splash-attention backend (jax.experimental.pallas.ops.tpu)
-    behind this framework's [b, seq, heads, d] layout — the mature,
-    internally-pipelined TPU kernel, offered as an alternative attention
-    implementation for A/B against the in-tree flash kernels (PERF.md's
-    ceiling reference). Interpret mode off a TPU device (ops/interpret.py),
-    so CPU tests exercise the real wrapper. Splash applies no logit scaling itself; q is pre-scaled
-    here, and gradients flow through splash's own custom vjp."""
-    import jax
-
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as _sk,
-        splash_attention_mask as _mk,
-    )
-
-    b, s, h, d = q.shape
-    if scale is None:
-        scale = 1.0 / np.sqrt(d)
-    interpret = interpret_for("splash_attention", (q, k, v), interpret)
-    mk_one = (_mk.CausalMask((s, s)) if causal
-              else _mk.FullMask((s, s)))
-    if s % 128:
-        # splash's lane constraint: every block dimension must be a
-        # multiple of 128 — shorter/odd sequences use the in-tree flash
-        # kernels (which clamp blocks to the sequence)
-        raise ValueError(
-            "splash_attention requires seq_len to be a multiple of 128 "
-            "(got %d); use the flash implementation instead" % s)
-    kern = _sk.make_splash_mha_single_device(
-        mask=_mk.MultiHeadMask([mk_one for _ in range(h)]),
-        interpret=interpret)
-    import jax.numpy as jnp
-
-    # scale in q's dtype: an np.float64 scalar would upcast bf16 q to
-    # f32 and break the kernel's matching-operand-dtype requirement
-    qt = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    o = jax.vmap(kern)(qt, kt, vt)
-    return o.transpose(0, 2, 1, 3)
-
-
 def _register():
     from .pallas_op import register_pallas_op
     from .param import Param
-    from .registry import register
 
     # dogfooding the public user-kernel API — mx.register_pallas_op IS how
     # this framework's own flash attention becomes an op (MXRtc parity,
@@ -638,20 +561,6 @@ def _register():
                 "block_k": Param("int-or-none", None)},
         infer_shape=lambda attrs, s: (s, [s[0]], []),
         hint="flashattention")
-
-    # plain registration (no custom fwd/bwd): splash ships its own
-    # custom_vjp, so the executor's jax.vjp differentiates through it
-    @register("_contrib_SplashAttention",
-              inputs=("query", "key", "value"),
-              params={"causal": Param(bool, True),
-                      "scale": Param("float-or-none", None)},
-              infer_shape=lambda attrs, shapes: (shapes, [shapes[0]], []),
-              hint="splashattention")
-    def _splash_op(opctx, attrs, query, key, value):
-        scale = attrs.get("scale")
-        return splash_attention(query, key, value,
-                                causal=bool(attrs.get("causal", True)),
-                                scale=scale)
 
 
 _register()

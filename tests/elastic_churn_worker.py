@@ -17,7 +17,12 @@ Env contract (beyond the usual DMLC_* worker vars):
 * ``CHURN_JOIN_STEP``    — step at which survivors gate until the
   mid-run joiner shows up in the membership table (default 6); the
   joiner starts its own loop at this step.
-* ``CHURN_EXPECT_MEMBERS`` — live-set size the gate waits for (default 3).
+* ``CHURN_EXPECT_MEMBERS`` — live-set size both gates wait for (default
+  3).  The launch-time workers also gate BEFORE step 0 until all of them
+  are members: an elastic barrier counts the members there are, so a
+  worker whose interpreter started late (a loaded host) would otherwise
+  find its peers rounds ahead, push its own ``CHURN_TOTAL_STEPS`` rounds
+  after them, and the weight would overshoot the invariant.
 * ``CHURN_KILL_RANK`` / ``CHURN_FAULTS_SPEC`` / ``CHURN_FAULTS_SEED`` —
   the victim installs the seeded FaultPlan IN-PROCESS (only the matching
   rank, never a joiner): a plain ``MXNET_FAULTS_SPEC`` env would reach
@@ -57,6 +62,18 @@ def main():
 
     kv = kvstore.create("dist_async")
     kv.init("w", mx.nd.zeros((4,)))
+
+    def wait_members(what):
+        deadline = time.monotonic() + 60.0
+        while len(kv.membership()["ranks"]) < expect:
+            if time.monotonic() > deadline:
+                print(json.dumps({"rank": rank, "error": what}), flush=True)
+                sys.exit(4)
+            time.sleep(0.05)
+
+    if not is_joiner:
+        # start gate: the rounds are counted from a full fleet
+        wait_members("launch-time fleet never complete")
     target = float(n_total * num_workers) * grad_c
     grad = mx.nd.ones((4,)) * grad_c
     out = mx.nd.zeros((4,))
@@ -68,14 +85,7 @@ def main():
         if not is_joiner and it == j_sync:
             # grow gate: wait for the mid-run joiner so post-join rounds
             # demonstrably count the full live set
-            deadline = time.monotonic() + 60.0
-            while len(kv.membership()["ranks"]) < expect:
-                if time.monotonic() > deadline:
-                    print(json.dumps({"rank": rank,
-                                      "error": "joiner never arrived"}),
-                          flush=True)
-                    sys.exit(4)
-                time.sleep(0.05)
+            wait_members("joiner never arrived")
         kv.push("w", grad)
         kv._barrier()
         steps += 1

@@ -21,7 +21,7 @@ def _cli(extra=()):
 def test_headline_prefers_lm_mfu():
     rec = {"metric": "resnet50_train_throughput", "value": 2400.0,
            "unit": "img/s", "vs_baseline": 13.2,
-           "transformer_lm_mfu": 0.514, "transformer_lm_attn": "flash"}
+           "transformer_lm_mfu": 0.514}
     out = bench._headline(dict(rec))
     assert out["metric"] == "transformer_lm_train_mfu"
     assert out["value"] == 0.514
@@ -79,11 +79,9 @@ def test_run_phase_passthrough_flags(monkeypatch):
 
     monkeypatch.setattr(bench.subprocess, "run", fake_run)
     bench._run_phase("resnet", _cli(["--skip-transformer",
-                                     "--skip-attention",
-                                     "--lm-attn", "splash"]), timeout=5)
+                                     "--skip-attention"]), timeout=5)
     cmd = seen["cmd"]
     assert "--skip-transformer" in cmd and "--skip-attention" in cmd
-    assert cmd[cmd.index("--lm-attn") + 1] == "splash"
     assert cmd[cmd.index("--phase") + 1] == "resnet"
 
 def test_lm_phase_skips_off_tpu():

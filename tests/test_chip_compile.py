@@ -173,7 +173,8 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
 
     import mxnet_tpu as mx
     from mxnet_tpu import compile_cache
-    from mxnet_tpu.models.transformer import get_transformer_lm_decode
+    from mxnet_tpu.models.transformer import (get_transformer_lm_decode,
+                                              lane_plane_names)
     from mxnet_tpu.ops.interpret import bind
 
     # (earlier tests of this worker may have left a bundle attached)
@@ -183,12 +184,10 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     num_pages, max_pages = 24, 8
     symbol = get_transformer_lm_decode(
         vocab, layers, d["heads"], hidden, max_seq_len=128,
-        lanes=d["lanes"], num_pages=num_pages, page_size=d["page_size"],
-        max_pages=max_pages)
+        page_size=d["page_size"])
     shapes = {"data": (d["lanes"],), "positions": (d["lanes"],),
               "page_table": (d["lanes"], max_pages)}
-    planes = ["layer%d_%s_pool" % (i, kv) for i in range(layers)
-              for kv in "kv"]
+    planes = lane_plane_names(layers)
     shapes.update({name: (num_pages, d["page_size"], d["heads"],
                           d["head_dim"]) for name in planes})
     ex = symbol.simple_bind(mx.cpu(), grad_req="null", **shapes)

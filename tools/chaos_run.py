@@ -174,10 +174,14 @@ def run_membership_churn(seed, timeout=120.0, workers=3, steps=10,
                 env=dict(base, DMLC_WORKER_ID=str(workers),
                          MXNET_KVSTORE_ELASTIC_JOIN="1"),
                 stdout=subprocess.PIPE, text=True)
-            grown = wait_members(lambda m: len(m["ranks"]) == workers,
+            # the joiner's join is the next bump.  Wait on the generation,
+            # not on the set: the survivors gate on the joiner, then need
+            # four rounds to finish and leave, which can fall between
+            # two polls on a loaded host
+            grown = wait_members(lambda m: m["gen"] >= workers + 2,
                                  "mid-run join")
-            print("chaos_run: membership grew back to %s (gen %d)"
-                  % (grown["ranks"], grown["gen"]),
+            print("chaos_run: membership grew back (gen %d, now %s)"
+                  % (grown["gen"], grown["ranks"]),
                   file=sys.stderr, flush=True)
             for r, p in procs.items():
                 out, _ = p.communicate(
