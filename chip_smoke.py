@@ -283,6 +283,43 @@ def paged_kernel_check(shapes, ctx, seed=0):
     return gap
 
 
+def lane_pick_check(eng, seed=0):
+    """The decode lane program picks its own tokens and feeds them on
+    (generation/engine.py, ``_plain_step``): on an idle engine, over pages
+    of its own, the ids it picks are the host's argmax of the logits it
+    returns, and a step fed on the device (``source``) returns bit for bit
+    the logits of the same step fed from the host."""
+    import numpy as np
+
+    b = eng.max_lanes
+    pred = eng._decode[b]
+    rng = np.random.RandomState(seed + 2)
+    sids = ["smoke-%d" % i for i in range(b)]
+    for sid in sids:
+        eng.pool.alloc(sid, 2)
+    try:
+        table = np.stack([eng.pool.page_table_row(sid, eng.max_pages)
+                          for sid in sids]).astype(np.float32)
+        ids = rng.randint(0, eng.vocab_size, size=b).astype(np.float32)
+        at = np.zeros((b,), np.float32)
+        logits = eng._run_lanes(pred, ids, at, table)
+        picked = pred._exec.arg_dict["prev_ids"].asnumpy()
+        check(np.array_equal(picked, logits.argmax(-1)),
+              "the lane program's picks are the host's argmax of its "
+              "logits: %s" % picked.astype(int).tolist())
+        # position 1 twice over the same K/V: lane i takes lane b-1-i's pick
+        back = np.arange(b, dtype=np.float32)[::-1].copy()
+        fed = eng._dispatch_lanes(pred, np.zeros_like(ids), at + 1, table,
+                                  source=back)[0].asnumpy()
+        host = eng._run_lanes(pred, picked[::-1].copy(), at + 1, table)
+        check(np.array_equal(fed, host),
+              "a step fed on the device returns the logits of the step fed "
+              "from the host, bit for bit")
+    finally:
+        for sid in sids:
+            eng.pool.free(sid)
+
+
 def server_phase(cfg, ctx, seed=0):
     """InferenceServer + generator on ``ctx`` behind its HTTP endpoint:
     >=4 ``POST /generate`` requests, two in flight at a time; the first
@@ -368,6 +405,14 @@ def server_phase(cfg, ctx, seed=0):
             "%d steps, ttft_ms %s"
             % (step_ms, eng.snapshot()["paged_attention"], total / wall,
                total, steps, ["%.0f" % r[2]["ttft_ms"] for r in results]))
+        snap = eng.snapshot()
+        check(0 < snap["steps_overlapped"] <= snap["steps"]
+              and snap["tokens_dropped"] == 0,
+              "one step in flight: %d of %d steps dispatched before the "
+              "step ahead was read, %d tokens dropped"
+              % (snap["steps_overlapped"], snap["steps"],
+                 snap["tokens_dropped"]))
+        lane_pick_check(eng, seed)
     finally:
         srv.stop()
     paged_gap = paged_kernel_check(cfg.get("paged", PAGED), ctx, seed)

@@ -46,9 +46,10 @@ XLA discipline: every XLA-visible shape here is static.
   through per-lane page tables into a shared paged KV pool
   (:mod:`.kv_pool`) whose planes stay on the device — every lane
   program binds them as carried arguments and updates them in place;
-  a step sends ids, positions and page tables up and reads the logits
-  down — compiled ONCE per lane-count bucket and primed
-  through the PR 10 compile cache (entry kinds ``gen-step`` /
+  a step sends ids, positions and page tables up and reads ``lanes``
+  picked ids down, one iteration late (the plain step keeps one step in
+  flight: see ``_plain_step``) — compiled ONCE per lane-count bucket and
+  primed through the PR 10 compile cache (entry kinds ``gen-step`` /
   ``gen-prefill`` / ``gen-verify`` / ``gen-draft-step`` /
   ``gen-draft-prefill``), so AOT bundles restore a generate-ready
   replica with zero cold compiles.
@@ -67,7 +68,7 @@ import logging
 import queue
 import threading
 import time
-from collections import deque
+from collections import deque, namedtuple
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -268,8 +269,11 @@ class _Seq:
 
     ``next_pos`` is the feed cursor: the position whose token goes into
     the NEXT decode/verify slot (every position below it has final K/V
-    materialized in the pool).  Steady state keeps ``next_pos ==
-    len(tokens) - 1``; a cached-prefix admission starts it at the hit
+    materialized in the pool, or will have by the step in flight).  It
+    advances when a step is dispatched: steady state keeps ``next_pos ==
+    len(tokens) - 1`` once that step is read and ``len(tokens)`` while it
+    is in flight (the token to feed is then still on the device); a
+    cached-prefix admission starts it at the hit
     length, a partial hit or a re-admitted preemptee walks the known
     suffix forward one slot per step without emitting.  ``draft_pos``
     is the same cursor for the draft model's pool; ``limit`` is
@@ -295,6 +299,12 @@ class _Seq:
         self.limit = len(stream.prompt) + self.max_new
 
 
+# A decode step that was dispatched and not yet read: its executable, its
+# lanes as ``(sequence, position fed)`` and the device array of the ids it
+# picks.
+_Flight = namedtuple("_Flight", "pred lanes ids")
+
+
 class _GenMetrics:
     """Telemetry collector for one engine: token throughput, TTFT/ITL
     histograms, admission/retire/preempt counters, lane occupancy, and
@@ -310,6 +320,11 @@ class _GenMetrics:
         self.rejected = reg.counter("mxtpu_gen_sequences_rejected_total")
         self.failed = reg.counter("mxtpu_gen_sequences_failed_total")
         self.steps = reg.counter("mxtpu_gen_decode_steps_total")
+        # steps dispatched before the step ahead of them was read, and
+        # tokens such a step computed for a lane that had retired meanwhile
+        self.steps_overlapped = reg.counter(
+            "mxtpu_gen_decode_steps_overlapped_total")
+        self.tokens_dropped = reg.counter("mxtpu_gen_tokens_dropped_total")
         self.cold_steps = reg.counter("mxtpu_gen_decode_cold_steps_total")
         self.cached_admissions = reg.counter(
             "mxtpu_gen_prefix_cached_admissions_total")
@@ -516,10 +531,14 @@ class DecodeEngine:
 
             def feeds(b):
                 shape = (b,) if width is None else (b, width)
-                return {"data": shape, "positions": shape,
-                        "page_table": (b, self.max_pages)}
+                out = {"data": shape, "positions": shape,
+                       "page_table": (b, self.max_pages)}
+                if width is None:  # the decode graph picks and feeds on
+                    out.update(source=shape, prev_ids=shape)
+                return out
 
             # outputs: the logits, then the planes in the pool's order
+            # (then, of the decode graph, the picked ids)
             names = lane_plane_names(pool.num_layers)
             planes = dict(zip(names, pool.planes()))
             carried = {name: 1 + i for i, name in enumerate(names)}
@@ -596,10 +615,11 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         def prefill_rows(logits, rows):
-            return jnp.take_along_axis(logits, rows[:, None, None],
-                                       axis=1)[:, 0]
+            return jnp.argmax(jnp.take_along_axis(
+                logits, rows[:, None, None], axis=1)[:, 0], axis=-1)
 
-        # (batch, L, vocab) logits -> each prompt's last row, on the device
+        # (batch, L, vocab) logits -> the greedy id of each prompt's last
+        # row, on the device
         self._prefill_rows = jax.jit(prefill_rows)
 
         # recompile-detector bookkeeping: lane buckets warmup compiled,
@@ -611,6 +631,8 @@ class DecodeEngine:
         self._cv = threading.Condition()
         self._pending: deque = deque()  # _Seq, FIFO (preempted go front)
         self._active: List[_Seq] = []
+        # the plain step dispatched and not yet read (engine thread only)
+        self._inflight: Optional[_Flight] = None
         self._sid = 0
         self._closed = False
         self._drain = True
@@ -660,7 +682,7 @@ class DecodeEngine:
             bp.warmup()
             # what a prefill does with the outputs, once per shape: the
             # scatter into the planes (scratch page 0 here), and for the
-            # target the pick of each prompt's last logits row
+            # target the pick of each prompt's first token
             for b, pred in bp._preds.items():
                 outs = pred.get_outputs()
                 slabs = [o._data for o in outs[1:]]
@@ -881,6 +903,9 @@ class DecodeEngine:
             snap = {"pending": len(self._pending),
                     "active": len(self._active),
                     "tokens_total": self.metrics.tokens.value,
+                    "steps": self.metrics.steps.value,
+                    "steps_overlapped": self.metrics.steps_overlapped.value,
+                    "tokens_dropped": self.metrics.tokens_dropped.value,
                     "cold_decode_runs": self.cold_decode_runs(),
                     "prefix_cache_pages": self.prefix_cache_pages,
                     # whether the step's program updates the planes in
@@ -912,9 +937,10 @@ class DecodeEngine:
         while True:
             with self._cv:
                 while not self._pending and not self._active \
-                        and not self._closed:
+                        and self._inflight is None and not self._closed:
                     self._cv.wait(0.05)
                 if self._closed and not self._active and \
+                        self._inflight is None and \
                         (not self._pending or not self._drain):
                     for seq in self._pending:
                         seq.stream._finish(ServerClosedError(
@@ -923,10 +949,11 @@ class DecodeEngine:
                     return
             try:
                 self._admit()
-                if self._active:
+                if self._active or self._inflight is not None:
                     self._decode_step()
             except BaseException as exc:  # fault-injected or real: contain
                 logging.warning("generation engine step failed: %r", exc)
+                self._inflight = None  # its lanes fail with the rest
                 with self._cv:
                     self._fail_all_locked(exc)
                 _telemetry.log_event("gen_engine_error", error=repr(exc))
@@ -1053,15 +1080,15 @@ class DecodeEngine:
             logits = prefill(self._prefill[L], self.pool, misses)
             rows = np.zeros((logits.shape[0],), np.int32)
             rows[:len(misses)] = [len(s.tokens) - 1 for s in misses]
-            # the one read of a prefill: each prompt's last logits row
-            last = np.asarray(self._prefill_rows(logits, rows))
+            # the one read of a prefill: each prompt's first token
+            first = np.asarray(self._prefill_rows(logits, rows))
             for i, seq in enumerate(misses):
                 n = len(seq.tokens)
                 seq.stream.prefill_tokens += n
                 seq.next_pos = n
                 if self.prefix_cache_pages:
                     self.pool.register_prefix(seq.sid, seq.tokens[:n])
-                self._emit(seq, int(np.argmax(last[i])))
+                self._emit(seq, int(first[i]))
         if self.prefix_cache_pages:
             self._catchup_group([s for s in admitted if s not in misses])
         for seq in admitted:
@@ -1214,31 +1241,32 @@ class DecodeEngine:
         one token via the decode executable, or up to K+1 via the
         draft/verify speculative pass."""
         faults.fire("generation.engine.step")
-        lanes = len(self._active)
-        with _span("gen:step", "gen",
-                   {"lanes": lanes, "bucket": self._lane_bucket_for(lanes),
-                    "sids": "|".join(str(s.sid) for s in self._active)}) \
-                as span:
-            self._grow_lanes()
-            active = list(self._active)
-            if not active:
+        # a lane whose budget ends with the token the step in flight picks
+        # takes no part in this one: the host knows that a step early
+        due = [s for s in self._active if s.next_pos + 1 < s.limit]
+        if not due:
+            # nothing to dispatch: what is left awaits the step in flight
+            with _span("gen:drain", "gen") as span:
+                flight, self._inflight = self._inflight, None
+                self._read_step(flight, span)
+            return
+        with _span("gen:step", "gen") as span:
+            self._grow_lanes(due)
+            lanes = [s for s in due if s in self._active]
+            if not lanes:
                 return
-            # the pages that hold the lanes' tokens up to this step's: what
-            # paged attention walks (times page_size: the live tokens)
-            span.set(pages=sum(seq.next_pos // self.page_size + 1
-                               for seq in active))
             if self._draft is not None:
-                self._spec_step(active)
+                self._spec_step(lanes, span)
             else:
-                self._plain_step(active)
+                self._plain_step(lanes, span)
 
-    def _grow_lanes(self):
+    def _grow_lanes(self, lanes: List[_Seq]):
         """Extend every lane's pages to the positions this iteration
         writes, preempting the youngest other lane when the pool is out."""
         width = self._verify_width
         with _span("gen:grow", "gen") as span:
             preempted = 0
-            for seq in list(self._active):
+            for seq in lanes:
                 # an earlier lane's extend may have preempted this one
                 while seq in self._active:
                     try:
@@ -1259,65 +1287,151 @@ class DecodeEngine:
                         preempted += 1
             span.set(preempted=preempted)
 
-    def _run_lanes(self, pred, data, positions, table):
-        """Run one lane-bucket executable and return its logits.  Ids,
-        positions and tables go up, the logits come down; the K/V planes
-        the executable binds are the pool's own and stay on the device
-        (``Executor.set_carried``)."""
+    def _describe_step(self, span, lanes: List[_Seq], b: int, **more):
+        """What a ``gen:step`` span says of the step it dispatches."""
+        span.set(lanes=len(lanes), bucket=b,
+                 sids="|".join(str(s.sid) for s in lanes),
+                 # the pages that hold the lanes' tokens up to this step's:
+                 # what paged attention walks (times page_size: the live
+                 # tokens)
+                 pages=sum(s.next_pos // self.page_size + 1 for s in lanes),
+                 **more)
+
+    def _dispatch_lanes(self, pred, data, positions, table, source=None):
+        """Upload one lane-bucket executable's feeds and dispatch it;
+        returns its outputs, unread.  Ids, positions and tables go up; the
+        K/V planes the executable binds are the pool's own and stay on
+        the device (``Executor.set_carried``).  The decode graph also
+        takes ``source`` (``None``: every lane feeds ``data``) and hands
+        its picked ids on to its own next call."""
         import jax
 
         args = pred._exec.arg_dict
+        feeds = {"data": data, "positions": positions, "page_table": table}
+        picks = "source" in args  # the decode graph
+        if picks:
+            feeds["source"] = (np.full(data.shape, -1, self._dtype)
+                               if source is None else source)
         with _span("gen:pool_h2d", "gen",
-                   {"bytes": data.nbytes + positions.nbytes + table.nbytes}):
-            feeds = jax.device_put((data, positions, table), self._device)
-            for name, fed in zip(("data", "positions", "page_table"), feeds):
+                   {"bytes": sum(v.nbytes for v in feeds.values())}):
+            put = jax.device_put(tuple(feeds.values()), self._device)
+            for name, fed in zip(feeds, put):
                 args[name]._set(fed)
         with _span("gen:forward", "gen"):
-            pred._exec.forward(is_train=False)
+            outs = pred._exec.forward(is_train=False)
+        if picks:
+            # rebound, not carried: a carried argument is donated, and the
+            # engine reads these ids after the next call's dispatch
+            args["prev_ids"]._set(outs[-1]._data)
+        return outs
+
+    def _run_lanes(self, pred, data, positions, table):
+        """Run one lane-bucket executable and return its logits."""
+        outs = self._dispatch_lanes(pred, data, positions, table)
         # the read blocks until the device has run the step: this span
         # holds the device's own work as well as the logits' way down
         with _span("gen:pool_d2h", "gen") as span:
-            logits = pred.get_output(0).asnumpy()
+            logits = outs[0].asnumpy()
             span.set(bytes=logits.nbytes)
         return logits
 
-    def _plain_step(self, active: List[_Seq]):
-        """Advance every active lane one position through the decode
-        executable: feed ``tokens[next_pos]`` at ``next_pos``, emit the
-        argmax only when the cursor crosses into generation (a lane
-        re-walking a known suffix — partial cache hit, re-admitted
-        preemptee — just materializes K/V silently)."""
-        b = self._lane_bucket_for(len(active))
+    def _plain_step(self, lanes: List[_Seq], span):
+        """Advance every lane one position through the decode executable,
+        one step in flight: this iteration's step is dispatched BEFORE the
+        ids of the step ahead of it are read, because the program picks the
+        token and feeds it on (``prev_ids`` / ``source``) and the host
+        knows positions, page tables and budgets without it.  What it does
+        not know is an EOS: such a lane rides one step more, and that
+        token is dropped.  A page is never handed to another sequence by a
+        program dispatched before the last step that names it: the device
+        runs programs in dispatch order and ``pool.free`` of a lane comes
+        after the dispatch of the last step that fed it."""
+        prev, self._inflight = self._inflight, None
+        b = self._lane_bucket_for(len(lanes))
+        early = prev is not None and prev.pred is not self._decode[b]
+        if early:
+            # the ids in flight are another lane count's program's: they
+            # come down first and every lane is fed from the host
+            self._read_step(prev, span)
+            prev = None
+            lanes = [s for s in lanes if s in self._active]
+            if not lanes:
+                return
+            b = self._lane_bucket_for(len(lanes))
+        self._inflight = self._dispatch_step(lanes, b, prev, span)
+        if not early:
+            self._read_step(prev, span)
+
+    def _dispatch_step(self, lanes: List[_Seq], b: int,
+                       prev: Optional[_Flight], span) -> _Flight:
+        """Feed ``tokens[next_pos]`` at ``next_pos`` for every lane and
+        dispatch the step.  A lane whose token the host does not hold yet
+        (``next_pos == len(tokens)``: ``prev`` is computing it) takes it on
+        the device from its lane of ``prev``."""
         self._note_lane_bucket(b)
-        pred = self._decode[b]
+        came = {seq.sid: j for j, (seq, _) in enumerate(prev.lanes)} \
+            if prev is not None else {}
         with _span("gen:feed", "gen"):
             data = np.zeros((b,), self._dtype)
             positions = np.zeros((b,), self._dtype)
+            source = np.full((b,), -1, self._dtype)
             table = np.zeros((b, self.max_pages), self._dtype)
-            for i, seq in enumerate(active):
-                data[i] = seq.tokens[seq.next_pos]
+            for i, seq in enumerate(lanes):
+                if seq.next_pos < len(seq.tokens):
+                    data[i] = seq.tokens[seq.next_pos]
+                else:
+                    source[i] = came[seq.sid]
                 positions[i] = seq.next_pos  # slot the new K/V lands in
                 table[i] = self.pool.page_table_row(seq.sid,
                                                     self.max_pages)
-        logits = self._run_lanes(pred, data, positions, table)
+        self._describe_step(span, lanes, b, inflight=int(prev is not None),
+                            fed_device=int((source >= 0).sum()))
+        outs = self._dispatch_lanes(self._decode[b], data, positions, table,
+                                    source)
         self.metrics.steps.inc()
-        with _span("gen:emit", "gen") as span:
+        if prev is not None:
+            self.metrics.steps_overlapped.inc()
+        flight = _Flight(self._decode[b], [(s, s.next_pos) for s in lanes],
+                         outs[-1]._data)
+        for seq in lanes:
+            seq.next_pos += 1
+        return flight
+
+    def _read_step(self, flight: Optional[_Flight], span):
+        """Read the ids a dispatched step picked (``None``: nothing was in
+        flight, nothing is read) and stream the token of every lane whose
+        cursor crossed into generation; a lane re-walking a known suffix
+        (partial cache hit, re-admitted preemptee) just materialized K/V.
+        A lane that left since the dispatch (an EOS seen a step late, a
+        preemption, a hand-off) rode the step for nothing: its token is
+        dropped, and greedy decode computes it again if it is needed."""
+        lanes, ids = (flight.lanes, flight.ids) if flight is not None \
+            else ((), np.zeros((0,), self._dtype))
+        # the read blocks until the device has run the step
+        with _span("gen:pool_d2h", "gen") as d2h:
+            ids = np.asarray(ids)
+            d2h.set(bytes=ids.nbytes)
+        with _span("gen:emit", "gen") as emit:
             retired = []
-            emitted = 0
-            for i, seq in enumerate(active):
+            emitted = dropped = 0
+            for i, (seq, pos) in enumerate(lanes):
+                emits = pos + 1 >= len(seq.tokens)
+                if seq not in self._active:
+                    dropped += emits
+                    continue
                 seq.iters += 1
-                seq.next_pos += 1
                 if self.prefix_cache_pages:
-                    self.pool.register_prefix(seq.sid,
-                                              seq.tokens[:seq.next_pos])
-                if seq.next_pos >= len(seq.tokens):
+                    self.pool.register_prefix(seq.sid, seq.tokens[:pos + 1])
+                if emits:
                     emitted += 1
-                    if self._emit(seq, int(np.argmax(logits[i]))):
+                    if self._emit(seq, int(ids[i])):
                         retired.append(seq)
             self._drop_retired(retired)
-            span.set(emitted=emitted, retired=len(retired))
+            self.metrics.tokens_dropped.inc(dropped)
+            emit.set(emitted=emitted, retired=len(retired))
+        span.set(dropped=dropped)
 
-    def _spec_step(self, active: List[_Seq]):
+    def _spec_step(self, active: List[_Seq], span):
         """One speculative iteration: draft K proposals per steady lane,
         then ONE windowed target verify pass scores feed slots
         ``[tokens[next_pos], d_1 .. d_K]`` at positions ``next_pos ..
@@ -1338,8 +1452,10 @@ class DecodeEngine:
             faults.fire("generation.draft.verify")
         except Exception:
             self.metrics.spec_fallbacks.inc()
-            self._plain_step(active)
+            self._read_step(self._dispatch_step(active, b, None, span), span)
             return
+        self._describe_step(span, active, b, inflight=0, fed_device=0,
+                            dropped=0)
         with _span("gen:draft", "gen", {"k": width - 1}):
             proposals = self._draft_propose(active, b)
         vpred = self._verify[b]

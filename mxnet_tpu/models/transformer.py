@@ -24,7 +24,11 @@ The lane program's contract: inputs ``data``, ``positions``,
 ``page_table`` and the planes :func:`lane_plane_names` lists, each
 ``(num_pages, page_size, heads, head_dim)``; outputs the logits, then the
 updated planes in that same order.  Every shape comes from the bind, so
-one Symbol serves every lane count, pool size and window width.
+one Symbol serves every lane count, pool size and window width.  The
+decode graph also picks: its last output ``next_ids`` is the greedy token
+of every lane, and its inputs ``prev_ids`` (the ``next_ids`` of the step
+before) and ``source`` let a lane take its token from there, so that the
+token need not cross the host boundary between two steps.
 """
 
 from .. import symbol as sym
@@ -176,6 +180,14 @@ def _lane_graph(vocab_size, num_layers, num_heads, hidden, max_seq_len,
         rows = sym.Reshape(data, shape=(-1,), name="tok_flat")
         row_positions = sym.Reshape(positions, shape=(-1,),
                                     name="pos_ids_flat")
+    else:
+        # source[i] >= 0: lane i feeds what lane source[i] of the step
+        # before picked; below 0 it feeds data[i] (take clips the index)
+        source = sym.Variable("source")
+        rows = sym.where(
+            sym._greater_equal_scalar(source, scalar=0, name="from_prev"),
+            sym.take(sym.Variable("prev_ids"), source, name="prev_take"),
+            data, name="fed_ids")
     x = _embed(rows, vocab_size, hidden, max_seq_len,
                positions=row_positions)
     names = lane_plane_names(num_layers)
@@ -184,7 +196,13 @@ def _lane_graph(vocab_size, num_layers, num_heads, hidden, max_seq_len,
         x, planes = _block(x, hidden, num_heads, "layer%d" % i,
                            over(*layer_planes))
         planes_out.extend(planes)
-    return sym.Group([_head(x, hidden, vocab_size)] + planes_out)
+    logits = _head(x, hidden, vocab_size)
+    if window:
+        return sym.Group([logits] + planes_out)
+    # greedy, on the device: the first maximum of the float32 row, as
+    # np.argmax takes it; in the ids' carrier dtype (vocab < 2**24 is exact)
+    next_ids = sym.argmax(logits, axis=-1, name="next_ids")
+    return sym.Group([logits] + planes_out + [next_ids])
 
 
 def get_transformer_lm_decode(vocab_size=32000, num_layers=4, num_heads=8,
@@ -193,10 +211,11 @@ def get_transformer_lm_decode(vocab_size=32000, num_layers=4, num_heads=8,
     token, reading and writing fixed-size KV pages through its page-table
     row instead of recomputing the prefix (``_contrib_PagedAttention``).
 
-    ``data`` and ``positions`` are (lanes,), ``page_table`` (lanes,
-    max_pages), logits (lanes, vocab); the rest is the module's lane
-    contract.  Everything is static-shape, so one executable per lane count
-    serves any mix of sequence lengths — the continuous-batching contract."""
+    ``data``, ``positions``, ``source`` and ``prev_ids`` are (lanes,),
+    ``page_table`` (lanes, max_pages), logits (lanes, vocab), ``next_ids``
+    (lanes,); the rest is the module's lane contract.  Everything is
+    static-shape, so one executable per lane count serves any mix of
+    sequence lengths — the continuous-batching contract."""
     return _lane_graph(vocab_size, num_layers, num_heads, hidden,
                        max_seq_len, page_size, window=False)
 

@@ -160,7 +160,8 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip):
 def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     """The whole decode step of a 12-layer model at the cell's widths, as
     the engine's Executor builds it (planes carried), compiled for the
-    chip: 12 kernels and, donated, no copy of a plane, staged or plain.
+    chip: 12 kernels, the picked ids as a ``(lanes,)`` output beside the
+    logits and, donated, no copy of a plane, staged or plain.
     Twelve layers because ``layer10`` sorts before ``layer1``: carried
     arguments handed over in a dict's order are paired crosswise with the
     outputs and XLA copies every plane.  Undonated (the rule under the
@@ -186,6 +187,7 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
         vocab, layers, d["heads"], hidden, max_seq_len=128,
         page_size=d["page_size"])
     shapes = {"data": (d["lanes"],), "positions": (d["lanes"],),
+              "source": (d["lanes"],), "prev_ids": (d["lanes"],),
               "page_table": (d["lanes"], max_pages)}
     planes = lane_plane_names(layers)
     shapes.update({name: (num_pages, d["page_size"], d["heads"],
@@ -199,8 +201,15 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     assert ex._carried_names() == planes
     fwd = ex._get_fwd(False)
     assert ex.carry_donated == donated
-    text = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
-        .compile().as_text()
+    compiled = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
+        .compile()
+    # the logits, the planes, then the ids the program picked itself: what
+    # the engine reads of a step (generation/engine.py, ``_read_step``)
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-1])] == \
+        [((d["lanes"], vocab), "float32"), ((d["lanes"],), "float32")]
+    assert len(outs) == 2 + len(planes)
+    text = compiled.as_text()
     assert len(re.findall(r"= [^\n]*\"tpu_custom_call\"", text)) == layers
     plane = "f32[%d,16,16,128]" % num_pages
     copied = [line for line in text.splitlines() if plane in line

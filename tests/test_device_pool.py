@@ -274,13 +274,13 @@ def test_lane_buckets_alternate_inside_a_run(dense_decode):
     through bucket 1, 2, 1, 2, ... on one pool, and the tokens hold."""
     eng = DecodeEngine(_PARAMS, **dict(SPEC, lane_buckets=(1, 2)))
     used = []
-    run = eng._run_lanes
+    run = eng._dispatch_lanes
 
     def recording(pred, *feeds):
         used.append(pred._exec._program_name)
         return run(pred, *feeds)
 
-    eng._run_lanes = recording
+    eng._dispatch_lanes = recording
     try:
         long = eng.submit([1, 2, 3], 24)
         got_short = []

@@ -244,6 +244,216 @@ def test_engine_contains_injected_step_fault():
 
 
 # ---------------------------------------------------------------------------
+# one step in flight: the transcript is the sequential one, whatever happens
+# between a step's dispatch and its read
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def greedy():
+    """Sequential greedy decode by dense recompute of the whole prefix: no
+    engine, no pool, nothing in flight.  ``greedy(prompt, n, eos=None)``."""
+    from mxnet_tpu.models.transformer import get_transformer_lm_prefill
+
+    sym = get_transformer_lm_prefill(V, LAYERS, HEADS, HID, seq_len=S,
+                                     max_seq_len=S)
+    pred = mx.Predictor(sym, dict(_PARAMS), {"data": (1, S)})
+    buf = np.zeros((1, S), np.float32)
+
+    def decode(prompt, max_new, eos=None):
+        toks, gen = list(prompt), []
+        while len(gen) < max_new and (not gen or gen[-1] != eos):
+            buf[:] = 0
+            buf[0, :len(toks)] = toks
+            logits = pred.forward(data=buf)[0].asnumpy()
+            gen.append(int(np.argmax(logits[0, len(toks) - 1])))
+            toks.append(gen[-1])
+        return gen
+
+    return decode
+
+
+def _flight_budget(greedy):
+    """Lanes retire by budget on different steps: the lanes behind a
+    retired one move down, and take their ids from where they were."""
+    rng = np.random.RandomState(31)
+    work = [(p, n) for p, n in zip(_prompts(rng, 4), (3, 9, 5, 7))]
+    eng = DecodeEngine(_PARAMS, **SPEC)
+    try:
+        streams = [eng.submit(p, n) for p, n in work]
+        got = [s.result(timeout=120) for s in streams]
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    assert got == [greedy(p, n) for p, n in work]
+    assert snap["tokens_total"] == sum(n for _, n in work)
+    assert 0 < snap["steps_overlapped"] <= snap["steps"]
+    assert snap["tokens_dropped"] == 0  # a budget is known a step early
+
+
+def _flight_eos(greedy):
+    """The EOS is seen when the step behind it is already dispatched: the
+    lane rides that step, its token is dropped, and the pages it wrote to
+    go to the request that waited for them only afterwards."""
+    rng = np.random.RandomState(37)
+    first, second = _prompts(rng, 2, lo=9, hi=10)
+    free_run = greedy(first, 12)
+    # an id whose first occurrence is past the first decode step
+    at = next(i for i in range(2, 11) if free_run[i] not in free_run[:i])
+    eos = free_run[at]
+    # 5 pages: the first request alone fills them (9 + 12 tokens reserve
+    # 6 > 5 would never fit; 9 + 10 -> 5), the second waits for its pages
+    eng = DecodeEngine(_PARAMS, start=False, **dict(
+        SPEC, num_pages=6, eos_id=eos, lane_buckets=(1,)))
+    try:
+        one = eng.submit(first, 10)
+        two = eng.submit(second, 6)
+        eng._admit()
+        assert eng.active_lanes() == 1 and eng.pending_depth() == 1
+        while not one.done:
+            eng._decode_step()
+        # the step dispatched before the EOS was read is still in flight
+        assert eng._inflight is not None and eng.active_lanes() == 0
+        assert eng.pool.free_pages() == eng.pool.capacity
+        eng._admit()  # the second request takes the first one's pages
+        while not two.done:
+            eng._decode_step()
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    assert one.result() == free_run[:at + 1] == greedy(first, 10, eos)
+    assert two.result() == greedy(second, 6, eos)
+    assert snap["tokens_dropped"] == 1
+    assert snap["tokens_total"] == at + 1 + len(two.result())
+
+
+def _flight_preempt(greedy):
+    """The pool runs out while a step is in flight: the victim's token in
+    flight is dropped and computed again after its re-admission."""
+    rng = np.random.RandomState(5)
+    work = [(p, 14) for p in _prompts(rng, 2, lo=6, hi=7)]
+    eng = DecodeEngine(_PARAMS, **dict(SPEC, num_pages=8,
+                                       lane_buckets=(1, 2)))
+    try:
+        streams = [eng.submit(p, n) for p, n in work]
+        got = [s.result(timeout=120) for s in streams]
+        assert eng.metrics.preempted.value >= 1
+        assert eng.pool.free_pages() == eng.pool.capacity
+    finally:
+        eng.stop()
+    assert got == [greedy(p, n) for p, n in work]
+
+
+def _flight_prefix(greedy):
+    """A partial prefix hit joins running lanes: its lane is fed from the
+    host (the walked suffix's last token) while its neighbours take their
+    ids on the device."""
+    rng = np.random.RandomState(41)
+    shared = [int(t) for t in rng.randint(0, V, size=12)]
+    tail = [int(t) for t in rng.randint(0, V, size=3)]
+    runners = [(p, 14) for p in _prompts(rng, 2)]
+    eng = DecodeEngine(_PARAMS, start=False, **dict(
+        SPEC, prefix_cache_pages=SPEC["num_pages"], lane_buckets=(4,)))
+    fed = []
+    dispatch = eng._dispatch_lanes
+
+    def recording(pred, data, positions, table, source=None):
+        fed.append(None if source is None else list(source))
+        return dispatch(pred, data, positions, table, source)
+
+    eng._dispatch_lanes = recording
+    try:
+        seeded = eng.submit(shared + [1], 2)  # publishes the shared pages
+        eng._admit()
+        while not seeded.done or eng._inflight is not None:
+            eng._decode_step()
+        streams = [eng.submit(p, n) for p, n in runners]
+        eng._admit()
+        eng._decode_step()
+        eng._decode_step()
+        hit = eng.submit(shared + tail, 5)
+        del fed[:]
+        eng._admit()  # with a step in flight
+        assert hit.cached_prefix_tokens > 0 and hit.prefill_tokens == 0
+        eng._decode_step()
+        # the two runners from their lanes of the step before, the hit
+        # from the host
+        assert fed[-1][:3] == [0.0, 1.0, -1.0], fed
+        while not all(s.done for s in streams + [hit]):
+            eng._decode_step()
+    finally:
+        eng.stop()
+    assert [s.result() for s in streams] == [greedy(p, n)
+                                             for p, n in runners]
+    assert hit.result() == greedy(shared + tail, 5)
+
+
+def _flight_admission(greedy):
+    """A request is admitted (its prefill runs) while a step is in flight,
+    into a larger lane count than the step in flight has."""
+    rng = np.random.RandomState(43)
+    (long, late) = _prompts(rng, 2)
+    eng = DecodeEngine(_PARAMS, start=False, **SPEC)
+    try:
+        a = eng.submit(long, 12)
+        eng._admit()
+        eng._decode_step()
+        eng._decode_step()
+        assert eng._inflight is not None
+        b = eng.submit(late, 6)
+        eng._admit()
+        assert eng.active_lanes() == 2 and eng._inflight is not None
+        while not (a.done and b.done):
+            eng._decode_step()
+        snap = eng.snapshot()
+    finally:
+        eng.stop()
+    assert a.result() == greedy(long, 12) and b.result() == greedy(late, 6)
+    assert snap["tokens_dropped"] == 0
+
+
+def _flight_drain(greedy):
+    """``stop(drain=True)`` with steps in flight: every stream finishes
+    whole."""
+    rng = np.random.RandomState(47)
+    work = [(p, 8) for p in _prompts(rng, 5)]
+    eng = DecodeEngine(_PARAMS, **SPEC)
+    streams = [eng.submit(p, n) for p, n in work]
+    streams[0]._q.get(timeout=60)  # decoding has begun
+    eng.stop(drain=True)
+    assert all(s.done for s in streams)
+    assert [s.result() for s in streams] == [greedy(p, n) for p, n in work]
+    assert eng._inflight is None
+
+
+def _flight_fault(greedy):
+    """The step fault fires with a step in flight: its streams fail, none
+    hangs, the step in flight is forgotten and the engine serves on."""
+    rng = np.random.RandomState(53)
+    work = [(p, 8) for p in _prompts(rng, 3)]
+    eng = DecodeEngine(_PARAMS, **SPEC)
+    try:
+        with faults.inject("generation.engine.step:ioerr=1@#3"):
+            streams = [eng.submit(p, n) for p, n in work]
+            for s in streams:
+                with pytest.raises(IOError):
+                    s.result(timeout=60)
+        assert eng.active_lanes() == 0
+        assert [eng.generate(p, n) for p, n in work] == \
+            [greedy(p, n) for p, n in work]
+        assert eng.pool.free_pages() == eng.pool.capacity
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("case", [
+    _flight_budget, _flight_eos, _flight_preempt, _flight_prefix,
+    _flight_admission, _flight_drain, _flight_fault],
+    ids=lambda f: f.__name__[len("_flight_"):])
+def test_step_in_flight_keeps_the_sequential_transcript(case, greedy):
+    case(greedy)
+
+
+# ---------------------------------------------------------------------------
 # recompile detector
 # ---------------------------------------------------------------------------
 

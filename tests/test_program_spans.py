@@ -147,9 +147,10 @@ def engine_session(tmp_path_factory):
                        for n in range(3)]
             for s in streams:
                 s.result(120)
+        snap = eng.snapshot()
     finally:
         eng.stop()
-    return ses, streams
+    return ses, streams, snap
 
 
 @pytest.mark.parametrize("name", ("gen:admit", "gen:prefill", "gen:queued",
@@ -158,40 +159,68 @@ def test_engine_records_every_span_of_the_table(engine_session, name):
     assert engine_session[0].named(name)
 
 
+def _children(ses, step):
+    kids = [k for n in STEP_CHILDREN for k in ses.named(n)
+            if _inside(k, step)]
+    return sorted(kids, key=lambda k: k[1])
+
+
 def test_step_children_lie_inside_their_step(engine_session):
-    ses, _ = engine_session
+    """One ``gen:step`` an iteration that dispatches, none overlapping the
+    next, each with exactly one of every child; the step that was in flight
+    is read after the dispatch, or before it where its ids are another lane
+    count's; a read with nothing to dispatch after it is a ``gen:drain``."""
+    ses = engine_session[0]
     steps = ses.named("gen:step")
-    for name in STEP_CHILDREN:
-        kids = ses.named(name)
-        assert len(kids) == len(steps)
-        assert all(any(_inside(k, s) for s in steps) for k in kids)
+    assert all(a[3] == b[3] and a[2] <= b[1]
+               for a, b in zip(steps, steps[1:]))
+    early = STEP_CHILDREN[:1] + STEP_CHILDREN[-2:] + STEP_CHILDREN[1:-2]
     for s in steps:
-        kids = [k for n in STEP_CHILDREN for k in ses.named(n)
-                if _inside(k, s)]
-        # siblings in order, none overlapping the next
-        kids.sort(key=lambda k: k[1])
-        assert [k[0] for k in kids] == list(STEP_CHILDREN)
+        kids = _children(ses, s)
+        # siblings, none overlapping the next
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        order = [k[0] for k in kids]
+        assert order == list(STEP_CHILDREN) or \
+            (not s[4]["inflight"] and order == list(early))
+    drains = ses.named("gen:drain")
+    assert drains and not any(_inside(d, s) for d in drains for s in steps)
+    for name in STEP_CHILDREN:
+        hosts = drains if name in STEP_CHILDREN[-2:] else []
+        assert all(any(_inside(k, s) for s in steps + hosts)
+                   for k in ses.named(name))
 
 
 def test_step_and_pool_args(engine_session):
-    ses, streams = engine_session
+    ses, streams, snap = engine_session
     sids = {str(s.sid) for s in streams}
     assert sids == {"0", "1", "2"}
-    for step in ses.named("gen:step"):
+    steps = ses.named("gen:step")
+    for step in steps:
         st = step[4]
         assert "," not in st["sids"] and "#" not in st["sids"]
         assert set(st["sids"].split("|")) <= sids
         assert st["lanes"] == len(st["sids"].split("|")) <= st["bucket"]
-    # ids, positions and tables up, logits down: no plane crosses
+        assert 0 <= st["fed_device"] <= st["lanes"] * st["inflight"]
+        assert st["dropped"] == 0  # no EOS set: no lane rides for nothing
+    # the step in flight counts once: a span a device step
+    assert len(steps) == snap["steps"] >= snap["steps_overlapped"] > 0
+    assert sum(s[4]["inflight"] for s in steps) == snap["steps_overlapped"]
+    assert snap["tokens_dropped"] == 0
+    # ids, positions, sources and tables up, picked ids down: no logits and
+    # no plane crosses
     plane = 48 * 4 * HEADS * (HID // HEADS) * 4  # one layer's K plane
     max_pages = S // 4
-    for up, down, step in zip(ses.named("gen:pool_h2d"),
-                              ses.named("gen:pool_d2h"),
-                              ses.named("gen:step")):
+    for step in steps:
+        kids = {k[0]: k[4] for k in _children(ses, step)}
         lanes = step[4]["bucket"]
-        assert up[4]["bytes"] == lanes * (2 + max_pages) * 4 < plane
-        assert down[4]["bytes"] == lanes * V * 4 < plane
+        assert kids["gen:pool_h2d"]["bytes"] == \
+            lanes * (3 + max_pages) * 4 < plane
+        down = kids["gen:pool_d2h"]["bytes"]
+        if step[4]["inflight"]:
+            # the read of the step dispatched an iteration earlier
+            assert down == lanes * 4
+        else:  # nothing was in flight, or another lane count's step
+            assert down in (0, 4, 8, 16) and down != lanes * 4
     assert not ses.named("gen:pool_copyback")
     emits = ses.named("gen:emit")
     assert sum(e[4]["emitted"] for e in emits) == \
@@ -201,7 +230,7 @@ def test_step_and_pool_args(engine_session):
 
 
 def test_one_queued_span_per_admitted_request(engine_session):
-    ses, streams = engine_session
+    ses, streams, _ = engine_session
     queued = ses.named("gen:queued")
     assert sorted(q[4]["sid"] for q in queued) == \
         sorted(s.sid for s in streams)

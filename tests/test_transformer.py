@@ -118,17 +118,18 @@ def _graph(which):
         return build(max_seq_len=_MAX_SEQ, page_size=_PAGE, **_TOY)
 
 
-# sha256 of tojson() as PR 29's five written-out blocks emitted it.  The
-# JSON is the compile-cache fingerprint and fixes every named scope of the
-# device trace; a PR that changes a graph on purpose replaces its digest
-# here and says so in CHANGES.md.
+# sha256 of tojson() as PR 29's five written-out blocks emitted it (decode:
+# as PR 31 left it, which gave it ``source`` / ``prev_ids`` / ``next_ids``).
+# The JSON is the compile-cache fingerprint and fixes every named scope of
+# the device trace; a PR that changes a graph on purpose replaces its
+# digest here and says so in CHANGES.md.
 _DIGESTS = {
     "train":
         "a4618d6fec48840218f0e34710c4d4b30ab7388996d44dbb8388ac5ed0856b7a",
     "prefill":
         "dc31b9bc296ecc03ad611e158aeae9415ffaaea63ea1767bcad9a13b9dec1029",
     "decode":
-        "790017cc10156a1efbbf91907d63074a6d2ac9f8be312b7692fd973ed28b1626",
+        "2d8d06418b4f29a1f216b158f089dfcd909aba9917db7bd9eb0c580bab4a06f7",
     "catchup":
         "bbcb236c75de3f6db21d6872d6bf65cea23a63419e028eae1db30707cbd174f1",
 }
@@ -144,7 +145,8 @@ def test_graph_json_is_pinned(which):
 
 @pytest.mark.parametrize("which,feeds", [
     ("prefill", {"data": (2, 16)}),
-    ("decode", {"data": (3,), "positions": (3,), "page_table": (3, 8)}),
+    ("decode", {"data": (3,), "positions": (3,), "page_table": (3, 8),
+                "source": (3,), "prev_ids": (3,)}),
     ("catchup", {"data": (3, 5), "positions": (3, 5),
                  "page_table": (3, 8)}),
 ])
