@@ -54,6 +54,27 @@ XLA discipline: every XLA-visible shape here is static.
   ``gen-draft-prefill``), so AOT bundles restore a generate-ready
   replica with zero cold compiles.
 
+The family seam: the engine builds no graph itself.  It asks a *model
+family* object (``models.TransformerLMFamily``, ``models.HybridLM``;
+``models.generator_family`` resolves the engine's keywords to one, here
+and for ``platform.ModelSpec.kv_footprint``) for the prefill
+graph of a length bucket (``prefill_symbol``; ``prefill_inputs`` names its
+per-prompt inputs, ``data`` and for some ``length``), the decode graph
+(``decode_symbol``), the windowed catch-up / verify graph or ``None``
+(``catchup_symbol``) and the planes the lane programs carry (``planes()``:
+name, kind ``paged`` or ``slot``, entry shape, dtype), which one
+:class:`~.kv_pool.PagedKVPool` owns, and the keys it adds to :meth:`spec`
+(``engine_spec()``).  A family with slot planes (recurrent
+state) gets a ``state_slot`` vector beside ``page_table``.  What a family
+without a catch-up graph cannot do is refused by name, never done wrongly:
+``draft=`` and ``prefix_cache_pages > 0`` raise at construction (a
+recurrent state cannot be rewound past a rejected token, nor rebuilt from
+cached pages), and a preempted sequence is re-admitted by a prefill over
+its whole transcript, or fails with :class:`StateNotRebuildableError` where
+that is longer than the largest prefill bucket.  Ids, positions, sources,
+slots and tables travel in a carrier dtype of their own (float32: exact for
+ids below 2**24), whatever the dtype of weights and planes.
+
 Backpressure: admission is a bounded pending queue (reject =
 :class:`~mxnet_tpu.serving.batcher.QueueFullError`, the HTTP 429/503
 contract) plus KV-pool capacity; a mid-decode pool exhaustion preempts
@@ -83,7 +104,16 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
 from ..ops.paged import decode_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
-__all__ = ["DecodeEngine", "GenStream"]
+__all__ = ["DecodeEngine", "GenStream", "StateNotRebuildableError"]
+
+# what ids, positions, sources, slots and page tables are fed as
+_CARRIER = np.dtype(np.float32)
+
+
+class StateNotRebuildableError(MXNetError):
+    """A preempted sequence of a recurrent family whose transcript is longer
+    than the largest prefill bucket: its state is gone and no graph the
+    engine has can rebuild it."""
 
 
 def _autotune_engine_config(num_layers, num_heads, head_dim, max_seq_len,
@@ -350,10 +380,17 @@ class DecodeEngine:
     ----------
     params : dict | str
         ``{name: array}`` (``arg:`` prefixes allowed) or a ``.params``
-        path — the ``get_transformer_lm`` training checkpoint; all
-        prefill/decode executors share one copy of the weights.
-    vocab_size, num_layers, num_heads, hidden, max_seq_len
-        Model geometry (must match the checkpoint).
+        path — the family's checkpoint (``get_transformer_lm``'s for the
+        default family); all prefill/decode executors share one copy of
+        the weights, bound in the dtype they come in.
+    vocab_size, num_layers, num_heads, hidden
+        The default family's geometry (``models.TransformerLMFamily``;
+        must match the checkpoint); not used with ``family``.
+    family : object | dict, optional
+        The model family (module docstring, "The family seam"), or its
+        ``spec()``.
+    max_seq_len : int
+        Positions a sequence may reach (prompt + generated).
     lane_buckets : sequence of int, optional
         Decode lane-count buckets (default ``pow2_buckets(
         MXNET_GEN_MAX_LANES)``); one executable per bucket.
@@ -377,7 +414,7 @@ class DecodeEngine:
         :meth:`spec` so bundles/replicas rebuild without re-tuning.
     """
 
-    def __init__(self, params, vocab_size, num_layers=4, num_heads=8,
+    def __init__(self, params, vocab_size=None, num_layers=4, num_heads=8,
                  hidden=512, max_seq_len=128,
                  lane_buckets: Optional[Sequence[int]] = None,
                  page_size: Optional[int] = None,
@@ -389,20 +426,24 @@ class DecodeEngine:
                  prefix_cache_pages: Optional[int] = None,
                  draft: Optional[Dict] = None,
                  ctx=None, dtype=np.float32, warmup: bool = True,
-                 start: bool = True):
+                 start: bool = True, family=None):
         from .. import ndarray as nd
-        from ..models.transformer import (get_transformer_lm_catchup,
-                                          get_transformer_lm_decode,
-                                          get_transformer_lm_prefill,
-                                          lane_plane_names)
+        from ..models import generator_family
         from ..predictor import Predictor, on_ctx
 
-        self.vocab_size = int(vocab_size)
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.hidden = int(hidden)
+        # ``dtype`` is the default family's planes'; the feeds' is _CARRIER
+        self._dtype = np.dtype(dtype)
+        if family is None and vocab_size is None:
+            raise MXNetError("DecodeEngine needs vocab_size or family")
+        self.family = family = generator_family(
+            family, vocab_size, num_layers, num_heads, hidden, self._dtype)
+        self.vocab_size = family.vocab_size
+        # the family's geometry (the autotuner's key, spec(), snapshot())
+        self.num_layers = family.num_layers
+        self.num_heads = family.num_heads
+        self.hidden = family.hidden
         self.max_seq_len = int(max_seq_len)
-        self.head_dim = self.hidden // self.num_heads
+        self.head_dim = family.head_dim
         self.eos_id = eos_id
         # None: the current context — the chip when one is attached
         # (docs/how_to/deviations.md "Default context")
@@ -410,7 +451,6 @@ class DecodeEngine:
 
         self._ctx = ctx = ctx or current_context()
         self._device = ctx.jax_device()
-        self._dtype = np.dtype(dtype)
         # unset knobs consult the autotuner before the env defaults:
         # explicit constructor args always pin, tuned winners beat the
         # built-in defaults, env vars remain the no-autotune fallback
@@ -450,6 +490,16 @@ class DecodeEngine:
             env("MXNET_GEN_PREFIX_CACHE_PAGES", 0, int)
             if prefix_cache_pages is None else prefix_cache_pages))
 
+        # what this family has no graph for is refused here, by name
+        if (draft or self.prefix_cache_pages) and family.catchup_symbol(
+                self.max_seq_len, self.page_size) is None:
+            raise MXNetError(
+                "the %s family has no windowed (catch-up / verify) graph: "
+                "%s needs one. A recurrent state cannot be rewound past a "
+                "rejected token, nor rebuilt from cached pages"
+                % (family.name, "draft=" if draft
+                   else "prefix_cache_pages=%d" % self.prefix_cache_pages))
+
         # -- speculative draft config (resolve K once, here) --------------
         self._draft: Optional[Dict] = None
         self._draft_params = None
@@ -487,9 +537,11 @@ class DecodeEngine:
         # bucket executor binds the same arrays
         self._params = {k: on_ctx(v, ctx) for k, v in params.items()}
 
+        # one manager for every plane the lane programs carry: K/V pages,
+        # and a slot a lane (+ scratch) of each state plane
         self.pool = PagedKVPool(self.num_pages, self.page_size,
-                                self.num_layers, self.num_heads,
-                                self.head_dim, dtype=self._dtype,
+                                planes=family.planes(),
+                                num_slots=self.max_lanes + 1,
                                 prefix_cache_pages=self.prefix_cache_pages,
                                 ctx=ctx)
         self.metrics = _GenMetrics()
@@ -502,32 +554,31 @@ class DecodeEngine:
         # digests the bundle was saved under.
         from ..name import NameManager
 
-        def prefill_rig(params, n_layers, n_heads, n_hidden, kind, name):
+        def prefill_rig(fam, params, kind, name):
             rig = {}
             for L in self.prefill_len_buckets:
                 with NameManager():
-                    symbol = get_transformer_lm_prefill(
-                        self.vocab_size, n_layers, n_heads, n_hidden,
-                        seq_len=L, max_seq_len=self.max_seq_len)
-                bp = BucketedPredictor(symbol, params, {"data": (L,)},
-                                       self.prefill_batch_buckets, ctx=ctx,
-                                       dtype=dtype)
+                    symbol = fam.prefill_symbol(L, self.max_seq_len)
+                items = {"data": (L,), "length": ()}
+                bp = BucketedPredictor(
+                    symbol, params, {k: items[k] for k in fam.prefill_inputs},
+                    self.prefill_batch_buckets, ctx=ctx, dtype=_CARRIER)
                 for pred in bp._preds.values():
                     pred._exec._cache_kind = kind
                     pred._exec._program_name = name % L
                 rig[L] = bp
             return rig
 
-        def lane_rig(get_symbol, params, pool, n_heads, n_hidden, kind, name,
-                     width=None):
+        def lane_rig(fam, params, pool, kind, name, width=None):
             """One fixed-lane Predictor per lane bucket (shared weights via
-            reshape; pool shapes are lane-independent).  Every one binds
-            the pool's own planes, carried: the step updates them in
+            reshape; pool shapes are lane-independent): the family's
+            decode graph, or with ``width`` its windowed one.  Every one
+            binds the pool's own planes, carried: the step updates them in
             place and nothing uploads or reads them."""
             with NameManager():
-                symbol = get_symbol(
-                    self.vocab_size, pool.num_layers, n_heads, n_hidden,
-                    max_seq_len=self.max_seq_len, page_size=self.page_size)
+                symbol = (fam.decode_symbol if width is None
+                          else fam.catchup_symbol)(self.max_seq_len,
+                                                   self.page_size)
 
             def feeds(b):
                 shape = (b,) if width is None else (b, width)
@@ -535,17 +586,19 @@ class DecodeEngine:
                        "page_table": (b, self.max_pages)}
                 if width is None:  # the decode graph picks and feeds on
                     out.update(source=shape, prev_ids=shape)
+                    if pool.num_slots:
+                        out.update(state_slot=shape)
                 return out
 
             # outputs: the logits, then the planes in the pool's order
             # (then, of the decode graph, the picked ids)
-            names = lane_plane_names(pool.num_layers)
+            names = pool.plane_names()
             planes = dict(zip(names, pool.planes()))
             carried = {name: 1 + i for i, name in enumerate(names)}
             shapes = feeds(self.max_lanes)
             shapes.update({k: v.shape for k, v in planes.items()})
             base = Predictor(symbol, dict(params, **planes), shapes,
-                             ctx=ctx, dtype=dtype)
+                             ctx=ctx, dtype=_CARRIER)
             rig = {self.max_lanes: base}
             for b in self.lane_buckets[:-1]:
                 rig[b] = base.reshape(feeds(b))
@@ -555,12 +608,10 @@ class DecodeEngine:
                 pred._exec.set_carried(carried)
             return rig
 
-        self._prefill = prefill_rig(self._params, self.num_layers,
-                                    self.num_heads, self.hidden,
-                                    "gen-prefill", "prefill_L%d")
-        self._decode = lane_rig(get_transformer_lm_decode, self._params,
-                                self.pool, self.num_heads, self.hidden,
-                                "gen-step", "decode_b%d")
+        self._prefill = prefill_rig(family, self._params, "gen-prefill",
+                                    "prefill_L%d")
+        self._decode = lane_rig(family, self._params, self.pool, "gen-step",
+                                "decode_b%d")
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -568,27 +619,24 @@ class DecodeEngine:
         self._draft_decode: Dict[int, "Predictor"] = {}
         self._verify: Dict[int, "Predictor"] = {}
         if self._draft is not None:
-            dl = self._draft["num_layers"]
-            dh = self._draft["num_heads"]
-            dhid = self._draft["hidden"]
+            small = generator_family(
+                None, self.vocab_size, self._draft["num_layers"],
+                self._draft["num_heads"], self._draft["hidden"], self._dtype)
             self._draft_pool = PagedKVPool(self.num_pages, self.page_size,
-                                           dl, dh, dhid // dh,
-                                           dtype=self._dtype, ctx=ctx)
+                                           planes=small.planes(), ctx=ctx)
             self._draft_prefill = prefill_rig(
-                self._draft_params, dl, dh, dhid, "gen-draft-prefill",
+                small, self._draft_params, "gen-draft-prefill",
                 "draft_prefill_L%d")
             self._draft_decode = lane_rig(
-                get_transformer_lm_decode, self._draft_params,
-                self._draft_pool, dh, dhid, "gen-draft-step",
-                "draft_decode_b%d")
+                small, self._draft_params, self._draft_pool,
+                "gen-draft-step", "draft_decode_b%d")
             # verification is teacher forcing too — the draft's K
             # proposals are known before the call — so the verify rig
             # uses the same windowed single-pass graph as catch-up
             # rather than chaining K+1 literal decode blocks (whose
             # dispatch cost eats the speculation win on small models)
             self._verify = lane_rig(
-                get_transformer_lm_catchup, self._params, self.pool,
-                self.num_heads, self.hidden, "gen-verify", "verify_b%d",
+                family, self._params, self.pool, "gen-verify", "verify_b%d",
                 width=self._verify_width)
 
         # -- prefix-cache catch-up rig: a windowed teacher-forcing
@@ -607,9 +655,8 @@ class DecodeEngine:
             # slots it doesn't use
             self._catchup_width = max(2, min(32, self.max_seq_len - 1))
             self._catchup = lane_rig(
-                get_transformer_lm_catchup, self._params, self.pool,
-                self.num_heads, self.hidden, "gen-catchup", "catchup_b%d",
-                width=self._catchup_width)
+                family, self._params, self.pool, "gen-catchup",
+                "catchup_b%d", width=self._catchup_width)
 
         import jax
         import jax.numpy as jnp
@@ -651,8 +698,7 @@ class DecodeEngine:
         The draft block carries the RESOLVED speculative K — a replica
         rebuilt from a bundle speculates with zero re-tuning."""
         out = {
-            "vocab_size": self.vocab_size, "num_layers": self.num_layers,
-            "num_heads": self.num_heads, "hidden": self.hidden,
+            **self.family.engine_spec(),
             "max_seq_len": self.max_seq_len,
             "lane_buckets": list(self.lane_buckets),
             "page_size": self.page_size, "num_pages": self.num_pages,
@@ -685,9 +731,9 @@ class DecodeEngine:
             # target the pick of each prompt's first token
             for b, pred in bp._preds.items():
                 outs = pred.get_outputs()
-                slabs = [o._data for o in outs[1:]]
-                pool.write_slots(slabs, np.zeros(slabs[0].shape[:2],
-                                                 np.int32))
+                pool.write_slots([o._data for o in outs[1:]],
+                                 np.zeros((b,) + bp.item_shapes["data"],
+                                          np.int32))
                 if pool is self.pool:
                     np.asarray(self._prefill_rows(
                         outs[0]._data, np.zeros((b,), np.int32)))
@@ -703,9 +749,9 @@ class DecodeEngine:
             for pred, dshape in rigs:
                 # all-zero feeds: every lane writes scratch page 0 of the
                 # pool's own planes, which the rig binds
-                self._run_lanes(pred, np.zeros(dshape, self._dtype),
-                                np.zeros(dshape, self._dtype),
-                                np.zeros((b, self.max_pages), self._dtype))
+                self._run_lanes(pred, np.zeros(dshape, _CARRIER),
+                                np.zeros(dshape, _CARRIER),
+                                np.zeros((b, self.max_pages), _CARRIER))
             self.warmed_lane_buckets.add(b)
         return self
 
@@ -918,9 +964,12 @@ class DecodeEngine:
                     # which formulation the lane program's attention runs
                     # where this engine's planes live (ops/paged.py)
                     "paged_attention": decode_formulation(
-                        self._device.platform, self.num_heads,
-                        self.hidden // self.num_heads, self._dtype),
+                        self._device.platform, self.num_heads, self.head_dim,
+                        self.pool.k_pools[0].dtype,
+                        kv_heads=self.family.kv_heads),
                     "kv": self.pool.snapshot()}
+            if self.pool.num_slots:
+                snap["state_slots"] = snap["kv"]["state_slots"]
             if self._draft is not None:
                 snap["draft"] = {
                     "k": self._draft["k"],
@@ -976,9 +1025,10 @@ class DecodeEngine:
         avail = self.pool.reclaimable_pages()
         d_avail = (self._draft_pool.free_pages()
                    if self._draft_pool is not None else None)
+        slots = self.pool.free_slots()  # None: the family carries none
         with self._cv:
-            while self._pending and \
-                    len(self._active) + len(batch) < self.max_lanes:
+            while self._pending and (slots is None or len(batch) < slots) \
+                    and len(self._active) + len(batch) < self.max_lanes:
                 seq = self._pending[0]
                 if seq.deadline is not None and now > seq.deadline:
                     self._pending.popleft()
@@ -1040,9 +1090,12 @@ class DecodeEngine:
         if not admitted:
             return
         misses = [s for s in admitted if s.next_pos == 0]
-        with _span("gen:prefill", "gen",
-                   {"bucket": L, "n": len(admitted),
-                    "tokens": sum(len(s.tokens) for s in misses)}):
+        args = {"bucket": L, "n": len(admitted),
+                "tokens": sum(len(s.tokens) for s in misses)}
+        if self.pool.num_slots:
+            args["state_slot"] = "|".join(
+                str(self.pool.state_slot(s.sid)) for s in admitted)
+        with _span("gen:prefill", "gen", args):
             start = time.monotonic()
             for seq in admitted:
                 wait_ms = max(0.0, (start - seq.stream._t0) * 1e3)
@@ -1059,13 +1112,16 @@ class DecodeEngine:
         # sequence through the draft model so proposals can start from
         # the first decode iteration
         def prefill(bp, pool, seqs):
-            """One prefill forward for ``seqs``; its K/V goes from the
-            program's outputs into the pool's planes on the device."""
+            """One prefill forward for ``seqs``; its K/V (and final
+            states) go from the program's outputs into the pool's planes
+            on the device."""
             items = []
             for seq in seqs:
-                buf = np.zeros((L,), self._dtype)
+                buf = np.zeros((L,), _CARRIER)
                 buf[:len(seq.tokens)] = seq.tokens
                 items.append({"data": buf})
+                if "length" in bp.item_shapes:  # the graph takes it
+                    items[-1]["length"] = len(seq.tokens)
             _, outs = bp.run_batch(items)
             pool.write_prefill([s.sid for s in seqs],
                                [o._data for o in outs[1:]],
@@ -1118,11 +1174,10 @@ class DecodeEngine:
             b = self._lane_bucket_for(len(pending))
             self._note_lane_bucket(b)
             pred = self._catchup[b]
-            data = np.zeros((b, W), self._dtype)
+            data = np.zeros((b, W), _CARRIER)
             # pads park in the scratch page's last slot (zero table row)
-            positions = np.full((b, W), self.max_seq_len - 1,
-                                dtype=self._dtype)
-            table = np.zeros((b, self.max_pages), self._dtype)
+            positions = np.full((b, W), self.max_seq_len - 1, dtype=_CARRIER)
+            table = np.zeros((b, self.max_pages), _CARRIER)
             spans = []
             for i, seq in enumerate(pending):
                 # the cursor's page can still be prefix-indexed/shared
@@ -1196,9 +1251,25 @@ class DecodeEngine:
                 return False
             victim = max(victims, key=lambda s: s.admitted_at)
             self._active.remove(victim)
-            self._pending.appendleft(victim)
+            # a recurrent family's state is rebuilt by a prefill over the
+            # whole transcript, or not at all
+            lost = bool(self.pool.num_slots) and \
+                len(victim.tokens) > self.prefill_len_buckets[-1]
+            if not lost:
+                self._pending.appendleft(victim)
             self.metrics.g_active.set(len(self._active))
             self.metrics.g_pending.set(len(self._pending))
+        if lost:
+            self.pool.free(victim.sid)
+            victim.stream._finish(StateNotRebuildableError(
+                "sequence %d was preempted after %d tokens: its recurrent "
+                "state can only be rebuilt by a prefill over the whole "
+                "transcript, and the largest prefill bucket is %d"
+                % (victim.sid, len(victim.tokens),
+                   self.prefill_len_buckets[-1])))
+            self.metrics.preempted.inc()
+            self.metrics.failed.inc()
+            return True
         if self.prefix_cache_pages:
             self.pool.register_prefix(victim.sid,
                                       victim.tokens[:victim.next_pos])
@@ -1297,21 +1368,26 @@ class DecodeEngine:
                  pages=sum(s.next_pos // self.page_size + 1 for s in lanes),
                  **more)
 
-    def _dispatch_lanes(self, pred, data, positions, table, source=None):
+    def _dispatch_lanes(self, pred, data, positions, table, source=None,
+                        slots=None):
         """Upload one lane-bucket executable's feeds and dispatch it;
         returns its outputs, unread.  Ids, positions and tables go up; the
-        K/V planes the executable binds are the pool's own and stay on
-        the device (``Executor.set_carried``).  The decode graph also
-        takes ``source`` (``None``: every lane feeds ``data``) and hands
-        its picked ids on to its own next call."""
+        planes the executable binds are the pool's own and stay on the
+        device (``Executor.set_carried``).  The decode graph also takes
+        ``source`` (``None``: every lane feeds ``data``) and hands its
+        picked ids on to its own next call; a family with state planes
+        takes ``state_slot`` (``None``: every lane on scratch slot 0)."""
         import jax
 
         args = pred._exec.arg_dict
         feeds = {"data": data, "positions": positions, "page_table": table}
         picks = "source" in args  # the decode graph
         if picks:
-            feeds["source"] = (np.full(data.shape, -1, self._dtype)
+            feeds["source"] = (np.full(data.shape, -1, _CARRIER)
                                if source is None else source)
+        if "state_slot" in args:
+            feeds["state_slot"] = (np.zeros(data.shape, _CARRIER)
+                                   if slots is None else slots)
         with _span("gen:pool_h2d", "gen",
                    {"bytes": sum(v.nbytes for v in feeds.values())}):
             put = jax.device_put(tuple(feeds.values()), self._device)
@@ -1371,23 +1447,32 @@ class DecodeEngine:
         self._note_lane_bucket(b)
         came = {seq.sid: j for j, (seq, _) in enumerate(prev.lanes)} \
             if prev is not None else {}
+        pool, more = self.pool, {}
+        slots = np.zeros((b,), _CARRIER) if pool.num_slots else None
         with _span("gen:feed", "gen"):
-            data = np.zeros((b,), self._dtype)
-            positions = np.zeros((b,), self._dtype)
-            source = np.full((b,), -1, self._dtype)
-            table = np.zeros((b, self.max_pages), self._dtype)
+            data = np.zeros((b,), _CARRIER)
+            positions = np.zeros((b,), _CARRIER)
+            source = np.full((b,), -1, _CARRIER)
+            table = np.zeros((b, self.max_pages), _CARRIER)
             for i, seq in enumerate(lanes):
                 if seq.next_pos < len(seq.tokens):
                     data[i] = seq.tokens[seq.next_pos]
                 else:
                     source[i] = came[seq.sid]
                 positions[i] = seq.next_pos  # slot the new K/V lands in
-                table[i] = self.pool.page_table_row(seq.sid,
-                                                    self.max_pages)
+                table[i] = pool.page_table_row(seq.sid, self.max_pages)
+                if slots is not None:
+                    slots[i] = pool.state_slot(seq.sid)
+        if slots is not None:
+            # the recurrent state of the step's lanes: read once, written
+            # once
+            more["state_bytes"] = len(lanes) * pool.slot_bytes
         self._describe_step(span, lanes, b, inflight=int(prev is not None),
-                            fed_device=int((source >= 0).sum()))
+                            fed_device=int((source >= 0).sum()), **more)
         outs = self._dispatch_lanes(self._decode[b], data, positions, table,
-                                    source)
+                                    source,
+                                    **({} if slots is None
+                                       else {"slots": slots}))
         self.metrics.steps.inc()
         if prev is not None:
             self.metrics.steps_overlapped.inc()
@@ -1406,7 +1491,7 @@ class DecodeEngine:
         preemption, a hand-off) rode the step for nothing: its token is
         dropped, and greedy decode computes it again if it is needed."""
         lanes, ids = (flight.lanes, flight.ids) if flight is not None \
-            else ((), np.zeros((0,), self._dtype))
+            else ((), np.zeros((0,), _CARRIER))
         # the read blocks until the device has run the step
         with _span("gen:pool_d2h", "gen") as d2h:
             ids = np.asarray(ids)
@@ -1459,12 +1544,12 @@ class DecodeEngine:
         with _span("gen:draft", "gen", {"k": width - 1}):
             proposals = self._draft_propose(active, b)
         vpred = self._verify[b]
-        data = np.zeros((b, width), self._dtype)
+        data = np.zeros((b, width), _CARRIER)
         # pad slots park at (token 0, position max_seq_len-1): with a
         # zero page-table row beyond the lane's allocation the write
         # lands in scratch page 0, and no live position ever attends it
-        positions = np.full((b, width), self.max_seq_len - 1, self._dtype)
-        table = np.zeros((b, self.max_pages), self._dtype)
+        positions = np.full((b, width), self.max_seq_len - 1, _CARRIER)
+        table = np.zeros((b, self.max_pages), _CARRIER)
         lane_width: Dict[object, int] = {}
         for i, seq in enumerate(active):
             table[i] = self.pool.page_table_row(seq.sid, self.max_pages)
@@ -1544,9 +1629,9 @@ class DecodeEngine:
             lag = [s for s in active if s.draft_pos < s.next_pos]
             if not lag:
                 break
-            data = np.zeros((b,), self._dtype)
-            positions = np.full((b,), self.max_seq_len - 1, self._dtype)
-            table = np.zeros((b, self.max_pages), self._dtype)
+            data = np.zeros((b,), _CARRIER)
+            positions = np.full((b,), self.max_seq_len - 1, _CARRIER)
+            table = np.zeros((b, self.max_pages), _CARRIER)
             for i, seq in enumerate(active):
                 if seq.draft_pos < seq.next_pos:
                     data[i] = seq.tokens[seq.draft_pos]
@@ -1564,9 +1649,9 @@ class DecodeEngine:
         if not proposals:
             return proposals
         for r in range(k):
-            data = np.zeros((b,), self._dtype)
-            positions = np.full((b,), self.max_seq_len - 1, self._dtype)
-            table = np.zeros((b, self.max_pages), self._dtype)
+            data = np.zeros((b,), _CARRIER)
+            positions = np.full((b,), self.max_seq_len - 1, _CARRIER)
+            table = np.zeros((b, self.max_pages), _CARRIER)
             live = []
             for i, seq in enumerate(active):
                 if seq.sid not in proposals:

@@ -21,6 +21,16 @@ rebinds those NDArrays to its outputs before anything else can read
 them.  The host keeps the bookkeeping: free list, page tables,
 refcounts, prefix index.
 
+A pool may carry a second kind of plane beside the paged ones: *slot*
+planes, ``(num_slots,) + shape`` each, that hold one fixed-size state a
+sequence and layer (a state-space layer's recurrent state and convolution
+tail, models/hybrid_lm.py) where a paged plane holds K/V a token.  One
+manager owns both: a sequence takes its slot with its first pages
+(:meth:`alloc_prefix`) and gives it back with them (:meth:`free`), so the
+rule that keeps a page from a new owner until the last step that names it
+has been dispatched (generation/engine.py) keeps a slot the same way.  Slot
+0 is scratch as page 0 is.
+
 Page 0 is reserved as scratch: inactive decode lanes point their
 page-table rows at it so their masked-out writes land harmlessly
 (ops/paged.py), and so do a prefill's padding rows.  Allocation is O(1)
@@ -55,7 +65,7 @@ from __future__ import annotations
 
 import hashlib
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, namedtuple
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -64,22 +74,33 @@ from .. import faults
 from .. import telemetry as _telemetry
 from ..base import MXNetError
 
-__all__ = ["PagedKVPool", "KVPoolExhaustedError"]
+__all__ = ["PagedKVPool", "KVPoolExhaustedError", "PlaneSpec"]
+
+# One carried plane: the lane program's argument ``name``; ``kind`` "paged"
+# (``shape`` a token: the plane is ``(num_pages, page_size) + shape``) or
+# "slot" (``shape`` a sequence: ``(num_slots,) + shape``); its dtype.
+PlaneSpec = namedtuple("PlaneSpec", "name kind shape dtype")
 
 
 class KVPoolExhaustedError(MXNetError):
     """No free pages — backpressure: callers queue, shed, or preempt."""
 
 
-def _write_program(length: int):
-    """The scatter of one prefill length bucket: every plane takes its
-    slab's rows at ``slots`` (indices into the plane's flattened
-    ``num_pages * page_size`` token axis).  Donates the planes."""
+def _write_program(length: int, kinds):
+    """The scatter of one prefill length bucket: every paged plane takes
+    its slab's rows at ``slots`` (indices into the plane's flattened
+    ``num_pages * page_size`` token axis), every slot plane its slab's
+    batch rows at ``state_slots`` (given only where the pool has such
+    planes).  Donates the planes."""
     import jax
 
-    def pool_write(planes, slabs, slots):
+    def pool_write(planes, slabs, slots, *state_slots):
         out = []
-        for plane, slab in zip(planes, slabs):
+        for plane, slab, kind in zip(planes, slabs, kinds):
+            if kind == "slot":
+                out.append(plane.at[state_slots[0]].set(
+                    slab.astype(plane.dtype)))
+                continue
             flat = plane.reshape((-1,) + plane.shape[2:])
             rows = slab.reshape((-1,) + plane.shape[2:]).astype(flat.dtype)
             out.append(flat.at[slots.reshape(-1)].set(rows)
@@ -110,7 +131,8 @@ def _page_digest(prev: bytes, chunk) -> bytes:
 
 
 class PagedKVPool:
-    """Paged K/V storage for ``num_layers`` attention layers: the planes
+    """Paged K/V storage for ``num_layers`` attention layers, and with
+    ``planes`` whatever else a model carries from step to step: the planes
     on the device, the bookkeeping on the host.
 
     Parameters
@@ -130,11 +152,17 @@ class PagedKVPool:
         caching entirely — legacy alloc/free semantics).
     ctx : Context, optional
         Where the planes live (default: the current context).
+    planes : sequence of (name, kind, shape, dtype), optional
+        The planes, in the order the programs take and return them, in
+        place of ``num_layers`` K/V pairs (:data:`PlaneSpec`); paged ones
+        come as K/V pairs.
+    num_slots : int, optional
+        Slots of every slot plane INCLUDING the reserved scratch slot 0.
     """
 
-    def __init__(self, num_pages, page_size, num_layers, num_heads,
-                 head_dim, dtype=np.float32, prefix_cache_pages: int = 0,
-                 ctx=None):
+    def __init__(self, num_pages, page_size, num_layers=None, num_heads=None,
+                 head_dim=None, dtype=np.float32, prefix_cache_pages: int = 0,
+                 ctx=None, planes=None, num_slots: int = 0):
         from .. import ndarray as nd
         from ..context import current_context
 
@@ -144,16 +172,33 @@ class PagedKVPool:
             raise ValueError("page_size must be >= 1")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.num_layers = int(num_layers)
         self.prefix_cache_pages = max(0, int(prefix_cache_pages))
         self._dtype = np.dtype(dtype)
-        shape = (self.num_pages, self.page_size, int(num_heads),
-                 int(head_dim))
+        if planes is None:
+            planes = [("layer%d_%s_pool" % (i, kv), "paged",
+                       (int(num_heads), int(head_dim)), self._dtype)
+                      for i in range(int(num_layers)) for kv in "kv"]
+        self.specs = [PlaneSpec(n, k, tuple(int(d) for d in shp),
+                                np.dtype(dt)) for n, k, shp, dt in planes]
+        has_slots = any(s.kind == "slot" for s in self.specs)
+        if has_slots and num_slots < 2:
+            raise ValueError("need >= 2 slots (slot 0 is reserved scratch)")
+        self.num_slots = int(num_slots) if has_slots else 0
         ctx = ctx or current_context()
-        self.k_pools = [nd.zeros(shape, ctx, dtype=self._dtype)
-                        for _ in range(self.num_layers)]
-        self.v_pools = [nd.zeros(shape, ctx, dtype=self._dtype)
-                        for _ in range(self.num_layers)]
+        self._planes = [
+            nd.zeros(((self.num_pages, self.page_size) if s.kind == "paged"
+                      else (self.num_slots,)) + s.shape, ctx, dtype=s.dtype)
+            for s in self.specs]
+        self._paged = [p for p, s in zip(self._planes, self.specs)
+                       if s.kind == "paged"]
+        self.k_pools, self.v_pools = self._paged[0::2], self._paged[1::2]
+        self.num_layers = len(self.k_pools)
+        # bytes of one sequence's slots over every slot plane
+        self.slot_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                              for s in self.specs if s.kind == "slot")
+        self._free_slots: List[int] = list(range(self.num_slots - 1, 0, -1))
+        self._slots: Dict[object, int] = {}
+        self.peak_slots = 0
         self._writers: Dict[int, object] = {}  # prefill length -> program
         self._copy_page = _copy_page_program()
         self._lock = threading.Lock()
@@ -185,6 +230,10 @@ class PagedKVPool:
         self._c_evict = reg.counter("mxtpu_gen_prefix_evictions_total")
         self._c_cow = reg.counter("mxtpu_gen_kv_cow_copies_total")
         self._c_hit_tokens = reg.counter("mxtpu_gen_prefix_hit_tokens_total")
+        if self.num_slots:
+            self._g_slots = reg.gauge("mxtpu_gen_state_slots_live")
+            self._g_slots_peak = reg.gauge("mxtpu_gen_state_slots_peak")
+            self._g_state_bytes = reg.gauge("mxtpu_gen_state_bytes")
         _telemetry.register_collector(self)
 
     # -- accounting -------------------------------------------------------
@@ -241,7 +290,25 @@ class PagedKVPool:
         with self._lock:
             return len(self._tables)
 
+    def free_slots(self) -> Optional[int]:
+        """State slots an admission can take; None where the pool has no
+        slot planes (nothing to run out of)."""
+        if not self.num_slots:
+            return None
+        with self._lock:
+            return len(self._free_slots)
+
+    def state_slot(self, seq_id) -> int:
+        """The slot of every slot plane that holds this sequence's state."""
+        with self._lock:
+            return self._slots[seq_id]
+
     def _refresh_gauges_locked(self):
+        if self.num_slots:
+            self.peak_slots = max(self.peak_slots, len(self._slots))
+            self._g_slots.set(len(self._slots))
+            self._g_slots_peak.set(self.peak_slots)
+            self._g_state_bytes.set(len(self._slots) * self.slot_bytes)
         live = self._live_locked()
         if live > self.peak_pages:
             self.peak_pages = live
@@ -379,7 +446,13 @@ class PagedKVPool:
                 else:
                     self._c_misses.inc()
             fresh_need = need_total - len(taken)
+            if self.num_slots and not self._free_slots:
+                raise KVPoolExhaustedError(
+                    "state slots exhausted: all %d hold a live sequence; "
+                    "retry, shed, or preempt" % (self.num_slots - 1))
             self._reserve_locked(fresh_need)
+            if self.num_slots:
+                self._slots[seq_id] = self._free_slots.pop()
             for page, key in zip(taken, digests):
                 r = self._ref.get(page, 0)
                 if r == 0:
@@ -430,6 +503,9 @@ class PagedKVPool:
             pages = self._tables.pop(seq_id, None)
             self._lengths.pop(seq_id, None)
             self._chain.pop(seq_id, None)
+            slot = self._slots.pop(seq_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
             if pages:
                 # reversed keeps the legacy free-list LIFO order: a
                 # follow-up alloc reuses the pages lowest-id-first
@@ -533,9 +609,12 @@ class PagedKVPool:
 
     # -- the planes (device side) ----------------------------------------
     def planes(self) -> list:
-        """The planes in the order the programs take and return them:
-        ``[k0, v0, k1, v1, ...]``."""
-        return [p for kv in zip(self.k_pools, self.v_pools) for p in kv]
+        """The planes in the order the programs take and return them
+        (``specs``' order): ``[k0, v0, k1, v1, ...]`` where K/V is all."""
+        return list(self._planes)
+
+    def plane_names(self) -> List[str]:
+        return [s.name for s in self.specs]
 
     def device_bytes(self) -> int:
         return sum(int(np.prod(p.shape)) * p.dtype.itemsize
@@ -545,44 +624,59 @@ class PagedKVPool:
         return sorted({str(d) for p in self.planes()
                        for d in p._data.devices()})
 
-    def _run(self, program, *args):
-        """One donating program over the planes: the old buffers are dead
+    def _run(self, program, planes, *args):
+        """One donating program over ``planes``: the old buffers are dead
         when it returns and the planes hold its outputs."""
-        planes = self.planes()
         for plane, new in zip(planes,
                               program([p._data for p in planes], *args)):
             plane._set(new)
 
-    def write_slots(self, slabs, slots):
-        """Scatter ``slabs`` — ``[k0, v0, k1, v1, ...]``, each ``(batch,
-        length, heads, head_dim)``, on the device or the host — into the
-        planes on the device: row ``[b, t]`` of every slab goes to token
-        slot ``slots[b, t]`` (``page * page_size + offset``).  One program
-        a length bucket (``jit_pool_write_L<length>``)."""
+    def write_slots(self, slabs, slots, state_slots=None):
+        """Scatter ``slabs`` — one a plane, in the planes' order, on the
+        device or the host — into the planes on the device.  A paged
+        plane's slab is ``(batch, length, heads, head_dim)``: row ``[b,
+        t]`` goes to token slot ``slots[b, t]`` (``page * page_size +
+        offset``).  A slot plane's slab is ``(batch,) + shape``: row ``b``
+        goes to slot ``state_slots[b]`` (default: scratch).  One program a
+        length bucket (``jit_pool_write_L<length>``)."""
         length = int(slots.shape[1])
         program = self._writers.get(length)
         if program is None:
-            program = self._writers[length] = _write_program(length)
-        self._run(program, list(slabs), np.asarray(slots, np.int32))
+            program = self._writers[length] = _write_program(
+                length, [s.kind for s in self.specs])
+        more = ()
+        if self.num_slots:
+            more = (np.zeros(slots.shape[:1], np.int32)
+                    if state_slots is None
+                    else np.asarray(state_slots, np.int32),)
+        self._run(program, self._planes, list(slabs),
+                  np.asarray(slots, np.int32), *more)
 
     def write_prefill(self, seq_ids, slabs, lengths):
-        """Scatter a prefill pass's K/V into its sequences' pages: batch
-        row ``b`` of the slabs (see :meth:`write_slots`) belongs to
-        ``seq_ids[b]``, of which only the first ``lengths[b]`` rows are
-        real.  Padding — the rest of a row, and batch rows beyond
-        ``seq_ids`` — lands in scratch page 0."""
+        """Scatter a prefill pass's K/V into its sequences' pages, and its
+        final states into their slots: batch row ``b`` of the slabs (see
+        :meth:`write_slots`) belongs to ``seq_ids[b]``, of which only the
+        first ``lengths[b]`` rows are real.  Padding — the rest of a row,
+        and batch rows beyond ``seq_ids`` — lands in scratch page 0 and
+        scratch slot 0."""
         ps = self.page_size
-        slots = np.zeros(slabs[0].shape[:2], np.int32)
+        paged = next(slab for slab, s in zip(slabs, self.specs)
+                     if s.kind == "paged")
+        slots = np.zeros(paged.shape[:2], np.int32)
+        state_slots = np.zeros(paged.shape[:1], np.int32)
         with self._lock:
             for b, (seq_id, n) in enumerate(zip(seq_ids, lengths)):
                 pos = np.arange(int(n))
                 pages = np.asarray(self._tables[seq_id], np.int32)
                 slots[b, :int(n)] = pages[pos // ps] * ps + pos % ps
-        self.write_slots(slabs, slots)
+                state_slots[b] = self._slots.get(seq_id, 0)
+        self.write_slots(slabs, slots, state_slots)
 
     def copy_page(self, src: int, dst: int):
-        """Page ``src`` onto page ``dst`` in every plane, one program."""
-        self._run(self._copy_page, np.int32(src), np.int32(dst))
+        """Page ``src`` onto page ``dst`` in every paged plane, one
+        program."""
+        self._run(self._copy_page, self._paged, np.int32(src),
+                  np.int32(dst))
 
     def read_page(self, layer: int, page: int):
         """``(k, v)`` of one page of one layer, read to the host: for
@@ -593,7 +687,12 @@ class PagedKVPool:
     def snapshot(self) -> dict:
         with self._lock:
             live = self._live_locked()
-            return {"capacity": self.capacity, "live_pages": live,
+            slots = {} if not self.num_slots else {"state_slots": {
+                "capacity": self.num_slots - 1, "live": len(self._slots),
+                "peak": self.peak_slots, "slot_bytes": self.slot_bytes,
+                "bytes": len(self._slots) * self.slot_bytes}}
+            return {**slots,
+                    "capacity": self.capacity, "live_pages": live,
                     "peak_pages": self.peak_pages,
                     "sequences": len(self._tables),
                     "occupancy": live / float(self.capacity),

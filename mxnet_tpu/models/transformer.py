@@ -240,3 +240,50 @@ def get_transformer_lm_catchup(vocab_size=32000, num_layers=4, num_heads=8,
     window slot ``w``."""
     return _lane_graph(vocab_size, num_layers, num_heads, hidden,
                        max_seq_len, page_size, window=True)
+
+
+class TransformerLMFamily:
+    """What the generation engine asks of a model family, answered for
+    this module's block (generation/engine.py, "The family seam"): the
+    graphs it has and the planes they carry.  ``dtype`` is the K/V planes'.
+    """
+
+    name = "transformer_lm"
+    prefill_inputs = ("data",)
+
+    def __init__(self, vocab_size, num_layers, num_heads, hidden,
+                 dtype="float32"):
+        self.vocab_size, self.num_layers = int(vocab_size), int(num_layers)
+        self.num_heads, self.hidden = int(num_heads), int(hidden)
+        self.kv_heads, self.head_dim = self.num_heads, hidden // num_heads
+        self.dtype = str(dtype)
+        self._sizes = dict(vocab_size=self.vocab_size,
+                           num_layers=self.num_layers,
+                           num_heads=self.num_heads, hidden=self.hidden)
+
+    def spec(self):
+        return dict(self._sizes, family=self.name, dtype=self.dtype)
+
+    def engine_spec(self):
+        """The keys this family adds to ``DecodeEngine.spec()``: the
+        engine's own width keywords, as before there were families."""
+        return dict(self._sizes)
+
+    def planes(self):
+        """(name, kind, shape of a token's entry, dtype) of every carried
+        plane, in the lane program's order."""
+        return [(name, "paged", (self.kv_heads, self.head_dim), self.dtype)
+                for name in lane_plane_names(self.num_layers)]
+
+    def prefill_symbol(self, seq_len, max_seq_len):
+        return get_transformer_lm_prefill(seq_len=seq_len,
+                                          max_seq_len=max_seq_len,
+                                          **self._sizes)
+
+    def decode_symbol(self, max_seq_len, page_size):
+        return get_transformer_lm_decode(max_seq_len=max_seq_len,
+                                         page_size=page_size, **self._sizes)
+
+    def catchup_symbol(self, max_seq_len, page_size):
+        return get_transformer_lm_catchup(max_seq_len=max_seq_len,
+                                          page_size=page_size, **self._sizes)

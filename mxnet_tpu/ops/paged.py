@@ -5,10 +5,12 @@ Three ops:
 
 * ``_contrib_DenseAttention`` — plain dense softmax attention over
   ``[b, s, h, d]`` (the ``parallel.ring.local_attention`` oracle as a
-  symbol op).  The generation prefill path uses it instead of the Pallas
-  flash kernels because interpret-mode Pallas is orders of magnitude too
-  slow on CPU, and prefill happens once per sequence; on TPU the flash
-  kernels remain the training/high-MFU choice (models/transformer.py).
+  symbol op); grouped-query when ``key`` / ``value`` hold fewer heads than
+  ``query`` (query head ``i`` reads K/V head ``i // (h / kv_heads)``).
+  The generation prefill path uses it instead of the Pallas flash kernels
+  because interpret-mode Pallas is orders of magnitude too slow on CPU,
+  and prefill happens once per sequence; on TPU the flash kernels remain
+  the training/high-MFU choice (models/transformer.py).
 
 * ``_contrib_PagedAttention`` — one autoregressive decode step over a
   paged KV pool (the vLLM PagedAttention layout): each decode *lane*
@@ -60,12 +62,39 @@ def _dense_infer(attrs, shapes):
                   "scale": Param("float-or-none", None)},
           infer_shape=_dense_infer, hint="denseattention")
 def _dense_attention(opctx, attrs, query, key, value):
+    import jax.numpy as jnp
+
     from ..parallel.ring import local_attention
 
     scale = attrs.get("scale")
-    return local_attention(query, key, value,
-                           causal=bool(attrs.get("causal", True)),
-                           scale=None if scale is None else float(scale))
+    scale = None if scale is None else float(scale)
+    causal = bool(attrs.get("causal", True))
+    b, s, heads, hd = query.shape
+    kv_heads = key.shape[2]
+    if kv_heads == heads:
+        return local_attention(query, key, value, causal=causal, scale=scale)
+    # grouped-query: the group is an axis of the query, K/V are read as
+    # they are (never repeated); local_attention's numerics
+    group = _group(heads, kv_heads)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
+    q = query.reshape(b, s, kv_heads, group, hd)
+    sc = jnp.einsum("bqkgd,btkd->bkgqt", q, key).astype(jnp.float32) * scale
+    if causal:
+        mask = jnp.arange(s)[:, None] >= jnp.arange(key.shape[1])[None, :]
+        sc = jnp.where(mask, sc, _NEG)
+    p = jnp.exp(sc - sc.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    out = jnp.einsum("bkgqt,btkd->bqkgd", p, value).astype(query.dtype)
+    return out.reshape(b, s, heads, hd)
+
+
+def _group(heads, kv_heads):
+    """Query heads a K/V head: head ``i`` reads K/V head ``i // group``."""
+    if heads % kv_heads:
+        raise ValueError("%d query heads over %d K/V heads" % (heads,
+                                                                kv_heads))
+    return heads // kv_heads
 
 
 def _paged_infer(attrs, shapes):
@@ -165,17 +194,18 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
             flat_v.reshape(num_pages, ps, heads, hd))
 
 
-def decode_formulation(platform, heads, head_dim, dtype):
+def decode_formulation(platform, heads, head_dim, dtype, kv_heads=None):
     """Which formulation ``_contrib_PagedAttention`` runs: ``"pallas"`` —
     the kernel that reads the live pages where they lie — where the
-    operands live on a TPU and a token's K (or V) is whole float32 tiles
-    (a page is then one contiguous block of the plane, the DMA's unit);
-    ``"xla"`` — the gather over the whole table — anywhere else.  An
+    operands live on a TPU, a token's K (or V) is whole float32 tiles
+    (a page is then one contiguous block of the plane, the DMA's unit) and
+    every query head has a K/V head of its own (the kernel knows no
+    groups); ``"xla"`` — the gather over the whole table — anywhere else.  An
     observation of the operands, as ``interpret.interpret_for`` is for the
     flash kernels: no attribute, environment variable or autotune entry
     chooses."""
     tiled = (np.dtype(dtype) == np.float32 and head_dim % 128 == 0
-             and heads % 8 == 0)
+             and heads % 8 == 0 and kv_heads in (None, heads))
     return "pallas" if platform == "tpu" and tiled else "xla"
 
 
@@ -183,11 +213,14 @@ def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
     """The XLA formulation, and the kernel's oracle: gather every lane's
     whole table (``max_pages * page_size`` slots), put this step's own K/V
     into the gathered copy at its position, masked softmax; the pool's
-    update is a scatter of ``lanes`` rows."""
+    update is a scatter of ``lanes`` rows.  Grouped-query where ``q`` holds
+    more heads than the planes: the planes are by K/V heads, and query head
+    ``i`` reads K/V head ``i // group``."""
     import jax.numpy as jnp
 
     num_pages, ps, heads, hd = k_pool.shape
     lanes, max_pages = pt.shape
+    group = _group(q.shape[1], heads)
     flat_k = k_pool.reshape(num_pages * ps, heads, hd)
     flat_v = v_pool.reshape(num_pages * ps, heads, hd)
 
@@ -212,14 +245,23 @@ def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
     flat_v = flat_v.at[slot].set(v_new)
 
     # -- masked softmax attention (local_attention numerics) -------------
-    s = jnp.einsum("lhd,lthd->lht", q, keys).astype(jnp.float32) * scale
     valid = (jnp.arange(max_pages * ps, dtype=jnp.int32)[None, :]
              <= pos[:, None])  # causal: history up to and incl. this token
-    s = jnp.where(valid[:, None, :], s, _NEG)
+    if group == 1:
+        s = jnp.einsum("lhd,lthd->lht", q, keys).astype(jnp.float32) * scale
+        s = jnp.where(valid[:, None, :], s, _NEG)
+    else:
+        s = jnp.einsum("lhgd,lthd->lhgt", q.reshape(lanes, heads, group, hd),
+                       keys).astype(jnp.float32) * scale
+        s = jnp.where(valid[:, None, None, :], s, _NEG)
     p = jnp.exp(s - s.max(-1, keepdims=True))
     p = p / p.sum(-1, keepdims=True)
-    out = jnp.einsum("lht,lthd->lhd", p, vals).astype(q.dtype)
-    return out, flat_k.reshape(k_pool.shape), flat_v.reshape(v_pool.shape)
+    if group == 1:
+        out = jnp.einsum("lht,lthd->lhd", p, vals)
+    else:
+        out = jnp.einsum("lhgt,lthd->lhgd", p, vals).reshape(q.shape)
+    return (out.astype(q.dtype), flat_k.reshape(k_pool.shape),
+            flat_v.reshape(v_pool.shape))
 
 
 # page slots per plane in VMEM; one less is in flight.  At the cell's shapes
@@ -384,8 +426,10 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     """One decode step for ``lanes`` sequences at once.
 
     Shapes (all static):
-      q, k_new, v_new : (lanes, heads, head_dim) — this step's projections
-      k_pool, v_pool  : (num_pages, page_size, heads, head_dim)
+      q               : (lanes, heads, head_dim) — this step's projections
+      k_new, v_new    : (lanes, kv_heads, head_dim); ``kv_heads`` divides
+                        ``heads`` (grouped-query), or is ``heads``
+      k_pool, v_pool  : (num_pages, page_size, kv_heads, head_dim)
       page_table      : (lanes, max_pages) pool-page ids per lane, in
                         sequence order (float carrier, cast to int32 —
                         Predictor feeds every input as its bind dtype)
@@ -403,6 +447,9 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     from .interpret import platform_of
 
     heads, hd = q.shape[-2:]
+    if k_new.shape[-2] != k_pool.shape[2]:
+        raise ValueError("this step's K holds %d heads, the pool's planes %d"
+                         % (k_new.shape[-2], k_pool.shape[2]))
     if int(attrs["page_size"]) != k_pool.shape[1]:
         raise ValueError("page_size %s, but the pool's pages hold %d slots"
                          % (attrs["page_size"], k_pool.shape[1]))
@@ -410,7 +457,7 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     scale = (1.0 / np.sqrt(hd)) if scale is None else float(scale)
     decode = {"pallas": _kernel_decode, "xla": _gather_decode}[
         decode_formulation(platform_of(q, k_pool, v_pool), heads, hd,
-                           k_pool.dtype)]
+                           k_pool.dtype, kv_heads=k_pool.shape[2])]
     return decode(q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
                   k_pool, v_pool, page_table.astype(jnp.int32),
                   positions.astype(jnp.int32), scale)

@@ -112,22 +112,26 @@ class ModelSpec:
             return 0
 
     def kv_footprint(self) -> int:
-        """Paged-KV-pool bytes a generate-capable model pins: K and V
-        pages across layers at the spec's (or default) pool geometry."""
+        """Pool bytes a generate-capable model pins: K and V pages across
+        layers at the spec's (or default) pool geometry and, for a family
+        that carries them, a lane's state slots (and scratch) in every
+        state plane, as ``DecodeEngine`` sizes them."""
         gs = self.generator_spec
         if not gs:
             return 0
-        num_layers = int(gs.get("num_layers", 4))
-        num_heads = int(gs.get("num_heads", 8))
-        hidden = int(gs.get("hidden", 512))
-        head_dim = hidden // num_heads
-        page_size = int(gs.get("page_size")
-                        or env("MXNET_GEN_PAGE_SIZE", 16, int))
-        num_pages = int(gs.get("num_pages")
-                        or env("MXNET_GEN_NUM_PAGES", 128, int))
-        dtype_size = np.dtype(gs.get("dtype", np.float32)).itemsize
-        return (2 * num_layers * num_pages * page_size
-                * num_heads * head_dim * dtype_size)
+        from ..models import generator_family
+
+        entries = {
+            "paged": int(gs.get("num_pages")
+                         or env("MXNET_GEN_NUM_PAGES", 128, int))
+            * int(gs.get("page_size")
+                  or env("MXNET_GEN_PAGE_SIZE", 16, int)),
+            "slot": 1 + max(gs.get("lane_buckets")
+                            or [env("MXNET_GEN_MAX_LANES", 8, int)])}
+        planes = generator_family(**gs).planes()
+        return sum(entries[kind] * int(np.prod(shape))
+                   * np.dtype(dtype).itemsize
+                   for _, kind, shape, dtype in planes)
 
     def exec_footprint(self) -> int:
         """Executable bytes: the live-run measurement when one exists,

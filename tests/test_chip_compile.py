@@ -215,3 +215,75 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     copied = [line for line in text.splitlines() if plane in line
               and re.search(r" copy(-start)?\(", line)]
     assert not copied if donated else len(copied) >= 2 * layers
+
+
+def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
+                                                         monkeypatch):
+    """The hybrid family's decode step (models/hybrid_lm.py: 12 layers, ``m
+    m m m m a`` twice, at reduced widths with the cell's head, state and
+    convolution sizes) as the engine's Executor builds it, planes carried and
+    donated, compiled for the chip.  Each state-space layer's recurrent state
+    goes through ONE fusion that makes both the new state and the lanes'
+    outputs: no gather of the lanes' slots, no scatter back, no loop over
+    lanes (XLA's lowering of a gather of whole slots), so the plane is read
+    once and written once.  Grouped-query attention takes the XLA
+    formulation: no paged-decode kernel in the program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import compile_cache
+    from mxnet_tpu.models import HybridLM
+    from mxnet_tpu.ops.interpret import bind
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    lanes, slots, pages, max_pages, vocab = 16, 17, 24, 8, 512
+    model = HybridLM(
+        vocab_size=vocab, hidden=512, layer_types=(["mamba"] * 5
+                                                    + ["attention"]) * 2,
+        num_heads=8, kv_heads=2, head_dim=64, intermediate=1024,
+        ssm_heads=16, ssm_head_dim=64, ssm_state=128, conv_kernel=4,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1 / 64.0, logits_scaling=8.0)
+    symbol = model.decode_symbol(128, 16)
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids", "state_slot")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    for name in symbol.list_arguments():
+        if name not in shapes:
+            types[name] = jnp.bfloat16  # the weights
+    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
+                            **shapes)
+    assert str(ex.arg_dict["layer0_in_proj_weight"].dtype) == "bfloat16"
+    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
+    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
+    carried, args, aux, rng = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ex._forward_args(None))
+    assert ex._carried_names() == planes
+    fwd = ex._get_fwd(False)
+    assert ex.carry_donated
+    compiled = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
+        .compile()
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-1])] == \
+        [((lanes, vocab), "float32"), ((lanes,), "float32")]
+    assert len(outs) == 2 + len(planes)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and " while(" not in text
+    state = "f32[%d,16,64,128]" % slots
+    moved = [line for line in text.splitlines() if state in line
+             and re.search(r" (gather|scatter|dynamic-update-slice)\(", line)]
+    assert not moved
+    # new state and outputs from one fusion, once a state-space layer
+    both = [line for line in text.splitlines() if ") fusion(" in line
+            and state + "{" in line.split(" fusion(")[0]
+            and "f32[%d,16,64]{" % slots in line.split(" fusion(")[0]]
+    assert len(both) == 10

@@ -1,0 +1,266 @@
+"""ops/ssm.py and grouped-query attention, against the plainest form of
+each: the token-by-token recurrence, a convolution written out, K/V heads
+repeated.  Small sizes, float32 unless a case says otherwise; tolerances are
+float32 rounding over a few dozen terms (1e-5 relative) unless stated."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import paged, ssm
+
+H, P, N, K = 3, 4, 5, 4          # heads, head size, state size, conv width
+C = H * P + 2 * N                # [x | B | C]
+SIZES = dict(heads=H, head_dim=P, state=N)
+TOL = dict(rtol=2e-5, atol=2e-5)
+
+
+def _inputs(b, L, seed=0):
+    r = np.random.RandomState(seed)
+    return dict(xbc=r.randn(b, L, C).astype(np.float32),
+                dt=r.randn(b, L, H).astype(np.float32),
+                A_log=np.log(r.uniform(1, 16, H)).astype(np.float32),
+                D=r.randn(H).astype(np.float32),
+                dt_bias=r.randn(H).astype(np.float32))
+
+
+def _recurrence(xbc, dt, A_log, D, dt_bias, S=None):
+    """One sequence, token by token: (y (L, H*P), final state)."""
+    S = np.zeros((H, P, N)) if S is None else np.array(S, np.float64)
+    A = -np.exp(A_log.astype(np.float64))
+    ys = []
+    for t in range(xbc.shape[0]):
+        x = xbc[t, :H * P].reshape(H, P).astype(np.float64)
+        B, Cm = xbc[t, H * P:H * P + N], xbc[t, H * P + N:]
+        d = np.log1p(np.exp(dt[t].astype(np.float64) + dt_bias))
+        S = np.exp(d * A)[:, None, None] * S \
+            + (d[:, None] * x)[:, :, None] * B[None, None, :]
+        ys.append((S @ Cm + D[:, None] * x).reshape(-1))
+    return np.stack(ys), S
+
+
+@pytest.mark.parametrize("L,chunk", [(16, 4), (10, 4), (8, 8), (12, 256),
+                                     (9, 2), (1, 4)])
+def test_scan_is_the_token_by_token_recurrence(L, chunk):
+    """Chunk boundaries included: L a multiple of the chunk, not a multiple
+    (padded inside the scan), one chunk, chunks of two, one token."""
+    a = _inputs(2, L)
+    y, S = ssm.ssm_scan(a["xbc"], a["dt"], a["A_log"], a["D"], a["dt_bias"],
+                        chunk=chunk, **SIZES)
+    for b in range(2):
+        want_y, want_S = _recurrence(a["xbc"][b], a["dt"][b], a["A_log"],
+                                     a["D"], a["dt_bias"])
+        np.testing.assert_allclose(np.asarray(y[b]), want_y, **TOL)
+        np.testing.assert_allclose(np.asarray(S[b]), want_S, **TOL)
+
+
+@pytest.mark.parametrize("length", [1, 2, 3, 5, 8, 11, 16])
+def test_right_padded_prompt_leaves_its_unpadded_state_and_tail(length):
+    """Positions at or past ``length`` contribute nothing; the tail is read
+    at ``length-3 .. length-1`` (zeros before the prompt's start)."""
+    a = _inputs(1, 16, seed=length)
+    r = np.random.RandomState(1)
+    w, bias = r.randn(C, K).astype(np.float32), r.randn(C).astype(np.float32)
+    n = np.array([length], np.int32)
+
+    def run(xbc_raw, dt, n):
+        conv, tail = ssm.causal_conv(xbc_raw, w, bias, n)
+        y, S = ssm.ssm_scan(conv, dt, a["A_log"], a["D"], a["dt_bias"], n,
+                            chunk=4, **SIZES)
+        return np.asarray(y), np.asarray(S), np.asarray(tail)
+
+    y_pad, S_pad, tail_pad = run(a["xbc"], a["dt"], n)
+    y, S, tail = run(a["xbc"][:, :length], a["dt"][:, :length], None)
+    np.testing.assert_allclose(S_pad, S, **TOL)
+    np.testing.assert_array_equal(tail_pad, tail)
+    np.testing.assert_allclose(y_pad[:, :length], y, **TOL)
+    want = np.concatenate([np.zeros((K - 1, C), np.float32),
+                           a["xbc"][0, :length]])[-(K - 1):]
+    np.testing.assert_array_equal(tail[0], want)
+
+
+@pytest.mark.parametrize("split", [1, 4, 7, 12])
+def test_steps_continued_from_a_scan_are_the_whole_recurrence(split):
+    """Prefill ``split`` tokens (right-padded to a bucket of 12), write
+    state and tail into slot 2 of the planes, decode the rest one token a
+    step beside a padded lane on scratch: the outputs and the final state
+    are those of the recurrence over the whole sequence."""
+    L = 16
+    a = _inputs(1, L, seed=split)
+    r = np.random.RandomState(2)
+    w, bias = r.randn(C, K).astype(np.float32), r.randn(C).astype(np.float32)
+    vec = (a["A_log"], a["D"], a["dt_bias"])
+    # the whole sequence in one unpadded pass: the oracle
+    conv_all, _ = ssm.causal_conv(a["xbc"], w, bias)
+    want_y, want_S = _recurrence(np.asarray(conv_all[0]), a["dt"][0], *vec)
+
+    n = np.array([split], np.int32)
+    conv, tail = ssm.causal_conv(a["xbc"][:, :12], w, bias, n)
+    y, S = ssm.ssm_scan(conv, a["dt"][:, :12], *vec, n, chunk=4, **SIZES)
+    np.testing.assert_allclose(np.asarray(y[0, :split]), want_y[:split],
+                               **TOL)
+    states = jnp.zeros((4, H, P, N)).at[2].set(S[0])
+    tails = jnp.zeros((4, K - 1, C)).at[2].set(tail[0])
+    slot = jnp.array([2, 0], jnp.int32)
+    for t in range(split, L):
+        row = jnp.stack([a["xbc"][0, t], jnp.ones(C)])
+        dt = jnp.stack([a["dt"][0, t], jnp.ones(H)])
+        conv, tails = ssm.conv_step(row, w, bias, tails, slot)
+        y, states = ssm.ssm_step(conv, dt, *vec, states, slot, **SIZES)
+        np.testing.assert_allclose(np.asarray(y[0]), want_y[t], **TOL)
+    np.testing.assert_allclose(np.asarray(states[2]), want_S, **TOL)
+    # the other live slots were never touched
+    assert not np.asarray(states[1]).any() and not np.asarray(states[3]).any()
+
+
+def test_a_step_leaves_scratch_and_a_diverged_neighbour_alone():
+    """Lanes parked on slot 0 write nothing there, however many steps they
+    ride (several of them summed into one slot would grow it without
+    bound); a lane whose inputs are NaN keeps them to itself."""
+    a = _inputs(1, 4, seed=9)
+    r = np.random.RandomState(9)
+    w, bias = r.randn(C, K).astype(np.float32), r.randn(C).astype(np.float32)
+    vec = (a["A_log"], a["D"], a["dt_bias"])
+    states = jnp.asarray(r.randn(4, H, P, N), jnp.float32)
+    tails = jnp.asarray(r.randn(4, K - 1, C), jnp.float32)
+    slot = jnp.array([2, 0, 3, 0], jnp.int32)
+    row = jnp.stack([a["xbc"][0, 0], a["xbc"][0, 1],
+                     jnp.full((C,), jnp.nan), a["xbc"][0, 2]])
+    dt = jnp.stack([a["dt"][0, 0], a["dt"][0, 1], jnp.full((H,), jnp.nan),
+                    a["dt"][0, 2]])
+    conv, tails2 = ssm.conv_step(row, w, bias, tails, slot)
+    y, states2 = ssm.ssm_step(conv, dt, *vec, states, slot, **SIZES)
+    for before, after in ((states, states2), (tails, tails2)):
+        np.testing.assert_array_equal(np.asarray(after[0]),
+                                      np.asarray(before[0]))  # scratch
+        np.testing.assert_array_equal(np.asarray(after[1]),
+                                      np.asarray(before[1]))  # no lane's
+        assert np.isfinite(np.asarray(after[2])).all()
+        assert np.isnan(np.asarray(after[3])).any()           # its own
+    # lane 0 alone in a step gives the same output, to the bit
+    conv1, _ = ssm.conv_step(row[:1], w, bias, tails, slot[:1])
+    y1, _ = ssm.ssm_step(conv1, dt[:1], *vec, states, slot[:1], **SIZES)
+    np.testing.assert_array_equal(np.asarray(y[0]), np.asarray(y1[0]))
+    assert np.isfinite(np.asarray(y[1])).all()
+
+
+def test_causal_conv_is_the_convolution_written_out():
+    r = np.random.RandomState(3)
+    x = r.randn(2, 9, C).astype(np.float32)
+    w, bias = r.randn(C, K).astype(np.float32), r.randn(C).astype(np.float32)
+    out, _ = ssm.causal_conv(x, w, bias)
+    xp = np.concatenate([np.zeros((2, K - 1, C), np.float32), x], 1)
+    for t in range(9):
+        pre = sum(xp[:, t + k] * w[:, k] for k in range(K)) + bias
+        np.testing.assert_allclose(np.asarray(out[:, t]),
+                                   pre / (1 + np.exp(-pre)), **TOL)
+
+
+def test_norms_and_gates():
+    r = np.random.RandomState(4)
+    x, z = r.randn(3, 8).astype(np.float32), r.randn(3, 8).astype(np.float32)
+    g = r.randn(8).astype(np.float32)
+    want = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * g
+    got = mx.nd._contrib_RMSNorm(mx.nd.array(x), mx.nd.array(g)).asnumpy()
+    np.testing.assert_allclose(got, want, **TOL)
+    silu = lambda v: v / (1 + np.exp(-v))  # noqa: E731
+    y = x * silu(z)
+    want = y / np.sqrt((y * y).mean(-1, keepdims=True) + 1e-5) * g
+    got = mx.nd._contrib_GatedRMSNorm(mx.nd.array(x), mx.nd.array(z),
+                                      mx.nd.array(g)).asnumpy()
+    np.testing.assert_allclose(got, want, **TOL)
+    got = mx.nd._contrib_SiluGate(mx.nd.array(x)).asnumpy()
+    np.testing.assert_allclose(got, silu(x[:, :4]) * x[:, 4:], **TOL)
+
+
+def test_scaled_logits_are_float32_whatever_the_operands():
+    r = np.random.RandomState(5)
+    x = jnp.asarray(r.randn(3, 8), jnp.bfloat16)
+    table = jnp.asarray(r.randn(11, 8), jnp.bfloat16)
+    got = ssm._scaled_logits(None, {"scale": 0.125}, x, table)
+    assert got.dtype == jnp.float32
+    want = np.asarray(x, np.float32) @ np.asarray(table, np.float32).T / 8
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+
+
+# -- grouped-query attention -------------------------------------------------
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 2), (4, 4), (6, 1)])
+def test_dense_attention_groups_are_repeated_kv_heads(heads, kv_heads):
+    r = np.random.RandomState(6)
+    q = r.randn(2, 7, heads, 8).astype(np.float32)
+    k = r.randn(2, 7, kv_heads, 8).astype(np.float32)
+    v = r.randn(2, 7, kv_heads, 8).astype(np.float32)
+    got = mx.nd._contrib_DenseAttention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), causal=True,
+        scale=0.2).asnumpy()
+    g = heads // kv_heads
+    want = mx.nd._contrib_DenseAttention(
+        mx.nd.array(q), mx.nd.array(np.repeat(k, g, 2)),
+        mx.nd.array(np.repeat(v, g, 2)), causal=True, scale=0.2).asnumpy()
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 2), (6, 1)])
+def test_gather_decode_groups_are_repeated_kv_heads(heads, kv_heads):
+    """Planes by K/V heads against planes with every K/V head repeated:
+    the same attention, and the same rows written."""
+    r = np.random.RandomState(7)
+    lanes, ps, pages, hd, g = 3, 4, 9, 8, heads // kv_heads
+    q = jnp.asarray(r.randn(lanes, heads, hd), jnp.float32)
+    k_new = jnp.asarray(r.randn(lanes, kv_heads, hd), jnp.float32)
+    v_new = jnp.asarray(r.randn(lanes, kv_heads, hd), jnp.float32)
+    k_pool = jnp.asarray(r.randn(pages, ps, kv_heads, hd), jnp.float32)
+    v_pool = jnp.asarray(r.randn(pages, ps, kv_heads, hd), jnp.float32)
+    pt = jnp.array([[1, 2, 0], [3, 4, 5], [0, 0, 0]], jnp.int32)
+    pos = jnp.array([5, 9, 0], jnp.int32)
+    out, k_out, v_out = paged._gather_decode(q, k_new, v_new, k_pool, v_pool,
+                                             pt, pos, 0.3)
+    rep = lambda a: jnp.repeat(a, g, axis=-2)  # noqa: E731
+    want, k_want, v_want = paged._gather_decode(
+        q, rep(k_new), rep(v_new), rep(k_pool), rep(v_pool), pt, pos, 0.3)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+    np.testing.assert_array_equal(np.asarray(rep(k_out)), np.asarray(k_want))
+    np.testing.assert_array_equal(np.asarray(rep(v_out)), np.asarray(v_want))
+
+
+def test_grouped_planes_take_the_gather_on_every_platform():
+    """The Pallas kernel knows no groups: planes with fewer heads than the
+    query go through the XLA formulation even where it would run."""
+    assert paged.decode_formulation("tpu", 16, 128, np.float32) == "pallas"
+    assert paged.decode_formulation("tpu", 16, 128, np.float32,
+                                    kv_heads=16) == "pallas"
+    assert paged.decode_formulation("tpu", 32, 128, np.float32,
+                                    kv_heads=8) == "xla"
+    assert paged.decode_formulation("tpu", 32, 64, jnp.bfloat16,
+                                    kv_heads=8) == "xla"
+
+
+def test_paged_attention_refuses_planes_of_other_heads():
+    with pytest.raises(ValueError, match="heads"):
+        paged._paged_attention(
+            None, {"page_size": 4}, jnp.zeros((2, 4, 8)),
+            jnp.zeros((2, 2, 8)), jnp.zeros((2, 2, 8)),
+            jnp.zeros((3, 4, 4, 8)), jnp.zeros((3, 4, 4, 8)),
+            jnp.zeros((2, 2)), jnp.zeros((2,)))
+
+
+def test_ops_carry_their_scopes():
+    """``ssm_step`` / ``ssm_scan`` reach the compiled text as scopes: what
+    the per-layer metrics of the device trace match."""
+    a = _inputs(1, 8)
+    vec = (a["A_log"], a["D"], a["dt_bias"])
+
+    def scan(x, dt):
+        return ssm._ssm_scan(None, dict(SIZES, chunk=4), x, dt, *vec)
+
+    def step(x, dt, states, slot):
+        return ssm._ssm_step(None, SIZES, x, dt, *vec, states, slot)
+
+    text = jax.jit(scan).lower(a["xbc"], a["dt"]).as_text(debug_info=True)
+    assert "ssm_scan" in text
+    text = jax.jit(step).lower(a["xbc"][:, 0], a["dt"][:, 0],
+                               jnp.zeros((2, H, P, N)),
+                               jnp.zeros((1,))).as_text(debug_info=True)
+    assert "ssm_step" in text
