@@ -85,6 +85,7 @@ continues seamlessly), which bounds memory without ever deadlocking.
 """
 from __future__ import annotations
 
+import json
 import logging
 import queue
 import threading
@@ -102,12 +103,25 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
 from ..ops.paged import decode_formulation
+from ..ops.ssm import step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
 __all__ = ["DecodeEngine", "GenStream", "StateNotRebuildableError"]
 
 # what ids, positions, sources, slots and page tables are fed as
 _CARRIER = np.dtype(np.float32)
+
+
+def _state_step(symbol, pool):
+    """``(head_dim, state, plane dtype)`` of a lane graph's
+    ``_contrib_SSMStep`` nodes (its first: a family's state-space layers
+    are of one shape), what ``ops/ssm.py`` ``step_formulation`` picks from;
+    None for a graph without one."""
+    nodes = json.loads(symbol.tojson())["nodes"]
+    dtypes = {s.name: s.dtype for s in pool.specs}
+    return next(((int(n["attr"]["head_dim"]), int(n["attr"]["state"]),
+                  dtypes[nodes[n["inputs"][5][0]]["name"]])
+                 for n in nodes if n["op"] == "_contrib_SSMStep"), None)
 
 
 class StateNotRebuildableError(MXNetError):
@@ -612,6 +626,8 @@ class DecodeEngine:
                                     "prefill_L%d")
         self._decode = lane_rig(family, self._params, self.pool, "gen-step",
                                 "decode_b%d")
+        self._ssm_step = _state_step(
+            self._decode[self.max_lanes]._symbol, self.pool)
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -970,6 +986,10 @@ class DecodeEngine:
                     "kv": self.pool.snapshot()}
             if self.pool.num_slots:
                 snap["state_slots"] = snap["kv"]["state_slots"]
+            if self._ssm_step:
+                # likewise for the lane program's state step (ops/ssm.py)
+                snap["ssm_step"] = step_formulation(
+                    self._device.platform, *self._ssm_step)
             if self._draft is not None:
                 snap["draft"] = {
                     "k": self._draft["k"],

@@ -24,12 +24,19 @@ with ``[x | B | C]`` the output of a causal depthwise convolution (width
   table indexes K/V pages; the engine carries the planes through the step
   donated (``Executor.set_carried``), so the update is in place.  Slot 0
   is scratch: padded lanes point there (generation/kv_pool.py), and a step
-  neither reads nor writes it.
+  leaves its bits alone.  The recurrence's step has two formulations, one
+  op (:func:`step_formulation` picks by where the operands live, as
+  ``ops/paged.py`` does for attention): on a TPU one Pallas kernel a layer
+  steps each live lane's slot where it lies in the plane; anywhere else
+  XLA makes one fused pass over the whole plane, which is also the
+  kernel's oracle (tests/test_ssm_ops.py).
 
 ``dt``, ``A``, the recurrence and the state are float32 whatever the
 activations' dtype; every op returns activations in the dtype it was given.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -273,31 +280,156 @@ def ssm_scan(xbc, dt_raw, A_log, D, dt_bias, length=None, *, heads,
     return y.astype(xbc.dtype), S
 
 
+def _routed_step(decay, u, B, C, states, slot):
+    """The XLA formulation, and the kernel's oracle.  One fused multiply-add
+    over the WHOLE plane and one reduction over its last axis: the plane is
+    read once and written once, in place when it is donated, with no gather
+    and no scatter (the lanes' small vectors are routed to slot order
+    instead, :func:`_route`); a slot no lane names decays by exactly 1 and
+    takes exactly 0, so it keeps its bits.  The engine holds one slot a lane
+    and the scratch slot, so a full step moves the lanes' state and one slot
+    more."""
+    route = _route(slot, states.shape[0])
+    idle = 1.0 - jnp.any(route, axis=0).astype(_F32)  # (slots,)
+    decay = _to_slots(route, decay) + idle[:, None]
+    u, Bs, Cs = (_to_slots(route, a) for a in (u, B, C))
+    S = decay[:, :, None, None] * states.astype(_F32) \
+        + u[..., None] * Bs[:, None, None, :]
+    y = _to_lanes(route, jnp.sum(S * Cs[:, None, None, :], axis=-1))
+    return y, S.astype(states.dtype)
+
+
+# bytes of one block of the plane in VMEM: the kernel's pipeline holds four
+# (two in flight each way) and the kernel a fifth, and its first read and
+# last write hide behind nothing, so a block is a small part of a lane's
+# slot (2 MB at the cell's shapes) and still a DMA long enough to run at
+# the rate the HBM gives reads and writes together
+_BLOCK_BYTES = 1 << 20
+
+
+def _head_block(heads, head_dim, state):
+    """Heads a block of the kernel: the most that divide ``heads`` and fit
+    ``_BLOCK_BYTES``."""
+    most = max(1, _BLOCK_BYTES // (4 * head_dim * state))
+    return max(h for h in range(1, most + 1) if heads % h == 0)
+
+
+def _step_kernel(slot_ref, decay_ref, u_ref, b_ref, c_ref, s_in, y_ref,
+                 s_out, taken):
+    """One lane, one block of its heads.  ``s_in`` / ``s_out`` are the same
+    block of the plane (the lane's slot, by the index map), ``u_ref`` and
+    ``y_ref`` (head_dim, heads of the block): a head's column lies along
+    the sublanes, as a state tile's rows do.  Two passes over the block's
+    heads, what the state takes (``u (outer) B``: a broadcast along the
+    lanes) into ``taken`` first, then the update and its sum over the
+    lanes: interleaved head by head the two cross-lane operations stall
+    each other (124 us of arithmetic a call at the cell's shapes against
+    44, where the DMAs take 105: my chip runs, PR 33)."""
+    from jax.experimental import pallas as pl
+
+    lane, block = pl.program_id(0), pl.program_id(1)
+    heads = s_in.shape[1]
+    live = slot_ref[lane] > 0
+
+    @pl.when(live)
+    def _():
+        B, C = b_ref[pl.ds(lane, 1), :], c_ref[pl.ds(lane, 1), :]  # (1, N)
+        u = u_ref[0, 0]
+        for h in range(heads):
+            taken[h] = u[:, h:h + 1] * B
+        at = lax.broadcasted_iota(jnp.int32, u.shape, 1)
+        y = jnp.zeros(u.shape, _F32)
+        for h in range(heads):
+            S = decay_ref[lane, block * heads + h] * s_in[0, h] + taken[h]
+            s_out[0, h] = S
+            y = jnp.where(at == h, jnp.sum(S * C, axis=-1, keepdims=True), y)
+        y_ref[0, 0] = y
+
+    # parked on scratch: the slot's bits go back as they came (several
+    # padded lanes name it at once), and the lane's y is D x alone
+    @pl.when(jnp.logical_not(live))
+    def _():
+        s_out[...] = s_in[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kernel_step(decay, u, B, C, states, slot, interpret=False):
+    """The Pallas formulation: the LIVE lanes' slots only, where they lie.
+    ``slot`` is the scalar-prefetch operand and the plane's index map reads
+    it, so each grid step's block is ``(1, heads a block, head_dim, state)``
+    of the plane at ``slot[lane]``, fetched and written back by the call's
+    own pipeline; the plane is aliased to its output, so a donated one goes
+    through in place and a slot no lane names is not touched at all (XLA
+    sees no whole-plane operand to stage in another memory space).  Each
+    live lane owns its slot (the pool's contract): in-place blocks never
+    overlap.  ``decay`` (lanes, heads) comes in SMEM (a scalar a head),
+    ``B`` / ``C`` (lanes, state) whole in VMEM, ``u`` (lanes, heads,
+    head_dim) with the heads of a block last.  Jitted on its own so that a
+    lane program's 36 call sites trace and lower the kernel once a start
+    (as ``ops/paged.py`` ``_kernel_decode``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, head_dim = u.shape
+    state = states.shape[-1]
+    hb = _head_block(heads, head_dim, state)
+    blocks = heads // hb
+    columns = pl.BlockSpec((1, 1, head_dim, hb),
+                           lambda lane, b, slot: (lane, b, 0, 0))
+    whole = pl.BlockSpec((lanes, state), lambda lane, b, slot: (0, 0))
+    in_place = pl.BlockSpec((1, hb, head_dim, state),
+                            lambda lane, b, slot: (slot[lane], b, 0, 0))
+    y, S = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(lanes, blocks),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), columns, whole,
+                      whole, in_place],
+            out_specs=[columns, in_place],
+            scratch_shapes=[pltpu.VMEM((hb, head_dim, state), _F32)]),
+        out_shape=[jax.ShapeDtypeStruct((lanes, blocks, head_dim, hb), _F32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        # operands count the scalar-prefetch one: the plane is 5
+        input_output_aliases={5: 1},
+        name="ssm_step", interpret=interpret,
+    )(slot, decay, u.reshape(lanes, blocks, hb, head_dim).swapaxes(2, 3), B,
+      C, states)
+    return y.swapaxes(2, 3).reshape(lanes, heads, head_dim), S
+
+
+def step_formulation(platform, head_dim, state, dtype):
+    """Which formulation ``_contrib_SSMStep`` runs: ``"pallas"`` -- the
+    kernel that steps the live lanes' slots where they lie -- where the
+    operands live on a TPU, the plane is float32 and a head's state is
+    whole tiles (a block of heads is then one contiguous piece of a slot);
+    ``"xla"`` -- one pass over the whole plane -- anywhere else.  An
+    observation of the operands, as ``ops/paged.py`` ``decode_formulation``
+    is: no attribute, environment variable or autotune entry chooses."""
+    tiled = (jnp.dtype(dtype) == jnp.float32 and head_dim % 8 == 0
+             and state % 128 == 0)
+    return "pallas" if platform == "tpu" and tiled else "xla"
+
+
 def ssm_step(xbc, dt_raw, A_log, D, dt_bias, states, slot, *, heads,
-             head_dim, state):
+             head_dim, state, step=_routed_step):
     """Decode form: one token a lane.  ``xbc`` (lanes, ...), ``dt_raw``
     (lanes, heads), ``states`` (slots, heads, head_dim, state) float32 the
-    plane, ``slot`` (lanes,) int32, every live lane a slot of its own.  One
-    fused multiply-add over the WHOLE plane and one reduction over its last
-    axis: the plane is read once and written once, in place when it is
-    donated, with no gather and no scatter (the lanes' small vectors are
-    routed to slot order instead, :func:`_route`); a slot no lane names
-    decays by exactly 1 and takes exactly 0, so it keeps its bits.  The
-    engine holds one slot a lane and the scratch slot, so a full step moves
-    the lanes' state and one slot more.  Returns ``y`` (lanes,
+    plane, ``slot`` (lanes,) int32, every live lane a slot of its own::
+
+        S[slot] <- exp(dt A) S[slot] + (dt x) (outer) B      y = S C + D x
+
+    for every lane at a slot above 0; a lane parked on the scratch slot 0
+    leaves it alone and gets ``D x``.  XLA makes the lanes' small vectors;
+    ``step`` moves the state: :func:`_routed_step` over the whole plane
+    (here, and wherever :func:`step_formulation` says ``"xla"``) or
+    :func:`_kernel_step` over the live lanes' slots.  Returns ``y`` (lanes,
     heads*head_dim) and the plane."""
     x, B, C = _split_xbc(xbc, heads, head_dim, state)
     dt, A = _dt_and_a(dt_raw, A_log, dt_bias)
-    route = _route(slot, states.shape[0])
-    idle = 1.0 - jnp.any(route, axis=0).astype(_F32)  # (slots,)
-    decay = _to_slots(route, jnp.exp(dt * A)) + idle[:, None]
-    u, Bs, Cs = (_to_slots(route, a) for a in (dt[:, :, None] * x, B, C))
-    S = decay[:, :, None, None] * states.astype(_F32) \
-        + u[..., None] * Bs[:, None, None, :]
-    y = _to_lanes(route, jnp.sum(S * Cs[:, None, None, :], axis=-1)) \
-        + D.astype(_F32)[None, :, None] * x
-    y = y.reshape(xbc.shape[0], heads * head_dim).astype(xbc.dtype)
-    return y, S.astype(states.dtype)
+    y, S = step(jnp.exp(dt * A), dt[:, :, None] * x, B, C, states, slot)
+    y = y + D.astype(_F32)[None, :, None] * x
+    return y.reshape(xbc.shape[0], heads * head_dim).astype(xbc.dtype), S
 
 
 _SSM_PARAMS = {"heads": Param(int, required=True),
@@ -339,5 +471,11 @@ def _ssm_step(opctx, attrs, data, dt, A_log, D, dt_bias, states, state_slot):
     plane ``states`` (slots, heads, head_dim, state) at ``state_slot``
     (lanes,; float carrier, cast to int32); writes ``out`` (lanes, inner)
     and the plane with those slots replaced."""
+    from .interpret import platform_of
+
+    sizes = _sizes(attrs)
+    step = {"pallas": _kernel_step, "xla": _routed_step}[step_formulation(
+        platform_of(data, states), sizes["head_dim"], sizes["state"],
+        states.dtype)]
     return ssm_step(data, dt, A_log, D, dt_bias, states,
-                    state_slot.astype(jnp.int32), **_sizes(attrs))
+                    state_slot.astype(jnp.int32), step=step, **sizes)

@@ -217,17 +217,31 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     assert not copied if donated else len(copied) >= 2 * layers
 
 
+# the hybrid family's lane program: reduced widths with the cell's head,
+# state and convolution sizes twice over, and the cell's own widths
+# (perfbench: g4hmicro-decode-closed16) over one period of its pattern
+HYBRID = {
+    "reduced": dict(hidden=512, periods=2, num_heads=8, kv_heads=2,
+                    intermediate=1024, ssm_heads=16),
+    "cell": dict(hidden=2048, periods=1, num_heads=32, kv_heads=8,
+                 intermediate=8192, ssm_heads=64),
+}
+
+
+@pytest.mark.parametrize("widths", sorted(HYBRID))
 def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
-                                                         monkeypatch):
-    """The hybrid family's decode step (models/hybrid_lm.py: 12 layers, ``m
-    m m m m a`` twice, at reduced widths with the cell's head, state and
-    convolution sizes) as the engine's Executor builds it, planes carried and
-    donated, compiled for the chip.  Each state-space layer's recurrent state
-    goes through ONE fusion that makes both the new state and the lanes'
-    outputs: no gather of the lanes' slots, no scatter back, no loop over
-    lanes (XLA's lowering of a gather of whole slots), so the plane is read
-    once and written once.  Grouped-query attention takes the XLA
-    formulation: no paged-decode kernel in the program."""
+                                                         monkeypatch, widths):
+    """The hybrid family's decode step (models/hybrid_lm.py: ``m m m m m
+    a`` once or twice) as the engine's Executor builds it, planes carried
+    and donated, compiled for the chip.  Each state-space layer's recurrent
+    state goes through ONE Pallas call (``ssm_step``) that takes the plane
+    where it lies and returns it in place: every plane is aliased in and
+    out of the module, and no line copies, stages (``copy-start`` into
+    memory space ``S(1)``: XLA's own pass over the whole plane had 4 of the
+    cell case's 5 planes staged so), gathers, scatters or slices a state
+    plane; no loop over lanes either.
+    Grouped-query attention takes the XLA formulation: no paged-decode
+    kernel in the program."""
     import re
 
     import jax
@@ -239,12 +253,15 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
     from mxnet_tpu.ops.interpret import bind
 
     monkeypatch.setattr(compile_cache, "active", lambda: False)
+    w = HYBRID[widths]
     lanes, slots, pages, max_pages, vocab = 16, 17, 24, 8, 512
+    mamba = 5 * w["periods"]
     model = HybridLM(
-        vocab_size=vocab, hidden=512, layer_types=(["mamba"] * 5
-                                                    + ["attention"]) * 2,
-        num_heads=8, kv_heads=2, head_dim=64, intermediate=1024,
-        ssm_heads=16, ssm_head_dim=64, ssm_state=128, conv_kernel=4,
+        vocab_size=vocab, hidden=w["hidden"],
+        layer_types=(["mamba"] * 5 + ["attention"]) * w["periods"],
+        num_heads=w["num_heads"], kv_heads=w["kv_heads"], head_dim=64,
+        intermediate=w["intermediate"], ssm_heads=w["ssm_heads"],
+        ssm_head_dim=64, ssm_state=128, conv_kernel=4,
         embedding_multiplier=12.0, residual_multiplier=0.22,
         attention_multiplier=1 / 64.0, logits_scaling=8.0)
     symbol = model.decode_symbol(128, 16)
@@ -277,13 +294,29 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
         [((lanes, vocab), "float32"), ((lanes,), "float32")]
     assert len(outs) == 2 + len(planes)
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and " while(" not in text
-    state = "f32[%d,16,64,128]" % slots
-    moved = [line for line in text.splitlines() if state in line
-             and re.search(r" (gather|scatter|dynamic-update-slice)\(", line)]
-    assert not moved
-    # new state and outputs from one fusion, once a state-space layer
-    both = [line for line in text.splitlines() if ") fusion(" in line
-            and state + "{" in line.split(" fusion(")[0]
-            and "f32[%d,16,64]{" % slots in line.split(" fusion(")[0]]
-    assert len(both) == 10
+    assert " while(" not in text
+    # one kernel a state-space layer, and no other
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert len(names) == mamba and all(n.startswith("ssm_step")
+                                       for n in names), names
+    # every plane goes through the module in place: argument i is output i
+    # (the logits are output 0, the planes follow in the carried order)
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    aliased = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases))
+    state_planes = [i for i, name in enumerate(planes)
+                    if name.endswith("_ssm_state")]
+    assert len(state_planes) == mamba
+    assert all(aliased.get(str(1 + i)) == str(i) for i in state_planes), \
+        aliases
+    assert len(aliased) == len(planes)
+    # the plane stays where it lies, in the layout it has
+    state = "f32[%d,%d,64,128]" % (slots, w["ssm_heads"])
+    lines = [line for line in text.splitlines() if state in line]
+    assert lines
+    moved = [line for line in lines if re.search(
+        r" (copy|copy-start|copy-done|gather|scatter|dynamic-update-slice)"
+        r"\(", line)]
+    assert not moved, moved[:2]
+    staged = [line for line in lines
+              if re.search(re.escape(state) + r"\{[^}]*S\(1\)", line)]
+    assert not staged, staged[:2]

@@ -140,6 +140,9 @@ def test_prefill_then_decode_logits_are_the_reference(dtype):
     eng.stop()
     assert snap["state_slots"]["live"] == 0 and \
         snap["state_slots"]["peak"] == 4
+    # on the host platform the state step is the pass over the whole plane
+    # (an engine without state planes has no such key: test_paged_kernel.py)
+    assert snap["ssm_step"] == "xla" and snap["paged_attention"] == "xla"
     for st in streams:
         assert st.done and st.exception() is None and len(st.tokens) == 9
         seq = st.prompt + st.tokens

@@ -185,6 +185,7 @@ def test_engine_says_which_formulation_and_how_many_pages(monkeypatch):
         # on the host platform the gather runs; what a chip would run is
         # the choice function's business (above)
         assert eng.snapshot()["paged_attention"] == "xla"
+        assert "ssm_step" not in eng.snapshot()  # no state planes, no step
         prompt, new = [3, 1, 4, 1, 5, 9], 7
         assert len(eng.generate(prompt, new)) == new
     finally:

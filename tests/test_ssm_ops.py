@@ -2,6 +2,8 @@
 each: the token-by-token recurrence, a convolution written out, K/V heads
 repeated.  Small sizes, float32 unless a case says otherwise; tolerances are
 float32 rounding over a few dozen terms (1e-5 relative) unless stated."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +27,7 @@ def _inputs(b, L, seed=0):
                 dt_bias=r.randn(H).astype(np.float32))
 
 
-def _recurrence(xbc, dt, A_log, D, dt_bias, S=None):
+def _recurrence(xbc, dt, A_log, D, dt_bias, S=None, H=H, P=P, N=N):
     """One sequence, token by token: (y (L, H*P), final state)."""
     S = np.zeros((H, P, N)) if S is None else np.array(S, np.float64)
     A = -np.exp(A_log.astype(np.float64))
@@ -264,3 +266,135 @@ def test_ops_carry_their_scopes():
                                jnp.zeros((2, H, P, N)),
                                jnp.zeros((1,))).as_text(debug_info=True)
     assert "ssm_step" in text
+
+
+# -- the Pallas formulation of the step, in interpret mode -------------------
+
+KERNEL = functools.partial(ssm._kernel_step, interpret=True)
+SLOTS = 17
+
+
+def _step_inputs(lanes, heads, head_dim, state, seed):
+    r = np.random.RandomState(seed)
+    return dict(
+        xbc=r.randn(lanes, heads * head_dim + 2 * state).astype(np.float32),
+        dt=r.randn(lanes, heads).astype(np.float32),
+        vec=(np.log(r.uniform(1, 16, heads)).astype(np.float32),
+             r.randn(heads).astype(np.float32),
+             r.randn(heads).astype(np.float32)),
+        states=jnp.asarray(r.randn(SLOTS, heads, head_dim, state),
+                           jnp.float32))
+
+
+@pytest.mark.parametrize("heads", [16, 64])
+@pytest.mark.parametrize("lanes,parked", [(1, ()), (5, (1, 3)),
+                                          (16, (0, 7, 8))])
+def test_kernel_step_is_the_routed_step(lanes, parked, heads):
+    """The cell's head and state sizes, 16 heads (one block of them) and the
+    cell's 64 (two): lanes in permuted slot order, some parked on scratch.
+    ``y`` and the lanes' slots equal the XLA formulation's to float32
+    rounding (a fused multiply-add; the order of a 128-term sum); scratch
+    and every slot no lane names keep their BITS."""
+    sizes = dict(heads=heads, head_dim=64, state=128)
+    a = _step_inputs(lanes, seed=lanes, **sizes)
+    slot = np.random.RandomState(lanes).permutation(
+        np.arange(1, SLOTS))[:lanes].astype(np.int32)
+    slot[list(parked)] = 0
+    args = (a["xbc"], a["dt"], *a["vec"], a["states"], jnp.asarray(slot))
+    want_y, want_S = ssm.ssm_step(*args, **sizes)
+    y, S = ssm.ssm_step(*args, step=KERNEL, **sizes)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(want_S), rtol=1e-6,
+                               atol=1e-6)
+    for s in set(range(SLOTS)) - set(slot[slot > 0].tolist()):
+        np.testing.assert_array_equal(np.asarray(S[s]),
+                                      np.asarray(a["states"][s]))
+    # a parked lane's output is D x alone, as the other formulation gives
+    for lane in parked:
+        np.testing.assert_array_equal(np.asarray(y[lane]),
+                                      np.asarray(want_y[lane]))
+
+
+def test_kernel_step_leaves_a_diverged_neighbour_alone():
+    """A lane whose state holds ``inf`` and one whose inputs are NaN keep
+    them to themselves: their neighbours' slots and outputs stay finite and
+    equal the XLA formulation's, scratch keeps its bits."""
+    sizes = dict(heads=16, head_dim=64, state=128)
+    a = _step_inputs(4, seed=9, **sizes)
+    states = a["states"].at[5, 3, 2, 7].set(jnp.inf)
+    xbc = jnp.asarray(a["xbc"]).at[2].set(jnp.nan)
+    slot = jnp.array([5, 0, 9, 2], jnp.int32)
+    args = (xbc, a["dt"], *a["vec"], states, slot)
+    want_y, want_S = ssm.ssm_step(*args, **sizes)
+    y, S = ssm.ssm_step(*args, step=KERNEL, **sizes)
+    assert not np.isfinite(np.asarray(S[5])).all()
+    assert np.isnan(np.asarray(S[9])).any() and np.isnan(np.asarray(y[2])).any()
+    for lane, s in ((1, 0), (3, 2)):
+        assert np.isfinite(np.asarray(y[lane])).all()
+        assert np.isfinite(np.asarray(S[s])).all()
+        np.testing.assert_allclose(np.asarray(y[lane]),
+                                   np.asarray(want_y[lane]), rtol=1e-5,
+                                   atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(S[0]), np.asarray(states[0]))
+    np.testing.assert_allclose(np.asarray(S[2]), np.asarray(want_S[2]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_kernel_steps_continued_from_a_scan_are_the_whole_recurrence():
+    """Prefill 12 tokens with the scan, write the final state into a slot,
+    then 20 tokens one step each through the kernel beside a parked lane:
+    outputs and final state are the token-by-token recurrence's over all
+    32."""
+    sizes = dict(heads=2, head_dim=8, state=128)
+    H2, P2, N2, L, split = 2, 8, 128, 32, 12
+    r = np.random.RandomState(11)
+    xbc = (0.5 * r.randn(1, L, H2 * P2 + 2 * N2)).astype(np.float32)
+    dt = r.randn(1, L, H2).astype(np.float32)
+    vec = (np.log(r.uniform(1, 16, H2)).astype(np.float32),
+           r.randn(H2).astype(np.float32), r.randn(H2).astype(np.float32))
+    want_y, want_S = _recurrence(xbc[0], dt[0], *vec, H=H2, P=P2, N=N2)
+    _, S = ssm.ssm_scan(xbc[:, :split], dt[:, :split], *vec, chunk=4, **sizes)
+    states = jnp.zeros((4, H2, P2, N2)).at[3].set(S[0])
+    slot = jnp.array([0, 3], jnp.int32)
+    for t in range(split, L):
+        row = jnp.stack([jnp.ones(xbc.shape[-1]), xbc[0, t]])
+        y, states = ssm.ssm_step(row, jnp.stack([jnp.ones(H2), dt[0, t]]),
+                                 *vec, states, slot, step=KERNEL, **sizes)
+        np.testing.assert_allclose(np.asarray(y[1]), want_y[t], rtol=1e-4,
+                                   atol=1e-4)
+    np.testing.assert_allclose(np.asarray(states[3]), want_S, rtol=1e-4,
+                               atol=1e-4)
+    assert not np.asarray(states[:3]).any()
+
+
+@pytest.mark.parametrize("platform,head_dim,state,dtype,want", [
+    ("tpu", 64, 128, np.float32, "pallas"),     # the cell's shapes
+    ("tpu", 8, 256, np.float32, "pallas"),
+    ("cpu", 64, 128, np.float32, "xla"),
+    ("gpu", 64, 128, np.float32, "xla"),
+    ("tpu", 64, 128, jnp.bfloat16, "xla"),      # not a float32 plane
+    ("tpu", 64, 64, np.float32, "xla"),         # a row is half a tile
+    ("tpu", 4, 128, np.float32, "xla"),         # a head is half a tile
+])
+def test_step_formulation_is_read_off_the_operands(platform, head_dim, state,
+                                                   dtype, want):
+    assert ssm.step_formulation(platform, head_dim, state, dtype) == want
+
+
+def test_the_op_picks_its_formulation_where_the_operands_live():
+    """``_contrib_SSMStep`` traced for a TPU lowers the kernel, under the
+    scope the XLA formulation carries; on this CPU it lowers none."""
+    from mxnet_tpu.ops.interpret import bind
+
+    sizes = dict(heads=2, head_dim=8, state=128)
+    a = _step_inputs(3, seed=1, **sizes)
+
+    def step(xbc, dt, states, slot):
+        return ssm._ssm_step(None, sizes, xbc, dt, *a["vec"], states, slot)
+
+    args = (a["xbc"], a["dt"], a["states"], jnp.zeros((3,)))
+    here = str(jax.make_jaxpr(step)(*args))
+    there = str(jax.make_jaxpr(bind(step, "tpu"))(*args))
+    assert "pallas_call" not in here
+    assert "pallas_call" in there and "name=ssm_step" in there
