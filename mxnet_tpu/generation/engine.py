@@ -65,7 +65,11 @@ per-prompt inputs, ``data`` and for some ``length``), the decode graph
 name, kind ``paged`` or ``slot``, entry shape, dtype), which one
 :class:`~.kv_pool.PagedKVPool` owns, and the keys it adds to :meth:`spec`
 (``engine_spec()``).  A family with slot planes (recurrent
-state) gets a ``state_slot`` vector beside ``page_table``.  What a family
+state, convolution tails) gets a ``state_slot`` vector beside
+``page_table``.  A family may name small outputs its lane program returns
+after the picked ids (``lane_extras``; ``expert_load``, the live lanes'
+picks by expert layer and expert, is the one the engine knows what to do
+with): they are read with the ids, one iteration late.  What a family
 without a catch-up graph cannot do is refused by name, never done wrongly:
 ``draft=`` and ``prefix_cache_pages > 0`` raise at construction (a
 recurrent state cannot be rewound past a rejected token, nor rebuilt from
@@ -102,6 +106,7 @@ from ..base import MXNetError, env, register_env
 from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
+from ..ops.moe import experts_formulation
 from ..ops.paged import decode_formulation
 from ..ops.ssm import step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
@@ -346,7 +351,9 @@ class _Seq:
 # A decode step that was dispatched and not yet read: its executable, its
 # lanes as ``(sequence, position fed)`` and the device array of the ids it
 # picks.
-_Flight = namedtuple("_Flight", "pred lanes ids")
+# ``extras``: what the lane program returned after the ids (the family's
+# ``lane_extras``), unread, by name
+_Flight = namedtuple("_Flight", "pred lanes ids extras")
 
 
 class _GenMetrics:
@@ -381,6 +388,11 @@ class _GenMetrics:
         self.g_active = reg.gauge("mxtpu_gen_active_lanes")
         self.g_pending = reg.gauge("mxtpu_gen_pending_requests")
         self.g_accept = reg.gauge("mxtpu_gen_draft_accept_rate")
+        # a family with routed experts: the live lanes' picks, and the
+        # experts with at least one pick in the last step read (summed over
+        # the expert layers)
+        self.expert_picks = reg.counter("mxtpu_gen_expert_picks")
+        self.g_experts_hit = reg.gauge("mxtpu_gen_experts_hit")
         _telemetry.register_collector(self)
 
     def render_prometheus(self):
@@ -628,6 +640,11 @@ class DecodeEngine:
                                 "decode_b%d")
         self._ssm_step = _state_step(
             self._decode[self.max_lanes]._symbol, self.pool)
+        # the lane program's outputs after the picked ids, and the routed
+        # experts' cumulative load (expert layers, experts) where it has one
+        self._lane_extras = tuple(getattr(family, "lane_extras", ()))
+        self._expert_load = None
+        self._expert_steps = self._experts_hit_total = 0
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -990,6 +1007,12 @@ class DecodeEngine:
                 # likewise for the lane program's state step (ops/ssm.py)
                 snap["ssm_step"] = step_formulation(
                     self._device.platform, *self._ssm_step)
+            if self._lane_extras:
+                # likewise for the routed experts' grouped products
+                # (ops/moe.py), and what the lanes picked so far
+                snap["moe_experts"] = experts_formulation(
+                    self._device.platform)
+                snap["experts"] = self._experts_snapshot()
             if self._draft is not None:
                 snap["draft"] = {
                     "k": self._draft["k"],
@@ -1000,6 +1023,20 @@ class DecodeEngine:
                     "kv": self._draft_pool.snapshot(),
                 }
             return snap
+
+    def _experts_snapshot(self):
+        """The routed experts' load since the start (``_cv`` held): by layer
+        and expert, the mean count of experts hit a step and layer, and the
+        worst layer's busiest expert over its mean one."""
+        load, steps = self._expert_load, self._expert_steps
+        if load is None:
+            return {"steps": 0, "load": [], "hit_per_step_layer": None,
+                    "max_over_mean": None}
+        mean = np.maximum(load.mean(axis=1), 1e-9)
+        return {"steps": steps, "load": load.tolist(),
+                "hit_per_step_layer": self._experts_hit_total
+                / float(steps * load.shape[0]),
+                "max_over_mean": float((load.max(axis=1) / mean).max())}
 
     # -- engine loop -------------------------------------------------------
     def _loop(self):
@@ -1115,6 +1152,9 @@ class DecodeEngine:
         if self.pool.num_slots:
             args["state_slot"] = "|".join(
                 str(self.pool.state_slot(s.sid)) for s in admitted)
+        if "expert_load" in self._lane_extras:
+            # every prompt token routes in every expert layer
+            args["expert_pairs"] = self.family.expert_pairs(args["tokens"])
         with _span("gen:prefill", "gen", args):
             start = time.monotonic()
             for seq in admitted:
@@ -1418,8 +1458,13 @@ class DecodeEngine:
         if picks:
             # rebound, not carried: a carried argument is donated, and the
             # engine reads these ids after the next call's dispatch
-            args["prev_ids"]._set(outs[-1]._data)
+            args["prev_ids"]._set(self._picked(outs)._data)
         return outs
+
+    def _picked(self, outs):
+        """The decode graph's ``next_ids`` among its outputs: the last but
+        for the family's ``lane_extras``."""
+        return outs[-1 - len(self._lane_extras)]
 
     def _run_lanes(self, pred, data, positions, table):
         """Run one lane-bucket executable and return its logits."""
@@ -1496,8 +1541,11 @@ class DecodeEngine:
         self.metrics.steps.inc()
         if prev is not None:
             self.metrics.steps_overlapped.inc()
+        extras = outs[len(outs) - len(self._lane_extras):]
         flight = _Flight(self._decode[b], [(s, s.next_pos) for s in lanes],
-                         outs[-1]._data)
+                         self._picked(outs)._data,
+                         {name: out._data for name, out
+                          in zip(self._lane_extras, extras)})
         for seq in lanes:
             seq.next_pos += 1
         return flight
@@ -1510,12 +1558,17 @@ class DecodeEngine:
         A lane that left since the dispatch (an EOS seen a step late, a
         preemption, a hand-off) rode the step for nothing: its token is
         dropped, and greedy decode computes it again if it is needed."""
-        lanes, ids = (flight.lanes, flight.ids) if flight is not None \
-            else ((), np.zeros((0,), _CARRIER))
+        lanes, ids, extras = \
+            (flight.lanes, flight.ids, flight.extras) if flight is not None \
+            else ((), np.zeros((0,), _CARRIER), {})
         # the read blocks until the device has run the step
         with _span("gen:pool_d2h", "gen") as d2h:
             ids = np.asarray(ids)
-            d2h.set(bytes=ids.nbytes)
+            extras = {name: np.asarray(v) for name, v in extras.items()}
+            d2h.set(bytes=ids.nbytes
+                    + sum(v.nbytes for v in extras.values()))
+        if "expert_load" in extras:
+            self._note_expert_load(extras["expert_load"], span)
         with _span("gen:emit", "gen") as emit:
             retired = []
             emitted = dropped = 0
@@ -1535,6 +1588,23 @@ class DecodeEngine:
             self.metrics.tokens_dropped.inc(dropped)
             emit.set(emitted=emitted, retired=len(retired))
         span.set(dropped=dropped)
+
+    def _note_expert_load(self, load, span):
+        """``load`` (expert layers, experts): the live lanes' picks in the
+        step just read.  On the span that read it: the experts with at least
+        one pick (summed over the layers), the (lane, pick) pairs, and the
+        bytes of the hit experts' weights -- what a kernel that skips idle
+        experts would fetch."""
+        hit, pairs = int((load > 0).sum()), int(load.sum())
+        span.set(experts_hit=hit, expert_pairs=pairs,
+                 expert_bytes=hit * self.family.expert_bytes())
+        with self._cv:
+            self._expert_load = load.astype(np.int64) + (
+                0 if self._expert_load is None else self._expert_load)
+            self._expert_steps += 1
+            self._experts_hit_total += hit
+        self.metrics.expert_picks.inc(pairs)
+        self.metrics.g_experts_hit.set(hit)
 
     def _spec_step(self, active: List[_Seq], span):
         """One speculative iteration: draft K proposals per steady lane,

@@ -1,6 +1,10 @@
-"""Hybrid decoder-only LM: state-space (Mamba-2) layers with a few
-grouped-query attention layers between them, no positional encoding
-(IBM Granite 4.0-H; ``granitemoehybrid`` with no experts).
+"""Hybrid decoder-only LM: layers that carry a small per-lane state
+(Mamba-2 state-space layers, or gated short convolutions) with a few
+grouped-query attention layers between them, and a dense or a routed-expert
+feed-forward (IBM Granite 4.0-H, ``granitemoehybrid`` with no experts:
+state-space layers, no positional encoding, dense; LiquidAI LFM2-MoE,
+``lfm2_moe``: short convolutions, rotary attention with per-head QK-norm,
+leading dense layers and then routed experts).
 
 ONE description of the model (:class:`HybridLM`: the layer pattern and the
 sizes) and three graphs assembled from one block, as ``transformer.py``
@@ -35,25 +39,53 @@ through the causal convolution and SiLU; the selective scan (ops/ssm.py);
 weights' dtype (the embedding's); norms, softmax, ``dt``, the recurrence and
 its state are float32; the logits are float32.
 
+The other kinds of the same block (every one off unless the description
+turns it on, and a description that turns none on emits the graphs it
+always did, byte for byte: tests/test_hybrid_lm.py pins them):
+
+* mixer ``conv``, the gated short convolution: ``[B | C | X] = W_in_proj
+  h``; ``c = conv1d(B * X)`` causal, depthwise, width ``conv_kernel``, no
+  bias, no activation; ``W_out_proj (C * c)``.  It carries the last
+  ``conv_kernel - 1`` rows of ``B * X`` a lane: a slot plane like Mamba's
+  convolution tail, with no recurrent state beside it;
+* attention with ``qk_norm`` (RMSNorm over each head of q and k, gains
+  ``(head_dim,)``) and ``rotary_theta`` (q and k rotated by their position
+  after the norm and before K is written: the pages hold rotated keys);
+* feed-forward ``experts`` from layer ``num_dense_layers`` on (ops/moe.py):
+  sigmoid router with a selection bias, ``experts_per_token`` picks a row,
+  the experts ``first_expert .. first_expert + experts_held - 1`` computed
+  here.  Rows that are not live (a padded lane, a position past a prompt's
+  length) pick nothing.  The lane graph then returns, after ``next_ids``,
+  ``expert_load`` (expert layers, num_experts) int32: the live lanes' picks
+  by expert in this step (:attr:`HybridLM.lane_extras` says so).
+
 There is no windowed (catch-up / verify) graph: a recurrent state cannot be
 rewound or rebuilt from cached pages, and the engine refuses what would
 need one by name (generation/engine.py).
 """
+import numpy as np
+
 from .. import symbol as sym
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, CONV = "mamba", "attention", "conv"
 
 
 class HybridLM:
     """The model's description, and the family object the generation
     engine asks (generation/engine.py, "The family seam").
 
-    ``layer_types`` is the pattern (``"mamba"`` / ``"attention"`` a
-    layer); ``num_heads`` / ``kv_heads`` / ``head_dim`` the attention
-    layers'; ``intermediate`` the gated MLP's inner width; ``ssm_heads`` /
-    ``ssm_head_dim`` / ``ssm_state`` / ``conv_kernel`` / ``chunk`` the
-    state-space layers' (one B/C group); ``dtype`` the K/V planes' and the
-    convolution tails' (the weights'); the recurrent state is float32.
+    ``layer_types`` is the pattern (``"mamba"``, ``"conv"`` or
+    ``"attention"`` a layer); ``num_heads`` / ``kv_heads`` / ``head_dim``
+    the attention layers', ``rotary_theta`` (None: no positions) and
+    ``qk_norm`` theirs too; ``intermediate`` the dense gated MLP's inner
+    width; ``ssm_heads`` / ``ssm_head_dim`` / ``ssm_state`` / ``chunk`` the
+    state-space layers' (one B/C group; needed only where there is one);
+    ``conv_kernel`` the convolutions' width, Mamba's and the short one's;
+    ``num_experts`` (0: every layer dense) / ``experts_per_token`` /
+    ``expert_width`` / ``num_dense_layers`` / ``first_expert`` /
+    ``experts_held`` (None: all) / ``norm_topk`` / ``routed_scaling`` the
+    expert layers'; ``dtype`` the K/V planes' and the convolution tails'
+    (the weights'); the recurrent state is float32.
     """
 
     name = "hybrid_lm"
@@ -64,30 +96,64 @@ class HybridLM:
                    ssm_state=None, conv_kernel=4, chunk=256, eps=1e-5,
                    embedding_multiplier=1.0, residual_multiplier=1.0,
                    attention_multiplier=None, logits_scaling=1.0,
-                   dtype="bfloat16")
+                   dtype="bfloat16", rotary_theta=None, qk_norm=False,
+                   num_experts=0, experts_per_token=0, expert_width=0,
+                   num_dense_layers=0, first_expert=0, experts_held=None,
+                   norm_topk=True, routed_scaling=1.0)
+    _REQUIRED = ("vocab_size", "hidden", "layer_types", "num_heads",
+                 "kv_heads", "head_dim", "intermediate")
+    _SSM = ("ssm_heads", "ssm_head_dim", "ssm_state")
 
     def __init__(self, **sizes):
         sizes.pop("family", None)
         unknown = set(sizes) - set(self._FIELDS)
-        missing = [k for k, v in self._FIELDS.items()
-                   if v is None and sizes.get(k) is None
-                   and k != "attention_multiplier"]
+        needed = self._REQUIRED + (
+            self._SSM if MAMBA in (sizes.get("layer_types") or ()) else ())
+        missing = [k for k in needed if sizes.get(k) is None]
         if unknown or missing:
             raise ValueError("HybridLM: unknown %s, missing %s"
                              % (sorted(unknown), missing))
         for k, default in self._FIELDS.items():
             setattr(self, k, sizes.get(k, default))
         self.layer_types = tuple(self.layer_types)
-        bad = set(self.layer_types) - {MAMBA, ATTENTION}
+        bad = set(self.layer_types) - {MAMBA, ATTENTION, CONV}
         if bad or ATTENTION not in self.layer_types:
-            raise ValueError("layer_types: every entry %r or %r, at least "
-                             "one attention layer (the engine's pages); "
-                             "got %s" % (MAMBA, ATTENTION, sorted(bad)))
+            raise ValueError("layer_types: every entry %r, %r or %r, at "
+                             "least one attention layer (the engine's "
+                             "pages); got %s"
+                             % (MAMBA, CONV, ATTENTION, sorted(bad)))
         if self.attention_multiplier is None:
             self.attention_multiplier = float(self.head_dim) ** -0.5
-        self.ssm_inner = self.ssm_heads * self.ssm_head_dim
-        self.conv_dim = self.ssm_inner + 2 * self.ssm_state
+        if MAMBA in self.layer_types:
+            self.ssm_inner = self.ssm_heads * self.ssm_head_dim
+            self.conv_dim = self.ssm_inner + 2 * self.ssm_state
         self.num_layers = len(self.layer_types)
+        if self.num_experts:
+            if self.experts_held is None:
+                self.experts_held = self.num_experts - self.first_expert
+            if not (0 < self.experts_per_token <= self.num_experts
+                    and self.expert_width > 0 and self.first_expert >= 0
+                    and 0 < self.experts_held
+                    <= self.num_experts - self.first_expert
+                    and 0 <= self.num_dense_layers < self.num_layers):
+                raise ValueError(
+                    "experts: %d a token of %d, width %d, held %d from %d, "
+                    "after %d dense layers of %d"
+                    % (self.experts_per_token, self.num_experts,
+                       self.expert_width, self.experts_held,
+                       self.first_expert, self.num_dense_layers,
+                       self.num_layers))
+            if set(self.layer_types) == {ATTENTION}:
+                raise ValueError(
+                    "experts: the lane graph knows a padded lane by its "
+                    "scratch state slot, and layer_types has no %r or %r "
+                    "layer to carry one" % (MAMBA, CONV))
+        self.expert_layers = tuple(
+            i for i in range(self.num_layers)
+            if self.num_experts and i >= self.num_dense_layers)
+        # what the lane program returns after ``next_ids`` (the engine reads
+        # these with the ids, one iteration late)
+        self.lane_extras = ("expert_load",) if self.expert_layers else ()
 
     def spec(self):
         out = {k: getattr(self, k) for k in self._FIELDS}
@@ -108,13 +174,26 @@ class HybridLM:
                 out += [("layer%d_%s_pool" % (i, kv), "paged",
                          (self.kv_heads, self.head_dim), self.dtype)
                         for kv in "kv"]
-            else:
+            elif kind == MAMBA:
                 out += [("layer%d_ssm_state" % i, "slot",
                          (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
                          "float32"),
                         ("layer%d_conv_tail" % i, "slot",
                          (self.conv_kernel - 1, self.conv_dim), self.dtype)]
+            else:
+                out += [("layer%d_conv_tail" % i, "slot",
+                         (self.conv_kernel - 1, self.hidden), self.dtype)]
         return out
+
+    def expert_pairs(self, rows):
+        """(row, pick) pairs ``rows`` live rows make over the expert
+        layers."""
+        return int(rows) * self.experts_per_token * len(self.expert_layers)
+
+    def expert_bytes(self):
+        """Bytes of one expert's weights as the program holds them."""
+        return 3 * self.hidden * self.expert_width * \
+            np.dtype(self.dtype).itemsize
 
     def prefill_symbol(self, seq_len, max_seq_len=None):
         return get_hybrid_lm_prefill(self, seq_len)
@@ -182,9 +261,37 @@ def _mamba_mixer(h, m, name, seq_len, carried):
     return _fc(y, m.hidden, name + "_out_proj"), [state, tail]
 
 
-def _attention_mixer(h, m, name, seq_len, attend):
+def _conv_mixer(h, m, name, seq_len, carried):
+    """The gated short convolution.  ``carried``: the sequence layout's
+    ``length`` Symbol or None, or the lane layout's ``(tail plane,
+    state_slot)``.  Returns the mixer's output rows and [tail]."""
+    width = m.hidden
+    bcx = _fc(h, 3 * width, name + "_in_proj")
+    b, c, x = (sym.slice_axis(bcx, axis=-1, begin=j * width,
+                              end=(j + 1) * width, name=name + "_" + part)
+               for j, part in enumerate(("b", "c", "x")))
+    u = sym.elemwise_mul(b, x, name=name + "_bx")
+    weight = sym.Variable(name + "_conv_weight",
+                          shape=(width, m.conv_kernel))
+    plain = dict(activation="none", no_bias=True, name=name + "_conv")
+    if seq_len is not None:
+        more = [] if carried is None else [carried]
+        u = sym.Reshape(u, shape=(-1, seq_len, width))
+        u, tail = sym._contrib_CausalConv1D(
+            u, weight, *more, use_length=bool(more), **plain)
+        u = sym.Reshape(u, shape=(-1, width))
+    else:
+        tails, slot = carried
+        u, tail = sym._contrib_CausalConv1DStep(u, weight, tails, slot,
+                                                **plain)
+    y = sym.elemwise_mul(c, u, name=name + "_cy")
+    return _fc(y, m.hidden, name + "_out_proj"), [tail]
+
+
+def _attention_mixer(h, m, name, seq_len, attend, positions=None):
     """``attend(q, k, v, name) -> (att, extras)`` over ``(..., heads,
-    head_dim)`` / ``(..., kv_heads, head_dim)``."""
+    head_dim)`` / ``(..., kv_heads, head_dim)``; ``positions`` the rows'
+    (the sequence axis' or the lanes'), read where the model rotates."""
     lead = (-1,) if seq_len is None else (-1, seq_len)
     hd = m.head_dim
 
@@ -194,26 +301,63 @@ def _attention_mixer(h, m, name, seq_len, attend):
     q = heads(_fc(h, m.num_heads * hd, name + "_q"), m.num_heads)
     k = heads(_fc(h, m.kv_heads * hd, name + "_k"), m.kv_heads)
     v = heads(_fc(h, m.kv_heads * hd, name + "_v"), m.kv_heads)
+    if m.qk_norm:
+        q, k = (sym._contrib_RMSNorm(x, _vec("%s_%s_norm_gamma" % (name, w),
+                                             hd),
+                                     eps=m.eps, name="%s_%s_norm" % (name, w))
+                for x, w in ((q, "q"), (k, "k")))
+    if m.rotary_theta is not None:
+        q, k = (sym._contrib_Rotary(x, positions, theta=m.rotary_theta,
+                                    name="%s_%s_rotary" % (name, w))
+                for x, w in ((q, "q"), (k, "k")))
     att, extras = attend(q, k, v, name + "_attn")
     att = sym.Reshape(att, shape=(-1, m.num_heads * hd))
     return _fc(att, m.hidden, name + "_o"), extras
 
 
-def _block(x, m, i, seq_len, attend, carried):
+def _experts(h, m, name, live):
+    """The routed expert layer over rows ``h``; ``live`` (rows,) or None.
+    Returns the rows and the router's load (num_experts,)."""
+    ids, weights, load = sym._contrib_MoERouter(
+        h, sym.Variable(name + "_router_weight",
+                        shape=(m.num_experts, m.hidden)),
+        _vec(name + "_router_bias", m.num_experts),
+        *([] if live is None else [live]), use_live=live is not None,
+        top_k=m.experts_per_token, normalize=m.norm_topk,
+        scale=m.routed_scaling, name=name + "_router")
+    held, width = m.experts_held, m.expert_width
+    h = sym._contrib_RoutedExperts(
+        h, ids, weights,
+        sym.Variable(name + "_experts_w13",
+                     shape=(held, m.hidden, 2 * width)),
+        sym.Variable(name + "_experts_w2", shape=(held, width, m.hidden)),
+        num_experts=m.num_experts, first_expert=m.first_expert,
+        name=name + "_experts")
+    return h, load
+
+
+def _block(x, m, i, seq_len, attend, carried, positions=None, live=None):
     """Layer ``i`` over rows ``x`` (every position of every sequence, or
-    every lane): returns the rows and what the layer carries."""
+    every lane): returns the rows, what the layer carries and, of an expert
+    layer, its router's load (else None)."""
     name = "layer%d" % i
     h = _norm(x, m, name + "_norm1")
     if m.layer_types[i] == ATTENTION:
-        h, extras = _attention_mixer(h, m, name, seq_len, attend)
+        h, extras = _attention_mixer(h, m, name, seq_len, attend, positions)
+    elif m.layer_types[i] == CONV:
+        h, extras = _conv_mixer(h, m, name, seq_len, carried)
     else:
         h, extras = _mamba_mixer(h, m, name, seq_len, carried)
     x = _residual(x, h, m, name + "_res1")
     h = _norm(x, m, name + "_norm2")
-    h = _fc(h, 2 * m.intermediate, name + "_mlp_in")
-    h = sym._contrib_SiluGate(h, name=name + "_mlp_gate")
-    h = _fc(h, m.hidden, name + "_mlp_out")
-    return _residual(x, h, m, name + "_res2"), extras
+    load = None
+    if i in m.expert_layers:
+        h, load = _experts(h, m, name, live)
+    else:
+        h = _fc(h, 2 * m.intermediate, name + "_mlp_in")
+        h = sym._contrib_SiluGate(h, name=name + "_mlp_gate")
+        h = _fc(h, m.hidden, name + "_mlp_out")
+    return _residual(x, h, m, name + "_res2"), extras, load
 
 
 def _embed(ids, m, table):
@@ -243,9 +387,19 @@ def _sequence_graph(m, seq_len, length):
 
     table = _table(m)
     x = _embed(sym.Variable("data"), m, table)
+    positions = live = None
+    routes = bool(m.expert_layers) and length is not None
+    if m.rotary_theta is not None or routes:
+        positions = sym._arange(start=0, stop=seq_len, name="positions")
+    if routes:
+        # a position past its prompt's length routes to no expert
+        live = sym.Reshape(sym.broadcast_lesser(
+            sym.Reshape(positions, shape=(1, seq_len)),
+            sym.Reshape(length, shape=(-1, 1)), name="live"), shape=(-1,))
     carried = []
     for i in range(m.num_layers):
-        x, extras = _block(x, m, i, seq_len, dense, length)
+        x, extras, _ = _block(x, m, i, seq_len, dense, length, positions,
+                              live)
         carried.extend(extras)
     return _head(x, m, table), carried
 
@@ -276,8 +430,9 @@ def get_hybrid_lm_decode(model, page_size=16):
     ``positions``, ``source``, ``prev_ids``, ``state_slot`` (lanes,),
     ``page_table`` (lanes, max_pages) and the planes of
     :meth:`HybridLM.planes`; outputs the logits (lanes, vocab), the planes
-    in that order, then ``next_ids`` (lanes,).  ``positions`` places the
-    attention layers' K/V only: the model has no positional encoding."""
+    in that order, then ``next_ids`` (lanes,) and :attr:`HybridLM.
+    lane_extras`.  ``positions`` places the attention layers' K/V and, where
+    the model rotates, turns their queries and keys."""
     m = model
     data, positions = sym.Variable("data"), sym.Variable("positions")
     page_table, slot = sym.Variable("page_table"), sym.Variable("state_slot")
@@ -301,13 +456,23 @@ def get_hybrid_lm_decode(model, page_size=16):
 
     table = _table(m)
     x = _embed(ids, m, table)
-    planes_out = []
+    # a padded lane of the bucket is parked on the scratch slot: it routes
+    # to no expert
+    live = sym._greater_scalar(slot, scalar=0, name="live") \
+        if m.expert_layers else None
+    planes_out, loads = [], []
     for i, kind in enumerate(m.layer_types):
-        carried = None if kind == ATTENTION else (
-            planes["layer%d_ssm_state" % i], planes["layer%d_conv_tail" % i],
-            slot)
-        x, extras = _block(x, m, i, None, paged(i), carried)
+        carried = {ATTENTION: None,
+                   CONV: (planes.get("layer%d_conv_tail" % i), slot),
+                   MAMBA: (planes.get("layer%d_ssm_state" % i),
+                           planes.get("layer%d_conv_tail" % i), slot)}[kind]
+        x, extras, load = _block(x, m, i, None, paged(i), carried, positions,
+                                 live)
         planes_out.extend(extras)
+        if load is not None:
+            loads.append(sym.Reshape(load, shape=(1, -1)))
     logits = _head(x, m, table)
     next_ids = sym.argmax(logits, axis=-1, name="next_ids")
-    return sym.Group([logits] + planes_out + [next_ids])
+    more = [sym.Concat(*loads, dim=0, num_args=len(loads),
+                       name="expert_load")] if loads else []
+    return sym.Group([logits] + planes_out + [next_ids] + more)

@@ -25,6 +25,7 @@ from . import contrib_ops  # noqa: F401
 from . import attention  # noqa: F401
 from . import paged  # noqa: F401
 from . import ssm  # noqa: F401
+from . import moe  # noqa: F401
 from . import ctc  # noqa: F401
 
 __all__ = ["Op", "OpContext", "register", "get_op", "list_ops",

@@ -104,24 +104,30 @@ def _scaled_logits(opctx, attrs, data, weight):
 # causal depthwise convolution with a carried tail
 # ---------------------------------------------------------------------------
 
-def _conv_window(window, weight, bias, dtype):
-    """``silu(sum_k window[..., k, c] * weight[c, k] + bias[c])``."""
+_ACTIVATIONS = {"silu": jax.nn.silu, "none": lambda x: x}
+
+
+def _conv_window(window, weight, bias, dtype, activation="silu"):
+    """``act(sum_k window[..., k, c] * weight[c, k] + bias[c])``; ``bias``
+    may be None, ``act`` is SiLU or nothing."""
     w = weight.astype(_F32).T  # (K, C)
-    out = jnp.sum(window.astype(_F32) * w, axis=-2) + bias.astype(_F32)
-    return jax.nn.silu(out).astype(dtype)
+    out = jnp.sum(window.astype(_F32) * w, axis=-2)
+    if bias is not None:
+        out = out + bias.astype(_F32)
+    return _ACTIVATIONS[activation](out).astype(dtype)
 
 
-def causal_conv(x, weight, bias, length=None):
+def causal_conv(x, weight, bias, length=None, activation="silu"):
     """Prefill form.  ``x`` (b, L, C), ``weight`` (C, K) (column ``K-1``
-    multiplies the current position), ``bias`` (C,), ``length`` (b,) or
-    None for whole rows.  Returns ``silu(conv(x))`` (b, L, C) and the tail
-    (b, K-1, C): ``x`` at ``length-(K-1) .. length-1``, zeros before the
-    prompt's start."""
+    multiplies the current position), ``bias`` (C,) or None, ``length``
+    (b,) or None for whole rows.  Returns ``act(conv(x))`` (b, L, C) and
+    the tail (b, K-1, C): ``x`` at ``length-(K-1) .. length-1``, zeros
+    before the prompt's start."""
     b, L, C = x.shape
     K = weight.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     window = jnp.stack([xp[:, k:k + L] for k in range(K)], axis=2)
-    out = _conv_window(window, weight, bias, x.dtype)
+    out = _conv_window(window, weight, bias, x.dtype, activation)
     if length is None:
         length = jnp.full((b,), L, jnp.int32)
     # x[t] sits at xp[t + K - 1]
@@ -156,17 +162,18 @@ def _to_lanes(route, rows):
     return jnp.sum(jnp.where(pick, rows[None], 0.0), axis=1)
 
 
-def conv_step(x, weight, bias, tails, slot):
+def conv_step(x, weight, bias, tails, slot, activation="silu"):
     """Decode form.  ``x`` (lanes, C), ``tails`` (slots, K-1, C) the
     plane, ``slot`` (lanes,) int32, every live lane a slot of its own.  The
     whole plane in one pass: a slot a lane names takes this token behind its
-    tail, the others keep theirs.  Returns ``silu(conv)`` (lanes, C) and
+    tail, the others keep theirs.  Returns ``act(conv)`` (lanes, C) and
     the plane."""
     route = _route(slot, tails.shape[0])
     named = jnp.any(route, axis=0)  # (slots,)
     window = jnp.concatenate(
         [tails, _to_slots(route, x)[:, None].astype(tails.dtype)], axis=1)
-    out = _to_lanes(route, _conv_window(window, weight, bias, _F32))
+    out = _to_lanes(route, _conv_window(window, weight, bias, _F32,
+                                        activation))
     return (out.astype(x.dtype),
             jnp.where(named[:, None, None], window[:, 1:], tails))
 
@@ -176,31 +183,66 @@ def _length_inputs(base):
                                        else [])
 
 
+def _conv_inputs(tail):
+    """``data``, ``weight``, ``bias`` unless ``no_bias``, then ``tail``."""
+    return lambda attrs: (["data", "weight"]
+                          + ([] if attrs.get("no_bias") else ["bias"])
+                          + tail(attrs))
+
+
+# both default to None, which a graph's JSON leaves out: a graph that names
+# neither keeps the bytes (and the compile-cache fingerprint) it had
+_CONV_PARAMS = {"activation": Param(str, None, enum=tuple(_ACTIVATIONS)),
+                "no_bias": Param(bool, None)}
+# the scope a convolution's device time is read under: the state-space
+# layers' own where it is theirs (SiLU: Mamba's), the gated short
+# convolution's where it is a mixer by itself (no activation)
+_CONV_SCOPES = {"silu": ("ssm_scan", "ssm_step"),
+                "none": ("short_conv", "short_conv_step")}
+
+
+def _conv_args(attrs, rest):
+    """(bias or None, what follows it, the activation) of a convolution
+    op's trailing inputs."""
+    activation = attrs.get("activation") or "silu"
+    if attrs.get("no_bias"):
+        return None, rest, activation
+    return rest[0], rest[1:], activation
+
+
 @register("_contrib_CausalConv1D",
-          inputs=_length_inputs(("data", "weight", "bias")),
-          params={"use_length": Param(bool, False)}, num_outputs=2,
+          inputs=_conv_inputs(lambda attrs: ["length"]
+                              if attrs.get("use_length") else []),
+          params=dict(_CONV_PARAMS, use_length=Param(bool, False)),
+          num_outputs=2,
           output_names=lambda attrs: ["out", "tail"], hint="causalconv1d")
-@jax.named_scope("ssm_scan")
-def _causal_conv1d(opctx, attrs, data, weight, bias, *length):
+def _causal_conv1d(opctx, attrs, data, weight, *rest):
     """:func:`causal_conv` as an op: reads ``data`` (b, L, C), ``weight``
-    (C, K), ``bias`` (C,) and, with ``use_length``, ``length`` (b,);
-    writes ``out`` (b, L, C) and ``tail`` (b, K-1, C)."""
-    return causal_conv(data, weight, bias, length[0] if length else None)
+    (C, K), ``bias`` (C,) unless ``no_bias`` and, with ``use_length``,
+    ``length`` (b,); ``activation`` is ``silu`` or ``none``.  Writes ``out``
+    (b, L, C) and ``tail`` (b, K-1, C)."""
+    bias, length, activation = _conv_args(attrs, rest)
+    with jax.named_scope(_CONV_SCOPES[activation][0]):
+        return causal_conv(data, weight, bias,
+                           length[0] if length else None, activation)
 
 
 @register("_contrib_CausalConv1DStep",
-          inputs=("data", "weight", "bias", "tails", "state_slot"),
-          num_outputs=2, no_grad_inputs=("state_slot",),
+          inputs=_conv_inputs(lambda attrs: ["tails", "state_slot"]),
+          params=dict(_CONV_PARAMS), num_outputs=2,
+          no_grad_inputs=("state_slot",),
           output_names=lambda attrs: ["out", "tails_out"],
           hint="causalconv1dstep")
-@jax.named_scope("ssm_step")
-def _causal_conv1d_step(opctx, attrs, data, weight, bias, tails, state_slot):
-    """:func:`conv_step` as an op: reads ``data`` (lanes, C), the lanes'
-    rows of the plane ``tails`` (slots, K-1, C) at ``state_slot`` (lanes,;
-    float carrier, cast to int32); writes ``out`` (lanes, C) and the plane
-    with those rows replaced."""
-    return conv_step(data, weight, bias, tails,
-                     state_slot.astype(jnp.int32))
+def _causal_conv1d_step(opctx, attrs, data, weight, *rest):
+    """:func:`conv_step` as an op: reads ``data`` (lanes, C), ``weight``,
+    ``bias`` unless ``no_bias``, the lanes' rows of the plane ``tails``
+    (slots, K-1, C) at ``state_slot`` (lanes,; float carrier, cast to
+    int32); writes ``out`` (lanes, C) and the plane with those rows
+    replaced."""
+    bias, (tails, state_slot), activation = _conv_args(attrs, rest)
+    with jax.named_scope(_CONV_SCOPES[activation][1]):
+        return conv_step(data, weight, bias, tails,
+                         state_slot.astype(jnp.int32), activation)
 
 
 # ---------------------------------------------------------------------------

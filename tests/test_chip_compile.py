@@ -320,3 +320,116 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
     staged = [line for line in lines
               if re.search(re.escape(state) + r"\{[^}]*S\(1\)", line)]
     assert not staged, staged[:2]
+
+
+@pytest.mark.parametrize("rows", [16, 512])
+def test_routed_experts_cost_their_pairs_on_v5e(one_chip, rows):
+    """One expert layer at the LFM2 cell's widths (32 experts of 1792 over
+    hidden 2048, 4 a row) compiled for the chip, a lane bucket's 16 rows and
+    a prefill bucket's 512: XLA's own grouped kernel (two Mosaic calls, one
+    a product), whose counted FLOPs are the pairs' (rows x 4 x 6 x 2048 x
+    1792, within 1.5 x: NOT experts x rows x that, which a dense expansion
+    would cost), and whose counted bytes hold each expert's weights once a
+    lane step (0.70 GB; a prefill's 2,048 sorted pairs span several row
+    tiles, and an expert whose group crosses a tile's edge is fetched for
+    both: under 2.5 x), with no float32 or second copy of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    H, F, E, K = 2048, 1792, 32, 4
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(g, router, bias, w13, w2):
+        ids, w, _ = moe.route(g, router, bias, top_k=K)
+        return moe.routed_experts(g, ids, w, w13, w2)
+
+    compiled = jax.jit(layer).lower(
+        S((rows, H), jnp.bfloat16), S((E, H), jnp.bfloat16),
+        S((E,), jnp.bfloat16), S((E, H, 2 * F), jnp.bfloat16),
+        S((E, F, H), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 2 and "tpu_custom_call" in text
+    cost = compiled.cost_analysis()
+    pairs = rows * K * 6 * H * F
+    assert pairs <= cost["flops"] <= 1.5 * pairs
+    weights = 3 * E * H * F * 2
+    assert weights <= cost["bytes accessed"] <= \
+        (1.3 if rows == 16 else 2.5) * weights
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
+    """The LFM2 family's decode step at the cell's widths, one period of its
+    pattern (``c c a c``: one dense and three expert layers), as the
+    engine's Executor builds it, planes carried and donated, compiled for
+    the chip.  Each expert layer is two grouped products (XLA's own kernel,
+    ``ragged-dot-none``) behind one ``ragged-dot-metadata``: every stacked
+    leaf is the operand of exactly one product, whole and in bfloat16 (no
+    copy, slice, convert or gather of it), so a step streams an expert's
+    weights at most once.  The outputs end with ``next_ids`` and
+    ``expert_load`` (3 layers x 32 experts, int32); every plane is aliased
+    in and out."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import compile_cache
+    from mxnet_tpu.models import HybridLM
+    from mxnet_tpu.ops.interpret import bind
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    lanes, slots, pages, max_pages, vocab = 16, 17, 24, 64, 512
+    model = HybridLM(
+        vocab_size=vocab, hidden=2048,
+        layer_types=["conv", "conv", "attention", "conv"], num_heads=32,
+        kv_heads=8, head_dim=64, intermediate=7168, conv_kernel=3,
+        rotary_theta=1e6, qk_norm=True, num_experts=32, experts_per_token=4,
+        expert_width=1792, num_dense_layers=1)
+    symbol = model.decode_symbol(1024, 16)
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids", "state_slot")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    for name in symbol.list_arguments():
+        if name not in shapes:
+            types[name] = jnp.bfloat16  # the weights
+    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
+                            **shapes)
+    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
+    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
+    carried, args, aux, rng = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ex._forward_args(None))
+    fwd = ex._get_fwd(False)
+    assert ex.carry_donated
+    compiled = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
+        .compile()
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
+        == [((lanes, vocab), "float32"), ((lanes,), "float32"),
+            ((3, 32), "int32")]
+    assert len(outs) == 3 + len(planes) == 3 + 5
+    text = compiled.as_text()
+    assert " while(" not in text
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    kinds = sorted(n.split(".")[0] for n in names)
+    assert kinds == ["ragged-dot-metadata"] * 3 + ["ragged-dot-none"] * 6
+    for leaf in ("bf16[32,2048,3584]", "bf16[32,1792,2048]"):
+        uses = [line for line in text.splitlines()
+                if leaf in line and re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)
+                and " parameter(" not in line]
+        assert len(uses) == 3 and all("ragged-dot-none" in u for u in uses), \
+            uses[:4]
+        assert leaf.replace("bf16", "f32") not in text
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)

@@ -419,3 +419,44 @@ def test_spans_and_counters_of_the_state(tmp_path):
     for name in ("mxtpu_gen_state_slots_live", "mxtpu_gen_state_slots_peak",
                  "mxtpu_gen_state_bytes"):
         assert name in text
+
+
+# ---------------------------------------------------------------------------
+# the graphs of the published configuration, pinned
+# ---------------------------------------------------------------------------
+
+# sha256 of tojson() of granite-4.0-h-micro's three graphs as PR 33 emitted
+# them (written against the parent of PR 34, which gave the block its other
+# kinds: a convolution mixer, rotary / QK-norm, routed experts).  The JSON is
+# the compile-cache fingerprint and fixes every named scope of the device
+# trace; a PR that changes a graph on purpose replaces its digest here and
+# says so in CHANGES.md.
+_DIGESTS = {
+    "score":
+        "709b3c0a98e8c33203bc78e65d41a9b59a1ce2be1e6aaaf73892eea570ba1e1c",
+    "prefill":
+        "fa8b9fc814ebe01ad9b21ab63e0451ca04c7a27738f2c60279196281ec15b514",
+    "decode":
+        "b3e417a9826d79e72bb7c6f948657bb79a0b16aafb91c6e8ef23ac60d664fc5e",
+}
+
+
+@pytest.mark.parametrize("which", sorted(_DIGESTS))
+def test_graph_json_is_pinned(which):
+    import hashlib
+    import json
+    import os
+
+    from mxnet_tpu.models import HybridLM
+    from mxnet_tpu.name import NameManager
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        model = HybridLM(**builder.family_spec(json.load(f)))
+    with NameManager():
+        net = {"score": lambda: mx.models.get_hybrid_lm(model, 1024),
+               "prefill": lambda: model.prefill_symbol(512, 1024),
+               "decode": lambda: model.decode_symbol(1024, 16)}[which]()
+    assert hashlib.sha256(net.tojson().encode()).hexdigest() == \
+        _DIGESTS[which]

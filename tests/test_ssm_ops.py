@@ -159,6 +159,76 @@ def test_causal_conv_is_the_convolution_written_out():
                                    pre / (1 + np.exp(-pre)), **TOL)
 
 
+@pytest.mark.parametrize("biased", [True, False])
+def test_a_plain_convolution_has_no_activation_and_may_have_no_bias(biased):
+    """``activation="none"`` (the gated short convolution's) with and
+    without a bias, prefill and steps: the written-out sum and nothing after
+    it, the tail what the steps go on from."""
+    r = np.random.RandomState(4)
+    x = r.randn(2, 9, C).astype(np.float32)
+    w = r.randn(C, K).astype(np.float32)
+    bias = r.randn(C).astype(np.float32) if biased else None
+    out, tail = ssm.causal_conv(x, w, bias, activation="none")
+    xp = np.concatenate([np.zeros((2, K - 1, C), np.float32), x], 1)
+    want = np.stack([sum(xp[:, t + k] * w[:, k] for k in range(K))
+                     for t in range(9)], 1) + (bias if biased else 0.0)
+    np.testing.assert_allclose(np.asarray(out), want, **TOL)
+    # steps from the tail after 6 positions give positions 6, 7, 8
+    _, tail6 = ssm.causal_conv(x[:, :6], w, bias, activation="none")
+    tails = jnp.zeros((3, K - 1, C)).at[jnp.array([2, 1])].set(tail6)
+    slot = jnp.array([2, 1])
+    for t in (6, 7, 8):
+        y, tails = ssm.conv_step(x[:, t], w, bias, tails, slot, "none")
+        np.testing.assert_allclose(np.asarray(y), want[:, t], **TOL)
+    np.testing.assert_array_equal(np.asarray(tails[jnp.array([2, 1])]),
+                                  np.asarray(tail))
+    assert (np.asarray(tails[0]) == 0).all()  # scratch
+
+
+def test_the_convolution_ops_default_to_what_they_were():
+    """No attribute named: SiLU and a bias, under the state-space layers'
+    scopes, and a graph's JSON does not mention the new attributes (the
+    compile-cache fingerprint of every graph built before they existed).
+    ``activation="none"`` / ``no_bias``: one input less, the short
+    convolution's own scopes."""
+    r = np.random.RandomState(5)
+    x = r.randn(1, 6, C).astype(np.float32)
+    w, bias = r.randn(C, K).astype(np.float32), r.randn(C).astype(np.float32)
+    out, _ = ssm._causal_conv1d(None, {}, x, w, bias)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ssm.causal_conv(x, w, bias)[0]))
+    plain = {"activation": "none", "no_bias": True}
+    out, _ = ssm._causal_conv1d(None, dict(plain, use_length=True), x, w,
+                                jnp.array([4]))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ssm.causal_conv(
+            x, w, None, jnp.array([4]), "none")[0]))
+    data, weight = mx.sym.Variable("data"), mx.sym.Variable("weight")
+    old = mx.sym._contrib_CausalConv1D(data, weight, mx.sym.Variable("bias"),
+                                       name="c")
+    assert old[0].list_arguments() == ["data", "weight", "bias"]
+    assert "activation" not in old.tojson() and "no_bias" not in old.tojson()
+    new = mx.sym._contrib_CausalConv1DStep(
+        data, weight, mx.sym.Variable("tails"), mx.sym.Variable("slot"),
+        name="c", **plain)
+    assert new[0].list_arguments() == ["data", "weight", "tails", "slot"]
+    with pytest.raises(ValueError, match="invalid value"):
+        mx.sym._contrib_CausalConv1D(data, weight, activation="relu")
+
+    def text(fn, *args):
+        return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+    tails, slot = jnp.zeros((2, K - 1, C)), jnp.zeros((1,))
+    seq = text(lambda x: ssm._causal_conv1d(None, plain, x, w), x)
+    assert "short_conv" in seq and "ssm_scan" not in seq
+    lane = text(lambda x, t, s: ssm._causal_conv1d_step(None, plain, x, w, t,
+                                                        s), x[:, 0], tails,
+                slot)
+    assert "short_conv_step" in lane and "ssm_step" not in lane
+    assert "ssm_scan" in text(
+        lambda x: ssm._causal_conv1d(None, {}, x, w, bias), x)
+
+
 def test_norms_and_gates():
     r = np.random.RandomState(4)
     x, z = r.randn(3, 8).astype(np.float32), r.randn(3, 8).astype(np.float32)
