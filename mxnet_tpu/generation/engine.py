@@ -129,6 +129,18 @@ def _state_step(symbol, pool):
                  for n in nodes if n["op"] == "_contrib_SSMStep"), None)
 
 
+def _expert_products(symbol, params):
+    """``(leaves' dtype, hidden, expert width)`` of a lane graph's
+    ``_contrib_RoutedExperts`` nodes (its first: a family's expert layers
+    are of one shape), what ``ops/moe.py`` ``experts_formulation`` picks
+    from; None for a graph without one."""
+    nodes = json.loads(symbol.tojson())["nodes"]
+    leaves = (params[nodes[n["inputs"][3][0]]["name"]] for n in nodes
+              if n["op"] == "_contrib_RoutedExperts")
+    return next(((np.dtype(w13.dtype), w13.shape[1], w13.shape[2] // 2)
+                 for w13 in leaves), None)
+
+
 class StateNotRebuildableError(MXNetError):
     """A preempted sequence of a recurrent family whose transcript is longer
     than the largest prefill bucket: its state is gone and no graph the
@@ -643,6 +655,8 @@ class DecodeEngine:
         # the lane program's outputs after the picked ids, and the routed
         # experts' cumulative load (expert layers, experts) where it has one
         self._lane_extras = tuple(getattr(family, "lane_extras", ()))
+        self._moe_experts = _expert_products(
+            self._decode[self.max_lanes]._symbol, self._params)
         self._expert_load = None
         self._expert_steps = self._experts_hit_total = 0
 
@@ -1011,7 +1025,7 @@ class DecodeEngine:
                 # likewise for the routed experts' grouped products
                 # (ops/moe.py), and what the lanes picked so far
                 snap["moe_experts"] = experts_formulation(
-                    self._device.platform)
+                    self._device.platform, *self._moe_experts)
                 snap["experts"] = self._experts_snapshot()
             if self._draft is not None:
                 snap["draft"] = {

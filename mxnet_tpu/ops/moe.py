@@ -18,18 +18,24 @@ and nothing stands in for absent chips.
 
 ``_contrib_RoutedExperts`` computes every (row, pick) pair once and no
 other: the pairs are sorted by expert, each held expert's group goes through
-its two products (``jax.lax.ragged_dot`` over the sorted rows: no capacity,
-no dropped token, no padding to a capacity), the results go back to their
-rows weighted and summed.  A pick outside the held range adds nothing, and
-so does a row the router was told is not live (a padded lane of a bucket,
-a position past a prompt's length): it picks expert ``E``, which no share
-holds.  :func:`experts_formulation` says what the grouped product is where
-the operands live: on a TPU XLA's own grouped kernel (one pass over the
-sorted rows; an expert's weights are fetched once for each row tile its
-group touches, so once a decode step, and an expert no row picked is not
-fetched), anywhere else XLA's dense expansion, which tests use as is.
+its two products and the gate between them (no capacity, no dropped token,
+no padding to a capacity), the results go back to their rows weighted and
+summed.  A pick outside the held range adds nothing, and so does a row the
+router was told is not live (a padded lane of a bucket, a position past a
+prompt's length): it picks expert ``E``, which no share holds.  The grouped
+products have two formulations, one op (:func:`experts_formulation` picks by
+where the operands live and what they are, as ``ops/paged.py`` and
+``ops/ssm.py`` do): on a TPU, for bfloat16 leaves of tiled widths, one
+Pallas kernel a layer (``moe_grouped``: it streams each expert with a group
+once a row tile of the sorted pairs, where it lies in the stacked leaves, the
+next tile in flight while this one multiplies; an expert no row picked costs
+no fetch); for other operands ``jax.lax.ragged_dot`` twice, which on a TPU is
+XLA's own grouped kernel and anywhere else XLA's dense expansion, the oracle
+of both (tests/test_moe_kernel.py).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,12 +48,16 @@ _F32 = jnp.float32
 ROUTER_EPS = 1e-6
 
 
+@functools.partial(jax.jit, static_argnames=("top_k", "normalize", "scale"))
 def route(rows, weight, bias, live=None, *, top_k, normalize=True, scale=1.0):
     """``rows`` (n, H), ``weight`` (E, H), ``bias`` (E,), ``live`` (n,) or
     None.  Returns ids (n, k) int32, weights (n, k) float32 and the load
     (E,) int32: the live rows' picks by expert.  Scores are float32
     whatever the rows' dtype (a near-tie must not be a tie of rounded
-    scores); a row that is not live picks expert ``E`` with weight 0."""
+    scores); a row that is not live picks expert ``E`` with weight 0.
+    Jitted on its own, as :func:`routed_experts` is: a program's 14 expert
+    layers then trace and lower the layer once, not 14 times, at every
+    start (a third of a second a program on the chip's host)."""
     experts = weight.shape[0]
     logits = lax.dot_general(rows, weight, (((1,), (1,)), ((), ())),
                              preferred_element_type=_F32)
@@ -67,23 +77,262 @@ def route(rows, weight, bias, live=None, *, top_k, normalize=True, scale=1.0):
     return ids, picked, load
 
 
-def experts_formulation(platform):
-    """What the grouped products of ``_contrib_RoutedExperts`` are where the
-    operands live: ``"ragged"`` -- XLA's grouped kernel over the sorted
-    pairs, whose work is the pairs' and whose weight traffic is the hit
-    experts' -- on a TPU; ``"ragged-dense"`` -- the same ``ragged_dot``
-    expanded by XLA into a masked dense product, every expert over every
-    pair: the oracle's cost, fine at test sizes -- anywhere else.  An
-    observation, as ``ops/paged.py`` ``decode_formulation`` is: no
-    attribute, environment variable or autotune entry chooses."""
-    return "ragged" if platform == "tpu" else "ragged-dense"
+def _ragged_grouped(x, sizes, w13, w2):
+    """The XLA formulation, and the kernel's oracle: ``jax.lax.ragged_dot``
+    twice over the sorted rows ``x`` (pairs, H), the gate between them.
+    On a TPU XLA's own grouped kernel (an expert's weights are fetched once
+    for each row tile its group touches), anywhere else its dense expansion
+    (every expert over every pair).  Returns (pairs, H) float32; what it
+    leaves in a row of no group is not to be looked at."""
+    h = lax.ragged_dot(x, w13, sizes, preferred_element_type=_F32)
+    g, u = jnp.split(h, 2, axis=-1)
+    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    return lax.ragged_dot(a, w2, sizes, preferred_element_type=_F32)
 
 
-def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0):
+# bytes of one weight tile in VMEM: a contiguous piece of a leaf (whole rows
+# of ``w13[e]`` or ``w2[e]``), long enough a DMA to run at the HBM's rate and
+# short enough that the first one of a call, which hides behind nothing, is
+# a small part of an expert's 22 MB; the pipeline holds two of each leaf
+_TILE_BYTES = 4 << 20
+# sorted pairs a row tile (what stays in VMEM while the experts of its
+# groups stream by) and a chunk (what one product multiplies: a group's rows
+# are kept by a mask, so a chunk is the waste a group of a few rows pays)
+_ROW_TILE, _CHUNK = 512, 64
+
+
+def _depth_tile(depth, width, itemsize):
+    """Rows of a weight tile ``(rows, width)``: the most that divide
+    ``depth``, are whole lane tiles (they are the last axis of the rows'
+    block) and fit ``_TILE_BYTES``; ``depth`` where none does."""
+    fits = [t for t in range(128, depth + 1, 128)
+            if depth % t == 0 and t * width * itemsize <= _TILE_BYTES]
+    return max(fits, default=depth)
+
+
+def _row_tiles(pairs):
+    """(row tile, chunk) for that many sorted pairs: one tile and one chunk
+    up to ``_CHUNK`` pairs (a lane step's), else chunks of ``_CHUNK`` in
+    tiles of at most ``_ROW_TILE`` (a prefill's)."""
+    if pairs <= _CHUNK:
+        tile = -(-pairs // 16) * 16
+        return tile, tile
+    return min(_ROW_TILE, -(-pairs // _CHUNK) * _CHUNK), _CHUNK
+
+
+def _visits(sizes, tiles, row_tile):
+    """The kernel's work list, as ``jax.experimental.pallas.ops.tpu.
+    megablox`` makes its group metadata: one visit for every (held expert
+    with a group, row tile that group touches), in the sorted rows' order,
+    so both the expert and the tile only ever step forward.  Returns the
+    groups' offsets (held + 1,), each visit's expert and row tile (visits,)
+    and the count of real visits (1,), all int32; ``visits = tiles + held -
+    1`` is the most there can be, and the ones past the count repeat the
+    last real one (no block index changes: nothing is fetched)."""
+    held = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first = offs[:-1] // row_tile
+    count = jnp.where(sizes > 0, (ends - 1) // row_tile - first + 1, 0)
+    done = jnp.cumsum(count)  # visits up to and with expert e
+    v = jnp.minimum(jnp.arange(tiles + held - 1, dtype=jnp.int32),
+                    jnp.maximum(done[-1] - 1, 0))
+    ve = jnp.minimum(jnp.sum(v[:, None] >= done[None, :], axis=1,
+                             dtype=jnp.int32), held - 1)
+    vt = jnp.take(first, ve) + v - (jnp.take(done, ve) - jnp.take(count, ve))
+    return offs, ve, vt, done[-1:]
+
+
+def _grouped_kernel(offs, ve, vt, nv, x_ref, w13_ref, w2_ref, o_ref, h_ref,
+                    a_ref, *, chunk):
+    """One visit (an expert with a group in this row tile), one weight
+    tile: steps ``0 .. k13-1`` add ``x[:, tile] W13[tile]`` into ``h_ref``,
+    the last of them gates it into ``a_ref`` (the rows of other groups
+    zeroed by selection: an inf or NaN in a row that is not this expert's
+    stays out), steps ``k13 ..`` add ``a[:, tile] W2[tile]`` into the
+    output's block, which stays in VMEM while the visits of its row tile
+    pass and holds the sum over their experts (each row has one).  Only
+    the chunks of the tile that the group touches multiply (a loop, not
+    unrolled: a program's start traces and lowers this body once a
+    shape)."""
+    from jax.experimental import pallas as pl
+
+    v, t = pl.program_id(0), pl.program_id(1)
+    k13, tile = x_ref.shape[0], x_ref.shape[1]
+    k2, d2 = a_ref.shape[0], a_ref.shape[2]
+    width = k2 * d2
+    e, base = ve[v], vt[v] * tile
+    # the group's rows in the tile's own numbering; none in a visit past
+    # the count
+    lo = offs[e] - base
+    hi = jnp.where(v < nv[0], offs[e + 1], offs[e]) - base
+
+    @pl.when((t == 0) & ((v == 0) | (vt[v] != vt[jnp.maximum(v - 1, 0)])))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def multiply(c, carry):
+        start = pl.multiple_of(c * chunk, chunk)
+        rows = pl.ds(start, chunk)
+
+        @pl.when(t < k13)
+        def _():
+            part = jnp.dot(x_ref[t, rows, :], w13_ref[...],
+                           preferred_element_type=_F32)
+            h_ref[rows, :] = jnp.where(t == 0, part, h_ref[rows, :] + part)
+
+        @pl.when(t == k13 - 1)
+        def _():
+            at = start + lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+            mine = (at >= lo) & (at < hi)
+            for j in range(k2):
+                g = h_ref[rows, j * d2:(j + 1) * d2]
+                u = h_ref[rows, width + j * d2:width + (j + 1) * d2]
+                a = (jax.nn.silu(g) * u).astype(a_ref.dtype)
+                a_ref[j, rows, :] = jnp.where(mine, a, jnp.zeros_like(a))
+
+        @pl.when(t >= k13)
+        def _():
+            o_ref[rows, :] += jnp.dot(a_ref[t - k13, rows, :], w2_ref[...],
+                                      preferred_element_type=_F32)
+
+        return carry
+
+    # the chunks the group's rows lie in: none where it has none
+    chunks = tile // chunk
+    first = jnp.clip(lo // chunk, 0, chunks)
+    last = jnp.where(hi > lo, jnp.clip((hi + chunk - 1) // chunk, 0, chunks),
+                     first)
+    lax.fori_loop(first, last, multiply, None)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "depths", "interpret"))
+def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
+                    interpret=False):
+    """The Pallas formulation: one call a layer, both products and the gate
+    (``h`` never leaves VMEM, and the second leaf's first tile is in flight
+    while the first leaf's last one multiplies: two calls would drain and
+    fill the pipeline between them, and write and read ``a``).  The grid is
+    (visit, weight tile); :func:`_visits`' lists are the scalar-prefetch
+    operands and every index map reads them, so the call's own pipeline
+    fetches the next tile of this expert, or the first tile of the NEXT
+    expert with a group, while this one multiplies, and an expert without a
+    group is never named: no byte of it moves.  The leaves are read where
+    they lie: a tile is ``depths[i]`` whole rows of ``w13[e]`` / ``w2[e]``,
+    one contiguous piece.  ``w2``'s map stays on the previous visit's last
+    tile until this visit's second product starts, so that every step
+    starts exactly one tile's fetch.  Jitted on its own so that a program's
+    14 call sites trace and lower it once (as ``ops/paged.py``
+    ``_kernel_decode``); ``rows`` (row tile, chunk) and ``depths`` (of a
+    tile of each leaf) are for tests (:func:`_row_tiles`,
+    :func:`_depth_tile`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    pairs, hidden = x.shape
+    held, width = w2.shape[:2]
+    row_tile, chunk = rows or _row_tiles(pairs)
+    item = w13.dtype.itemsize
+    d13, d2 = depths or (_depth_tile(hidden, 2 * width, item),
+                         _depth_tile(width, hidden, item))
+    k13, k2 = hidden // d13, width // d2
+    tiles = -(-pairs // row_tile)
+
+    def x_map(v, t, offs, ve, vt, nv):
+        return 0, vt[v], 0
+
+    def w13_map(v, t, offs, ve, vt, nv):
+        return ve[v], jnp.where(v < nv[0], jnp.minimum(t, k13 - 1),
+                                k13 - 1), 0
+
+    def w2_map(v, t, offs, ve, vt, nv):
+        now = (v < nv[0]) & (t >= k13)
+        return (jnp.where(now, ve[v], ve[jnp.maximum(v - 1, 0)]),
+                jnp.where(now, t - k13, jnp.where(v == 0, 0, k2 - 1)), 0)
+
+    def o_map(v, t, offs, ve, vt, nv):
+        return vt[v], 0
+
+    vmem = (2 * (d13 * 2 * width + d2 * hidden + k13 * row_tile * d13) * item
+            + row_tile * (2 * hidden * 4 + 2 * width * 4 + width * item))
+    call = pl.pallas_call(
+        functools.partial(_grouped_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(tiles + held - 1, k13 + k2),
+            in_specs=[pl.BlockSpec((k13, row_tile, d13), x_map),
+                      pl.BlockSpec((None, d13, 2 * width), w13_map),
+                      pl.BlockSpec((None, d2, hidden), w2_map)],
+            out_specs=pl.BlockSpec((row_tile, hidden), o_map),
+            scratch_shapes=[pltpu.VMEM((row_tile, 2 * width), _F32),
+                            pltpu.VMEM((k2, row_tile, d2), x.dtype)]),
+        out_shape=jax.ShapeDtypeStruct((tiles * row_tile, hidden), _F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the blocks above and as much again for the products' values
+            vmem_limit_bytes=min(2 * vmem + (8 << 20), 100 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * pairs * hidden * width,
+            transcendentals=pairs * width,
+            bytes_accessed=(min(held, pairs) * 3 * hidden * width * item
+                            + pairs * hidden * (item + 4))),
+        name="moe_grouped", interpret=interpret)
+
+    @jax.custom_vjp
+    def grouped(x, sizes, w13, w2):
+        # rows past the pairs belong to no group; the rows' block is (weight
+        # tile, row, feature of the tile): a step indexes its leading axis
+        x = jnp.pad(x, ((0, tiles * row_tile - pairs), (0, 0)))
+        x = x.reshape(tiles * row_tile, k13, d13).swapaxes(0, 1)
+        return call(*_visits(sizes, tiles, row_tile), x, w13, w2)[:pairs]
+
+    # the kernel is the forward pass alone: a gradient goes through the XLA
+    # formulation of the same products (training does not stop working where
+    # the kernel engages; a backward kernel is ROADMAP B5's)
+    def backward(operands, dy):
+        x, sizes, w13, w2 = operands
+        dx, dw13, dw2 = jax.vjp(
+            lambda x, w13, w2: _ragged_grouped(x, sizes, w13, w2),
+            x, w13, w2)[1](dy)
+        return dx, None, dw13, dw2
+
+    grouped.defvjp(lambda *operands: (grouped(*operands), operands),
+                   backward)
+    return grouped(x, sizes, w13, w2)
+
+
+def experts_formulation(platform, dtype, hidden, width):
+    """What the grouped products of ``_contrib_RoutedExperts`` are, read off
+    where the operands live and what they are: ``"pallas"`` -- the kernel
+    ``moe_grouped``, which streams each expert with a group once a row tile
+    -- on a TPU for bfloat16 leaves whose widths (``hidden``, ``width`` and
+    so ``2 x width``) are whole lane tiles; ``"ragged"`` -- XLA's own
+    grouped kernel over the sorted pairs -- on a TPU for any other operands;
+    ``"ragged-dense"`` -- the same ``ragged_dot`` expanded by XLA into a
+    masked dense product, every expert over every pair: the oracle of both,
+    fine at test sizes -- anywhere else.  An observation, as ``ops/paged.py``
+    ``decode_formulation`` is: no attribute, environment variable or
+    autotune entry chooses."""
+    if platform != "tpu":
+        return "ragged-dense"
+    tiled = (jnp.dtype(dtype) == jnp.bfloat16 and hidden % 128 == 0
+             and width % 128 == 0)
+    return "pallas" if tiled else "ragged"
+
+
+_GROUPED = {"pallas": _kernel_grouped, "ragged": _ragged_grouped,
+            "ragged-dense": _ragged_grouped}
+
+
+@functools.partial(jax.jit, static_argnames=("first_expert", "grouped"))
+def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0,
+                   grouped=_ragged_grouped):
     """``rows`` (n, H), ``ids`` / ``weights`` (n, k), ``w13`` (held, H, 2F)
     ``[W1 | W3]`` and ``w2`` (held, F, H), the experts ``first_expert ..
-    first_expert + held - 1``.  Returns (n, H) in ``rows``' dtype: the held
-    experts' part of the layer's output."""
+    first_expert + held - 1``.  ``grouped`` makes the two products of the
+    pairs sorted by expert: :func:`_ragged_grouped` (here, and wherever
+    :func:`experts_formulation` says so) or :func:`_kernel_grouped`.
+    Returns (n, H) in ``rows``' dtype: the held experts' part of the
+    layer's output."""
     n, k = ids.shape
     held = w13.shape[0]
     local = ids.reshape(-1) - int(first_expert)
@@ -94,11 +343,7 @@ def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0):
     order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)
-    x = jnp.take(rows, order // k, axis=0)
-    h = lax.ragged_dot(x, w13, sizes, preferred_element_type=_F32)
-    g, u = jnp.split(h, 2, axis=-1)
-    a = (jax.nn.silu(g) * u).astype(rows.dtype)
-    y = lax.ragged_dot(a, w2, sizes, preferred_element_type=_F32)
+    y = grouped(jnp.take(rows, order // k, axis=0), sizes, w13, w2)
     # back to (row, pick) order; a select, not a product with a zero weight:
     # what the grouped product leaves in a row of no group is not looked at
     back = jnp.zeros_like(order).at[order].set(
@@ -145,12 +390,18 @@ def _routed_experts(opctx, attrs, data, ids, weights, w13, w2):
     router's ``ids`` and ``weights`` (rows, k), ``w13`` (held, H, 2F) and
     ``w2`` (held, F, H); ``num_experts`` is the router's width,
     ``first_expert`` the first of the held ones.  Writes (rows, H)."""
+    from .interpret import platform_of
+
     first, held = int(attrs.get("first_expert", 0)), w13.shape[0]
     if first < 0 or first + held > int(attrs["num_experts"]):
         raise ValueError("experts %d..%d are not among the router's %d"
                          % (first, first + held - 1,
                             int(attrs["num_experts"])))
-    return routed_experts(data, ids, weights, w13, w2, first_expert=first)
+    grouped = _GROUPED[experts_formulation(
+        platform_of(data, w13, w2), jnp.result_type(data, w13, w2),
+        data.shape[1], w2.shape[1])]
+    return routed_experts(data, ids, weights, w13, w2, first_expert=first,
+                          grouped=grouped)
 
 
 # ---------------------------------------------------------------------------

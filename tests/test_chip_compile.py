@@ -326,13 +326,20 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
 def test_routed_experts_cost_their_pairs_on_v5e(one_chip, rows):
     """One expert layer at the LFM2 cell's widths (32 experts of 1792 over
     hidden 2048, 4 a row) compiled for the chip, a lane bucket's 16 rows and
-    a prefill bucket's 512: XLA's own grouped kernel (two Mosaic calls, one
-    a product), whose counted FLOPs are the pairs' (rows x 4 x 6 x 2048 x
-    1792, within 1.5 x: NOT experts x rows x that, which a dense expansion
-    would cost), and whose counted bytes hold each expert's weights once a
-    lane step (0.70 GB; a prefill's 2,048 sorted pairs span several row
-    tiles, and an expert whose group crosses a tile's edge is fetched for
-    both: under 2.5 x), with no float32 or second copy of them."""
+    a prefill bucket's 512: ONE Mosaic call, the repo's ``moe_grouped`` (both
+    products and the gate; no ``ragged-dot``), whose counted FLOPs are the
+    pairs' (rows x 4 x 6 x 2048 x 1792: NOT experts x rows x that, which a
+    dense expansion would cost), and whose counted bytes hold each expert's
+    weights once (0.70 GB: a lane step's 64 pairs hit at most 32 experts, a
+    prefill's 2,048 sorted pairs in row tiles of 512 fetch an expert again
+    only where its group crosses a tile's edge, 35 fetches for 32 experts at
+    the most; around the call XLA counts the prefill's sort, gathers and the
+    combine of 2,048 x 2,048 float32 at 0.6 GB more: under 2 x in all, where
+    its own grouped kernel counted 2.5 x), each stacked leaf the call's
+    operand whole and in bfloat16, with no copy, conversion, transpose or
+    gather of it."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
@@ -345,21 +352,43 @@ def test_routed_experts_cost_their_pairs_on_v5e(one_chip, rows):
 
     def layer(g, router, bias, w13, w2):
         ids, w, _ = moe.route(g, router, bias, top_k=K)
-        return moe.routed_experts(g, ids, w, w13, w2)
+        return moe.routed_experts(g, ids, w, w13, w2,
+                                  grouped=moe._kernel_grouped)
 
+    assert moe.experts_formulation("tpu", jnp.bfloat16, H, F) == "pallas"
     compiled = jax.jit(layer).lower(
         S((rows, H), jnp.bfloat16), S((E, H), jnp.bfloat16),
         S((E,), jnp.bfloat16), S((E, H, 2 * F), jnp.bfloat16),
         S((E, F, H), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert text.count("ragged-dot-none") >= 2 and "tpu_custom_call" in text
+    assert "ragged-dot" not in text
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert [n.split(".")[0] for n in names] == ["moe_grouped"], names
+    _leaves_go_whole_into(text, "moe_grouped", calls=1)
     cost = compiled.cost_analysis()
     pairs = rows * K * 6 * H * F
     assert pairs <= cost["flops"] <= 1.5 * pairs
     weights = 3 * E * H * F * 2
     assert weights <= cost["bytes accessed"] <= \
-        (1.3 if rows == 16 else 2.5) * weights
+        (1.3 if rows == 16 else 2.0) * weights
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _leaves_go_whole_into(text, kernel, calls):
+    """Each stacked leaf of the cell's experts is used by ``calls``
+    instructions of the compiled module and no other, every one a call of
+    ``kernel``: whole, in bfloat16, where it lies (no copy, slice, convert,
+    transpose or gather of it, and no float32 twin)."""
+    import re
+
+    for leaf in ("bf16[32,2048,3584]", "bf16[32,1792,2048]"):
+        uses = [line for line in text.splitlines()
+                if leaf in line and re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)
+                and " parameter(" not in line]
+        assert len(uses) == calls and all(
+            re.match(r"\s*(ROOT )?%" + kernel + r"[.\d]* = ", u)
+            for u in uses), uses[:4]
+        assert leaf.replace("bf16", "f32") not in text
 
 
 def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
@@ -423,13 +452,12 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     assert " while(" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
     kinds = sorted(n.split(".")[0] for n in names)
-    assert kinds == ["ragged-dot-metadata"] * 3 + ["ragged-dot-none"] * 6
-    for leaf in ("bf16[32,2048,3584]", "bf16[32,1792,2048]"):
-        uses = [line for line in text.splitlines()
-                if leaf in line and re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)
-                and " parameter(" not in line]
-        assert len(uses) == 3 and all("ragged-dot-none" in u for u in uses), \
-            uses[:4]
-        assert leaf.replace("bf16", "f32") not in text
+    assert kinds == ["moe_grouped"] * 3 and "ragged-dot" not in text
+    _leaves_go_whole_into(text, "moe_grouped", calls=3)
+    scoped = re.findall(r"%moe_grouped[.\d]* = [^\n]*op_name=\"([^\"]*)\"",
+                        text)
+    assert len(scoped) == 3 and all(
+        re.search(r"layer\d+_experts/moe_experts/", s) for s in scoped), scoped
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)

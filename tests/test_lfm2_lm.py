@@ -160,6 +160,9 @@ def test_prefill_then_decode_logits_are_the_reference(dtype):
         4 if dtype == "float32" else 2)
     assert "ssm_step" not in snap and snap["paged_attention"] == "xla"
     assert snap["moe_experts"] == "ragged-dense"  # the host's formulation
+    # picked from the operands' facts: the leaves' dtype and the two widths
+    # (ops/moe.py ``experts_formulation``)
+    assert eng._moe_experts == (np.dtype(dtype), 32, 16)
     # a padded lane of the bucket picks nothing: live lanes x k a layer
     k = cfg["num_experts_per_tok"]
     for lanes, load in loads:

@@ -8,6 +8,8 @@ terms in another order than the dense ones (1e-5 on outputs of order 0.1-1);
 the router's scores are compared to 1e-6 and its picks exactly (the seeds
 hold no tie).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -178,11 +180,18 @@ def test_padded_rows_add_nothing_and_poison_nothing():
         **TOL)
 
 
-def test_two_shares_add_up_to_the_whole_layer_and_to_the_reference():
+@pytest.mark.parametrize("grouped", [
+    moe._ragged_grouped,
+    functools.partial(moe._kernel_grouped, interpret=True)],
+    ids=["ragged-dense", "pallas"])
+def test_two_shares_add_up_to_the_whole_layer_and_to_the_reference(grouped):
     """The guide's share test: an op that holds experts 0-15 and one that
     holds 16-31, both routed over all 32, add up to the op that holds all 32
     -- and that is the plain reference's whole layer (every expert over
-    every row).  No part is counted twice: there is no shared expert."""
+    every row).  No part is counted twice: there is no shared expert.  In
+    both formulations: XLA's, and the kernel ``moe_grouped`` in interpret
+    mode (a share's 16 leaves, the other share's pairs in no group)."""
+    experts = functools.partial(moe.routed_experts, grouped=grouped)
     cfg = dict(vocab_size=64, hidden_size=H, layer_types=["conv"],
                num_attention_heads=4, num_key_value_heads=2,
                intermediate_size=64, moe_intermediate_size=F, conv_L_cache=3,
@@ -194,9 +203,9 @@ def test_two_shares_add_up_to_the_whole_layer_and_to_the_reference():
     g = jnp.asarray(np.random.RandomState(9).randn(23, H), jnp.float32)
     ids, w, load = moe.route(g, p["router_weight"], p["router_bias"],
                              top_k=4)
-    whole = moe.routed_experts(g, ids, w, p["experts_w13"], p["experts_w2"])
-    parts = [moe.routed_experts(g, ids, w, p["experts_w13"][lo:lo + 16],
-                                p["experts_w2"][lo:lo + 16], first_expert=lo)
+    whole = experts(g, ids, w, p["experts_w13"], p["experts_w2"])
+    parts = [experts(g, ids, w, p["experts_w13"][lo:lo + 16],
+                     p["experts_w2"][lo:lo + 16], first_expert=lo)
              for lo in (0, 16)]
     assert all(float(jnp.abs(part).max()) > 0.01 for part in parts)
     np.testing.assert_allclose(np.asarray(parts[0] + parts[1]),
@@ -272,8 +281,26 @@ def test_ops_carry_their_scopes_and_the_formulation_is_an_observation():
     text = jax.jit(layer).lower(a["g"]).as_text(debug_info=True)
     for scope in ("moe_router", "moe_experts", "rotary"):
         assert scope in text
-    assert moe.experts_formulation("tpu") == "ragged"
-    assert moe.experts_formulation("cpu") == "ragged-dense"
+    # what the op runs is read off its operands (here float32 on the host),
+    # the same facts the engine's ``snapshot()`` hands over
+    assert "ragged_dot" in text and "moe_grouped" not in text
+    assert moe.experts_formulation("cpu", a["w13"].dtype, H,
+                                   F) == "ragged-dense"
+    assert moe.experts_formulation("tpu", jnp.bfloat16, 2048,
+                                   1792) == "pallas"
+
+
+@pytest.mark.parametrize("platform,dtype,hidden,width,want", [
+    ("tpu", "bfloat16", 2048, 1792, "pallas"),   # the LFM2 cell's widths
+    ("tpu", "bfloat16", 256, 128, "pallas"),
+    ("tpu", "float32", 2048, 1792, "ragged"),    # float32 leaves
+    ("tpu", "bfloat16", 2048, 1760, "ragged"),   # 2F splits off a lane tile
+    ("tpu", "bfloat16", 2000, 1792, "ragged"),
+    ("cpu", "bfloat16", 2048, 1792, "ragged-dense"),
+    ("gpu", "float32", 32, 16, "ragged-dense")])
+def test_experts_formulation_is_read_off_the_operands(platform, dtype, hidden,
+                                                      width, want):
+    assert moe.experts_formulation(platform, dtype, hidden, width) == want
 
 
 # ---------------------------------------------------------------------------
