@@ -5,30 +5,31 @@ every reader: the run's ``info`` in, a number out, or None where the trace
 holds nothing for it (an untraced run, a rehearsal on the host, a program
 without these scopes and span arguments).
 
-On a TPU the two grouped products of a layer are XLA's own kernel, and its
-custom calls reach the trace as ``ragged-dot-none.N`` (behind one
-``ragged-dot-metadata.N`` a layer) WITHOUT the scope they were traced under
-(the compiler names the rewritten operation anew: my chip runs, PR 34), so
-the expert layer's device time is the operations under ``moe_experts`` (sort,
-gate, combine) plus the events of those names.
+Since PR 37 a layer's two grouped products and its gate are the repo's own
+kernel, one call a layer (``moe_grouped.N``), and carry the scope they were
+traced under, so ``moe_experts`` holds the layer's whole cost.  Under the
+``"ragged"`` formulation (operands the kernel does not take) the products are
+XLA's kernel, whose custom calls reach the trace as ``ragged-dot-none.N``
+WITHOUT that scope (the compiler names the rewritten operation anew: my chip
+runs, PR 34): the expert layer's device time is the operations under
+``moe_experts`` plus the events of those names.
 
 ``decode_bytes_roofline_pct`` divides the bytes a lane step must read by the
-decode program's WHOLE device time (its events on the device's ``XLA
-Modules`` line), not by the ``moe_experts`` scope: XLA prefetches weights
-under waits that carry no scope (PERF.md section 5), and bytes over a scope
-that misses them would read over 100 %.
+decode program's WHOLE device time (``gen_device_ms_per_step``: its runs on
+the device's ``XLA Modules`` line), not by the ``moe_experts`` scope: XLA
+prefetches weights under waits that carry no scope (PERF.md section 5), and
+bytes over a scope that misses them would read over 100 %.  The bytes count
+each HIT expert's weights once a layer, which is what ``moe_grouped`` fetches
+for the 64 pairs of a lane step (one row tile: one visit an expert).
 """
 import math
-import os
 import re
 import statistics
 
 from perfbench.harness import peaks
 from perfbench.harness import spans as _spans
-from perfbench.harness import trace as _trace
 
 EXPERT_LEAVES = ("experts_w13", "experts_w2")
-DECODE_MODULE = "decode_b"  # ``jit_decode_b16(...)`` on the module line
 _GROUPED_RE = re.compile(r"^ragged-dot-")
 _IN_SCOPE = _spans.in_scope("moe_experts")
 
@@ -56,8 +57,8 @@ def moe_experts_ms_per_step(info):
     """The expert layers' device time inside the decode program's runs, over
     their count (a prefill's expert time is not a step's)."""
     tr = _spans.of_run(info)
-    runs = module_runs(info, DECODE_MODULE)
-    if tr is None or not runs:
+    runs = _spans.module_runs(tr, _spans.DECODE_MODULE) if tr else {}
+    if not runs:
         return None
     secs, n = 0.0, 0
     for plane, spans in runs.items():
@@ -111,46 +112,18 @@ def decode_step_bytes(cfg, weight_shapes, expert_bytes, pages, page_size,
     return dense + expert_bytes + kv + 2 * state_bytes
 
 
-def module_runs(info, part):
-    """{device plane: [(start, end)], sorted} of the runs in the window of
-    the programs whose name on the device's ``XLA Modules`` line holds
-    ``part``; {} where there is no trace or no such program."""
-    tr = _spans.of_run(info)
-    if tr is None:
-        return {}
-    import jax
-
-    path = _trace.find_xplane(os.path.join(_spans.ROOT, ".perfbench_trace",
-                                           info["workload"]))
-    t0, t1 = tr.window or (float("-inf"), float("inf"))
-    out = {}
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/device:TPU:"):
-            continue
-        runs = [(max(e.start_ns * 1e-9, t0),
-                 min((e.start_ns + e.duration_ns) * 1e-9, t1))
-                for line in plane.lines if line.name == "XLA Modules"
-                for e in line.events if part in e.name]
-        runs = sorted(r for r in runs if r[1] > r[0])
-        if runs:
-            out[plane.name] = runs
-    return out
-
-
 def decode_bytes_roofline_pct(info):
     """The bytes a lane step must move (:func:`decode_step_bytes`, from the
     step spans' own counts) over the decode program's device time a run
-    times the HBM's published rate."""
+    (``gen_device_ms_per_step``) times the HBM's published rate."""
     cfg = info.get("config", {})
     byts, pages, state = (_step_stat(info, k) for k in
                           ("expert_bytes", "pages", "state_bytes"))
     if not byts or not pages or "num_dense_layers" not in cfg:
         return None
-    runs = [r for spans in module_runs(info, DECODE_MODULE).values()
-            for r in spans]
-    if not runs:
+    step_ms = _spans.gen_device_ms_per_step(info)
+    if step_ms is None:
         return None
-    step_s = sum(b - a for a, b in runs) / len(runs)
     from perfbench.models import lfm2_moe_lm
 
     need = decode_step_bytes(
@@ -158,4 +131,4 @@ def decode_bytes_roofline_pct(info):
         statistics.fmean(byts), statistics.fmean(pages),
         int(info["mix"]["page_size"]), statistics.fmean(state or [0.0]))
     rate = peaks.peak(info["device_kind"], "hbm_bytes_per_s")
-    return 100.0 * need / (step_s * rate)
+    return 100.0 * need / (1e-3 * step_ms * rate)

@@ -10,6 +10,13 @@ with the sources ``program_span`` and (by scope or kernel name)
 ``device_trace`` are its readers, one two-line file each under
 ``layer_metrics/``.
 
+An operation's scope is its PROGRAM's: two programs may each hold a
+``fusion.73``, so the scope is looked up by the operation's name and the
+program whose run on the device's ``XLA Modules`` line it falls in (a run is
+named ``jit_decode_b16(<program id>)``, and each operation's metadata carries
+that id).  The runs themselves are kept too: a program's own device time is
+the duration of its runs.
+
 Events, times and the window (the host span ``bench:window``) are taken as
 ``trace.load`` and ``trace.reduce`` take them.  ``jax.profiler.ProfileData``
 exposes an event's own stats but not its metadata's, where the scope is: that
@@ -19,6 +26,7 @@ imported).  A reader returns None where the trace holds nothing for it: an
 untraced run, a rehearsal on the host (no scopes there), a program without
 that span (the parent of the PR that added it).
 """
+import bisect
 import collections
 import functools
 import os
@@ -32,13 +40,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 Span = collections.namedtuple("Span", "name start end thread stats")
 Op = collections.namedtuple("Op", "name start end scope")
-Trace = collections.namedtuple("Trace", "spans devices window")
+# ``modules``: {device plane: [(name, start, end)], by start}, the runs of
+# whole programs on the device's ``XLA Modules`` line; empty (a hand-made
+# trace: None) where the trace has no such line, as on the host platform
+Trace = collections.namedtuple("Trace", "spans devices window modules",
+                               defaults=(None,))
 
-# a program span is ``layer:what`` or ``Class.method[:part]``; the runtime's
-# own events (``tpu::System::Execute=>Done``, ``PjitFunction(f)``) and the
-# host platform's operations (``dot_general.66``) are not
-NAME_RE = re.compile(r"^(?!.*\.\d+$)[A-Za-z_]\w*"
-                     r"(?:[.:](?!:)[\w\[\]%=\-]+)+$")
+NAME_RE = _trace.NAME_RE  # what a program span is named
+PROGRAM_ID_RE = re.compile(r"\((\d+)\)$")  # ``jit_decode_b16(<id>)``
+# the lane programs, ``jit_decode_b<lanes>``, on the module line
+DECODE_MODULE = "jit_decode_b"
 
 
 # ---------------------------------------------------------------------------
@@ -89,11 +100,12 @@ def _map_entry(buf):
 
 
 def op_scopes(path):
-    """{plane name: {event name: scope}} of the device planes of an
-    ``.xplane.pb``: the ``tf_op`` stat of each event's metadata, which holds
-    the operation's ``op_name`` (``jit(fused_step)/fc1/dot_general:``),
-    without the trailing colon.  A name that two programs give different
-    scopes has none."""
+    """{plane name: {event name: {program id: scope}}} of the device planes of
+    an ``.xplane.pb``: the ``tf_op`` stat of each event's metadata, which
+    holds the operation's ``op_name`` (``jit(fused_step)/fc1/dot_general:``),
+    without the trailing colon, under the metadata's ``program_id`` (None
+    where it has none).  The scope is None where the program's operation of
+    that name has none."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
     out = {}
@@ -113,29 +125,48 @@ def op_scopes(path):
                         stat_names[sid] = bytes(mv).decode()
         if not name or not name.startswith("/device:TPU:"):
             continue
-        names = {v: k for k, v in stat_names.items()}
-        want = names.get("tf_op")
-        scopes = {}
+        ids = {v: k for k, v in stat_names.items()}
+        want, want_program = ids.get("tf_op"), ids.get("program_id")
+        found = {}  # {event name: {program id: {scope}}}
         for meta in metas:
-            ev_name, scope = None, None
+            ev_name = scope = program = None
             for mf, mv in _fields(meta):
                 if mf == 2:  # XEventMetadata.name
                     ev_name = bytes(mv).decode()
-                elif mf == 5 and want is not None:  # XEventMetadata.stats
+                elif mf == 5:  # XEventMetadata.stats
                     stat = dict(_fields(mv))
-                    if stat.get(1) != want:
+                    sid = stat.get(1)  # XStat.metadata_id
+                    if sid is None:
                         continue
-                    if 5 in stat:  # str_value
-                        scope = bytes(stat[5]).decode()
-                    elif 7 in stat:  # ref_value: a stat_metadata's name
-                        scope = stat_names.get(stat[7])
-            if ev_name is None or not scope:
+                    if sid == want_program:
+                        # uint64_value or int64_value
+                        program = stat.get(3, stat.get(4))
+                    elif sid == want:
+                        if 5 in stat:  # str_value
+                            scope = bytes(stat[5]).decode()
+                        elif 7 in stat:  # ref_value: a stat_metadata's name
+                            scope = stat_names.get(stat[7])
+            if ev_name is None or (program is None and not scope):
                 continue
-            scope = scope.rsplit(":", 1)[0]
-            scopes[ev_name] = scope if scopes.get(ev_name, scope) == scope \
-                else None
-        out[name] = {k: v for k, v in scopes.items() if v}
+            seen = found.setdefault(ev_name, {}).setdefault(program, set())
+            if scope:
+                seen.add(scope.rsplit(":", 1)[0])
+        out[name] = {ev: {prog: next(iter(seen)) if len(seen) == 1 else None
+                          for prog, seen in progs.items()}
+                     for ev, progs in found.items()}
     return out
+
+
+def _scope(by_program, program):
+    """The scope of an operation that ran in ``program`` (None: not known),
+    of ``{program id: scope}``: that program's, or, where the program is not
+    known, the one scope every program gives the name."""
+    if not by_program:
+        return None
+    if program in by_program:
+        return by_program[program]
+    scopes = {s for s in by_program.values() if s}
+    return scopes.pop() if len(scopes) == 1 else None
 
 
 # ---------------------------------------------------------------------------
@@ -149,15 +180,24 @@ def _stat(value):
 @functools.lru_cache(maxsize=4)
 def load(path):
     """The program spans of the host plane, the device operations with their
-    scopes, and the window, of one ``.xplane.pb``: once a process."""
+    scopes, the window, and the programs' runs on the devices, of one
+    ``.xplane.pb``: once a process."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(path)
     scopes = op_scopes(path)
-    spans, devices, cpu_ops = [], {}, []
+    spans, devices, cpu_ops, modules = [], {}, [], {}
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             names = scopes.get(plane.name, {})
+            runs = sorted((e.start_ns * 1e-9,
+                           (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                          for line in plane.lines
+                          if line.name == "XLA Modules"
+                          for e in line.events)
+            starts = [r[0] for r in runs]
+            programs = [int(m.group(1)) if m else None for m in
+                        (PROGRAM_ID_RE.search(r[2]) for r in runs)]
             ops = []
             for line in plane.lines:
                 if line.name != "XLA Ops":
@@ -167,10 +207,16 @@ def load(path):
                     if _trace.ENVELOPE_RE.match(short):
                         continue
                     s = e.start_ns * 1e-9
+                    # the run the operation started in says whose it is
+                    i = bisect.bisect_right(starts, s) - 1
+                    program = programs[i] \
+                        if i >= 0 and s < runs[i][1] else None
                     ops.append(Op(short, s, s + e.duration_ns * 1e-9,
-                                  names.get(e.name)))
+                                  _scope(names.get(e.name), program)))
             if ops:
                 devices[plane.name] = sorted(ops, key=lambda o: o.start)
+            if runs:
+                modules[plane.name] = [(n, a, b) for a, b, n in runs]
         elif plane.name == "/host:CPU":
             for thread, line in enumerate(plane.lines):
                 if "XLAPjRtCpuClient" in line.name:
@@ -196,7 +242,7 @@ def load(path):
     spans.sort(key=lambda s: (s.start, -s.end))
     window = next(((s.start, s.end) for s in spans
                    if s.name == "bench:window"), None)
-    return Trace(spans, devices, window)
+    return Trace(spans, devices, window, modules)
 
 
 def of_run(info):
@@ -219,7 +265,7 @@ def of_run(info):
 
 def named(trace, name):
     """The spans of that name that start inside the window, by start."""
-    t0, t1 = trace.window or (float("-inf"), float("inf"))
+    t0, t1 = _window(trace)
     return [s for s in trace.spans if s.name == name and t0 <= s.start < t1]
 
 
@@ -253,49 +299,51 @@ def median_ms(spans):
         if spans else None
 
 
-def _window_ops(trace):
-    """Per device, the operations clipped to the window."""
-    t0, t1 = trace.window or (float("-inf"), float("inf"))
-    for ops in trace.devices.values():
-        yield [Op(o.name, max(o.start, t0), min(o.end, t1), o.scope)
-               for o in ops if min(o.end, t1) > max(o.start, t0)]
+def _window(trace):
+    return trace.window or (float("-inf"), float("inf"))
 
 
 def busy_s(trace):
     """Device busy time in the window, mean over devices (as
-    ``trace.reduce`` counts it)."""
-    n = len(trace.devices)
-    return sum(sum(b - a for a, b in _trace.union((o.start, o.end)
-                                                  for o in ops))
-               for ops in _window_ops(trace)) / n if n else 0.0
-
-
-def busy_inside_s(trace, spans):
-    """Device busy time inside the union of ``spans``, mean over devices."""
-    cover = _trace.union((s.start, s.end) for s in spans)
-    n = len(trace.devices)
-    if not n or not cover:
-        return 0.0
+    ``trace.reduce`` counts it): one pass over each device's operations,
+    which lie by start."""
+    t0, t1 = _window(trace)
     total = 0.0
     for ops in trace.devices.values():
-        busy = _trace.union((o.start, o.end) for o in ops)
-        j = 0
-        for a, b in busy:
-            while j < len(cover) and cover[j][1] <= a:
-                j += 1
-            k = j
-            while k < len(cover) and cover[k][0] < b:
-                total += min(b, cover[k][1]) - max(a, cover[k][0])
-                k += 1
-    return total / n
+        edge = t0  # up to here the device's time is counted
+        for o in ops:
+            a, b = max(o.start, edge), min(o.end, t1)
+            if b > a:
+                total += b - a
+                edge = b
+    return total / len(trace.devices) if trace.devices else 0.0
+
+
+def module_runs(trace, part):
+    """{device plane: [(start, end)], by start} of the whole runs that start
+    inside the window of the programs whose name on the device's ``XLA
+    Modules`` line holds ``part``; {} where the trace has no such line or no
+    such program."""
+    t0, t1 = _window(trace)
+    out = {}
+    for plane, runs in (trace.modules or {}).items():
+        mine = [(a, b) for name, a, b in runs if part in name and t0 <= a < t1]
+        if mine:
+            out[plane] = mine
+    return out
 
 
 def op_s(trace, match):
     """Device seconds in the window, mean over devices, of the operations
     ``match(op)`` picks."""
-    n = len(trace.devices)
-    return sum(o.end - o.start for ops in _window_ops(trace)
-               for o in ops if match(o)) / n if n else 0.0
+    t0, t1 = _window(trace)
+    total = 0.0
+    for ops in trace.devices.values():
+        for o in ops:
+            a, b = max(o.start, t0), min(o.end, t1)
+            if b > a and match(o):
+                total += b - a
+    return total / len(trace.devices) if trace.devices else 0.0
 
 
 def in_scope(component):
@@ -303,7 +351,10 @@ def in_scope(component):
     component stands in the scope path whole, whatever wraps it
     (``transpose(jvp(..))``) or follows it."""
     rx = re.compile(r"(?:^|[/(])%s(?:[/)]|$)" % component)
-    return lambda op: bool(op.scope and rx.search(op.scope))
+    # a trace holds millions of operations under a few thousand scopes
+    found = functools.lru_cache(maxsize=None)(
+        lambda scope: bool(scope and rx.search(scope)))
+    return lambda op: found(op.scope)
 
 
 def kernel(name):
@@ -338,28 +389,27 @@ def _gen_part_ms_per_step(info, name):
 
 
 def gen_pool_h2d_ms_per_step(info):
+    """One ``device_put`` a step of the lanes' ids, positions, sources and
+    page tables (a few KB): no plane crosses, they live on the device."""
     return _gen_part_ms_per_step(info, "gen:pool_h2d")
 
 
 def gen_pool_d2h_ms_per_step(info):
-    """Holds the device's own work: the first read blocks until the step
-    has run, then the planes come back."""
+    """The wait for the step dispatched one iteration back, then its lanes'
+    picked ids (32 bytes at 8 lanes): the engine thread's wait on the
+    device, not a transfer's time."""
     return _gen_part_ms_per_step(info, "gen:pool_d2h")
-
-
-def gen_pool_copyback_ms_per_step(info):
-    return _gen_part_ms_per_step(info, "gen:pool_copyback")
 
 
 def gen_sched_ms_per_step(info):
     """What is left of the engine thread per step: the steps without the
-    pool's three trips (so: the dispatch, grow / feed / emit, the step's
+    pool's two trips (so: the dispatch, grow / feed / emit, the step's
     self time) and the admissions without their prefills."""
     tr, steps = _steps(info)
     if tr is None:
         return None
     pool = sum(total_s(inside(tr, steps, n)) for n in
-               ("gen:pool_h2d", "gen:pool_d2h", "gen:pool_copyback"))
+               ("gen:pool_h2d", "gen:pool_d2h"))
     admit = sum(self_s(tr, a) for a in named(tr, "gen:admit"))
     return 1e3 * (total_s(steps) - pool + admit) / len(steps)
 
@@ -377,10 +427,16 @@ def gen_queue_wait_p50_ms(info):
 
 
 def gen_device_ms_per_step(info):
-    tr, steps = _steps(info)
-    if tr is None:
-        return None
-    return 1e3 * busy_inside_s(tr, steps) / len(steps)
+    """The lane program's own time on the device: the mean duration of the
+    runs of ``jit_decode_b<lanes>`` on the ``XLA Modules`` line that start in
+    the window.  Not the busy time inside ``gen:step`` spans: one step is in
+    flight while the engine thread is in ``gen:admit``, outside every
+    ``gen:step``.  Beside ``gen_step_ms_p50`` it says whether the device or
+    the host sets the pace."""
+    tr = of_run(info)
+    runs = [b - a for spans in module_runs(tr, DECODE_MODULE).values()
+            for a, b in spans] if tr else []
+    return 1e3 * statistics.fmean(runs) if runs else None
 
 
 def _share_pct(info, match):

@@ -10,17 +10,25 @@ PjRt CPU client's thread lines of ``/host:CPU`` stand in for a device.
 Host spans (``jax.profiler.TraceAnnotation`` and the profiler's own Python
 events) are on the same clock and name the gaps.
 """
+import functools
 import glob
 import os
 import re
 
 OPCODE_RE = re.compile(r" ([a-z][a-z0-9_\-]*)\(")
+# a program span is ``layer:what`` or ``Class.method[:part]``; the runtime's
+# own events (``tpu::System::Execute=>Done``, ``PjitFunction(f)``) and the
+# host platform's operations (``dot_general.66``) are not
+NAME_RE = re.compile(r"^(?!.*\.\d+$)[A-Za-z_]\w*"
+                     r"(?:[.:](?!:)[\w\[\]%=\-]+)+$")
 
 
+@functools.lru_cache(maxsize=None)
 def short_name(text):
     """``name:opcode`` of a device event whose name is the operation's whole
     HLO text (``%fusion.3 = f32[..]{..} fusion(..), kind=..``); any other
-    name is kept as it is."""
+    name is kept as it is.  (Remembered: a trace holds millions of events of
+    a few thousand operations.)"""
     if not text.startswith("%") or " = " not in text:
         return text
     name, rest = text[1:].split(" = ", 1)
@@ -109,11 +117,15 @@ def _leaf_events(events):
 
 
 def _host_name(host, a, b):
-    """What the host was doing in [a, b]: of the spans that cover at least
-    half of it, the shortest (the most specific), ``bench:`` spans first.
-    Where no one span does, the name whose spans together cover most of it
-    (a quarter at least), marked ``mostly``; else ``unannotated``."""
-    best, key, total = None, None, {}
+    """What the host was doing in [a, b].  Of the spans that cover at least
+    half of it, the innermost (shortest) span of the program names it,
+    followed by the innermost event of the runtime inside that span where
+    there is one (``gen:prefill > ExecutePrepare``); where no span of the
+    program does, the innermost ``bench:`` span, the benchmark's own; where
+    none of those either, the shortest event of any kind.  Where no one span
+    covers half, the name whose spans together cover most of it (a quarter
+    at least), marked ``mostly``; else ``unannotated``."""
+    program, bench, other, total = [], [], [], {}
     for name, s, d in host:
         if s >= b:
             break
@@ -121,11 +133,16 @@ def _host_name(host, a, b):
         if ov <= 0 or name == "bench:window":
             continue
         total[name] = total.get(name, 0.0) + ov
-        k = (name.startswith("bench:"), -d)
-        if ov >= 0.5 * (b - a) and (key is None or k > key):
-            best, key = name, k
-    if best is not None:
-        return best
+        if ov >= 0.5 * (b - a):
+            kind = bench if name.startswith("bench:") else \
+                program if NAME_RE.match(name) else other
+            kind.append((d, s, name))
+    if program:
+        d, s, name = min(program)
+        inner = [e for e in other if s <= e[1] and e[1] + e[0] <= s + d]
+        return "%s > %s" % (name, min(inner)[2]) if inner else name
+    if bench or other:
+        return min(bench or other)[2]
     if total:
         name = max(total, key=total.get)
         # spans nest, so a name can sum to more than the gap; what counts

@@ -5,11 +5,10 @@ driver runs it.  The toy configuration, mix and limits live under
 BENCHMARK.json itself (the cell, its configuration and its metrics renamed),
 as ``test_add_by_files.py`` makes its own.
 
-The four readers of the state-space layers (``perfbench/harness/ssm.py``,
-``layer_metrics/ssm_*_g4h.py``) have no entry in BENCHMARK.json yet (PERF.md
-section 7 says why, and what a ``benchmark`` PR adds); until they do, the
-manifest made here lists them, so that the suite runs them.  The cell reports
-the accepted decode metrics, whose ``workloads`` it was appended to."""
+The cell reports the accepted decode metrics, whose ``workloads`` it was
+appended to, and the four of the state-space layers
+(``perfbench/harness/ssm.py``, ``layer_metrics/ssm_*_g4h.py``), which are its
+own: the manifest made here takes all of them from BENCHMARK.json."""
 import functools
 import json
 import os
@@ -20,16 +19,6 @@ from perfbench.harness import manifest as mf
 
 CELL, CONFIG = "g4hmicro-decode-closed16", "granite-4.0-h-micro"
 TOY_CELL, TOY_CONFIG = "toy-g4h-decode", "toy-g4h"
-ENGINE = "decode engine (generation/engine.py, kv_pool.py)"
-# (name, unit, source, layer): listed here where BENCHMARK.json does not
-SSM_METRICS = [
-    ("ssm_state_gb_per_step_g4h", "GB", "program_span", ENGINE),
-    ("ssm_step_share_pct_g4h", "%", "device_trace",
-     "state-space ops (ops/ssm.py)"),
-    ("ssm_scan_share_pct_g4h", "%", "device_trace",
-     "state-space ops (ops/ssm.py)"),
-    ("ssm_step_ms_per_step_g4h", "ms", "device_trace",
-     "state-space ops (ops/ssm.py)")]
 
 
 @functools.lru_cache(maxsize=None)
@@ -53,12 +42,6 @@ def _manifest(tmp):
                 metric["workloads"] = [TOY_CELL]
             kept.append(metric)
         m[section] = kept
-    listed = {metric["name"] for metric in m["per_layer"]}
-    m["per_layer"] += [
-        {"name": name, "unit": unit, "better": "lower", "source": source,
-         "layer": layer, "moves": "decode_tokens_per_s",
-         "workloads": [TOY_CELL]} for name, unit, source, layer in SSM_METRICS
-        if name not in listed]
     assert mf.validate(m) == []
     path = os.path.join(tmp, "BENCHMARK.json")
     with open(path, "w") as f:
@@ -80,8 +63,14 @@ def test_the_cell_and_its_files_are_in_the_manifest():
         "served_token_logit_gap", "cold_runs_in_window"}
     # the cell reports per-layer metrics of its end-to-end metric
     assert m.cell_metrics("per_layer", CELL, moves={"decode_tokens_per_s"})
-    for name, _, _, _ in SSM_METRICS:  # the readers are there, by name
-        assert callable(m.load_module("layer_metrics", name + ".py").read)
+    # and four of its own, whose readers are there by name
+    own = [x for x in m.data["per_layer"] if x.get("workloads") == [CELL]]
+    assert len(own) == 4
+    for x in own:
+        assert x["name"].startswith("ssm_") and x["name"].endswith("_g4h")
+        assert (x["better"], x["moves"]) == ("lower", "decode_tokens_per_s")
+        assert callable(m.load_module("layer_metrics",
+                                      x["name"] + ".py").read)
 
 
 def test_the_configuration_holds_every_published_key():

@@ -6,11 +6,10 @@ hybrid cell's: the two cells run one traffic); the manifest is made here from
 BENCHMARK.json itself (the cell, its configuration and its metrics renamed),
 as ``test_cell_g4h_cpu.py`` makes its own.
 
-The four readers of the expert layers (``perfbench/harness/moe.py``,
-``layer_metrics/*_lfm2.py``) have no entry in BENCHMARK.json yet (PERF.md
-section 7 says why, and what a ``benchmark`` PR adds); until they do, the
-manifest made here lists them, so that the suite runs them.  The cell reports
-the accepted decode metrics, whose ``workloads`` it was appended to."""
+The cell reports the accepted decode metrics, whose ``workloads`` it was
+appended to, and the four of the expert layers (``perfbench/harness/moe.py``,
+``layer_metrics/*_lfm2.py``), which are its own: the manifest made here takes
+all of them from BENCHMARK.json."""
 import functools
 import json
 import os
@@ -21,17 +20,6 @@ from perfbench.harness import manifest as mf
 
 CELL, CONFIG = "lfm2moe-decode-closed16", "lfm2-8b-a1b"
 TOY_CELL, TOY_CONFIG = "toy-lfm2-decode", "toy-lfm2"
-ENGINE = "decode engine (generation/engine.py, kv_pool.py)"
-EXPERTS = "expert ops (ops/moe.py)"
-# (name, unit, better, source, layer): listed here where BENCHMARK.json
-# does not
-MOE_METRICS = [
-    ("moe_experts_hit_per_step_lfm2", "count", "lower", "program_span",
-     ENGINE),
-    ("moe_experts_share_pct_lfm2", "%", "lower", "device_trace", EXPERTS),
-    ("moe_experts_ms_per_step_lfm2", "ms", "lower", "device_trace", EXPERTS),
-    ("decode_bytes_roofline_pct_lfm2", "%", "higher", "device_trace",
-     EXPERTS)]
 
 
 @functools.lru_cache(maxsize=None)
@@ -55,13 +43,6 @@ def _manifest(tmp):
                 metric["workloads"] = [TOY_CELL]
             kept.append(metric)
         m[section] = kept
-    listed = {metric["name"] for metric in m["per_layer"]}
-    m["per_layer"] += [
-        {"name": name, "unit": unit, "better": better, "source": source,
-         "layer": layer, "moves": "decode_tokens_per_s",
-         "workloads": [TOY_CELL]}
-        for name, unit, better, source, layer in MOE_METRICS
-        if name not in listed]
     assert mf.validate(m) == []
     path = os.path.join(tmp, "BENCHMARK.json")
     with open(path, "w") as f:
@@ -90,12 +71,24 @@ def test_the_cell_and_its_files_are_in_the_manifest():
     assert set(m.load_json("limits", CELL + ".json")["limits"]) == {
         "served_token_logit_gap", "cold_runs_in_window"}
     # the cell reports the 16 decode metrics the hybrid cell reports
-    assert m.cell_metrics("per_layer", CELL,
-                          moves={"decode_tokens_per_s"}) == \
-        m.cell_metrics("per_layer", "g4hmicro-decode-closed16",
-                       moves={"decode_tokens_per_s"})
-    for name, *_ in MOE_METRICS:  # the readers are there, by name
-        assert callable(m.load_module("layer_metrics", name + ".py").read)
+
+    def shared(cell):
+        return [n for n in m.cell_metrics("per_layer", cell,
+                                          moves={"decode_tokens_per_s"})
+                if m.per_layer[n]["workloads"] != [cell]]
+
+    assert len(shared(CELL)) == 16
+    assert shared(CELL) == shared("g4hmicro-decode-closed16")
+    # and four of its own, whose readers are there by name
+    own = [x for x in m.data["per_layer"] if x.get("workloads") == [CELL]]
+    assert len(own) == 4
+    for x in own:
+        assert x["name"].endswith("_lfm2")
+        # a share of a peak is the one that is better higher
+        assert x["better"] == ("higher" if "roofline" in x["name"]
+                               else "lower")
+        assert callable(m.load_module("layer_metrics",
+                                      x["name"] + ".py").read)
 
 
 def test_the_configuration_holds_every_published_key():

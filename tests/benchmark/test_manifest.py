@@ -118,6 +118,21 @@ def test_every_named_file_is_there(manifest_data):
                                       metric["name"] + ".py").read)
 
 
+def test_no_reader_without_an_entry_and_no_entry_without_a_reader(
+        manifest_data):
+    """A file under ``layer_metrics/`` that no entry names is never run by
+    the driver; an entry without its file fails every traced run."""
+    m = mf.Manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    files = {f[:-3] for f in os.listdir(os.path.join(ROOT, "perfbench",
+                                                     "layer_metrics"))
+             if f.endswith(".py")}
+    entries = [metric["name"] for metric in manifest_data["per_layer"]]
+    assert sorted(files - set(entries)) == [], "readers without an entry"
+    assert sorted(set(entries) - files) == [], "entries without a reader"
+    for name in entries:
+        assert callable(m.load_module("layer_metrics", name + ".py").read)
+
+
 def test_config_files_state_source_widths_and_cut(manifest_data):
     m = mf.Manifest(os.path.join(ROOT, "BENCHMARK.json"))
     cfg = m.config("cerebras-gpt-1.3b")

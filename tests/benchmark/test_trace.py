@@ -64,6 +64,34 @@ def test_gaps_are_named_by_the_most_specific_host_span():
     assert bare["idle_gaps"][0][0] == "unannotated"
 
 
+# a gap from 1.0 to 3.0 between two operations; what the host recorded
+@pytest.mark.parametrize("host,want", [
+    # training: the program's innermost span, not the benchmark's around it
+    ([("bench:step", 0.5, 3.0), ("Module.update", 0.9, 2.4),
+      ("Executor.fused_step", 1.1, 1.6), ("Executor.fused_step:pack", 1.1, 0.3)],
+     "Executor.fused_step"),
+    ([("bench:step", 0.5, 3.0), ("Module.update", 0.9, 2.4)],
+     "Module.update"),
+    # decode: the span, then the runtime's innermost event inside it
+    ([("serve:generate", 0.0, 9.0), ("gen:admit", 0.8, 2.6),
+      ("gen:prefill", 0.9, 2.4), ("PjitFunction(prefill_L512)", 1.0, 2.2),
+      ("CommonPjRtLoadedExecutable::ExecutePrepare", 1.2, 1.5)],
+     "gen:prefill > CommonPjRtLoadedExecutable::ExecutePrepare"),
+    # an event of another thread that the span does not hold is not its own
+    ([("gen:step", 1.0, 1.8), ("ReadSyncFlag", 0.5, 2.0)], "gen:step"),
+    # the benchmark's own span names a gap where no span of the program does
+    ([("bench:step", 0.5, 3.0), ("PjitFunction(f)", 1.0, 1.5)], "bench:step"),
+    # a program span that covers under half of it does not
+    ([("bench:step", 0.5, 3.0), ("Module.update", 2.5, 1.0)], "bench:step"),
+    # and the runtime's shortest event where neither is there
+    ([("PjitFunction(f)", 0.9, 2.4), ("ExecutePrepare", 1.2, 1.5)],
+     "ExecutePrepare")])
+def test_gaps_are_named_by_what_the_program_was_doing(host, want):
+    t = _trace({"/device:TPU:0": [("fusion", 0.0, 1.0), ("fusion", 3.0, 1.0)]},
+               host=[("bench:window", 0.0, 4.0)] + host)
+    assert trace.reduce(t)["idle_gaps"][0] == [want, pytest.approx(2.0)]
+
+
 def test_a_gap_of_many_short_spans_is_named_by_what_fills_most_of_it():
     host = [("H2D Dispatch", 1.0 + 0.1 * i, 0.06) for i in range(20)] \
         + [("Linearize", 1.0 + 0.1 * i + 0.06, 0.02) for i in range(20)]
