@@ -40,6 +40,15 @@ cache overlay and its whole warmup is deserialize-only.
 
 Enable with ``MXNET_COMPILE_CACHE_DIR=/path`` (empty default = off: the
 executor stack behaves exactly as before).
+
+The compile ledger (always on; it fires once a compile, never a step): a
+``jax.monitoring`` listener for the start of JAX's own timed events and one
+for their end, registered when this module is imported, turn them into rows
+of :func:`ledger`: which program was traced, lowered, compiled or loaded
+from JAX's persistent cache, for how long, and inside which of the
+program's spans (``profiler.Frame``): a ``start:program`` at start-up, a
+``gen:step`` or ``Module.update`` for a recompile in service.
+:func:`stats` counts that layer's hits and misses beside this module's own.
 """
 from __future__ import annotations
 
@@ -50,11 +59,17 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+# bound here, on the importing thread: the ledger's listener runs on
+# whatever thread compiles, and a relative import there would wait for a
+# package import still in progress (the kvstore server's bootstrap)
+from . import profiler as _prof
+from . import telemetry as _tm
 from .artifact_store import EntryStore, digest_of
 from .base import MXNetError, env, register_env
 
 __all__ = [
     "enabled", "cache_dir", "env_fingerprint", "stats", "reset_stats",
+    "ledger",
     "maybe_cached", "CachedFunction", "attach_bundle", "detach_bundles",
     "save_bundle", "read_manifest", "ls_entries", "verify_entry", "prune",
     "entry_meta", "MANIFEST_NAME", "ENTRY_SUFFIX",
@@ -130,9 +145,7 @@ _instruments = None
 def _metrics():
     global _instruments
     if _instruments is None:
-        from . import telemetry as tm
-
-        reg = tm.registry()
+        reg = _tm.registry()
         _instruments = {
             "hits": reg.counter(
                 "mxtpu_compile_cache_hits_total",
@@ -156,21 +169,35 @@ def _metrics():
                 "mxtpu_compile_cache_deserialize_ms",
                 "Executable deserialize time per cache hit (ms).",
                 start=0.25, factor=4.0, count=12),
+            # JAX's own layer, from the compile ledger's listener
+            "jit_hits": reg.counter(
+                "mxtpu_jit_cache_hits_total",
+                "Programs loaded from JAX's persistent compilation cache."),
+            "jit_misses": reg.counter(
+                "mxtpu_jit_cache_misses_total",
+                "Programs the backend compiled (no persistent-cache entry, "
+                "or no persistent cache)."),
         }
+        for phase in LEDGER_PHASES:
+            _instruments["jit_" + phase] = reg.counter(
+                "mxtpu_jit_%s_seconds_total" % phase,
+                "Seconds JAX spent in phase %r of building programs (own "
+                "time: nested events taken out)." % phase)
     return _instruments
 
 
 def _log_event(kind, **fields):
     try:
-        from . import telemetry as tm
-
-        tm.log_event(kind, **fields)
+        _tm.log_event(kind, **fields)
     except Exception:
         pass
 
 
 def stats() -> dict:
-    """Compact counters for BENCH / capture records."""
+    """Compact counters for BENCH / capture records: this module's
+    executable cache (the ``.mxc`` layer) at the top level, JAX's
+    persistent compilation cache under ``"jax"`` (hits: programs loaded
+    from it; misses: programs the backend compiled; seconds by phase)."""
     m = _metrics()
     return {
         "dir": cache_dir() or None,
@@ -180,17 +207,138 @@ def stats() -> dict:
         "errors": m["errors"].value,
         "compile_ms": round(m["compile_ms"].sum, 1),
         "deserialize_ms": round(m["deserialize_ms"].sum, 1),
+        "jax": dict(
+            {"hits": m["jit_hits"].value, "misses": m["jit_misses"].value},
+            **{phase + "_s": round(m["jit_" + phase].value, 3)
+               for phase in LEDGER_PHASES}),
     }
 
 
 def reset_stats() -> None:
     """Test hook: drop instrument handles (a telemetry registry reset
-    leaves stale handles otherwise) and the in-memory executable cache."""
+    leaves stale handles otherwise), the in-memory executable cache and
+    the compile ledger."""
     global _instruments
     with _lock:
         _instruments = None
         _mem.clear()
         del _bundles[:]
+        _ledger_rows.clear()
+        _ledger_programs.clear()
+
+
+# ---------------------------------------------------------------------------
+# the compile ledger: JAX's own compile events as rows
+# ---------------------------------------------------------------------------
+
+LEDGER_PHASES = ("trace", "lower", "compile", "load")
+LEDGER_PROGRAMS = 256
+# the spans a compile is charged to: a program's first call at start-up, a
+# step or a prefill in service (innermost first where they nest)
+PROGRAM_SPANS = frozenset(("start:program", "gen:step", "gen:prefill",
+                           "Module.forward_backward", "Module.update"))
+_EVENT_PHASE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile"}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# (program, phase, span, span_id) -> [events, seconds]
+_ledger_rows: Dict[tuple, list] = {}
+_ledger_programs = set()
+_ledger_local = threading.local()
+
+
+def _on_compile_begin(event, _start, fun_name=None, **_):
+    """The ``jax.monitoring`` scalar listener: JAX reports the start of
+    each of its timed events.  Events nest (a function jitted inside
+    another is traced inside the outer's trace, a constant is compiled
+    while tracing): the open ones of a thread are a stack."""
+    if event in _EVENT_PHASE:
+        stack = getattr(_ledger_local, "stack", None)
+        if stack is None:
+            stack = _ledger_local.stack = []
+        stack.append([event, _program_name(fun_name), 0.0])
+
+
+def _program_name(fun_name):
+    name = str(fun_name or "?")
+    return name[4:-1] if name.startswith("jit(") and name.endswith(")") \
+        else name
+
+
+def _on_compile_event(event, secs, fun_name=None, **_):
+    """The ``jax.monitoring`` duration listener: an event that ended, as a
+    row.  A backend-compile event inside which the persistent cache's
+    retrieval event fired (same thread) is a ``load``.  A row holds the
+    event's OWN seconds (the events inside it taken out) under the name of
+    the outermost open event, so the rows under a span add up to no more
+    than the span and a program's rows carry its name, not its parts'."""
+    local = _ledger_local
+    if event == _RETRIEVAL_EVENT:
+        local.loaded = True
+        return
+    phase = _EVENT_PHASE.get(event)
+    if phase is None:
+        return
+    if phase == "compile" and getattr(local, "loaded", False):
+        phase, local.loaded = "load", False
+    stack = getattr(local, "stack", None) or []
+    program, own = _program_name(fun_name), secs
+    if stack and stack[-1][0] == event:
+        own = max(secs - stack.pop()[2], 0.0)
+        if stack:
+            stack[-1][2] += secs
+            program = stack[0][1]
+    else:  # a start went unheard: start afresh
+        del stack[:]
+    span = next((f for f in reversed(_prof.open_frames())
+                 if f.name in PROGRAM_SPANS), None)
+    if span is not None and span.name == "start:program":
+        # whatever its first call builds on the way (a constant computed
+        # while tracing) is that program's to pay
+        program = span.args["program"]
+    with _lock:
+        if program not in _ledger_programs:
+            if len(_ledger_programs) < LEDGER_PROGRAMS:
+                _ledger_programs.add(program)
+            else:
+                program = "other"
+        row = _ledger_rows.setdefault(
+            (program, phase, span.name if span else None,
+             span._rec["id"] if span and span._rec else None), [0, 0.0])
+        row[0] += 1
+        row[1] += own
+    m = _metrics()
+    m["jit_" + phase].inc(own)
+    if phase in ("compile", "load"):
+        m["jit_hits" if phase == "load" else "jit_misses"].inc()
+
+
+def ledger() -> List[dict]:
+    """The compile ledger's rows, in order of their first event:
+    ``program`` (under a ``start:program`` that span's program; elsewhere
+    the name of the outermost jitted function being built, without JAX's
+    ``jit(...)``; ``other`` for what came after ``LEDGER_PROGRAMS``
+    names), ``phase`` (``trace``, ``lower``,
+    ``compile`` for a backend compile that ran, ``load`` for a retrieval
+    from JAX's persistent cache), ``span`` (the innermost open program
+    span of the compiling thread, or None for a jit that is not the
+    program's), ``span_id`` (the start-up record's id where the span is a
+    ``start:program``), ``events`` and ``seconds`` (own time)."""
+    with _lock:
+        return [{"program": k[0], "phase": k[1], "span": k[2],
+                 "span_id": k[3], "events": v[0], "seconds": v[1]}
+                for k, v in _ledger_rows.items()]
+
+
+def _register_listener():
+    import jax
+
+    jax.monitoring.register_scalar_listener(_on_compile_begin)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+_register_listener()
 
 
 # ---------------------------------------------------------------------------

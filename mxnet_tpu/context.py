@@ -19,6 +19,7 @@ from __future__ import annotations
 import threading
 from typing import List, Optional
 
+from . import profiler as _prof
 from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
@@ -70,17 +71,15 @@ class Context:
         the ``device_id``-th accelerator or raise — see the module
         docstring for the one exception (platform forced to the host).
         """
-        import jax
-
         if self.device_type in ("cpu", "cpu_pinned"):
-            devs = jax.local_devices(backend="cpu")
+            devs = _local_devices(backend="cpu")
             return devs[self.device_id % len(devs)]
         devs = _accelerators()
         if not devs:
             raise MXNetError(
                 "%s: no accelerator is attached (jax.local_devices() = %s). "
                 "Use mx.cpu(), or set JAX_PLATFORMS=cpu to let host devices "
-                "stand in for chips." % (self, jax.local_devices()))
+                "stand in for chips." % (self, _local_devices()))
         if not 0 <= self.device_id < len(devs):
             raise MXNetError(
                 "%s: device_id out of range, %d %s device(s) attached"
@@ -109,9 +108,7 @@ class Context:
         scoped = getattr(cls._default_ctx, "value", None)
         if scoped is not None:
             return scoped
-        import jax
-
-        if any(d.platform != "cpu" for d in jax.local_devices()):
+        if any(d.platform != "cpu" for d in _local_devices()):
             return Context("tpu", 0)
         return Context("cpu", 0)
 
@@ -143,10 +140,27 @@ def num_tpus() -> int:
     return len(_accelerators())
 
 
-def _accelerators() -> List:
+_backend_met = False
+
+
+def _local_devices(backend=None) -> List:
+    """``jax.local_devices``; this module's first contact with the
+    devices (where nobody reached them before, the backend's start) is the
+    ``start:backend`` span of the start-up record."""
+    global _backend_met
     import jax
 
-    devs = jax.local_devices()
+    if _backend_met:
+        return jax.local_devices(backend=backend)
+    _backend_met = True
+    with _prof.Frame("start:backend", "startup") as span:
+        devs = jax.local_devices(backend=backend)
+        span.set(platform=devs[0].platform, devices=len(devs))
+    return devs
+
+
+def _accelerators() -> List:
+    devs = _local_devices()
     chips = [d for d in devs if d.platform != "cpu"]
     if chips:
         return chips

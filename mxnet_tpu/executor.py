@@ -20,6 +20,7 @@ Semantics kept from the reference:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -291,6 +292,13 @@ class Executor:
 
         return bind(fn, self._ctx.jax_device().platform, *self._kernel_mesh)
 
+    def _first_call(self, key, program, kind):
+        """``program`` as it first goes into ``_jit_cache[key]``: its first
+        call is a ``start:program`` span and puts the program itself
+        there (profiler.first_call)."""
+        return _prof.first_call(
+            program, kind, functools.partial(self._jit_cache.__setitem__, key))
+
     def set_carried(self, carried: Dict[str, int]):
         """Declare arguments the forward carries: ``{argument name: index
         of the output that is its next value}`` (a KV pool plane and the
@@ -346,8 +354,8 @@ class Executor:
                     self.carry_donated = bool(donate)
                     jit_kw = {"donate_argnums": donate}
                     static_key = key + (("donate", donate),)
-                self._jit_cache[key] = _cc.maybe_cached(
-                    jax.jit(fn, **jit_kw), kind, static_key, self)
+                self._jit_cache[key] = self._first_call(key, _cc.maybe_cached(
+                    jax.jit(fn, **jit_kw), kind, static_key, self), kind)
         return self._jit_cache[key]
 
     def _get_fwd_bwd(self, is_train: bool, diff_names: tuple, add_names: tuple):
@@ -388,8 +396,8 @@ class Executor:
             else:
                 from . import compile_cache as _cc
 
-                self._jit_cache[key] = _cc.maybe_cached(
-                    jax.jit(fn), "fwdbwd", key, self)
+                self._jit_cache[key] = self._first_call(key, _cc.maybe_cached(
+                    jax.jit(fn), "fwdbwd", key, self), "fwdbwd")
         return self._jit_cache[key]
 
     # ------------------------------------------------------------------
@@ -632,8 +640,8 @@ class Executor:
                 if stable_key is not None:
                     stable_key = stable_key + (("donate", tuple(donate)),
                                                ("remat", int(remat)))
-                self._jit_cache[key] = _cc.maybe_cached(
-                    jfn, "fused", stable_key, self)
+                self._jit_cache[key] = self._first_call(key, _cc.maybe_cached(
+                    jfn, "fused", stable_key, self), "fused")
         return self._jit_cache[key]
 
     def fused_step(self, optimizer, updater, param_names):

@@ -89,6 +89,7 @@ continues seamlessly), which bounds memory without ever deadlocking.
 """
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import queue
@@ -101,7 +102,8 @@ import numpy as np
 
 from .. import faults
 from .. import telemetry as _telemetry
-from ..profiler import Frame as _span
+from ..profiler import Frame as _span, first_call as _first_call, \
+    framed as _framed, leaves_bytes as _leaves_bytes
 from ..base import MXNetError, env, register_env
 from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
@@ -452,6 +454,7 @@ class DecodeEngine:
         :meth:`spec` so bundles/replicas rebuild without re-tuning.
     """
 
+    @_framed("start:engine")
     def __init__(self, params, vocab_size=None, num_layers=4, num_heads=8,
                  hidden=512, max_seq_len=128,
                  lane_buckets: Optional[Sequence[int]] = None,
@@ -573,7 +576,9 @@ class DecodeEngine:
         # one shared copy of the weights ON the engine's device: Predictor
         # passes live NDArrays of its own context through, so every
         # bucket executor binds the same arrays
-        self._params = {k: on_ctx(v, ctx) for k, v in params.items()}
+        with _span("start:params", "startup") as span:
+            self._params = {k: on_ctx(v, ctx) for k, v in params.items()}
+            span.set(**_leaves_bytes(self._params.values()))
 
         # one manager for every plane the lane programs carry: K/V pages,
         # and a slot a lane (+ scratch) of each state plane
@@ -705,16 +710,9 @@ class DecodeEngine:
                 family, self._params, self.pool, "gen-catchup",
                 "catchup_b%d", width=self._catchup_width)
 
-        import jax
-        import jax.numpy as jnp
-
-        def prefill_rows(logits, rows):
-            return jnp.argmax(jnp.take_along_axis(
-                logits, rows[:, None, None], axis=1)[:, 0], axis=-1)
-
         # (batch, L, vocab) logits -> the greedy id of each prompt's last
-        # row, on the device
-        self._prefill_rows = jax.jit(prefill_rows)
+        # row, on the device: one program a shape (``_first_ids``)
+        self._prefill_rows: Dict[tuple, object] = {}
 
         # recompile-detector bookkeeping: lane buckets warmup compiled,
         # post-warmup steps that hit a novel (never-warmed) bucket
@@ -739,6 +737,23 @@ class DecodeEngine:
             self.start()
 
     # -- construction helpers ---------------------------------------------
+    def _first_ids(self, logits, rows):
+        """The greedy id of row ``rows[b]`` of each prompt's logits, picked
+        on the device."""
+        program = self._prefill_rows.get(logits.shape)
+        if program is None:
+            import jax
+            import jax.numpy as jnp
+
+            def prefill_rows(logits, rows):
+                return jnp.argmax(jnp.take_along_axis(
+                    logits, rows[:, None, None], axis=1)[:, 0], axis=-1)
+
+            program = self._prefill_rows[logits.shape] = _first_call(
+                jax.jit(prefill_rows), "gen-prefill", functools.partial(
+                    self._prefill_rows.__setitem__, logits.shape))
+        return program(logits, rows)
+
     def spec(self) -> Dict:
         """Model/engine geometry needed to rebuild this engine against a
         new checkpoint (hot-swap, AOT warmup manifests, shadow replicas).
@@ -782,7 +797,7 @@ class DecodeEngine:
                                  np.zeros((b,) + bp.item_shapes["data"],
                                           np.int32))
                 if pool is self.pool:
-                    np.asarray(self._prefill_rows(
+                    np.asarray(self._first_ids(
                         outs[0]._data, np.zeros((b,), np.int32)))
         if self.prefix_cache_pages:
             self.pool.copy_page(0, 0)  # the copy-on-write split's program
@@ -992,6 +1007,9 @@ class DecodeEngine:
                 "prefill": of(prefill), "decode": of(decode)}
 
     def snapshot(self) -> dict:
+        # what this process's start was spent on (spans by name, the
+        # compile ledger by program): read before the lock is taken
+        startup = _telemetry.startup_report()
         with self._cv:
             snap = {"pending": len(self._pending),
                     "active": len(self._active),
@@ -1014,7 +1032,7 @@ class DecodeEngine:
                         self._device.platform, self.num_heads, self.head_dim,
                         self.pool.k_pools[0].dtype,
                         kv_heads=self.family.kv_heads),
-                    "kv": self.pool.snapshot()}
+                    "kv": self.pool.snapshot(), "startup": startup}
             if self.pool.num_slots:
                 snap["state_slots"] = snap["kv"]["state_slots"]
             if self._ssm_step:
@@ -1211,7 +1229,7 @@ class DecodeEngine:
             rows = np.zeros((logits.shape[0],), np.int32)
             rows[:len(misses)] = [len(s.tokens) - 1 for s in misses]
             # the one read of a prefill: each prompt's first token
-            first = np.asarray(self._prefill_rows(logits, rows))
+            first = np.asarray(self._first_ids(logits, rows))
             for i, seq in enumerate(misses):
                 n = len(seq.tokens)
                 seq.stream.prefill_tokens += n

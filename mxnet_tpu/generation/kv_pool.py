@@ -63,6 +63,7 @@ failed stream).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 from collections import OrderedDict, namedtuple
@@ -71,6 +72,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import faults
+from .. import profiler as _prof
 from .. import telemetry as _telemetry
 from ..base import MXNetError
 
@@ -185,10 +187,14 @@ class PagedKVPool:
             raise ValueError("need >= 2 slots (slot 0 is reserved scratch)")
         self.num_slots = int(num_slots) if has_slots else 0
         ctx = ctx or current_context()
-        self._planes = [
-            nd.zeros(((self.num_pages, self.page_size) if s.kind == "paged"
-                      else (self.num_slots,)) + s.shape, ctx, dtype=s.dtype)
-            for s in self.specs]
+        with _prof.Frame("start:pool", "startup") as span:
+            self._planes = [
+                nd.zeros(((self.num_pages, self.page_size)
+                          if s.kind == "paged" else (self.num_slots,))
+                         + s.shape, ctx, dtype=s.dtype)
+                for s in self.specs]
+            span.set(bytes=self.device_bytes(), pages=self.num_pages,
+                     slots=self.num_slots)
         self._paged = [p for p, s in zip(self._planes, self.specs)
                        if s.kind == "paged"]
         self.k_pools, self.v_pools = self._paged[0::2], self._paged[1::2]
@@ -199,8 +205,10 @@ class PagedKVPool:
         self._free_slots: List[int] = list(range(self.num_slots - 1, 0, -1))
         self._slots: Dict[object, int] = {}
         self.peak_slots = 0
-        self._writers: Dict[int, object] = {}  # prefill length -> program
-        self._copy_page = _copy_page_program()
+        self._writers: Dict[tuple, object] = {}  # slots' shape -> program
+        self._copy_page = _prof.first_call(
+            _copy_page_program(), "pool",
+            functools.partial(setattr, self, "_copy_page"))
         self._lock = threading.Lock()
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
         self._tables: Dict[object, List[int]] = {}
@@ -639,11 +647,12 @@ class PagedKVPool:
         offset``).  A slot plane's slab is ``(batch,) + shape``: row ``b``
         goes to slot ``state_slots[b]`` (default: scratch).  One program a
         length bucket (``jit_pool_write_L<length>``)."""
-        length = int(slots.shape[1])
-        program = self._writers.get(length)
+        shape = tuple(slots.shape)
+        program = self._writers.get(shape)
         if program is None:
-            program = self._writers[length] = _write_program(
-                length, [s.kind for s in self.specs])
+            program = self._writers[shape] = _prof.first_call(
+                _write_program(shape[1], [s.kind for s in self.specs]),
+                "pool", functools.partial(self._writers.__setitem__, shape))
         more = ()
         if self.num_slots:
             more = (np.zeros(slots.shape[:1], np.int32)

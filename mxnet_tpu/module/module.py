@@ -29,6 +29,22 @@ from .executor_group import DataParallelExecutorGroup
 __all__ = ["Module"]
 
 
+def _leaves_bytes(*param_dicts):
+    """A ``start:params`` span's args for parameter dicts."""
+    return _prof.leaves_bytes(v for d in param_dicts
+                              for v in (d or {}).values())
+
+
+def _state_leaves(state):
+    """The arrays of one optimizer state (an NDArray, a tuple of them, or
+    None)."""
+    if state is None:
+        return []
+    if isinstance(state, (tuple, list)):
+        return [leaf for s in state for leaf in _state_leaves(s)]
+    return [state]
+
+
 class Module(BaseModule):
     def __init__(self, symbol, data_names=("data",),
                  label_names=("softmax_label",), logger=logging,
@@ -163,6 +179,13 @@ class Module(BaseModule):
                 "init_params call ignored.")
             return
         assert self.binded, "call bind before initializing the parameters"
+        with _prof.Frame("start:params", "startup") as span:
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing)
+            span.set(**_leaves_bytes(self._arg_params, self._aux_params))
+
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing):
         if initializer is None and not (arg_params and aux_params):
             initializer = Uniform(0.01)
 
@@ -216,7 +239,9 @@ class Module(BaseModule):
                 "Parameters already initialized and force_init=False. "
                 "set_params call ignored.")
             return
-        self._exec_group.set_params(arg_params, aux_params)
+        with _prof.Frame("start:params", "startup",
+                         _leaves_bytes(arg_params, aux_params)):
+            self._exec_group.set_params(arg_params, aux_params)
         self._params_dirty = True
         self.params_initialized = True
 
@@ -246,7 +271,16 @@ class Module(BaseModule):
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
         assert not (for_training is False and inputs_need_grad)
+        with _prof.Frame("start:bind", "startup", {
+                "kind": "train" if for_training else "predict"}) as span:
+            self._bind(data_shapes, label_shapes, shared_module, grad_req,
+                       mesh, partition_rules)
+            span.set(bucket=self._exec_group.batch_size)
 
+    def _bind(self, data_shapes, label_shapes, shared_module, grad_req, mesh,
+              partition_rules):
+        for_training = self.for_training
+        inputs_need_grad = self.inputs_need_grad
         self._data_shapes = self._exec_group_descs(data_shapes)
         self._label_shapes = self._exec_group_descs(label_shapes) \
             if label_shapes else None
@@ -313,7 +347,17 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _prof.Frame("start:optimizer", "startup") as span:
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+            # the fused step makes its states at its first call: what is
+            # here now is what a checkpoint or a kvstore brought
+            held = _prof.leaves_bytes(
+                leaf for state in (self._updater.states.values()
+                                   if self._updater is not None else ())
+                for leaf in _state_leaves(state))
+            span.set(states=held["leaves"], bytes=held["bytes"])
 
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         (kvstore, update_on_kvstore) = _create_kvstore(
             kvstore, len(self._context), self._arg_params)
         if self._exec_group._multiprocess:

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from . import profiler as _prof
 from .base import MXNetError
 from .context import Context, current_context
 
@@ -85,16 +86,38 @@ class Predictor:
         self._symbol = symbol
         self._input_shapes = {k: tuple(v) for k, v in input_shapes.items()}
         self._dtype = np.dtype(dtype)
+        # one predictor a bucket: ``bucket`` is its inputs' leading size
+        with _prof.Frame("start:bind", "startup", {
+                "kind": "predict", "bucket": next(
+                    (s[0] for s in self._input_shapes.values() if s), 0)}):
+            self._bind(symbol, arg_params, aux_params)
 
+    def _bind(self, symbol, arg_params, aux_params):
         arg_shapes, _, aux_shapes = symbol.infer_shape(**self._input_shapes)
         if arg_shapes is None:
             raise MXNetError("cannot infer shapes from the given inputs")
         arg_names = symbol.list_arguments()
         aux_names = symbol.list_auxiliary_states()
 
+        # the checkpoint onto the context (nothing moves for what another
+        # owner, the engine or the base bucket, already placed there)
+        with _prof.Frame("start:params", "startup") as span:
+            args, aux = self._place(arg_params, aux_params,
+                                    zip(arg_names, arg_shapes), aux_names)
+            span.set(**_prof.leaves_bytes(
+                list(args.values()) + list(aux.values())))
+        self._bound_inputs = set(self._input_shapes) & set(arg_params)
+        self._exec = symbol.bind(self._ctx, args, args_grad=None,
+                                 grad_req="null", aux_states=aux)
+        self._input_names = list(self._input_shapes)
+
+    def _place(self, arg_params, aux_params, arg_shapes, aux_names):
+        """The bound arrays on the context: (args, aux)."""
+        from . import ndarray as nd
+
         args = {}
         self._synthesized = set()
-        for name, shape in zip(arg_names, arg_shapes):
+        for name, shape in arg_shapes:
             if name in self._input_shapes and name not in arg_params:
                 args[name] = nd.zeros(shape, self._ctx, dtype=self._dtype)
             elif name in arg_params:
@@ -112,15 +135,11 @@ class Predictor:
                 args[name] = nd.zeros(shape, self._ctx, dtype=self._dtype)
                 self._synthesized.add(name)
         aux = {}
-        for name, shape in zip(aux_names, aux_shapes):
+        for name in aux_names:
             if name not in aux_params:
                 raise MXNetError("missing auxiliary state %r" % name)
             aux[name] = on_ctx(aux_params[name], self._ctx)
-
-        self._bound_inputs = set(self._input_shapes) & set(arg_params)
-        self._exec = symbol.bind(self._ctx, args, args_grad=None,
-                                 grad_req="null", aux_states=aux)
-        self._input_names = list(self._input_shapes)
+        return args, aux
 
     @classmethod
     def from_checkpoint(cls, prefix, epoch, input_shapes, ctx=None,

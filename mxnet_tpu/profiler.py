@@ -10,18 +10,21 @@ chrome://tracing.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import time
 import threading
 from typing import List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .base import env, register_env
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "pause", "resume", "Frame", "trace_tid"]
+           "pause", "resume", "Frame", "trace_tid", "framed", "first_call",
+           "startup", "open_frames", "leaves_bytes"]
 
 _state = {"mode": "symbolic", "filename": "profile.json", "running": False,
           "events": [], "tnames": {}, "jax_trace_dir": None,
@@ -47,6 +50,65 @@ def trace_tid() -> int:
             _tid_next[0] += 1
         _tid_local.tid = tid
     return tid
+
+# The start-up record: every span named ``start:*`` (construction and
+# warm-up sites, never a step) stamps its two ends here whether or not
+# anything listens, because a process's first minute is over before an
+# operator can start a profiler session.  Bounded: a process that builds
+# servers all its life (hot swaps) keeps the first STARTUP_SPANS.
+STARTUP_SPANS = 512
+_startup: List[dict] = []
+_startup_dropped = [0]
+# the open Frames of each thread, innermost last: a start-up span's parent,
+# and the span a compile is charged to (compile_cache's ledger)
+_open_local = threading.local()
+
+
+def open_frames() -> List["Frame"]:
+    """This thread's open Frames, outermost first (the live list)."""
+    frames = getattr(_open_local, "frames", None)
+    if frames is None:
+        frames = _open_local.frames = []
+    return frames
+
+
+def _startup_open(name, args, start=None):
+    """A new record of the start-up ledger (None once it is full); its
+    ``end`` is filled in when the span closes."""
+    parent = next((f._rec["id"] for f in reversed(open_frames())
+                   if f._rec is not None), None)
+    with _state["lock"]:
+        if len(_startup) >= STARTUP_SPANS:
+            _startup_dropped[0] += 1
+            return None
+        rec = {"id": len(_startup), "name": name,
+               "start": time.perf_counter() if start is None else start,
+               "end": None, "thread": threading.current_thread().name,
+               "parent": parent, "args": dict(args or {})}
+        _startup.append(rec)
+    return rec
+
+
+def stamp(name, start, args=None):
+    """Record a start-up span that began at ``start`` (``time.
+    perf_counter()``) and ends now: for what runs before this module can be
+    imported (``start:import``)."""
+    rec = _startup_open(name, args, start)
+    if rec is not None:
+        rec["end"] = time.perf_counter()
+
+
+def startup() -> dict:
+    """The start-up record: ``spans`` (closed ``start:*`` spans in order of
+    entry: ``id``, ``name``, ``start`` and ``end`` in seconds of
+    ``time.perf_counter()``, ``thread``, ``parent`` (the ``id`` of the
+    enclosing start-up span of its thread, or None), ``args``) and
+    ``dropped`` (spans that came after the record was full)."""
+    with _state["lock"]:
+        return {"spans": [dict(r, args=dict(r["args"])) for r in _startup
+                          if r["end"] is not None],
+                "dropped": _startup_dropped[0]}
+
 
 # external span sink installed by mxnet_tpu.telemetry.tracer: when set,
 # Frame/record_event deliver each event (plus the recording thread's name)
@@ -128,12 +190,17 @@ class Frame:
     read no clock and take no lock: what is left is the annotation's own
     check that no session is active.
 
+    A span named ``start:*`` (construction and warm-up, never a step) is
+    all of that and is ALSO always stamped into the bounded start-up record
+    (:func:`startup`): two clock reads a span, a few hundred in a
+    process's life.
+
     ``args`` is a flat dict of str/int/float.  A value never holds ``,`` or
     ``#``: the trace format cuts a stat there.  The profiler session gets
     the args as they are on entry; the Chrome trace reads them on exit, so
     a caller may attach fields while the span is open."""
 
-    __slots__ = ("name", "category", "args", "_t0", "_ann")
+    __slots__ = ("name", "category", "args", "_t0", "_ann", "_rec", "_open")
 
     def __init__(self, name, category="python", args=None):
         self.name = name
@@ -149,12 +216,22 @@ class Frame:
     def __enter__(self):
         self._t0 = time.perf_counter_ns() // 1000 \
             if _state["running"] or _sink is not None else None
+        self._rec = _startup_open(self.name, self.args) \
+            if self.name.startswith("start:") else None
+        # the list is kept: a span closed on another thread than it was
+        # opened on (a generator that moved) leaves the list it is on
+        self._open = open_frames()
+        self._open.append(self)
         self._ann = _TraceAnnotation(self.name, **(self.args or {}))
         self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._ann.__exit__(*exc)
+        self._open.remove(self)
+        if self._rec is not None:
+            self._rec["args"] = dict(self.args or {})
+            self._rec["end"] = time.perf_counter()
         if self._t0 is None:
             return
         sink = _sink
@@ -174,6 +251,58 @@ class Frame:
                     _state["tnames"][tid] = tname
             if sink is not None:
                 sink(ev, tname)
+
+
+def leaves_bytes(arrays):
+    """``leaves`` and ``bytes`` of some arrays (NDArray, numpy or jax):
+    the args of a ``start:params`` span."""
+    arrays = list(arrays)
+    return {"leaves": len(arrays),
+            "bytes": int(sum(a.size * a.dtype.itemsize for a in arrays))}
+
+
+def framed(name, category="startup"):
+    """Decorator: the whole call is one :class:`Frame` (a constructor as
+    the root its parts nest in)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with Frame(name, category):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+class first_call:
+    """A freshly built program in its owner's slot until it has run once.
+
+    The FIRST call is a ``start:program`` span (args ``program``, the
+    jitted function's name, and ``kind``) from the call through the end of
+    the program's first execution: tracing, lowering, the compile or the
+    load from the persistent cache, the dispatch and the run.  ``settle``
+    puts the program itself into the slot before that call, so after it
+    nothing of this wrapper is left on the call path.  Attributes (``lower``,
+    ``__name__``, a cached function's ``records``) are the program's."""
+
+    __slots__ = ("_fn", "_kind", "_settle")
+
+    def __init__(self, fn, kind, settle):
+        self._fn, self._kind, self._settle = fn, kind, settle
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+    def __call__(self, *args):
+        fn, settle = self._fn, self._settle
+        if settle is None:  # a caller that kept the wrapper calls again
+            return fn(*args)
+        self._settle = None
+        settle(fn)
+        name = getattr(fn, "__name__", None) or getattr(
+            getattr(fn, "_fn", None), "__name__", self._kind)
+        with Frame("start:program", "startup",
+                   {"program": name, "kind": self._kind}):
+            return jax.block_until_ready(fn(*args))
 
 
 def record_event(name, t0_us, dur_us, category="op"):

@@ -99,6 +99,7 @@ class InferenceServer:
         Pre-compile every bucket before accepting traffic (default True).
     """
 
+    @profiler.framed("start:server")
     def __init__(self, symbol, params, input_shapes: Dict[str, Sequence[int]],
                  ctx=None, buckets: Optional[Sequence[int]] = None,
                  max_wait_us: Optional[int] = None,

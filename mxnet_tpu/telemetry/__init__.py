@@ -26,10 +26,12 @@ Activate with ``MXNET_TELEMETRY=1`` in the environment or
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import weakref
 from typing import Optional
 
+from .. import profiler as _prof
 from ..base import env, register_env
 from . import distributed, flight_recorder, tracer
 from .distributed import (FleetAggregator, proc_identity, proc_label,
@@ -45,7 +47,7 @@ __all__ = [
     "histogram", "labeled_counter", "log_event", "events", "events_of",
     "event_log",
     "span", "dump_trace", "merged_trace", "validate_trace",
-    "render_prometheus", "register_collector", "summary",
+    "render_prometheus", "register_collector", "summary", "startup_report",
     "current_step_monitor", "Registry", "Counter", "Gauge", "Histogram",
     "LabeledCounter", "EventLog", "StepMonitor", "RecompileWarning",
     "peak_flops", "fused_cost_analysis", "lower_and_analyze",
@@ -227,8 +229,6 @@ def disable() -> None:
 
 def _reset_for_tests() -> None:
     """Drop all global state (registry contents, collectors, monitors)."""
-    import sys
-
     global _registry, _event_log, _current_monitor
     disable()
     with _lock:
@@ -287,10 +287,50 @@ def render_prometheus() -> str:
                    for p in parts if p)
 
 
+def startup_report() -> dict:
+    """What this process's start was spent on, for an operator: the
+    start-up record (``profiler.startup()``) by span name, the wall time
+    the spans cover together, and each program's first call beside the
+    compile ledger's rows for it (``compile_cache.ledger()``)."""
+    # no import statement: a thread may ask while the package is still
+    # being imported (the kvstore server's bootstrap), and compile_cache
+    # imports this module
+    compile_cache = sys.modules["mxnet_tpu.compile_cache"]
+    record = _prof.startup()
+    spans = {}
+    for s in record["spans"]:
+        agg = spans.setdefault(s["name"], {"count": 0, "seconds": 0.0})
+        agg["count"] += 1
+        agg["seconds"] = round(agg["seconds"] + s["end"] - s["start"], 4)
+    wall, edge = 0.0, float("-inf")
+    for start, end in sorted((s["start"], s["end"])
+                             for s in record["spans"]):
+        wall += max(0.0, end - max(start, edge))
+        edge = max(edge, end)
+    programs = {s["id"]: dict(s["args"], seconds=round(
+        s["end"] - s["start"], 4)) for s in record["spans"]
+        if s["name"] == "start:program"}
+    in_service = []
+    for row in compile_cache.ledger():
+        first = programs.get(row["span_id"])
+        if first is not None:
+            first[row["phase"] + "_s"] = round(
+                first.get(row["phase"] + "_s", 0.0) + row["seconds"], 4)
+        elif row["span"] is not None:
+            in_service.append(dict(row, seconds=round(row["seconds"], 4)))
+    return {"spans": spans, "wall_s": round(wall, 4),
+            "dropped": record["dropped"],
+            "programs": list(programs.values()),
+            # compiles charged to a step: the program changed in service
+            "recompiles": in_service,
+            "jax_cache": compile_cache.stats()["jax"]}
+
+
 def summary() -> dict:
     """Compact run summary for embedding (bench.py BENCH json): non-zero
     counters/gauges from the global registry plus the active StepMonitor
-    report."""
+    report, and once the process has built anything what its start was
+    spent on (:func:`startup_report`)."""
     out = {}
     if _registry is not None:
         flat = {}
@@ -309,6 +349,9 @@ def summary() -> dict:
         out["step"] = mon.report()
     if _event_log is not None and _event_log.path:
         out["events_jsonl"] = _event_log.path
+    report = startup_report()
+    if set(report["spans"]) - {"start:import"}:  # something was built
+        out["startup"] = report
     return out
 
 
