@@ -1,11 +1,29 @@
 """Fused attention — Pallas TPU kernels, forward AND backward (new
 capability; the reference predates attention, SURVEY.md §5.7).
 
-Forward: the standard flash-attention schedule — Q tiles on the grid, K/V
-STREAMED block-by-block through VMEM via the grid's innermost dimension
-(BlockSpec index maps; nothing is staged whole), online-softmax (m, l, acc)
-carried in VMEM scratch across K steps, logsumexp written out for the
-backward.
+Forward: the flash-attention schedule — Q tiles on the grid, K/V STREAMED
+block-by-block through VMEM via the grid's innermost dimension (BlockSpec
+index maps; nothing is staged whole), online-softmax (m, l, acc) carried in
+VMEM scratch across K steps, logsumexp written out for the backward.  What
+the schedule is since PR 40 (one kernel; causal or not, the count and the
+size of the blocks decide which of its bodies exist):
+  * the running maximum and sum live as [rows, 128], every lane of a row
+    the same value — the layout a row reduction leaves and a broadcast
+    along the keys reads — and not as [rows] vectors a row a lane, whose
+    five relayouts a block were half the kernel's time;
+  * under the causal mask a dead step revisits the block that is in VMEM
+    (the K/V index maps are clamped to the q tile's last live block), a
+    block wholly under the diagonal builds no mask, and a square block on
+    the diagonal leaves out its tiles above it;
+  * a fetched block is worked through in 512 x 512 tiles unrolled in the
+    body, so the tiles of different row groups (independent chains of
+    product, row maximum, exp2, product) overlap; a call that names no
+    block sizes fetches up to 2048 rows a block.
+Measured on v5e (my chip runs, PR 40; device trace, causal, bfloat16,
+4 x 2048 x 16 x 128, the LM cell's shape): 1.995 ms a call before, 0.868
+with 512 x 512 blocks, 0.755 with 1024, 0.620 with the default 2048 (ten
+live tiles a (batch, head) pair, 0.97 us each, 70 % of the MXU's peak on
+the FLOPs executed); non-causal 2.893 -> 0.879.
 
 Backward: two Pallas kernels in the flash-v2 style, recomputing P per block
 from (Q, K, logsumexp):
@@ -14,7 +32,10 @@ from (Q, K, logsumexp):
   * dK/dV kernel — grid over K tiles, Q/dO streamed innermost,
     dV += Pᵀ·dO,  dK += (P ∘ (dO·Vᵀ − Δ))ᵀ·Q.
 Both run O(s²) time in O(s) memory — sequence length is bounded by HBM,
-not VMEM, so ≥16k-token training steps fit on one chip.
+not VMEM, so ≥16k-token training steps fit on one chip.  Their index maps
+are clamped at the diagonal like the forward's (same shape, PR 40: dQ
+1.000 -> 0.975 ms, dK/dV 1.400 -> 1.265); a second, unmasked body for the
+blocks under the diagonal made both SLOWER (1.113 / 1.325) and is not there.
 
 On a non-TPU device the same kernels run in Pallas interpret mode (chosen
 from where the operands live — ops/interpret.py), so the CPU test mesh
@@ -77,15 +98,47 @@ def _pick_block(block, seq):
 # forward kernel — K/V streamed over the innermost grid dimension
 # ---------------------------------------------------------------------------
 
+_LANES = 128
+# A fetched block is worked through in compute tiles of at most this many
+# rows and keys, unrolled in the body: the tiles of different row groups are
+# independent chains (product, row maximum, exp2, product), which the
+# scheduler overlaps; a tile of 256 keys is slower on v5e (PERF.md, PR 40).
+_FWD_TILE = 512
+# The forward's own default block: as many rows as keep one operand block
+# within 512 KiB, at most 2048 (2048 at d=128 in bfloat16: 10 MB of VMEM
+# with both buffers, the state and a tile's scores).
+_FWD_BLOCK_BYTES = 512 * 1024
+_FWD_BLOCK_MAX = 2048
+
+
+def _lanes(x, n):
+    """``x`` [rows, 128], every lane of a row the same value, as [rows, n]
+    with no relayout where ``n`` is a multiple of the lane count."""
+    import jax.numpy as jnp
+
+    if n % _LANES == 0:
+        return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _last_live_block(i, bq, bk):
+    """Index of the last K/V block q tile ``i`` reads under the causal mask.
+    The grid still steps past it; the index maps hand those steps the block
+    that is already in VMEM, so the pipeline issues no copy for them."""
+    return (i * bq + bq - 1) // bk
+
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, bq, bk, nk, scale, causal):
+                *, bq, bk, nq, nk, tq, tk, scale, causal):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
+    d = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -93,51 +146,136 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: blocks strictly above the diagonal are fully masked — skip
-    # their MXU work entirely (the old fori_loop bounded the loop at the
-    # diagonal; on a grid the block body is guarded instead)
-    live = (j * bk <= qi * bq + bq - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
+    def tile(a, c, masked):
+        """Online-softmax update of row group ``a`` with key chunk ``c``
+        (m and l as [rows, 128], every lane of a row the same: see the
+        module's note)."""
+        rows = slice(a * tq, (a + 1) * tq)
+        keys = slice(c * tk, (c + 1) * tk)
         # dots stay in the input dtype (bf16 on TPU -> MXU) with f32
         # accumulation; only the softmax state is f32. Scores live in the
         # base-2 domain (scale folded with log2e — see _LOG2E note).
-        q = q_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
+        vblk = v_ref[0, keys, :]
+        s = jax.lax.dot_general(q_ref[0, rows, :], k_ref[0, keys, :],
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
             * (scale * _LOG2E)
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        if masked:
+            q_pos = qi * bq + a * tq \
+                + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+            k_pos = j * bk + c * tk \
+                + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp2(s - m_new[:, None])
+        m = m_scr[rows, :]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp2(s - _lanes(m_new, tk))
         corr = jnp.exp2(m - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        l_scr[rows, :] = l_scr[rows, :] * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[rows, :] = acc_scr[rows, :] * _lanes(corr, d) \
+            + jax.lax.dot_general(p.astype(vblk.dtype), vblk,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        m_scr[rows, :] = m_new
+
+    def block(status):
+        for c in range(bk // tk):
+            for a in range(bq // tq):
+                kind = status(a, c)
+                if kind != "dead":
+                    tile(a, c, kind == "masked")
+
+    def on_diagonal(a, c):
+        """Tile of a block whose first row and first key are the same
+        position (bq == bk): what the diagonal leaves of it is static."""
+        if (c + 1) * tk - 1 <= a * tq:
+            return "open"
+        if c * tk > (a + 1) * tq - 1:
+            return "dead"
+        return "masked"
+
+    if not causal:
+        block(lambda a, c: "open")
+    else:
+        # Three kinds of block: wholly under the diagonal (no mask is
+        # built), crossed by it (masked; where the block is square, its
+        # tiles above the diagonal are not computed at all), and dead
+        # (nothing runs, and nothing was fetched: _last_live_block).
+        under = (j + 1) * bk - 1 <= qi * bq
+        live = j * bk <= qi * bq + bq - 1
+        if (nq - 1) * bq >= bk - 1:   # else no q tile has a block under it
+            pl.when(under)(lambda: block(lambda a, c: "open"))
+        pl.when(jnp.logical_and(live, jnp.logical_not(under)))(
+            lambda: block(on_diagonal if bq == bk
+                          else lambda a, c: "masked"))
 
     @pl.when(j == nk - 1)
     def _finish():
         l = l_scr[...]
         lsafe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / lsafe[:, None]).astype(o_ref.dtype)
-        # back to natural log at the boundary (ring/backward contract)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log2(lsafe)) * _LN2
+        o_ref[0] = (acc_scr[...] / _lanes(lsafe, d)).astype(o_ref.dtype)
+        # back to natural log at the boundary (ring/backward contract), and
+        # to a row a lane for the [1, 1, bq] output: the one relayout a q
+        # tile pays
+        lse_ref[0, 0] = ((m_scr[...] + jnp.log2(lsafe)) * _LN2)[:, 0]
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_call(causal, scale, bq, bk, interpret):
+    """The forward ``pallas_call`` on flattened [b*h, s, d] operands, jitted
+    on its own: a graph of N attention layers traces and lowers the
+    kernel's unrolled body once, not N times."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def call(qt, kt, vt):
+        bh, sq, d = qt.shape
+        nq, nk = sq // bq, kt.shape[1] // bk
+        kernel = functools.partial(
+            _fwd_kernel, bq=bq, bk=bk, nq=nq, nk=nk,
+            tq=_FWD_TILE if bq % _FWD_TILE == 0 else bq,
+            tk=_FWD_TILE if bk % _FWD_TILE == 0 else bk,
+            scale=scale, causal=causal)
+        if causal:
+            kv_map = lambda bh, i, j: (
+                bh, jnp.minimum(j, _last_live_block(i, bq, bk)), 0)
+        else:
+            kv_map = lambda bh, i, j: (bh, j, 0)
+        # lse carries a singleton middle dim so its block's trailing dims
+        # (1, bq) satisfy the Mosaic tiling rule (second-to-last equals the
+        # array dim, last divisible by 128); squeezed before returning
+        vma = jax.typeof(qt).vma
+        return pl.pallas_call(
+            kernel,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, d), qt.dtype, vma=vma),
+                jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32, vma=vma)],
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+            interpret=interpret,
+            # the kernel's name in the compiled program and the device trace
+            name="flash_fwd",
+        )(qt, kt, vt)
+
+    return jax.jit(call)
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     """Returns (o, lse) with o: [b, s, h, d], lse: [b*h, s] (f32)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
     b, sq, h, d = q.shape
     sk = k.shape[1]
     bq = _pick_block(block_q, sq)
@@ -145,39 +283,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    nk = sk // bk
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk,
-                               scale=scale, causal=causal)
-    # lse carries a singleton middle dim so its block's trailing dims
-    # (1, bq) satisfy the Mosaic tiling rule (second-to-last equals the
-    # array dim, last divisible by 128); squeezed before returning
-    vma = jax.typeof(qt).vma
-    out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
-                 jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma)]
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq // bq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        interpret=interpret,
-        # the kernel's name in the compiled program and the device trace
-        name="flash_fwd",
-    )(qt, kt, vt)
+    o, lse = _fwd_call(bool(causal), float(scale), bq, bk,
+                       bool(interpret))(qt, kt, vt)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse.reshape(b * h, sq)
 
 
@@ -323,14 +430,28 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(qt).vma)
 
+    # Under the causal mask a dead step (its body is guarded off) is handed
+    # the block the live step beside it holds: no copy is issued for it.
+    # dQ's dead steps follow a q tile's last live K/V block; dK/dV's come
+    # before a k tile's first live q block.
+    if causal:
+        kv_of = lambda i, j: jnp.minimum(j, _last_live_block(i, bq, bk))
+        q_of = lambda j, i: jnp.maximum(
+            i, jnp.minimum((j * bk) // bq, nq - 1))
+    else:
+        kv_of = lambda i, j: j
+        q_of = lambda j, i: i
+
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, nk=nk, scale=scale,
                           causal=causal),
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),   # v
+            pl.BlockSpec((1, bk, d),
+                         lambda bh, i, j: (bh, kv_of(i, j), 0)),     # k
+            pl.BlockSpec((1, bk, d),
+                         lambda bh, i, j: (bh, kv_of(i, j), 0)),     # v
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),   # do
             pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i)),   # lse
             pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i)),   # delta
@@ -347,12 +468,16 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                           causal=causal),
         grid=(b * h, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),   # q
+            pl.BlockSpec((1, bq, d),
+                         lambda bh, j, i: (bh, q_of(j, i), 0)),      # q
             pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),   # k
             pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0)),   # do
-            pl.BlockSpec((1, 1, bq), lambda bh, j, i: (bh, 0, i)),   # lse
-            pl.BlockSpec((1, 1, bq), lambda bh, j, i: (bh, 0, i)),   # delta
+            pl.BlockSpec((1, bq, d),
+                         lambda bh, j, i: (bh, q_of(j, i), 0)),      # do
+            pl.BlockSpec((1, 1, bq),
+                         lambda bh, j, i: (bh, 0, q_of(j, i))),      # lse
+            pl.BlockSpec((1, 1, bq),
+                         lambda bh, j, i: (bh, 0, q_of(j, i))),      # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
@@ -430,62 +555,103 @@ def _autotune_blocks(seq_q, seq_k, head_dim, dtype, causal):
         build_fn=build, measure_fn=measure, default=None)
 
 
+def _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype, causal):
+    """((forward block_q, block_k), (backward block_q, block_k)) of a call.
+    Explicit ints are respected as given by all three kernels, and so is
+    the autotuner's winner for this shape family (it measured the forward
+    at those blocks).  What is left None falls back to the measured
+    defaults: 512 for the backward kernels, and for the forward the most
+    rows that keep an operand block within ``_FWD_BLOCK_BYTES`` (it works a
+    fetched block through in ``_FWD_TILE`` tiles, so its block is how much
+    one grid step holds, not how much one product covers).  Each is clamped
+    by ``_pick_block``."""
+    import jax.numpy as jnp
+
+    if block_q is None or block_k is None:
+        try:
+            tuned = _autotune_blocks(seq_q, seq_k, head_dim, dtype,
+                                     causal) or {}
+        except Exception:
+            tuned = {}
+        if block_q is None:
+            block_q = tuned.get("block_q")
+        if block_k is None:
+            block_k = tuned.get("block_k")
+    cap = _FWD_BLOCK_MAX
+    while cap > _DEFAULT_BLOCK and \
+            cap * head_dim * jnp.dtype(dtype).itemsize > _FWD_BLOCK_BYTES:
+        cap //= 2
+
+    def wide(seq):
+        """Whole tiles only: a length no big block divides runs the
+        forward at the backward's default."""
+        b = cap
+        while b > _DEFAULT_BLOCK and seq % b:
+            b //= 2
+        return b
+
+    def pick(default_q, default_k):
+        return (_pick_block(int(block_q or default_q), seq_q),
+                _pick_block(int(block_k or default_k), seq_k))
+
+    return (pick(wide(seq_q), wide(seq_k)),
+            pick(_DEFAULT_BLOCK, _DEFAULT_BLOCK))
+
+
 def resolve_blocks(block_q, block_k, seq_q, seq_k, head_dim=128,
                    dtype="bfloat16", causal=False):
-    """The EFFECTIVE (block_q, block_k) a call runs with: explicit ints
-    are respected as-is, None consults the autotuner (winner for this
+    """The EFFECTIVE (block_q, block_k) a call's backward kernels run with
+    (and its forward, wherever a block size was given or tuned): explicit
+    ints are respected as-is, None consults the autotuner (winner for this
     shape family when enabled) and falls back to the measured default
     (512/512 — PERF.md's v5e-validated config); either way the result
     is clamped by ``_pick_block``."""
-    if block_q is None or block_k is None:
-        tuned = None
-        try:
-            tuned = _autotune_blocks(seq_q, seq_k, head_dim, dtype, causal)
-        except Exception:
-            tuned = None
-        if block_q is None:
-            block_q = (tuned or {}).get("block_q", _DEFAULT_BLOCK)
-        if block_k is None:
-            block_k = (tuned or {}).get("block_k", _DEFAULT_BLOCK)
-    return _pick_block(int(block_q), seq_q), _pick_block(int(block_k), seq_k)
+    return _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype,
+                    causal)[1]
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q=None, block_k=None, interpret=None):
     """Exact fused attention, Pallas fwd+bwd. q, k, v: [b, seq, heads, d].
 
-    Default 512 blocks: measured on v5e (d=128, s=8k), 512-wide tiles run
-    ~3x faster than 128 (the MXU is fed longer contractions and the VPU
-    softmax amortizes); blocks are clamped to the sequence length for
-    short inputs.  Passing None (the default) consults the autotuner
-    (``MXNET_AUTOTUNE``) for this shape family's winner before falling
-    back to 512; explicit block sizes are always respected.
+    Blocks are clamped to the sequence length for short inputs.  Passing
+    None (the default) consults the autotuner (``MXNET_AUTOTUNE``) for this
+    shape family's winner before falling back to the measured defaults;
+    explicit block sizes are always respected, by all three kernels.  The
+    defaults (PERF.md section 6, PR 40, has the readings): 512 x 512 for
+    the two backward kernels (v5e, d=128, s=8k, measured before PR 24:
+    512-wide tiles ran ~3x faster than 128; v5e, causal, bfloat16,
+    4 x 2048 x 16 x 128, PR 40: dQ 0.975 and dK/dV 1.265 ms a call, at
+    1024 x 1024 0.99 and 1.26 by the host's clock where 512 reads 1.14
+    and 1.35); for the forward a block of up to 2048 rows worked through
+    in 512 x 512 tiles (the same shape, PR 40: 0.620 ms where one
+    512 x 512 tile a grid step takes 0.868 and the kernel before PR 40
+    1.995).
     ``interpret`` None picks compiled-vs-interpreted from the device the
     operands live on (ops/interpret.py)."""
     import jax
 
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
-    block_q, block_k = resolve_blocks(block_q, block_k, q.shape[1],
+    fwd_blocks, bwd_blocks = _resolve(block_q, block_k, q.shape[1],
                                       k.shape[1], q.shape[-1], q.dtype,
                                       causal)
     interpret = interpret_for("flash_attention", (q, k, v), interpret)
 
     @jax.custom_vjp
     def run(q, k, v):
-        o, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret)
+        o, _ = _flash_forward(q, k, v, causal, scale, *fwd_blocks, interpret)
         return o
 
     def fwd(q, k, v):
-        o, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
+        o, lse = _flash_forward(q, k, v, causal, scale, *fwd_blocks,
                                 interpret)
         return o, (q, k, v, o, lse)
 
     def bwd(res, g):
         q, k, v, o, lse = res
-        return _flash_backward(q, k, v, o, lse, g, causal, scale, block_q,
-                               block_k, interpret)
+        return _flash_backward(q, k, v, o, lse, g, causal, scale,
+                               *bwd_blocks, interpret)
 
     run.defvjp(fwd, bwd)
     return run(q, k, v)
@@ -497,36 +663,37 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 
 
 def _attrs_config(attrs, q, k):
-    """(causal, scale, block_q, block_k) for the registered op.  Attrs
-    without pinned block sizes resolve through the autotuner (falling
-    back to the measured 512 default) — the fwd and bwd kernels see the
-    same deterministic resolution for one (attrs, shapes) pair."""
+    """(causal, scale, forward blocks, backward blocks) for the registered
+    op.  Attrs without pinned block sizes resolve through the autotuner
+    (falling back to the measured defaults) — the fwd and bwd kernels see
+    the same deterministic resolution for one (attrs, shapes) pair."""
     d = q.shape[-1]
     scale = attrs.get("scale")
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     causal = bool(attrs.get("causal", False))
-    bq, bk = resolve_blocks(attrs.get("block_q"), attrs.get("block_k"),
-                            q.shape[1], k.shape[1], d, q.dtype, causal)
-    return causal, float(scale), bq, bk
+    fwd_blocks, bwd_blocks = _resolve(
+        attrs.get("block_q"), attrs.get("block_k"), q.shape[1], k.shape[1],
+        d, q.dtype, causal)
+    return causal, float(scale), fwd_blocks, bwd_blocks
 
 
 def _fa_fn(attrs, query, key, value):
-    causal, scale, bq, bk = _attrs_config(attrs, query, key)
+    causal, scale, blocks, _ = _attrs_config(attrs, query, key)
     interpret = interpret_for("_contrib_FlashAttention", (query, key, value))
 
     def kernel(q, k, v):
-        return _flash_forward(q, k, v, causal, scale, bq, bk, interpret)[0]
+        return _flash_forward(q, k, v, causal, scale, *blocks, interpret)[0]
 
     return over_batch_shards(kernel)(query, key, value)
 
 
 def _fa_fwd(attrs, query, key, value):
-    causal, scale, bq, bk = _attrs_config(attrs, query, key)
+    causal, scale, blocks, _ = _attrs_config(attrs, query, key)
     interpret = interpret_for("_contrib_FlashAttention", (query, key, value))
 
     def kernel(q, k, v):
-        return _flash_forward(q, k, v, causal, scale, bq, bk, interpret)
+        return _flash_forward(q, k, v, causal, scale, *blocks, interpret)
 
     o, lse = over_batch_shards(kernel)(query, key, value)
     return o, (query, key, value, o, lse)
@@ -534,11 +701,11 @@ def _fa_fwd(attrs, query, key, value):
 
 def _fa_bwd(attrs, res, ct):
     q, k, v, o, lse = res
-    causal, scale, bq, bk = _attrs_config(attrs, q, k)
+    causal, scale, _, blocks = _attrs_config(attrs, q, k)
     interpret = interpret_for("_contrib_FlashAttention", (q, k, v))
 
     def kernel(q, k, v, o, lse, ct):
-        return _flash_backward(q, k, v, o, lse, ct, causal, scale, bq, bk,
+        return _flash_backward(q, k, v, o, lse, ct, causal, scale, *blocks,
                                interpret)
 
     return over_batch_shards(kernel)(q, k, v, o, lse, ct)
@@ -556,7 +723,7 @@ def _register():
         inputs=("query", "key", "value"),
         params={"causal": Param(bool, False),
                 "scale": Param("float-or-none", None),
-                # None = autotuner winner, else the measured 512 default
+                # None = autotuner winner, else the measured defaults
                 "block_q": Param("int-or-none", None),
                 "block_k": Param("int-or-none", None)},
         infer_shape=lambda attrs, s: (s, [s[0]], []),

@@ -149,3 +149,99 @@ def test_flash_long_sequence_train_step():
     val2, _ = step(w - (0.05 / gnorm) * grad.astype(dt), q, k, v)
     assert np.isfinite(float(val2))
     assert float(val2) < float(val)
+
+
+# (seq_q, seq_k, head_dim, block_q, block_k, causal).  The forward's schedule
+# (PR 40): K/V index maps clamped to a q tile's last live block, blocks under
+# the diagonal unmasked, square blocks on it worked through in 512 x 512
+# tiles with those above it left out, the softmax state a row a sublane.
+SCHEDULES = {
+    "causal_one_block": (64, 64, 16, 64, 64, True),
+    "causal_two_blocks": (64, 64, 16, 32, 32, True),
+    "causal_four_blocks": (128, 128, 16, 32, 32, True),
+    # a q tile's diagonal crosses four k blocks
+    "causal_bq_over_bk": (128, 128, 16, 64, 16, True),
+    # a k block is crossed by the diagonals of four q tiles; dead blocks
+    # after the clamp in all but the last
+    "causal_bq_under_bk": (128, 128, 16, 16, 64, True),
+    "causal_more_rows_than_keys": (96, 32, 16, 16, 16, True),
+    # keys beyond every row: k tiles with no live q block (dK/dV's clamp)
+    "causal_more_keys_than_rows": (32, 96, 16, 16, 16, True),
+    "noncausal": (64, 64, 16, 32, 32, False),
+    # the ring's off-diagonal shard
+    "noncausal_rectangular": (32, 96, 16, 32, 16, False),
+    "shorter_than_the_block": (24, 24, 8, 512, 512, True),
+    # 512 x 512 tiles inside a block: one block under the diagonal and two
+    # on it, each with a tile that is not computed
+    "causal_tiles_in_a_block": (2048, 2048, 8, 1024, 1024, True),
+    "noncausal_tiles_in_a_block": (1024, 1024, 8, 1024, 1024, False),
+    # what a call without block sizes runs: one 2048 block, ten live tiles
+    "causal_default_blocks": (2048, 2048, 8, None, None, True),
+}
+
+
+def _schedule_case(name, heads=2):
+    import jax.numpy as jnp
+
+    sq, sk, d, bq, bk, causal = SCHEDULES[name]
+    rng = np.random.RandomState(len(name))
+    q = jnp.asarray(rng.randn(1, sq, heads, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(1, sk, heads, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, sk, heads, d).astype(np.float32))
+    return q, k, v, bq, bk, causal
+
+
+@pytest.mark.parametrize("name", sorted(SCHEDULES))
+def test_flash_forward_schedule_matches_dense(name):
+    """Forward output AND logsumexp (the backward's and the ring merge's
+    input) of every kind of block the schedule knows."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att
+
+    q, k, v, bq, bk, causal = _schedule_case(name)
+    b, sq, h, d = q.shape
+    scale = 1.0 / np.sqrt(d)
+    (fq, fk), _ = att._resolve(bq, bk, sq, k.shape[1], d, q.dtype, causal)
+    o, lse = att._flash_forward(q, k, v, causal, scale, fq, fk, True)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        s = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(k.shape[1]),
+                      s, -1e30)
+    assert_almost_equal(np.asarray(o), np.asarray(_reference_attention(
+        q, k, v, causal, scale)), rtol=2e-5, atol=2e-5)
+    assert_almost_equal(
+        np.asarray(lse),
+        np.asarray(jax.nn.logsumexp(s, axis=-1).reshape(b * h, sq)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", [
+    "causal_bq_over_bk", "causal_bq_under_bk", "causal_more_keys_than_rows",
+    "causal_more_rows_than_keys", "noncausal_rectangular",
+    "causal_default_blocks"])
+def test_flash_gradients_through_the_schedule(name):
+    """The backward kernels read the forward's lse, and their own index
+    maps are clamped at the diagonal too: gradients through the public
+    call against the dense vjp."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, bq, bk, causal = _schedule_case(name, heads=1)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    w = jnp.asarray(np.random.RandomState(1).randn(*q.shape)
+                    .astype(np.float32))
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=bq,
+                                       block_k=bk) * w)
+
+    def f_ref(q, k, v):
+        return jnp.sum(_reference_attention(q, k, v, causal, scale) * w)
+
+    g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        assert_almost_equal(np.asarray(a), np.asarray(b),
+                            rtol=1e-4, atol=1e-4)

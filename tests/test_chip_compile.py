@@ -15,8 +15,13 @@ import pytest
 LM_TRAIN = (4, 4096, 16, 128, 512, 512)       # Module step, default blocks
 LM_TRAIN_BK1024 = (4, 4096, 16, 128, 512, 1024)  # the flash bench's grid
 LM_SCORE = (2, 1024, 16, 128, 512, 512)       # serving: /predict scoring
+# the benchmark's LM cells (perfbench: cgpt13b-train-s2048, -dp4, a chip's
+# share): the backward kernels' grid, and the forward's own default block
+LM_CELL = (4, 2048, 16, 128, 512, 512)
+LM_CELL_FWD = (4, 2048, 16, 128, 2048, 2048)
 GRIDS = {"lm_train": LM_TRAIN, "lm_train_bk1024": LM_TRAIN_BK1024,
-         "lm_score": LM_SCORE}
+         "lm_score": LM_SCORE, "lm_cell": LM_CELL,
+         "lm_cell_fwd": LM_CELL_FWD}
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +73,92 @@ def test_flash_forward_compiles_for_v5e(one_chip, name):
         return att._flash_forward(q, k, v, True, 1.0 / np.sqrt(d), bq, bk,
                                   interpret=False)
 
-    _assert_mosaic(jax.jit(fwd).lower(*_qkv(grid, one_chip)).compile())
+    compiled = jax.jit(fwd).lower(*_qkv(grid, one_chip)).compile()
+    _assert_mosaic(compiled)
+    names = _custom_call_names(compiled.as_text())
+    assert len(names) == 1 and names[0].startswith("flash_fwd"), names
+
+
+def _custom_call_names(text):
+    """The compiler names each Mosaic custom call after its kernel: what a
+    device trace shows, and what the benchmark's per-kernel metrics read
+    (``flash_fwd.3`` under a Symbol node's scope, ``jvp_flash_fwd_.1``
+    under a transformation)."""
+    import re
+
+    return re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", None)
+            if sub is not None:
+                yield from _pallas_calls(getattr(sub, "jaxpr", sub))
+
+
+def _blocks_fetched(eqn, operand):
+    """{grid point: block index} of one operand of a ``pallas_call``."""
+    import itertools
+
+    import jax
+
+    gm = eqn.params["grid_mapping"]
+    imap = gm.block_mappings[operand].index_map_jaxpr
+    return {pt: tuple(int(x) for x in jax.core.eval_jaxpr(
+        imap.jaxpr, imap.consts, *pt))
+        for pt in itertools.product(*(range(n) for n in gm.grid))}
+
+
+def test_flash_fetches_no_dead_block_at_the_lm_cell():
+    """Under the causal mask the pipeline copies a block from HBM when its
+    index differs from the step's before: at the LM cell's grids every
+    kernel's streamed operands change block on live steps only (the
+    clamp), 10 of 16 steps a (batch, head) pair, and the forward's own
+    default block is one step a pair."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att
+
+    b, s, h, d, bq, bk = LM_CELL
+    x = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, s), jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    def fwd(q, k, v):
+        return att._flash_forward(q, k, v, True, scale, bq, bk, True)
+
+    def bwd(q, k, v, o, lse, do):
+        return att._flash_backward(q, k, v, o, lse, do, True, scale, bq, bk,
+                                   True)
+
+    calls = {e.params["name"]: e for e in
+             list(_pallas_calls(jax.make_jaxpr(fwd)(x, x, x).jaxpr))
+             + list(_pallas_calls(jax.make_jaxpr(bwd)(x, x, x, x, lse,
+                                                      x).jaxpr))}
+    # operand 1 is k in the q-major kernels; operand 0 is q in dK/dV
+    for name, operand in (("flash_fwd", 1), ("flash_bwd_dq", 1),
+                          ("flash_bwd_dkv", 0)):
+        at = _blocks_fetched(calls[name], operand)
+        n = s // bq
+        fetched = 0
+        for tile in range(n):
+            row = [at[(0, tile, step)] for step in range(n)]
+            fetched += len(set(row))
+            live = (lambda j: j <= tile) if operand else \
+                (lambda i: i >= tile)
+            assert sorted(set(row)) == sorted(
+                (0, j, 0) for j in range(n) if live(j)), (name, tile, row)
+        assert fetched == 10, (name, fetched)
+
+    (fq, fk), (dq, dk) = att._resolve(None, None, s, s, d, jnp.bfloat16,
+                                      True)
+    assert (fq, fk, dq, dk) == LM_CELL_FWD[4:] + LM_CELL[4:]
+    assert att._resolve(512, 512, s, s, d, jnp.bfloat16, True) == \
+        ((512, 512), (512, 512))
 
 
 @pytest.mark.parametrize("name", ["lm_train", "lm_train_bk1024"])
@@ -109,12 +199,9 @@ def test_flash_attention_vjp_through_the_public_call(one_chip):
         *_qkv(LM_TRAIN, one_chip)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
-    # the compiler names each custom call after its kernel: what a device
-    # trace shows, and what the benchmark's per-kernel metrics read
-    # (``flash_fwd.3`` under a Symbol node's scope, ``jvp_flash_fwd_.1`` here)
     import re
 
-    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    names = _custom_call_names(text)
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert any(re.search(r"(?<![A-Za-z0-9])%s(?![A-Za-z0-9])" % kernel, n)
                    for n in names), (kernel, names)
