@@ -1030,7 +1030,7 @@ class DecodeEngine:
                     # where this engine's planes live (ops/paged.py)
                     "paged_attention": decode_formulation(
                         self._device.platform, self.num_heads, self.head_dim,
-                        self.pool.k_pools[0].dtype,
+                        self.pool.paged_planes()[0].dtype,
                         kv_heads=self.family.kv_heads),
                     "kv": self.pool.snapshot(), "startup": startup}
             if self.pool.num_slots:

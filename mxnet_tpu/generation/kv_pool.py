@@ -156,8 +156,10 @@ class PagedKVPool:
         Where the planes live (default: the current context).
     planes : sequence of (name, kind, shape, dtype), optional
         The planes, in the order the programs take and return them, in
-        place of ``num_layers`` K/V pairs (:data:`PlaneSpec`); paged ones
-        come as K/V pairs.
+        place of ``num_layers`` K/V pairs (:data:`PlaneSpec`).  A paged
+        plane is one plane: a layer's K and V are two (``*_k_pool`` /
+        ``*_v_pool``), a layer that caches one row a token (a latent) has
+        one; write, copy and copy-on-write go plane by plane.
     num_slots : int, optional
         Slots of every slot plane INCLUDING the reserved scratch slot 0.
     """
@@ -197,8 +199,15 @@ class PagedKVPool:
                      slots=self.num_slots)
         self._paged = [p for p, s in zip(self._planes, self.specs)
                        if s.kind == "paged"]
-        self.k_pools, self.v_pools = self._paged[0::2], self._paged[1::2]
-        self.num_layers = len(self.k_pools)
+        # the K/V pairs among them, for the readers that want a layer's K
+        # and V (tests, ``read_page``); ``num_layers`` counts the layers
+        # that page anything, paired or not
+        self.k_pools, self.v_pools = (
+            [p for p, s in zip(self._planes, self.specs)
+             if s.kind == "paged" and s.name.endswith(end)]
+            for end in ("_k_pool", "_v_pool"))
+        self.num_layers = len({s.name.split("_", 1)[0] for s in self.specs
+                               if s.kind == "paged"})
         # bytes of one sequence's slots over every slot plane
         self.slot_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
                               for s in self.specs if s.kind == "slot")
@@ -624,6 +633,15 @@ class PagedKVPool:
     def plane_names(self) -> List[str]:
         return [s.name for s in self.specs]
 
+    def paged_planes(self) -> list:
+        """The paged planes alone, in ``specs``' order."""
+        return list(self._paged)
+
+    def token_bytes(self) -> int:
+        """Bytes one token holds over every paged plane."""
+        return sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                   for s in self.specs if s.kind == "paged")
+
     def device_bytes(self) -> int:
         return sum(int(np.prod(p.shape)) * p.dtype.itemsize
                    for p in self.planes())
@@ -670,7 +688,7 @@ class PagedKVPool:
         scratch slot 0."""
         ps = self.page_size
         paged = next(slab for slab, s in zip(slabs, self.specs)
-                     if s.kind == "paged")
+                     if s.kind == "paged")  # any: they share (batch, length)
         slots = np.zeros(paged.shape[:2], np.int32)
         state_slots = np.zeros(paged.shape[:1], np.int32)
         with self._lock:
@@ -688,8 +706,9 @@ class PagedKVPool:
                   np.int32(dst))
 
     def read_page(self, layer: int, page: int):
-        """``(k, v)`` of one page of one layer, read to the host: for
-        tests and debugging, nothing on the serving path reads a plane."""
+        """``(k, v)`` of one page of one K/V layer (``k_pools`` /
+        ``v_pools``' order), read to the host: for tests and debugging,
+        nothing on the serving path reads a plane."""
         return (self.k_pools[layer][int(page)].asnumpy(),
                 self.v_pools[layer][int(page)].asnumpy())
 

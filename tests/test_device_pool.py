@@ -120,6 +120,52 @@ def test_pool_planes_are_device_arrays_with_one_owner():
     assert pool.read_page(0, 7)[0].all() and not pool.read_page(0, 6)[0].any()
 
 
+def test_a_paged_plane_may_stand_alone():
+    """A pool whose layers cache ONE row a token (a latent, no head axis)
+    beside a layer with a K/V pair: alloc, extend, the prefill's scatter,
+    copy-on-write and the prefix index go plane by plane."""
+    planes = [("layer0_latent_pool", "paged", (6,), np.float32),
+              ("layer1_k_pool", "paged", (2, 4), np.float32),
+              ("layer1_v_pool", "paged", (2, 4), np.float32),
+              ("layer2_latent_pool", "paged", (6,), np.float32)]
+    pool = PagedKVPool(num_pages=8, page_size=PAGE, planes=planes,
+                       prefix_cache_pages=4)
+    assert pool.num_layers == 3 and pool.num_slots == 0
+    assert len(pool.k_pools) == len(pool.v_pools) == 1
+    assert pool.k_pools[0] is pool.planes()[1]
+    assert len(pool.paged_planes()) == 4
+    assert pool.token_bytes() == (6 + 8 + 8 + 6) * 4
+    assert pool.device_bytes() == 8 * PAGE * pool.token_bytes()
+    pool.alloc("a", 6)
+    assert len(pool.extend("a", 9)) == 3
+    r = np.random.RandomState(0)
+    slabs = [r.randn(1, 12, 6), r.randn(1, 12, 2, 4), r.randn(1, 12, 2, 4),
+             r.randn(1, 12, 6)]
+    slabs = [s.astype(np.float32) for s in slabs]
+    pool.write_prefill(["a"], slabs, [9])
+    pages = pool._tables["a"]
+    for plane, slab in zip(pool.planes(), slabs):
+        got = plane.asnumpy()[pages].reshape((3 * PAGE,) + slab.shape[2:])
+        np.testing.assert_array_equal(got[:9], slab[0, :9])
+    k, v = pool.read_page(0, pages[1])
+    np.testing.assert_array_equal(k, slabs[1][0, PAGE:2 * PAGE])
+    # the prefix index: "b" shares "a"'s two complete pages, and a write
+    # into the second splits it in every plane, the lone ones too
+    tokens = list(range(9))
+    assert pool.register_prefix("a", tokens) == 2
+    got, cached = pool.alloc_prefix("b", 9, tokens=tokens)
+    assert cached == 8 and got[:2] == pages[:2]
+    assert pool.ensure_writable("b", 7)
+    own = pool._tables["b"][1]
+    assert own != pages[1]
+    for plane in pool.planes():
+        host = plane.asnumpy()
+        np.testing.assert_array_equal(host[own], host[pages[1]])
+    pool.free("a")
+    pool.free("b")
+    assert pool.total_refcount() == 0
+
+
 # ---------------------------------------------------------------------------
 # the executor's carried arguments, through the engine's rigs
 # ---------------------------------------------------------------------------
