@@ -109,7 +109,7 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
 from ..ops.moe import experts_formulation
-from ..ops.paged import decode_formulation
+from ..ops.paged import LATENT_FORMULATIONS, decode_formulation
 from ..ops.ssm import step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
@@ -407,6 +407,10 @@ class _GenMetrics:
         # the expert layers)
         self.expert_picks = reg.counter("mxtpu_gen_expert_picks")
         self.g_experts_hit = reg.gauge("mxtpu_gen_experts_hit")
+        # a family with latent attention: the latent rows the last step
+        # dispatched had to read (live tokens x a token's bytes over the
+        # latent planes)
+        self.g_latent_bytes = reg.gauge("mxtpu_gen_latent_bytes")
         _telemetry.register_collector(self)
 
     def render_prometheus(self):
@@ -664,6 +668,9 @@ class DecodeEngine:
             self._decode[self.max_lanes]._symbol, self._params)
         self._expert_load = None
         self._expert_steps = self._experts_hit_total = 0
+        # bytes a token holds over the family's latent planes (0: none)
+        self._latent_token_bytes = int(
+            getattr(family, "latent_token_bytes", lambda: 0)())
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -1039,6 +1046,9 @@ class DecodeEngine:
                 # likewise for the lane program's state step (ops/ssm.py)
                 snap["ssm_step"] = step_formulation(
                     self._device.platform, *self._ssm_step)
+            if self._latent_token_bytes:
+                # likewise for the latent layers' two ops (ops/paged.py)
+                snap["latent_attention"] = dict(LATENT_FORMULATIONS)
             if self._lane_extras:
                 # likewise for the routed experts' grouped products
                 # (ops/moe.py), and what the lanes picked so far
@@ -1564,6 +1574,12 @@ class DecodeEngine:
             # the recurrent state of the step's lanes: read once, written
             # once
             more["state_bytes"] = len(lanes) * pool.slot_bytes
+        if self._latent_token_bytes:
+            # the latent rows of the lanes' tokens up to this step's: what
+            # the absorbed attention reads
+            more["latent_bytes"] = self._latent_token_bytes * sum(
+                s.next_pos + 1 for s in lanes)
+            self.metrics.g_latent_bytes.set(more["latent_bytes"])
         self._describe_step(span, lanes, b, inflight=int(prev is not None),
                             fed_device=int((source >= 0).sum()), **more)
         outs = self._dispatch_lanes(self._decode[b], data, positions, table,
@@ -1623,11 +1639,15 @@ class DecodeEngine:
 
     def _note_expert_load(self, load, span):
         """``load`` (expert layers, experts): the live lanes' picks in the
-        step just read.  On the span that read it: the experts with at least
-        one pick (summed over the layers), the (lane, pick) pairs, and the
-        bytes of the hit experts' weights -- what a kernel that skips idle
-        experts would fetch."""
-        hit, pairs = int((load > 0).sum()), int(load.sum())
+        step just read, over the router's whole width.  On the span that
+        read it: the experts HELD HERE with at least one pick (summed over
+        the layers; a family that holds a share of the experts fetches no
+        other), the (lane, pick) pairs, and the bytes of the hit experts'
+        weights -- what a kernel that skips idle experts would fetch."""
+        first = int(getattr(self.family, "first_expert", 0))
+        held = getattr(self.family, "experts_held", None) or load.shape[1]
+        hit = int((load[:, first:first + held] > 0).sum())
+        pairs = int(load.sum())
         span.set(experts_hit=hit, expert_pairs=pairs,
                  expert_bytes=hit * self.family.expert_bytes())
         with self._cv:

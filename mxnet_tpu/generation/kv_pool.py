@@ -199,15 +199,14 @@ class PagedKVPool:
                      slots=self.num_slots)
         self._paged = [p for p, s in zip(self._planes, self.specs)
                        if s.kind == "paged"]
-        # the K/V pairs among them, for the readers that want a layer's K
-        # and V (tests, ``read_page``); ``num_layers`` counts the layers
-        # that page anything, paired or not
+        # the K/V pairs among them, by the names this pool gives its own
+        # (``*_k_pool`` / ``*_v_pool``), for the readers that want a layer's
+        # K and V (tests, ``read_page``); ``num_layers`` counts those pairs
         self.k_pools, self.v_pools = (
             [p for p, s in zip(self._planes, self.specs)
              if s.kind == "paged" and s.name.endswith(end)]
             for end in ("_k_pool", "_v_pool"))
-        self.num_layers = len({s.name.split("_", 1)[0] for s in self.specs
-                               if s.kind == "paged"})
+        self.num_layers = len(self.k_pools)
         # bytes of one sequence's slots over every slot plane
         self.slot_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
                               for s in self.specs if s.kind == "slot")
@@ -636,11 +635,6 @@ class PagedKVPool:
     def paged_planes(self) -> list:
         """The paged planes alone, in ``specs``' order."""
         return list(self._paged)
-
-    def token_bytes(self) -> int:
-        """Bytes one token holds over every paged plane."""
-        return sum(int(np.prod(s.shape)) * s.dtype.itemsize
-                   for s in self.specs if s.kind == "paged")
 
     def device_bytes(self) -> int:
         return sum(int(np.prod(p.shape)) * p.dtype.itemsize

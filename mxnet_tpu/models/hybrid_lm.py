@@ -59,6 +59,28 @@ always did, byte for byte: tests/test_hybrid_lm.py pins them):
   ``expert_load`` (expert layers, num_experts) int32: the live lanes' picks
   by expert in this step (:attr:`HybridLM.lane_extras` says so).
 
+* mixer ``latent``, latent attention (ops/paged.py; openPangu-Ultra-MoE,
+  ``pangu_ultra_moe``): ``c_q = RMSNorm(W_qa h)`` (``q_rank``), ``[q_n |
+  q_r] = W_qb c_q`` a head (``nope_dim | rope_dim``), ``[c | k_r] = W_kva h``
+  (``kv_rank | rope_dim``), ``c = RMSNorm(c)``, ``q_r`` and the ONE ``k_r``
+  rotated, ``[k_n | v] = W_kvb c`` a head (``nope_dim | v_dim``), scores
+  ``(q_n . k_n + q_r . k_r) * attention_multiplier``.  It carries ONE paged
+  plane a layer, ``layer<i>_latent_pool``, a token's entry the ``kv_rank +
+  rope_dim`` values ``[c | k_r]`` after the norm and the rotation; the
+  sequence graphs run it expanded, the lane graph absorbed over that plane;
+* ``shared_expert_width``: an expert layer adds ``Shared(x)``, a SiLU-gated
+  MLP every row takes (the dense layers' three ops under the names
+  ``layer<i>_shared_in`` / ``_gate`` / ``_out``), to its routed part;
+  ``router_bias=False``: the top k of the scores alone;
+* ``sandwich_norm``: ``a = x + RMSNorm(Mixer(RMSNorm(x; norm1));
+  post_norm1)`` and likewise ``post_norm2`` after the feed-forward;
+* ``tied_head=False``: the logits go through ``lm_head_weight`` (vocab,
+  hidden), not the embedding.
+
+A description whose layers carry no slot plane (all ``attention`` or
+``latent``) has no ``state_slot``: its lane graph knows a padded lane by its
+page table, whose first page is the scratch page 0.
+
 There is no windowed (catch-up / verify) graph: a recurrent state cannot be
 rewound or rebuilt from cached pages, and the engine refuses what would
 need one by name (generation/engine.py).
@@ -67,15 +89,19 @@ import numpy as np
 
 from .. import symbol as sym
 
-MAMBA, ATTENTION, CONV = "mamba", "attention", "conv"
+MAMBA, ATTENTION, CONV, LATENT = "mamba", "attention", "conv", "latent"
+# the kinds whose layers page what they cache (the rest hold a slot a lane)
+_PAGED = (ATTENTION, LATENT)
 
 
 class HybridLM:
     """The model's description, and the family object the generation
     engine asks (generation/engine.py, "The family seam").
 
-    ``layer_types`` is the pattern (``"mamba"``, ``"conv"`` or
-    ``"attention"`` a layer); ``num_heads`` / ``kv_heads`` / ``head_dim``
+    ``layer_types`` is the pattern (``"mamba"``, ``"conv"``, ``"attention"``
+    or ``"latent"`` a layer; for ``latent`` ``head_dim`` is ``nope_dim +
+    rope_dim``, ``kv_heads`` is ``num_heads``, and ``q_rank`` / ``kv_rank`` /
+    ``nope_dim`` / ``rope_dim`` / ``v_dim`` are needed); ``num_heads`` / ``kv_heads`` / ``head_dim``
     the attention layers', ``rotary_theta`` (None: no positions) and
     ``qk_norm`` theirs too; ``intermediate`` the dense gated MLP's inner
     width; ``ssm_heads`` / ``ssm_head_dim`` / ``ssm_state`` / ``chunk`` the
@@ -83,8 +109,10 @@ class HybridLM:
     ``conv_kernel`` the convolutions' width, Mamba's and the short one's;
     ``num_experts`` (0: every layer dense) / ``experts_per_token`` /
     ``expert_width`` / ``num_dense_layers`` / ``first_expert`` /
-    ``experts_held`` (None: all) / ``norm_topk`` / ``routed_scaling`` the
-    expert layers'; ``dtype`` the K/V planes' and the convolution tails'
+    ``experts_held`` (None: all) / ``norm_topk`` / ``routed_scaling`` /
+    ``router_bias`` / ``shared_expert_width`` the expert
+    layers'; ``sandwich_norm`` and ``tied_head`` the block's and the
+    head's (module docstring); ``dtype`` the K/V planes' and the convolution tails'
     (the weights'); the recurrent state is float32.
     """
 
@@ -99,16 +127,22 @@ class HybridLM:
                    dtype="bfloat16", rotary_theta=None, qk_norm=False,
                    num_experts=0, experts_per_token=0, expert_width=0,
                    num_dense_layers=0, first_expert=0, experts_held=None,
-                   norm_topk=True, routed_scaling=1.0)
+                   norm_topk=True, routed_scaling=1.0, router_bias=True,
+                   shared_expert_width=0, sandwich_norm=False,
+                   tied_head=True, q_rank=None, kv_rank=None, nope_dim=None,
+                   rope_dim=None, v_dim=None)
     _REQUIRED = ("vocab_size", "hidden", "layer_types", "num_heads",
                  "kv_heads", "head_dim", "intermediate")
     _SSM = ("ssm_heads", "ssm_head_dim", "ssm_state")
+    _LATENT = ("q_rank", "kv_rank", "nope_dim", "rope_dim", "v_dim",
+               "rotary_theta")
 
     def __init__(self, **sizes):
         sizes.pop("family", None)
         unknown = set(sizes) - set(self._FIELDS)
-        needed = self._REQUIRED + (
-            self._SSM if MAMBA in (sizes.get("layer_types") or ()) else ())
+        kinds = sizes.get("layer_types") or ()
+        needed = self._REQUIRED + (self._SSM if MAMBA in kinds else ()) \
+            + (self._LATENT if LATENT in kinds else ())
         missing = [k for k in needed if sizes.get(k) is None]
         if unknown or missing:
             raise ValueError("HybridLM: unknown %s, missing %s"
@@ -116,12 +150,19 @@ class HybridLM:
         for k, default in self._FIELDS.items():
             setattr(self, k, sizes.get(k, default))
         self.layer_types = tuple(self.layer_types)
-        bad = set(self.layer_types) - {MAMBA, ATTENTION, CONV}
-        if bad or ATTENTION not in self.layer_types:
-            raise ValueError("layer_types: every entry %r, %r or %r, at "
-                             "least one attention layer (the engine's "
-                             "pages); got %s"
-                             % (MAMBA, CONV, ATTENTION, sorted(bad)))
+        bad = set(self.layer_types) - {MAMBA, ATTENTION, CONV, LATENT}
+        if bad or not set(self.layer_types) & set(_PAGED):
+            raise ValueError("layer_types: every entry %r, %r, %r or %r, at "
+                             "least one attention or latent layer (the "
+                             "engine's pages); got %s"
+                             % (MAMBA, CONV, ATTENTION, LATENT, sorted(bad)))
+        if LATENT in self.layer_types and (
+                self.head_dim != self.nope_dim + self.rope_dim
+                or self.kv_heads != self.num_heads):
+            raise ValueError("latent: head_dim is nope_dim + rope_dim and "
+                             "kv_heads is num_heads; got %d, %d + %d, %d, %d"
+                             % (self.head_dim, self.nope_dim, self.rope_dim,
+                                self.kv_heads, self.num_heads))
         if self.attention_multiplier is None:
             self.attention_multiplier = float(self.head_dim) ** -0.5
         if MAMBA in self.layer_types:
@@ -143,11 +184,12 @@ class HybridLM:
                        self.expert_width, self.experts_held,
                        self.first_expert, self.num_dense_layers,
                        self.num_layers))
-            if set(self.layer_types) == {ATTENTION}:
-                raise ValueError(
-                    "experts: the lane graph knows a padded lane by its "
-                    "scratch state slot, and layer_types has no %r or %r "
-                    "layer to carry one" % (MAMBA, CONV))
+            if self.shared_expert_width < 0:
+                raise ValueError("shared_expert_width %d"
+                                 % self.shared_expert_width)
+        # a layer that carries a slot plane gives the lane graph its
+        # ``state_slot``
+        self.has_slots = not set(self.layer_types) <= set(_PAGED)
         self.expert_layers = tuple(
             i for i in range(self.num_layers)
             if self.num_experts and i >= self.num_dense_layers)
@@ -174,6 +216,9 @@ class HybridLM:
                 out += [("layer%d_%s_pool" % (i, kv), "paged",
                          (self.kv_heads, self.head_dim), self.dtype)
                         for kv in "kv"]
+            elif kind == LATENT:
+                out += [("layer%d_latent_pool" % i, "paged",
+                         (self.kv_rank + self.rope_dim,), self.dtype)]
             elif kind == MAMBA:
                 out += [("layer%d_ssm_state" % i, "slot",
                          (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
@@ -193,6 +238,13 @@ class HybridLM:
     def expert_bytes(self):
         """Bytes of one expert's weights as the program holds them."""
         return 3 * self.hidden * self.expert_width * \
+            np.dtype(self.dtype).itemsize
+
+    def latent_token_bytes(self):
+        """Bytes a token holds over the latent planes (0: no latent
+        layer)."""
+        return self.layer_types.count(LATENT) * (
+            (self.kv_rank or 0) + (self.rope_dim or 0)) * \
             np.dtype(self.dtype).itemsize
 
     def prefill_symbol(self, seq_len, max_seq_len=None):
@@ -315,17 +367,73 @@ def _attention_mixer(h, m, name, seq_len, attend, positions=None):
     return _fc(att, m.hidden, name + "_o"), extras
 
 
+def _latent_mixer(h, m, name, seq_len, attend, positions):
+    """Latent attention.  ``attend(q_n, q_r, latent, w_kvb, name) -> (att,
+    extras)`` over ``(..., heads, nope | rope)``, the rows' ``(..., kv_rank
+    + rope)`` ``[c | k_r]`` and ``W_kvb``; ``att`` is ``(..., heads,
+    v_dim)``."""
+    lead = (-1,) if seq_len is None else (-1, seq_len)
+    heads, nope, rope, rank = m.num_heads, m.nope_dim, m.rope_dim, m.kv_rank
+
+    def part(x, begin, end, what):
+        return sym.slice_axis(x, axis=-1, begin=begin, end=end,
+                              name="%s_%s" % (name, what))
+
+    def turn(x, what):
+        return sym._contrib_Rotary(x, positions, theta=m.rotary_theta,
+                                   name="%s_%s_rotary" % (name, what))
+
+    c_q = sym._contrib_RMSNorm(
+        _fc(h, m.q_rank, name + "_q_a"), _vec(name + "_q_a_norm_gamma",
+                                              m.q_rank),
+        eps=m.eps, name=name + "_q_a_norm")
+    q = sym.Reshape(_fc(c_q, heads * (nope + rope), name + "_q_b"),
+                    shape=lead + (heads, nope + rope))
+    q_n, q_r = part(q, 0, nope, "q_n"), turn(part(q, nope, nope + rope,
+                                                  "q_r"), "q")
+    ckr = _fc(h, rank + rope, name + "_kv_a")
+    c = sym._contrib_RMSNorm(
+        part(ckr, 0, rank, "c"), _vec(name + "_kv_a_norm_gamma", rank),
+        eps=m.eps, name=name + "_kv_a_norm")
+    # ONE rotated key a row, whatever the head
+    k_r = sym.Reshape(part(ckr, rank, rank + rope, "k_r"),
+                      shape=lead + (1, rope))
+    k_r = sym.Reshape(turn(k_r, "k"), shape=(-1, rope))
+    latent = sym.Reshape(sym.Concat(c, k_r, dim=1, num_args=2,
+                                    name=name + "_latent"),
+                         shape=lead + (rank + rope,))
+    w_kvb = sym.Variable(name + "_kv_b_weight",
+                         shape=(heads * (nope + m.v_dim), rank))
+    att, extras = attend(q_n, q_r, latent, w_kvb, name + "_attn")
+    att = sym.Reshape(att, shape=(-1, heads * m.v_dim))
+    return _fc(att, m.hidden, name + "_o"), extras
+
+
+def _gated_mlp(h, m, width, name):
+    """The SiLU-gated MLP ``W_out (silu(W1 h) * W3 h)``, ``[W1 | W3]`` one
+    product: the dense feed-forward (``name`` ``layer<i>_mlp``) and the
+    shared expert (``layer<i>_shared``)."""
+    h = _fc(h, 2 * width, name + "_in")
+    h = sym._contrib_SiluGate(h, name=name + "_gate")
+    return _fc(h, m.hidden, name + "_out")
+
+
 def _experts(h, m, name, live):
     """The routed expert layer over rows ``h``; ``live`` (rows,) or None.
     Returns the rows and the router's load (num_experts,)."""
+    bias = [_vec(name + "_router_bias", m.num_experts)] if m.router_bias \
+        else []
+    # only what departs from the op's defaults is written into the graph
+    more = {} if m.router_bias else {"use_bias": False}
     ids, weights, load = sym._contrib_MoERouter(
         h, sym.Variable(name + "_router_weight",
                         shape=(m.num_experts, m.hidden)),
-        _vec(name + "_router_bias", m.num_experts),
-        *([] if live is None else [live]), use_live=live is not None,
+        *(bias + ([] if live is None else [live])),
+        use_live=live is not None,
         top_k=m.experts_per_token, normalize=m.norm_topk,
-        scale=m.routed_scaling, name=name + "_router")
+        scale=m.routed_scaling, name=name + "_router", **more)
     held, width = m.experts_held, m.expert_width
+    rows = h
     h = sym._contrib_RoutedExperts(
         h, ids, weights,
         sym.Variable(name + "_experts_w13",
@@ -333,6 +441,10 @@ def _experts(h, m, name, live):
         sym.Variable(name + "_experts_w2", shape=(held, width, m.hidden)),
         num_experts=m.num_experts, first_expert=m.first_expert,
         name=name + "_experts")
+    if m.shared_expert_width:
+        h = sym.elemwise_add(
+            _gated_mlp(rows, m, m.shared_expert_width, name + "_shared"), h,
+            name=name + "_ff")
     return h, load
 
 
@@ -344,19 +456,23 @@ def _block(x, m, i, seq_len, attend, carried, positions=None, live=None):
     h = _norm(x, m, name + "_norm1")
     if m.layer_types[i] == ATTENTION:
         h, extras = _attention_mixer(h, m, name, seq_len, attend, positions)
+    elif m.layer_types[i] == LATENT:
+        h, extras = _latent_mixer(h, m, name, seq_len, attend, positions)
     elif m.layer_types[i] == CONV:
         h, extras = _conv_mixer(h, m, name, seq_len, carried)
     else:
         h, extras = _mamba_mixer(h, m, name, seq_len, carried)
+    if m.sandwich_norm:
+        h = _norm(h, m, name + "_post_norm1")
     x = _residual(x, h, m, name + "_res1")
     h = _norm(x, m, name + "_norm2")
     load = None
     if i in m.expert_layers:
         h, load = _experts(h, m, name, live)
     else:
-        h = _fc(h, 2 * m.intermediate, name + "_mlp_in")
-        h = sym._contrib_SiluGate(h, name=name + "_mlp_gate")
-        h = _fc(h, m.hidden, name + "_mlp_out")
+        h = _gated_mlp(h, m, m.intermediate, name + "_mlp")
+    if m.sandwich_norm:
+        h = _norm(h, m, name + "_post_norm2")
     return _residual(x, h, m, name + "_res2"), extras, load
 
 
@@ -368,9 +484,12 @@ def _embed(ids, m, table):
 
 
 def _head(x, m, table):
-    """Final norm and the tied vocabulary projection: float32 logits by
-    rows."""
+    """Final norm and the vocabulary projection (the embedding's table, or
+    an untied head's own): float32 logits by rows."""
     x = _norm(x, m, "norm_f")
+    if not m.tied_head:
+        table = sym.Variable("lm_head_weight",
+                             shape=(m.vocab_size, m.hidden))
     return sym._contrib_ScaledLogits(x, table, scale=1.0 / m.logits_scaling,
                                      name="lm_head")
 
@@ -385,6 +504,11 @@ def _sequence_graph(m, seq_len, length):
             q, k, v, causal=True, scale=m.attention_multiplier,
             name=name), [k, v]
 
+    def latent(q_n, q_r, rows, w_kvb, name):
+        return sym._contrib_LatentAttention(
+            q_n, q_r, rows, w_kvb, scale=m.attention_multiplier,
+            name=name), [rows]
+
     table = _table(m)
     x = _embed(sym.Variable("data"), m, table)
     positions = live = None
@@ -398,7 +522,8 @@ def _sequence_graph(m, seq_len, length):
             sym.Reshape(length, shape=(-1, 1)), name="live"), shape=(-1,))
     carried = []
     for i in range(m.num_layers):
-        x, extras, _ = _block(x, m, i, seq_len, dense, length, positions,
+        attend = latent if m.layer_types[i] == LATENT else dense
+        x, extras, _ = _block(x, m, i, seq_len, attend, length, positions,
                               live)
         carried.extend(extras)
     return _head(x, m, table), carried
@@ -428,7 +553,8 @@ def get_hybrid_lm_prefill(model, seq_len):
 def get_hybrid_lm_decode(model, page_size=16):
     """One decode step, every lane one token.  Inputs ``data``,
     ``positions``, ``source``, ``prev_ids``, ``state_slot`` (lanes,),
-    ``page_table`` (lanes, max_pages) and the planes of
+    ``page_table`` (lanes, max_pages) (``state_slot`` only where a layer
+    carries a slot plane) and the planes of
     :meth:`HybridLM.planes`; outputs the logits (lanes, vocab), the planes
     in that order, then ``next_ids`` (lanes,) and :attr:`HybridLM.
     lane_extras`.  ``positions`` places the attention layers' K/V and, where
@@ -446,6 +572,15 @@ def get_hybrid_lm_decode(model, page_size=16):
     planes = {name: sym.Variable(name) for name, _, _, _ in m.planes()}
 
     def paged(i):
+        if m.layer_types[i] == LATENT:
+            def attend(q_n, q_r, rows, w_kvb, name):
+                att, pool = sym._contrib_PagedLatentAttention(
+                    q_n, q_r, rows, w_kvb, planes["layer%d_latent_pool" % i],
+                    page_table, positions, page_size=page_size,
+                    scale=m.attention_multiplier, name=name)
+                return att, [pool]
+            return attend
+
         def attend(q, k, v, name):
             att, k_out, v_out = sym._contrib_PagedAttention(
                 q, k, v, planes["layer%d_k_pool" % i],
@@ -456,13 +591,19 @@ def get_hybrid_lm_decode(model, page_size=16):
 
     table = _table(m)
     x = _embed(ids, m, table)
-    # a padded lane of the bucket is parked on the scratch slot: it routes
-    # to no expert
-    live = sym._greater_scalar(slot, scalar=0, name="live") \
-        if m.expert_layers else None
+    # a padded lane of the bucket is parked on the scratch slot (or, where
+    # no layer carries a slot, on the scratch page): it routes to no expert
+    live = None
+    if m.expert_layers and m.has_slots:
+        live = sym._greater_scalar(slot, scalar=0, name="live")
+    elif m.expert_layers:
+        live = sym._greater_scalar(sym.Reshape(
+            sym.slice_axis(page_table, axis=1, begin=0, end=1,
+                           name="first_page"), shape=(-1,)),
+            scalar=0, name="live")
     planes_out, loads = [], []
     for i, kind in enumerate(m.layer_types):
-        carried = {ATTENTION: None,
+        carried = {ATTENTION: None, LATENT: None,
                    CONV: (planes.get("layer%d_conv_tail" % i), slot),
                    MAMBA: (planes.get("layer%d_ssm_state" % i),
                            planes.get("layer%d_conv_tail" % i), slot)}[kind]

@@ -7,6 +7,7 @@ experts of inner width ``F``, ``k`` picks a row::
 
     s   = sigmoid(W_r g)                      float32, (rows, E)
     I   = top_k(s + bias)                     the selection alone sees bias
+                                              (a router may have none)
     w_i = scale * s_i / (sum_{j in I} s_j + 1e-6)   (``normalize``), i in I
     y   = sum_{i in I} w_i * W2_i (silu(W1_i g) * W3_i g)
 
@@ -49,10 +50,12 @@ ROUTER_EPS = 1e-6
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "normalize", "scale"))
-def route(rows, weight, bias, live=None, *, top_k, normalize=True, scale=1.0):
-    """``rows`` (n, H), ``weight`` (E, H), ``bias`` (E,), ``live`` (n,) or
-    None.  Returns ids (n, k) int32, weights (n, k) float32 and the load
-    (E,) int32: the live rows' picks by expert.  Scores are float32
+def route(rows, weight, bias=None, live=None, *, top_k, normalize=True,
+          scale=1.0):
+    """``rows`` (n, H), ``weight`` (E, H), ``bias`` (E,) or None (the top k
+    of the scores alone), ``live`` (n,) or None.  Returns ids (n, k) int32,
+    weights (n, k) float32 and the load (E,) int32: the live rows' picks by
+    expert.  Scores are float32
     whatever the rows' dtype (a near-tie must not be a tie of rounded
     scores); a row that is not live picks expert ``E`` with weight 0.
     Jitted on its own, as :func:`routed_experts` is: a program's 14 expert
@@ -62,7 +65,8 @@ def route(rows, weight, bias, live=None, *, top_k, normalize=True, scale=1.0):
     logits = lax.dot_general(rows, weight, (((1,), (1,)), ((), ())),
                              preferred_element_type=_F32)
     scores = jax.nn.sigmoid(logits)
-    _, ids = lax.top_k(scores + bias.astype(_F32), int(top_k))
+    _, ids = lax.top_k(
+        scores if bias is None else scores + bias.astype(_F32), int(top_k))
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     if normalize:
         picked = picked / (jnp.sum(picked, -1, keepdims=True) + ROUTER_EPS)
@@ -355,25 +359,30 @@ def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0,
 
 
 def _router_inputs(attrs):
-    return ["data", "weight", "bias"] + (["live"] if attrs.get("use_live")
-                                          else [])
+    return (["data", "weight"]
+            + (["bias"] if attrs.get("use_bias", True) else [])
+            + (["live"] if attrs.get("use_live") else []))
 
 
 @register("_contrib_MoERouter", inputs=_router_inputs,
           params={"top_k": Param(int, required=True),
                   "normalize": Param(bool, True),
                   "scale": Param(float, 1.0),
+                  "use_bias": Param(bool, True),
                   "use_live": Param(bool, False)},
           num_outputs=3, no_grad_inputs=("live",),
           output_names=lambda attrs: ["ids", "weights", "load"],
           hint="moerouter")
 @jax.named_scope("moe_router")
-def _moe_router(opctx, attrs, data, weight, bias, *live):
+def _moe_router(opctx, attrs, data, weight, *more):
     """:func:`route` as an op (sigmoid scores): reads ``data`` (rows, H),
-    ``weight`` (E, H), ``bias`` (E,) and, with ``use_live``, ``live``
-    (rows,; nonzero: the row routes); writes ``ids`` (rows, k) int32,
-    ``weights`` (rows, k) float32 and ``load`` (E,) int32."""
-    return route(data, weight, bias, live[0] if live else None,
+    ``weight`` (E, H), unless ``use_bias`` is off ``bias`` (E,) and, with
+    ``use_live``, ``live`` (rows,; nonzero: the row routes); writes ``ids``
+    (rows, k) int32, ``weights`` (rows, k) float32 and ``load`` (E,)
+    int32."""
+    more = list(more)
+    bias = more.pop(0) if attrs.get("use_bias", True) else None
+    return route(data, weight, bias, more[0] if more else None,
                  top_k=int(attrs["top_k"]),
                  normalize=bool(attrs.get("normalize", True)),
                  scale=float(attrs.get("scale", 1.0)))

@@ -35,6 +35,25 @@ Three ops:
   It keeps the gather on every platform: ``width`` queries a lane want a
   kernel of their own, and no benchmark cell runs it to judge one.
 
+Latent attention (one cached row a token and layer, ``[c | k_r]``: the
+compressed K/V after its norm and the one rotated key all heads share) has
+the same two places in a model, under two ops of its own:
+
+* ``_contrib_LatentAttention`` — a whole sequence, *expanded*: every head's
+  ``[k_n | v] = W_kvb c`` is made from the latent rows, the scores are ``q_n
+  . k_n + q_r . k_r``, a block of heads at a time so that ``heads x L x L``
+  scores never exist at once.
+
+* ``_contrib_PagedLatentAttention`` — one decode step over ONE paged plane
+  of latent rows, *absorbed*: ``W_kvb``'s key part goes into the query
+  (``q_c = q_n W_k``, ``rank`` wide), every head reads the same gathered
+  rows (``score = q_c . c + q_r . k_r``), the weighted sum of latents leaves
+  through ``W_kvb``'s value part.  The same mathematics as the expanded
+  form (tests/test_latent_lm.py holds them together), at ``rank +
+  rope`` values a token instead of ``heads x (nope + v)``.  An XLA
+  formulation over the gathered table (:data:`LATENT_FORMULATIONS`); a
+  kernel that walks the live pages is a later change's.
+
 Page 0 of the pool is reserved as a scratch page: inactive lanes carry
 an all-zero page-table row and position 0, so their (masked-out) writes
 land harmlessly in the scratch page and never corrupt a live sequence.
@@ -461,3 +480,177 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     return decode(q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
                   k_pool, v_pool, page_table.astype(jnp.int32),
                   positions.astype(jnp.int32), scale)
+
+
+# ---------------------------------------------------------------------------
+# latent attention
+# ---------------------------------------------------------------------------
+
+# heads, and queries, whose scores exist at once in the expanded form: at 8 x
+# 512 a block's float32 scores over 2,048 keys are 33 MB, which XLA works
+# through in one pass; 8 x 2,048 x 2,048 (134 MB) took sixteen times as long
+# for four times the work (PERF.md section 6, PR 41)
+_LATENT_HEAD_BLOCK, _LATENT_QUERY_BLOCK = 8, 512
+
+
+# what the two latent-attention ops run: XLA wherever the operands live
+# (``prefill``: the expanded form over blocks of heads and queries;
+# ``decode``: the absorbed form over the gathered table).  A kernel that
+# walks the live pages would make this an observation of the operands, as
+# :func:`decode_formulation` is.
+LATENT_FORMULATIONS = {"prefill": "xla-expanded-head-blocks",
+                       "decode": "xla-absorbed-gather"}
+
+
+def _softmax(s):
+    import jax.numpy as jnp
+
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    return p / p.sum(-1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def latent_attention(q_n, q_r, latent, weight, *, scale):
+    """The expanded form: ``q_n`` (b, s, heads, nope), ``q_r`` (b, s, heads,
+    rope) rotated, ``latent`` (b, s, rank + rope) ``[c | k_r]``, ``weight``
+    ``W_kvb`` (heads * (nope + v), rank).  Causal; returns (b, s, heads, v).
+    Scores and softmax float32, the products' operands in the rows' dtype."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, s, heads, nope = q_n.shape
+    rank = weight.shape[-1]
+    c, k_r = latent[..., :rank], latent[..., rank:]
+    block = max(d for d in range(1, _LATENT_HEAD_BLOCK + 1) if heads % d == 0)
+    f32 = jnp.float32
+
+    def blocks(x):  # (b, s, heads, d) -> (heads / block, b, s, block, d)
+        return jnp.moveaxis(
+            x.reshape(b, s, heads // block, block, x.shape[-1]), 2, 0)
+
+    def attend(operands):
+        qn, qr, w = operands  # w (block, nope + v, rank)
+        kv = jnp.einsum("btr,hmr->bthm", c, w,
+                        preferred_element_type=f32).astype(latent.dtype)
+        k_n, val = kv[..., :nope], kv[..., nope:]
+        out = []
+        # a block of queries against the keys up to its last row: nothing
+        # above the diagonal block is computed, and a block's scores
+        # (heads x queries x keys, float32) stay small enough to be one pass
+        for start in range(0, s, _LATENT_QUERY_BLOCK):
+            end = min(start + _LATENT_QUERY_BLOCK, s)
+            sc = (jnp.einsum("bqhd,bthd->bhqt", qn[:, start:end],
+                             k_n[:, :end], preferred_element_type=f32)
+                  + jnp.einsum("bqhr,btr->bhqt", qr[:, start:end],
+                               k_r[:, :end], preferred_element_type=f32)
+                  ) * scale
+            causal = (jnp.arange(start, end)[:, None]
+                      >= jnp.arange(end)[None, :])
+            p = _softmax(jnp.where(causal, sc, _NEG)).astype(val.dtype)
+            out.append(jnp.einsum("bhqt,bthd->bqhd", p, val[:, :end],
+                                  preferred_element_type=f32))
+        return jnp.concatenate(out, axis=1).astype(q_n.dtype)
+
+    out = lax.map(attend, (blocks(q_n), blocks(q_r),
+                           weight.reshape(heads // block, block, -1, rank)))
+    return jnp.moveaxis(out, 0, 2).reshape(b, s, heads, -1)
+
+
+def _latent_infer(attrs, shapes):
+    q_n, weight = shapes[0], shapes[3]
+    if q_n is None or weight is None:
+        return shapes, [None], []
+    return shapes, [q_n[:-1] + (weight[0] // q_n[-2] - q_n[-1],)], []
+
+
+@register("_contrib_LatentAttention",
+          inputs=("q_nope", "q_rope", "latent", "kv_b_weight"),
+          params={"scale": Param(float, required=True)},
+          infer_shape=_latent_infer, hint="latentattention")
+@jax.named_scope("latent_attention")
+def _latent_attention(opctx, attrs, q_n, q_r, latent, weight):
+    """:func:`latent_attention` as an op (a whole sequence, causal,
+    expanded)."""
+    return latent_attention(q_n, q_r, latent, weight,
+                            scale=float(attrs["scale"]))
+
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def paged_latent_attention(q_n, q_r, new, weight, pool, pt, pos, *, scale):
+    """The absorbed form, one token a lane: ``q_n`` (lanes, heads, nope),
+    ``q_r`` (lanes, heads, rope) rotated, ``new`` (lanes, rank + rope) this
+    step's own ``[c | k_r]``, ``weight`` ``W_kvb``, ``pool`` (num_pages,
+    page_size, rank + rope), ``pt`` (lanes, max_pages) and ``pos`` (lanes,)
+    int32.  Gathers every lane's table (as :func:`_gather_decode`), puts
+    this step's row into the copy at its position and writes it to the pool:
+    ``lanes`` rows.  Returns (lanes, heads, v) and the pool."""
+    import jax.numpy as jnp
+
+    lanes, heads, nope = q_n.shape
+    num_pages, ps, width = pool.shape
+    rank = weight.shape[-1]
+    f32 = jnp.float32
+    # a head's rows of W_kvb are [k_n | v]
+    w = weight.reshape(heads, -1, rank)
+    w_k, w_v = w[:, :nope], w[:, nope:]
+    new = new.astype(pool.dtype)
+    flat = pool.reshape(num_pages * ps, width)
+    idx = (pt[:, :, None] * ps
+           + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(lanes, -1)
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    rows = flat[idx].at[lane, pos].set(new)          # (lanes, T, width)
+    cur = jnp.take_along_axis(pt, (pos // ps)[:, None], axis=1)[:, 0]
+    flat = flat.at[cur * ps + pos % ps].set(new)     # inactive: page 0
+
+    # the key part of W_kvb goes into the query: rank wide, beside q_r the
+    # cached row's own layout
+    # (head-major operands: the host's XLA has no bfloat16 product with the
+    # batch axis in the middle)
+    q_c = jnp.einsum("hln,hnr->hlr", q_n.swapaxes(0, 1), w_k,
+                     preferred_element_type=f32).swapaxes(0, 1).astype(
+                         pool.dtype)
+    q = jnp.concatenate([q_c, q_r.astype(pool.dtype)], axis=-1)
+    s = jnp.einsum("lhw,ltw->lht", q, rows,
+                   preferred_element_type=f32) * scale
+    valid = jnp.arange(idx.shape[1], dtype=jnp.int32)[None, :] <= pos[:, None]
+    p = _softmax(jnp.where(valid[:, None, :], s, _NEG)).astype(pool.dtype)
+    o_c = jnp.einsum("lht,ltr->lhr", p, rows[..., :rank],
+                     preferred_element_type=f32).astype(pool.dtype)
+    out = jnp.einsum("lhr,hvr->lhv", o_c, w_v, preferred_element_type=f32)
+    return out.astype(q_n.dtype), flat.reshape(pool.shape)
+
+
+def _paged_latent_infer(attrs, shapes):
+    q_n, weight, pool = shapes[0], shapes[3], shapes[4]
+    if q_n is None or weight is None or pool is None:
+        return shapes, [None, None], []
+    return shapes, [q_n[:-1] + (weight[0] // q_n[-2] - q_n[-1],), pool], []
+
+
+@register("_contrib_PagedLatentAttention",
+          inputs=("q_nope", "q_rope", "latent", "kv_b_weight", "latent_pool",
+                  "page_table", "positions"),
+          params={"page_size": Param(int, required=True),
+                  "scale": Param(float, required=True)},
+          num_outputs=2, infer_shape=_paged_latent_infer,
+          no_grad_inputs=("page_table", "positions"),
+          output_names=lambda attrs: ["out", "latent_pool_out"],
+          hint="pagedlatentattention")
+@jax.named_scope("paged_attention")
+@jax.named_scope("paged_attention_latent")
+def _paged_latent_attention(opctx, attrs, q_n, q_r, latent, weight, pool,
+                            page_table, positions):
+    """:func:`paged_latent_attention` as an op.  Two scopes, one inside the
+    other: ``paged_attention`` for whoever reads the decode step's
+    attention whatever its kind, ``paged_attention_latent`` for this one."""
+    import jax.numpy as jnp
+
+    if int(attrs["page_size"]) != pool.shape[1]:
+        raise ValueError("page_size %s, but the pool's pages hold %d slots"
+                         % (attrs["page_size"], pool.shape[1]))
+    if latent.shape[-1] != pool.shape[2]:
+        raise ValueError("this step's latent row is %d wide, the pool's %d"
+                         % (latent.shape[-1], pool.shape[2]))
+    return paged_latent_attention(
+        q_n, q_r, latent, weight, pool, page_table.astype(jnp.int32),
+        positions.astype(jnp.int32), scale=float(attrs["scale"]))

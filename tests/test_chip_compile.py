@@ -461,14 +461,16 @@ def test_routed_experts_cost_their_pairs_on_v5e(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def _leaves_go_whole_into(text, kernel, calls):
-    """Each stacked leaf of the cell's experts is used by ``calls``
+def _leaves_go_whole_into(text, kernel, calls,
+                          leaves=("bf16[32,2048,3584]", "bf16[32,1792,2048]")):
+    """Each stacked leaf of the cell's experts (the LFM2 cell's unless
+    ``leaves`` says otherwise) is used by ``calls``
     instructions of the compiled module and no other, every one a call of
     ``kernel``: whole, in bfloat16, where it lies (no copy, slice, convert,
     transpose or gather of it, and no float32 twin)."""
     import re
 
-    for leaf in ("bf16[32,2048,3584]", "bf16[32,1792,2048]"):
+    for leaf in leaves:
         uses = [line for line in text.splitlines()
                 if leaf in line and re.match(r"\s*(ROOT )?%[\w.\-]+ = ", line)
                 and " parameter(" not in line]
@@ -546,5 +548,80 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     assert len(scoped) == 3 and all(
         re.search(r"layer\d+_experts/moe_experts/", s) for s in scoped), scoped
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
+def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
+    """The latent-attention family's decode step at openPangu-Ultra-MoE's
+    widths, one dense and one expert layer (16 of 256 experts held, a shared
+    expert beside them, sandwich norms, an untied head), as the engine's
+    Executor builds it, compiled for the chip.  ONE latent plane a layer,
+    aliased in and out; the absorbed attention under ``paged_attention/
+    paged_attention_latent`` (both readers' scopes); the routed products the
+    kernel ``moe_grouped`` at 7680 x 2048 (its tiles fit), the shared expert
+    the dense MLP's three ops under ``layer<i>_shared_*``; no
+    ``state_slot``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import compile_cache
+    from mxnet_tpu.models import HybridLM
+    from mxnet_tpu.ops.interpret import bind
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    lanes, pages, max_pages, vocab = 16, 40, 152, 512
+    model = HybridLM(
+        vocab_size=vocab, hidden=7680, layer_types=["latent"] * 2,
+        num_heads=128, kv_heads=128, head_dim=192, nope_dim=128, rope_dim=64,
+        v_dim=128, q_rank=1536, kv_rank=512, intermediate=18432,
+        rotary_theta=25.6e6, num_experts=256, experts_per_token=8,
+        expert_width=2048, num_dense_layers=1, experts_held=16,
+        routed_scaling=2.5, router_bias=False,
+        shared_expert_width=2048, sandwich_norm=True, tied_head=False)
+    symbol = model.decode_symbol(2432, 16)
+    assert "state_slot" not in symbol.list_arguments()
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        assert (kind, shape) == ("paged", (576,))
+        shapes[name] = (pages, 16) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    for name in symbol.list_arguments():
+        if name not in shapes:
+            types[name] = jnp.bfloat16  # the weights
+    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
+                            **shapes)
+    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
+    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
+    call = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ex._forward_args(None))
+    fwd = ex._get_fwd(False)
+    assert ex.carry_donated
+    compiled = getattr(fwd, "_fn", fwd).lower(*call).compile()
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
+        == [((lanes, vocab), "float32"), ((lanes,), "float32"),
+            ((1, 256), "int32")]
+    assert len(outs) == 3 + len(planes) == 3 + 2
+    text = compiled.as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert [n.split(".")[0] for n in names] == ["moe_grouped"]
+    _leaves_go_whole_into(text, "moe_grouped", calls=1,
+                          leaves=("bf16[16,7680,4096]", "bf16[16,2048,7680]"))
+    ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
+    assert any("layer1_experts/moe_experts/" in o for o in ops)
+    for part in ("in", "gate", "out"):
+        assert any(("layer1_shared_%s/" % part) in o for o in ops), part
+    assert any(re.search(r"layer\d_attn/paged_attention/"
+                         r"paged_attention_latent/", o) for o in ops)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)

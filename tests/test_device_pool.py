@@ -130,12 +130,11 @@ def test_a_paged_plane_may_stand_alone():
               ("layer2_latent_pool", "paged", (6,), np.float32)]
     pool = PagedKVPool(num_pages=8, page_size=PAGE, planes=planes,
                        prefix_cache_pages=4)
-    assert pool.num_layers == 3 and pool.num_slots == 0
+    assert pool.num_layers == 1 and pool.num_slots == 0
     assert len(pool.k_pools) == len(pool.v_pools) == 1
     assert pool.k_pools[0] is pool.planes()[1]
     assert len(pool.paged_planes()) == 4
-    assert pool.token_bytes() == (6 + 8 + 8 + 6) * 4
-    assert pool.device_bytes() == 8 * PAGE * pool.token_bytes()
+    assert pool.device_bytes() == 8 * PAGE * (6 + 8 + 8 + 6) * 4
     pool.alloc("a", 6)
     assert len(pool.extend("a", 9)) == 3
     r = np.random.RandomState(0)

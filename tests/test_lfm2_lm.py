@@ -326,10 +326,12 @@ def test_the_family_says_which_planes_and_outputs_it_carries():
     assert dense.decode_symbol(S, 4).list_outputs()[-1] == "next_ids_output"
     with pytest.raises(ValueError, match="experts: 9 a token of 8"):
         HybridLM(**dict(spec, experts_per_token=9))
-    # a padded lane is known by its scratch state slot: experts without a
-    # slot plane are refused by name
-    with pytest.raises(ValueError, match="scratch state slot"):
-        HybridLM(**dict(spec, layer_types=["attention"] * 3))
+    # a padded lane is known by its scratch state slot; a description whose
+    # layers carry no slot plane knows it by its scratch page instead
+    # (tests/test_latent_lm.py runs one) and takes no state_slot
+    paged = HybridLM(**dict(spec, layer_types=["attention"] * 3))
+    assert not paged.has_slots and fam.has_slots
+    assert "state_slot" not in paged.decode_symbol(S, 4).list_arguments()
     assert "state_slot" in fam.decode_symbol(S, 4).list_arguments()
 
 
