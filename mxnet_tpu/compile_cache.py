@@ -544,7 +544,8 @@ class CachedFunction:
     """
 
     __slots__ = ("_fn", "_kind", "_static_key", "_executor", "_by_sig",
-                 "records", "digest", "meta", "cost_info", "cache_state")
+                 "records", "digest", "meta", "cost_info", "collectives",
+                 "cache_state")
 
     def __init__(self, fn, kind: str, static_key, executor):
         self._fn = fn
@@ -559,6 +560,7 @@ class CachedFunction:
         self.digest = None
         self.meta = None
         self.cost_info = None
+        self.collectives = None
         self.cache_state = None  # "hit" | "miss" | "bypass"
 
     # delegation keeps telemetry.lower_and_analyze / perf_probe working
@@ -627,6 +629,7 @@ class CachedFunction:
         self.digest = digest
         self.meta = meta
         self.cost_info = (meta or {}).get("cost") or None
+        self.collectives = (meta or {}).get("collectives") or None
         if digest is not None:
             self.records.append(
                 {"digest": digest, "meta": meta, "compiled": compiled})
@@ -689,6 +692,11 @@ class CachedFunction:
             "created": round(time.time(), 3),
             "compile_ms": round(compile_ms, 1),
             "cost": cost,
+            # of a program over several devices: its collectives and how
+            # many of them run asynchronously, read from the fresh
+            # executable as the cost is (the step monitor's gauges)
+            "collectives": _collectives_of(compiled) if len(devs) > 1
+            else None,
         }
 
 
@@ -696,6 +704,15 @@ def _cost_of(compiled) -> Optional[dict]:
     from .hlo_analysis import cost_analysis
 
     return cost_analysis(compiled)
+
+
+def _collectives_of(compiled) -> Optional[dict]:
+    from .hlo_analysis import collective_counts
+
+    try:
+        return collective_counts(compiled.as_text())
+    except Exception:
+        return None
 
 
 def maybe_cached(fn, kind: str, static_key, executor):

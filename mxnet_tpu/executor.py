@@ -610,16 +610,24 @@ class Executor:
                 # process deserializes it.  The default (cache off) keeps
                 # in-place buffer reuse.
                 donate_allowed = not _cc.active()
+                # the mesh this executor was given decides what the step
+                # is compiled with: data-parallel over several TPUs, gradient
+                # all-reduces that run under the backward pass; a step that
+                # partition rules shard (_shard_mesh) is compiled as before
+                from .sharding import collective_compiler_options
+
+                options = {} if self._shard_mesh is not None else \
+                    collective_compiler_options(self._kernel_mesh[0])
 
                 def make_jit(remat, donate_on):
                     donate = (0, 1, 2) if (donate_on and donate_allowed) \
                         else ()
-                    fn = make_fn(remat)
+                    kw = {"compiler_options": options} if options else {}
                     if shardings is not None:
-                        return jax.jit(fn, donate_argnums=donate,
-                                       in_shardings=shardings[0],
-                                       out_shardings=shardings[1])
-                    return jax.jit(fn, donate_argnums=donate)
+                        kw.update(in_shardings=shardings[0],
+                                  out_shardings=shardings[1])
+                    return jax.jit(make_fn(remat), donate_argnums=donate,
+                                   **kw)
 
                 remat, donate_on = env_remat, donate_allowed
                 tuned = self._autotune_fused(stable_key, abstract_args,
@@ -633,13 +641,17 @@ class Executor:
                 jfn = make_jit(remat, donate_on)
                 # the persistent key uses stable_key (no object ids) so a
                 # fresh process — or a fresh optimizer instance with the
-                # same hypers — maps to the same disk entry; donation and
-                # remat change the compiled program, so they are part of
-                # the key
+                # same hypers — maps to the same disk entry; donation, remat
+                # and the compiler's options change the compiled program,
+                # so they are part of the key (a step with no options keeps
+                # the key it had)
                 donate = (0, 1, 2) if (donate_on and donate_allowed) else ()
                 if stable_key is not None:
                     stable_key = stable_key + (("donate", tuple(donate)),
                                                ("remat", int(remat)))
+                    if options:
+                        stable_key += (("compiler_options",
+                                        tuple(sorted(options.items()))),)
                 self._jit_cache[key] = self._first_call(key, _cc.maybe_cached(
                     jfn, "fused", stable_key, self), "fused")
         return self._jit_cache[key]
@@ -669,10 +681,12 @@ class Executor:
         self._guard_verdict = verdict if guard else None
         if first_build and not self._naive:
             # when the compile cache primed this executable, XLA's cost
-            # analysis rode along (entry meta on hits, read once from the
-            # fresh Compiled on misses) — StepMonitor consumes this instead
-            # of re-lowering+re-compiling the program
+            # analysis and, over a mesh, its count of collectives rode along
+            # (entry meta on hits, read once from the fresh Compiled on
+            # misses) — StepMonitor consumes these instead of
+            # re-lowering+re-compiling the program
             self._fused_cost_info = getattr(fn, "cost_info", None)
+            self._fused_collectives = getattr(fn, "collectives", None)
 
         with _prof.Frame("Executor.fused_step:rebind", "exec"):
             for name, idx, _, _ in infos:

@@ -18,8 +18,8 @@ from typing import Optional
 from .base import env, register_env
 
 __all__ = ["peak_flops", "hbm_bytes_per_s", "cost_analysis",
-           "lower_and_analyze", "roofline_ms", "hlo_op_counts", "op_scopes",
-           "bn_fusion_analysis"]
+           "lower_and_analyze", "roofline_ms", "hlo_op_counts",
+           "collective_counts", "op_scopes", "bn_fusion_analysis"]
 
 register_env("MXNET_TELEMETRY_HBM_GBS", 0.0, float,
              "HBM bandwidth (GB/s) for the roofline bytes term; "
@@ -113,6 +113,36 @@ def hlo_op_counts(hlo_text, interesting=None) -> dict:
     if interesting is None:
         return dict(ops)
     return {k: v for k, v in ops.most_common() if k in interesting}
+
+
+_COLLECTIVE_OP_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*?[\]})] "
+    r"(?:all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\((?:[^\n]*?channel_id=(\d+))?")
+
+
+def collective_counts(hlo_text) -> dict:
+    """``{"collectives", "asynchronous"}`` of a compiled module's text: its
+    collectives (one per channel, not per opcode: the TPU compiler runs an
+    asynchronous one as a chain of ``async_collective_fusion`` computations,
+    each a step of the same all-reduce fused with the compute it runs
+    under), and how many of them run asynchronously (such a chain, or a
+    ``-start`` / ``-done`` pair).  One the scheduler found nothing to run
+    under is a plain, synchronous op again and counts as that."""
+    seen, asynchronous = set(), set()
+    computation = ""
+    for line in hlo_text.splitlines():
+        if line[:1] not in ("", " ", "}"):  # a computation's header
+            computation = line.replace("ENTRY ", "", 1).lstrip("%")
+            continue
+        m = _COLLECTIVE_OP_RE.match(line)
+        if not m:
+            continue
+        key = m.group(3) or m.group(1)
+        seen.add(key)
+        if m.group(2) or computation.startswith("async_collective_fusion"):
+            asynchronous.add(key)
+    return {"collectives": len(seen), "asynchronous": len(asynchronous)}
 
 
 _SCOPED_RE = re.compile(

@@ -36,8 +36,9 @@ from .mesh import MeshConfig, build_mesh, mesh_axes, mesh_fingerprint
 from .rules import (PartitionRules, PRESETS, as_rules,
                     explain_partition_rules, get_preset,
                     match_partition_rules)
-from .placement import (gather_params, make_shardings, param_bytes, place,
-                        shard_params, spec_shard_factor, validate_specs)
+from .placement import (collective_compiler_options, gather_params,
+                        make_shardings, param_bytes, place, shard_params,
+                        spec_shard_factor, validate_specs)
 
 __all__ = [
     "MeshConfig", "build_mesh", "mesh_axes", "mesh_fingerprint",
@@ -45,6 +46,7 @@ __all__ = [
     "match_partition_rules", "explain_partition_rules",
     "shard_params", "gather_params", "make_shardings", "place",
     "param_bytes", "spec_shard_factor", "validate_specs",
+    "collective_compiler_options",
 ]
 
 register_env("MXNET_SHARDING_MESH", "", str,

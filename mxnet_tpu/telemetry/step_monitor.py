@@ -23,7 +23,8 @@ import warnings
 from typing import Optional
 
 from ..base import env
-from ..hlo_analysis import lower_and_analyze, peak_flops
+from ..hlo_analysis import (collective_counts, lower_and_analyze,
+                            peak_flops)
 
 __all__ = ["StepMonitor", "RecompileWarning", "peak_flops",
            "lower_and_analyze", "fused_cost_analysis"]
@@ -33,20 +34,33 @@ class RecompileWarning(UserWarning):
     """The fused train step recompiled after warmup (shape change)."""
 
 
-def fused_cost_analysis(executor):
-    """Cost analysis of an executor's last-compiled fused step, or None.
+def _fused_analysis(executor):
+    """(cost analysis, collective counts) of an executor's last-compiled
+    fused step; either may be None, and the counts are None for a step over
+    one device.
 
     When the persistent compile cache primed the step it already carries
-    XLA's cost analysis (read once from the fresh executable on a miss,
-    or from the cache-entry metadata on a hit) — use that and skip the
+    both (read once from the fresh executable on a miss, or from the
+    cache-entry metadata on a hit) — use those and skip the
     re-lower+re-compile entirely, which is what keeps a warm-cache cold
-    start at zero compiler invocations even with telemetry on."""
+    start at zero compiler invocations even with telemetry on.  Otherwise
+    the one re-compile serves both."""
     info = getattr(executor, "_fused_cost_info", None)
     if info and info.get("flops"):
-        return info
+        return info, getattr(executor, "_fused_collectives", None)
     fn, abstract = getattr(executor, "_fused_introspect", (None, None))
-    _, info = lower_and_analyze(fn, abstract)
-    return info
+    compiled, info = lower_and_analyze(fn, abstract)
+    counts = None
+    if compiled is not None and \
+            len(compiled.runtime_executable().local_devices()) > 1:
+        counts = collective_counts(compiled.as_text())
+    return info, counts
+
+
+def fused_cost_analysis(executor):
+    """Cost analysis of an executor's last-compiled fused step, or None
+    (:func:`_fused_analysis`)."""
+    return _fused_analysis(executor)[0]
 
 
 def _batch_signature(data_batch):
@@ -184,14 +198,25 @@ class StepMonitor:
                            data_wait_ms=round(self._data_wait_ms, 3))
 
     def note_compile(self, executor):
-        """Compile-miss path: one XLA cost analysis per new executable."""
+        """Compile-miss path: one XLA cost analysis per new executable and,
+        for a step over a mesh, its collectives and how many of them the
+        compiler runs asynchronously (whether
+        ``sharding.collective_compiler_options`` engaged)."""
         self.c_compiles.inc()
         if not env("MXNET_TELEMETRY_MFU", 1, int):
             return
         try:
-            info = fused_cost_analysis(executor)
+            info, collectives = _fused_analysis(executor)
         except Exception:
-            info = None
+            info = collectives = None
+        if collectives:
+            reg = self._tm.registry()
+            reg.gauge("mxtpu_fused_step_collectives",
+                      "Collectives in the compiled fused step under the "
+                      "mesh").set(collectives["collectives"])
+            reg.gauge("mxtpu_fused_step_collectives_async",
+                      "Of those, the ones the compiler runs asynchronously, "
+                      "under other work").set(collectives["asynchronous"])
         if info and info.get("flops"):
             self._flops_per_step = float(info["flops"])
             self._tm.log_event("compile", flops=self._flops_per_step,

@@ -25,13 +25,12 @@ GRIDS = {"lm_train": LM_TRAIN, "lm_train_bk1024": LM_TRAIN_BK1024,
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
     try:
@@ -42,9 +41,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _qkv(grid, sharding):
@@ -625,3 +631,72 @@ def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_data_parallel_step_reduces_under_its_compute(topo, monkeypatch,
+                                                       chips):
+    """A ``Module``'s fused step (three 2048-wide layers, momentum SGD,
+    float32) as the executor builds it, compiled for the described chips.
+    Bound over four of them, the mesh's compile options engage and the
+    gradient all-reduces run asynchronously, as chains of
+    ``async_collective_fusion`` steps; bound to one chip no option is set
+    and the text holds no collective.  (The arrays live on host devices,
+    where a described device can hold none: the executor is handed the
+    described mesh and the arguments' shapes carry its shardings.)"""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.hlo_analysis import collective_counts
+    from mxnet_tpu.ops.interpret import bind
+
+    net = mx.sym.Variable("data")
+    for i in range(3):
+        net = mx.sym.FullyConnected(net, num_hidden=2048, name="fc%d" % i)
+        net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(chips)])
+    mod.bind(data_shapes=[("data", (32 * chips, 2048))],
+             label_shapes=[("softmax_label", (32 * chips,))])
+    mod.init_params()
+    mod.init_optimizer(kvstore="local", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    ex = mod._exec_group.execs[0]
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    if chips > 1:
+        ex._kernel_mesh = (mesh, "data")
+        ex._bound = lambda fn: bind(fn, "tpu", mesh, "data")
+    else:
+        ex._bound = lambda fn: bind(fn, "tpu")
+    seen = []
+    real_jit = jax.jit
+
+    def spy(fn, **kw):
+        seen.append(kw.get("compiler_options"))
+        return real_jit(fn, **kw)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "jit", spy)
+        program, args, *_ = ex._fused_pack(mod._optimizer, mod._updater,
+                                           mod._exec_group.param_names)
+
+    def described(x):
+        spec = getattr(getattr(x, "sharding", None), "spec",
+                       PartitionSpec())
+        return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = program.lower(*jax.tree_util.tree_map(described, args)) \
+        .compile().as_text()
+    counts = collective_counts(text)
+    if chips == 1:
+        assert seen == [None]
+        assert counts == {"collectives": 0, "asynchronous": 0}
+        return
+    assert seen == [mx.sharding.collective_compiler_options(mesh)] and seen[0]
+    # three weights of 16.8 MB, each a reduction of its own; the biases'
+    assert counts["collectives"] >= 3
+    assert counts["asynchronous"] >= 1
+    assert "async_collective_fusion" in text

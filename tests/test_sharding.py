@@ -381,3 +381,188 @@ def test_bind_rejects_uneven_rule_split():
                  label_shapes=[("softmax_label", (8,))],
                  mesh="data=-1,model=2",
                  partition_rules=rules)
+
+
+# ----------------------------------------------------------------------
+# what a step over a mesh is compiled with
+# ----------------------------------------------------------------------
+class _StubMesh:
+    """All ``collective_compiler_options`` reads of a mesh: its devices'
+    platforms and processes, and its shape."""
+
+    def __init__(self, platforms, shape=None, processes=None):
+        processes = processes or [0] * len(platforms)
+        self.devices = np.array(
+            [type("Device", (), {"platform": p, "process_index": i})()
+             for p, i in zip(platforms, processes)],
+            dtype=object).reshape(shape or len(platforms))
+
+
+@pytest.mark.parametrize("mesh,engaged", [
+    ("none", False), ("one_cpu", False), ("cpu", False), ("cpu_2d", False),
+    ("one_tpu", False), ("mixed", False), ("tpu_2x2", False),
+    ("tpu_two_hosts", False), ("tpu", True)])
+def test_collective_compiler_options_follow_the_mesh(mesh, engaged):
+    """``{}`` for no mesh, one device and host devices (an ``xla_tpu_*``
+    option is an error to the CPU compiler), and for what was not measured
+    (a mesh of two axes, a mesh over two processes); the
+    asynchronous-collective set where several TPUs of this process make one
+    data axis.  Nothing but the mesh decides."""
+    import jax
+
+    meshes = {
+        "none": lambda: None,
+        "one_cpu": lambda: sharding.build_mesh(
+            "data=1", devices=jax.devices()[:1]),
+        "cpu": lambda: sharding.build_mesh("data=-1"),
+        "cpu_2d": lambda: sharding.build_mesh("data=-1,model=2"),
+        "one_tpu": lambda: _StubMesh(["tpu"]),
+        "mixed": lambda: _StubMesh(["tpu", "cpu"]),
+        "tpu_2x2": lambda: _StubMesh(["tpu"] * 4, (2, 2)),
+        "tpu_two_hosts": lambda: _StubMesh(["tpu"] * 4,
+                                           processes=[0, 0, 1, 1]),
+        "tpu": lambda: _StubMesh(["tpu"] * 4),
+    }
+    options = sharding.collective_compiler_options(meshes[mesh]())
+    if not engaged:
+        assert options == {}
+        return
+    assert options["xla_enable_async_all_reduce"] is True
+    assert options[
+        "xla_tpu_enable_async_collective_fusion_fuse_all_reduce"] is True
+    # a copy: a caller's edit does not reach the next program
+    options.clear()
+    assert sharding.collective_compiler_options(meshes[mesh]())
+
+
+def _fused_program(mod):
+    ex = mod._exec_group.execs[0]
+    (program,) = [v for k, v in ex._jit_cache.items() if k[0] == "fused"]
+    return program
+
+
+_CPU_OPTION = {"xla_cpu_enable_fast_min_max": False}
+
+
+@pytest.mark.parametrize("path,options", [
+    ("contexts", {}), ("contexts", _CPU_OPTION), ("rules", _CPU_OPTION)])
+def test_compiler_options_join_the_stable_key(path, options, monkeypatch,
+                                              tmp_path):
+    """The options are part of the program: they reach ``jax.jit`` and the
+    persistent key of the framework's compile cache; a step compiled with
+    none keeps the key it had (an option the CPU compiler knows stands in
+    for the TPU's).  A step that partition rules shard is never given any:
+    only the data-parallel step was measured with them."""
+    import jax
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setattr(sharding, "collective_compiler_options",
+                        lambda mesh: dict(options))
+    seen = []
+    real_jit = jax.jit
+
+    def spy(fn, **kw):
+        seen.append(kw.get("compiler_options"))
+        return real_jit(fn, **kw)
+
+    monkeypatch.setattr(jax, "jit", spy)
+    if path == "contexts":
+        mod = mx.mod.Module(_mlp(), context=[mx.cpu(i) for i in range(4)])
+        how = {}
+    else:
+        mod = mx.mod.Module(_mlp(), context=mx.cpu())
+        how = {"mesh": sharding.build_mesh("data=-1,model=2"),
+               "partition_rules": MLP_RULES}
+    mod.bind(data_shapes=[("data", (16, 64))],
+             label_shapes=[("softmax_label", (16,))], **how)
+    shapes = {"data": (16, 64), "softmax_label": (16,)}
+    mod.set_params(*_init_params(_mlp(), shapes))
+    _train(mod, _batches((16, 64), (16,), 1))
+    program = _fused_program(mod)
+    assert isinstance(program, compile_cache.CachedFunction)
+    keyed = [part for part in program._static_key
+             if isinstance(part, tuple) and part[:1] == ("compiler_options",)]
+    if options and path == "contexts":
+        assert options in seen
+        assert keyed == [("compiler_options", tuple(sorted(options.items())))]
+    else:
+        assert set(seen) == {None} and not keyed
+    assert program._static_key[-1 - len(keyed)] == ("remat", 0)
+
+
+@pytest.mark.parametrize("cached", [False, True])
+def test_data_parallel_contexts_note_their_collectives(cached, monkeypatch,
+                                                       tmp_path):
+    """A module bound over several contexts counts, at its fused step's
+    first build, the collectives of the compiled step and how many run
+    asynchronously: on host devices the gradient all-reduce, and none.  The
+    step monitor sets the pair beside the cost analysis: from the one
+    re-compile that serves both or, where the compile cache built or loaded
+    the step, from its entry's metadata with no compile of its own."""
+    import mxnet_tpu.telemetry as telemetry
+    from mxnet_tpu.telemetry import step_monitor
+
+    if cached:
+        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    analyses = []
+    real = step_monitor.lower_and_analyze
+    monkeypatch.setattr(step_monitor, "lower_and_analyze",
+                        lambda *a: analyses.append(a) or real(*a))
+    snaps = []
+    for _ in range(2):  # the second module's step is a hit where cached
+        telemetry._reset_for_tests()
+        telemetry.enable()
+        try:
+            mod = mx.mod.Module(_mlp(),
+                                context=[mx.cpu(i) for i in range(4)])
+            mod.bind(data_shapes=[("data", (16, 64))],
+                     label_shapes=[("softmax_label", (16,))])
+            shapes = {"data": (16, 64), "softmax_label": (16,)}
+            mod.set_params(*_init_params(_mlp(), shapes))
+            _train(mod, _batches((16, 64), (16,), 2))
+            snaps.append(telemetry.registry().snapshot())
+        finally:
+            telemetry._reset_for_tests()
+    assert len(analyses) == (0 if cached else 2)
+    for snap in snaps:
+        assert snap["mxtpu_fused_step_collectives"] >= 1
+        assert snap["mxtpu_fused_step_collectives_async"] == 0
+
+
+@pytest.mark.parametrize("case", ["chain", "pair", "synchronous", "none"])
+def test_collective_counts_reads_a_compiled_text(case):
+    """One collective per channel: the steps of an asynchronous chain are
+    one all-reduce, a start/done pair is one, and one the scheduler turned
+    synchronous again (it keeps ``async_collective_name``) is not
+    asynchronous."""
+    from mxnet_tpu.hlo_analysis import collective_counts
+
+    ar = ("  %%all-reduce.%d = bf16[8,8]{1,0} all-reduce(%%p), channel_id=%d, "
+          "replica_groups=[1,4]<=[4], to_apply=%%add%s\n")
+    texts = {
+        "chain": (
+            "HloModule m\n\n%async_collective_fusion.1 (p: bf16[8,8]) -> "
+            "bf16[8,8] {\n" + ar % (1, 7, ', frontend_attributes={chain_id="0"}')
+            + "}\n\n%async_collective_fusion.2 (p: bf16[8,8]) -> bf16[8,8] {\n"
+            + ar % (2, 7, ', frontend_attributes={chain_id="0"}')
+            + "}\n\nENTRY %main (p: bf16[8,8]) -> bf16[8,8] {\n"
+            + ar % (3, 9, "") + "}\n", (2, 1)),
+        "pair": (
+            "ENTRY %main (p: f32[8]) -> f32[8] {\n"
+            "  %ars = f32[8]{0} all-reduce-start(%p), channel_id=3, "
+            "to_apply=%add\n"
+            "  ROOT %ard = f32[8]{0} all-reduce-done(%ars)\n}\n", (1, 1)),
+        "synchronous": (
+            "ENTRY %main (p: bf16[8,8]) -> bf16[8,8] {\n"
+            + ar % (1, 4, ', frontend_attributes={async_collective_name='
+                          '"all-reduce-start.5"}')
+            + "  %t = (f32[2]{0}, f32[4]{0}) all-reduce(%a, %b), "
+              "channel_id=5, to_apply=%add\n}\n", (2, 0)),
+        "none": ("ENTRY %main (p: f32[8]) -> f32[8] {\n"
+                 "  ROOT %n = f32[8]{0} negate(%p)\n}\n", (0, 0)),
+    }
+    text, (collectives, asynchronous) = texts[case]
+    assert collective_counts(text) == {"collectives": collectives,
+                                       "asynchronous": asynchronous}
