@@ -407,6 +407,9 @@ class _GenMetrics:
         # the expert layers)
         self.expert_picks = reg.counter("mxtpu_gen_expert_picks")
         self.g_experts_hit = reg.gauge("mxtpu_gen_experts_hit")
+        # the picks of the last step read that landed on experts held here:
+        # the pairs the grouped products multiply
+        self.g_expert_pairs_held = reg.gauge("mxtpu_gen_expert_pairs_held")
         # a family with latent attention: the latent rows the last step
         # dispatched had to read (live tokens x a token's bytes over the
         # latent planes)
@@ -1642,13 +1645,17 @@ class DecodeEngine:
         step just read, over the router's whole width.  On the span that
         read it: the experts HELD HERE with at least one pick (summed over
         the layers; a family that holds a share of the experts fetches no
-        other), the (lane, pick) pairs, and the bytes of the hit experts'
-        weights -- what a kernel that skips idle experts would fetch."""
+        other), the (lane, pick) pairs, those of them that land on held
+        experts (what the grouped products multiply), and the bytes of the
+        hit experts' weights -- what a kernel that skips idle experts would
+        fetch."""
         first = int(getattr(self.family, "first_expert", 0))
         held = getattr(self.family, "experts_held", None) or load.shape[1]
-        hit = int((load[:, first:first + held] > 0).sum())
-        pairs = int(load.sum())
+        mine = load[:, first:first + held]
+        hit = int((mine > 0).sum())
+        pairs, pairs_held = int(load.sum()), int(mine.sum())
         span.set(experts_hit=hit, expert_pairs=pairs,
+                 expert_pairs_held=pairs_held,
                  expert_bytes=hit * self.family.expert_bytes())
         with self._cv:
             self._expert_load = load.astype(np.int64) + (
@@ -1657,6 +1664,7 @@ class DecodeEngine:
             self._experts_hit_total += hit
         self.metrics.expert_picks.inc(pairs)
         self.metrics.g_experts_hit.set(hit)
+        self.metrics.g_expert_pairs_held.set(pairs_held)
 
     def _spec_step(self, active: List[_Seq], span):
         """One speculative iteration: draft K proposals per steady lane,

@@ -52,7 +52,9 @@ always did, byte for byte: tests/test_hybrid_lm.py pins them):
   ``(head_dim,)``) and ``rotary_theta`` (q and k rotated by their position
   after the norm and before K is written: the pages hold rotated keys);
 * feed-forward ``experts`` from layer ``num_dense_layers`` on (ops/moe.py):
-  sigmoid router with a selection bias, ``experts_per_token`` picks a row,
+  sigmoid router with a selection bias (``router_score="softmax"``, IBM
+  Granite 4.0-H's expert models: the top k of the logits weighted by the
+  softmax over the picked ones, no bias), ``experts_per_token`` picks a row,
   the experts ``first_expert .. first_expert + experts_held - 1`` computed
   here.  Rows that are not live (a padded lane, a position past a prompt's
   length) pick nothing.  The lane graph then returns, after ``next_ids``,
@@ -88,6 +90,7 @@ need one by name (generation/engine.py).
 import numpy as np
 
 from .. import symbol as sym
+from ..ops.moe import SCORES as _ROUTER_SCORES
 
 MAMBA, ATTENTION, CONV, LATENT = "mamba", "attention", "conv", "latent"
 # the kinds whose layers page what they cache (the rest hold a slot a lane)
@@ -110,7 +113,7 @@ class HybridLM:
     ``num_experts`` (0: every layer dense) / ``experts_per_token`` /
     ``expert_width`` / ``num_dense_layers`` / ``first_expert`` /
     ``experts_held`` (None: all) / ``norm_topk`` / ``routed_scaling`` /
-    ``router_bias`` / ``shared_expert_width`` the expert
+    ``router_bias`` / ``router_score`` / ``shared_expert_width`` the expert
     layers'; ``sandwich_norm`` and ``tied_head`` the block's and the
     head's (module docstring); ``dtype`` the K/V planes' and the convolution tails'
     (the weights'); the recurrent state is float32.
@@ -129,8 +132,8 @@ class HybridLM:
                    num_dense_layers=0, first_expert=0, experts_held=None,
                    norm_topk=True, routed_scaling=1.0, router_bias=True,
                    shared_expert_width=0, sandwich_norm=False,
-                   tied_head=True, q_rank=None, kv_rank=None, nope_dim=None,
-                   rope_dim=None, v_dim=None)
+                   tied_head=True, router_score="sigmoid", q_rank=None,
+                   kv_rank=None, nope_dim=None, rope_dim=None, v_dim=None)
     _REQUIRED = ("vocab_size", "hidden", "layer_types", "num_heads",
                  "kv_heads", "head_dim", "intermediate")
     _SSM = ("ssm_heads", "ssm_head_dim", "ssm_state")
@@ -187,6 +190,11 @@ class HybridLM:
             if self.shared_expert_width < 0:
                 raise ValueError("shared_expert_width %d"
                                  % self.shared_expert_width)
+            if self.router_score not in _ROUTER_SCORES or (
+                    self.router_score == "softmax" and self.router_bias):
+                raise ValueError("router_score: sigmoid, or softmax with "
+                                 "router_bias=False; got %r, router_bias %r"
+                                 % (self.router_score, self.router_bias))
         # a layer that carries a slot plane gives the lane graph its
         # ``state_slot``
         self.has_slots = not set(self.layer_types) <= set(_PAGED)
@@ -425,6 +433,8 @@ def _experts(h, m, name, live):
         else []
     # only what departs from the op's defaults is written into the graph
     more = {} if m.router_bias else {"use_bias": False}
+    if m.router_score != "sigmoid":
+        more["score"] = m.router_score
     ids, weights, load = sym._contrib_MoERouter(
         h, sym.Variable(name + "_router_weight",
                         shape=(m.num_experts, m.hidden)),

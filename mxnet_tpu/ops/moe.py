@@ -11,6 +11,15 @@ experts of inner width ``F``, ``k`` picks a row::
     w_i = scale * s_i / (sum_{j in I} s_j + 1e-6)   (``normalize``), i in I
     y   = sum_{i in I} w_i * W2_i (silu(W1_i g) * W3_i g)
 
+A second scoring rule (``score="softmax"``; IBM Granite 4.0-H's routed
+layers): the selection is over the float32 LOGITS and the weights are the
+softmax over the picked ones, which sum to one by construction (no bias, no
+eps; equal to the softmax over all ``E`` renormalised over the picks)::
+
+    z   = W_r g                               float32, (rows, E)
+    I   = top_k(z)
+    w_i = scale * exp(z_i - max_I z) / sum_{j in I} exp(z_j - max_I z)
+
 Two ops, so that a chip that holds a share of the experts routes over all of
 them and computes its own part (``first_expert`` and the held count, read
 from the stacked weights' leading axis): the parts of every share add up to
@@ -47,29 +56,48 @@ from .registry import register
 
 _F32 = jnp.float32
 ROUTER_EPS = 1e-6
+SCORES = ("sigmoid", "softmax")
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "normalize", "scale"))
+@functools.partial(jax.jit, static_argnames=("top_k", "normalize", "scale",
+                                             "score"))
 def route(rows, weight, bias=None, live=None, *, top_k, normalize=True,
-          scale=1.0):
+          scale=1.0, score="sigmoid"):
     """``rows`` (n, H), ``weight`` (E, H), ``bias`` (E,) or None (the top k
     of the scores alone), ``live`` (n,) or None.  Returns ids (n, k) int32,
     weights (n, k) float32 and the load (E,) int32: the live rows' picks by
     expert.  Scores are float32
     whatever the rows' dtype (a near-tie must not be a tie of rounded
     scores); a row that is not live picks expert ``E`` with weight 0.
+    ``score`` is the rule (module docstring): ``"sigmoid"``, or
+    ``"softmax"`` -- the top k of the logits, weighted by the softmax over
+    the picked ones: it takes no ``bias``, and its weights sum to one
+    whatever ``normalize`` says.
     Jitted on its own, as :func:`routed_experts` is: a program's 14 expert
     layers then trace and lower the layer once, not 14 times, at every
     start (a third of a second a program on the chip's host)."""
+    if score not in SCORES:
+        raise ValueError("route: score is one of %s; got %r"
+                         % (SCORES, score))
+    if score == "softmax" and bias is not None:
+        raise ValueError("route: the softmax rule takes no selection bias")
     experts = weight.shape[0]
     logits = lax.dot_general(rows, weight, (((1,), (1,)), ((), ())),
                              preferred_element_type=_F32)
-    scores = jax.nn.sigmoid(logits)
-    _, ids = lax.top_k(
-        scores if bias is None else scores + bias.astype(_F32), int(top_k))
-    picked = jnp.take_along_axis(scores, ids, axis=-1)
-    if normalize:
-        picked = picked / (jnp.sum(picked, -1, keepdims=True) + ROUTER_EPS)
+    if score == "softmax":
+        # top_k returns the picked logits largest first: [:, :1] is max_I z
+        picked, ids = lax.top_k(logits, int(top_k))
+        picked = jnp.exp(picked - picked[:, :1])
+        picked = picked / jnp.sum(picked, -1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, ids = lax.top_k(
+            scores if bias is None else scores + bias.astype(_F32),
+            int(top_k))
+        picked = jnp.take_along_axis(scores, ids, axis=-1)
+        if normalize:
+            picked = picked / (jnp.sum(picked, -1, keepdims=True)
+                               + ROUTER_EPS)
     picked = picked * float(scale)
     ids = ids.astype(jnp.int32)
     if live is not None:
@@ -368,6 +396,8 @@ def _router_inputs(attrs):
           params={"top_k": Param(int, required=True),
                   "normalize": Param(bool, True),
                   "scale": Param(float, 1.0),
+                  # None (not written into a graph): sigmoid
+                  "score": Param(str, None, enum=SCORES),
                   "use_bias": Param(bool, True),
                   "use_live": Param(bool, False)},
           num_outputs=3, no_grad_inputs=("live",),
@@ -375,7 +405,8 @@ def _router_inputs(attrs):
           hint="moerouter")
 @jax.named_scope("moe_router")
 def _moe_router(opctx, attrs, data, weight, *more):
-    """:func:`route` as an op (sigmoid scores): reads ``data`` (rows, H),
+    """:func:`route` as an op (``score``: ``sigmoid`` or ``softmax``, both
+    under this one scope): reads ``data`` (rows, H),
     ``weight`` (E, H), unless ``use_bias`` is off ``bias`` (E,) and, with
     ``use_live``, ``live`` (rows,; nonzero: the row routes); writes ``ids``
     (rows, k) int32, ``weights`` (rows, k) float32 and ``load`` (E,)
@@ -385,7 +416,8 @@ def _moe_router(opctx, attrs, data, weight, *more):
     return route(data, weight, bias, more[0] if more else None,
                  top_k=int(attrs["top_k"]),
                  normalize=bool(attrs.get("normalize", True)),
-                 scale=float(attrs.get("scale", 1.0)))
+                 scale=float(attrs.get("scale", 1.0)),
+                 score=attrs.get("score") or "sigmoid")
 
 
 @register("_contrib_RoutedExperts",

@@ -486,6 +486,29 @@ def _leaves_go_whole_into(text, kernel, calls,
         assert leaf.replace("bf16", "f32") not in text
 
 
+def _compile_for_the_chip(symbol, shapes, types, planes, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.interpret import bind
+
+    for name in symbol.list_arguments():
+        if name not in shapes:
+            types[name] = jnp.bfloat16  # the weights
+    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
+                            **shapes)
+    if planes:
+        ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
+    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
+    call = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ex._forward_args(None))
+    fwd = ex._get_fwd(False)
+    assert ex.carry_donated or not planes
+    return getattr(fwd, "_fn", fwd).lower(*call).compile()
+
+
 def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     """The LFM2 family's decode step at the cell's widths, one period of its
     pattern (``c c a c``: one dense and three expert layers), as the
@@ -524,20 +547,7 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
         shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
         types[name] = jnp.dtype(dtype)
         planes.append(name)
-    for name in symbol.list_arguments():
-        if name not in shapes:
-            types[name] = jnp.bfloat16  # the weights
-    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
-                            **shapes)
-    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
-    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
-    carried, args, aux, rng = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        ex._forward_args(None))
-    fwd = ex._get_fwd(False)
-    assert ex.carry_donated
-    compiled = getattr(fwd, "_fn", fwd).lower(carried, args, aux, rng) \
-        .compile()
+    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip)
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
         == [((lanes, vocab), "float32"), ((lanes,), "float32"),
@@ -599,19 +609,7 @@ def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
         shapes[name] = (pages, 16) + shape
         types[name] = jnp.dtype(dtype)
         planes.append(name)
-    for name in symbol.list_arguments():
-        if name not in shapes:
-            types[name] = jnp.bfloat16  # the weights
-    ex = symbol.simple_bind(mx.cpu(), grad_req="null", type_dict=types,
-                            **shapes)
-    ex.set_carried({name: 1 + i for i, name in enumerate(planes)})
-    ex._bound = lambda fn: bind(fn, "tpu")  # as on a tpu context
-    call = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        ex._forward_args(None))
-    fwd = ex._get_fwd(False)
-    assert ex.carry_donated
-    compiled = getattr(fwd, "_fn", fwd).lower(*call).compile()
+    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip)
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
         == [((lanes, vocab), "float32"), ((lanes,), "float32"),
@@ -631,6 +629,115 @@ def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
+def _granite_small(layer_types, vocab=512):
+    """Granite 4.0-H Small's block at its published widths, 36 of its 72
+    experts held (perfbench/configs/granite-4.0-h-small.json)."""
+    from mxnet_tpu.models import HybridLM
+
+    return HybridLM(
+        vocab_size=vocab, hidden=4096, layer_types=layer_types, num_heads=32,
+        kv_heads=8, head_dim=128, intermediate=1536, ssm_heads=128,
+        ssm_head_dim=64, ssm_state=128, conv_kernel=4, chunk=256,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1 / 128.0, logits_scaling=16.0, num_experts=72,
+        experts_per_token=10, expert_width=768, experts_held=36,
+        router_bias=False, router_score="softmax", shared_expert_width=1536)
+
+
+def test_granite_moe_lane_program_steps_state_and_streams_experts(
+        one_chip, monkeypatch):
+    """The decode step of the hybrid family WITH experts at Granite 4.0-H
+    Small's widths, one state-space and one attention layer, as the engine's
+    Executor builds it, compiled for the chip: the recurrent state of 128
+    heads through ONE ``ssm_step`` call, in place; each layer's routed
+    products ONE ``moe_grouped`` call at 4096 x 768 over the 36 held leaves
+    (160 pairs a step: its tiles fit), whole and in bfloat16; the router
+    (the softmax over the picked logits) under ``moe_router``, the shared
+    MLP the dense MLP's three ops; ``expert_load`` (2 layers x 72) the last
+    output; every plane aliased in and out."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    lanes, slots, pages, max_pages, vocab = 16, 17, 40, 152, 512
+    model = _granite_small(["mamba", "attention"], vocab)
+    symbol = model.decode_symbol(2432, 16)
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids", "state_slot")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip)
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
+        == [((lanes, vocab), "float32"), ((lanes,), "float32"),
+            ((2, 72), "int32")]
+    assert len(outs) == 3 + len(planes) == 3 + 4
+    text = compiled.as_text()
+    assert " while(" not in text and "ragged-dot" not in text
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert sorted(n.split(".")[0] for n in names) == \
+        ["moe_grouped", "moe_grouped", "ssm_step"]
+    _leaves_go_whole_into(text, "moe_grouped", calls=2,
+                          leaves=("bf16[36,4096,1536]", "bf16[36,768,4096]"))
+    ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
+    for i in (0, 1):
+        assert any(("layer%d_router/moe_router/" % i) in o for o in ops)
+        assert any(("layer%d_experts/moe_experts/" % i) in o for o in ops)
+        for part in ("in", "gate", "out"):
+            assert any(("layer%d_shared_%s/" % (i, part)) in o for o in ops)
+    state = "f32[%d,128,64,128]" % slots
+    moved = [line for line in text.splitlines() if state in line
+             and re.search(r" (copy|copy-start|copy-done|gather|scatter|"
+                           r"dynamic-update-slice)\(", line)]
+    assert not moved, moved[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
+def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
+        one_chip, monkeypatch):
+    """The same two layers' prefill of the cell's longest bucket (one prompt
+    of 2,048: 8 chunks of 256 through the scan at 128 heads, 20,480 (row,
+    pick) pairs a layer, about half of them on held experts), compiled for
+    the chip: the routed products ``moe_grouped`` (no ``ragged-dot``), the
+    scan under ``ssm_scan``, the scratch memory under 2 GB (the whole ten
+    layers' program takes 1.22 GB: my compile, PR 43), and the slabs the
+    layers carry into decode beside the logits."""
+    import re
+
+    import jax
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    L, vocab = 2048, 512
+    model = _granite_small(["mamba", "attention"], vocab)
+    compiled = _compile_for_the_chip(
+        model.prefill_symbol(L, 2432), {"data": (1, L), "length": (1,)}, {},
+        [], one_chip)
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.shape for o in outs] == [
+        (1, L, vocab), (1, 128, 64, 128), (1, 3, 8448), (1, L, 8, 128),
+        (1, L, 8, 128)]
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert [n.split(".")[0] for n in names] == ["moe_grouped"] * 2
+    ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
+    assert any("layer0_ssm/ssm_scan/" in o for o in ops)
+    assert any("layer1_router/moe_router/" in o for o in ops)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
 @pytest.mark.parametrize("chips", [4, 1])
