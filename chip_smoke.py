@@ -41,6 +41,15 @@ SERVE = dict(LM, max_seq=1024, lanes=8, page_size=16,
 # paged-attention kernel is compared with the XLA formulation
 PAGED = dict(lanes=8, num_pages=176, page_size=16, heads=16, head_dim=128,
              max_pages=128, positions=(63, 351))
+# the hybrid cells' attention layers (perfbench: g4hmicro- / lfm2moe- and
+# g4hsmall-decode-closed16): 32 query heads over 8 K/V heads of 64 and of
+# 128, bfloat16, a token held as ONE row of the plane
+PAGED_GROUPED = (
+    dict(lanes=16, num_pages=705, page_size=16, heads=32, kv_heads=8,
+         head_dim=64, max_pages=44, positions=(176, 528), dtype="bfloat16"),
+    dict(lanes=16, num_pages=2433, page_size=16, heads=32, kv_heads=8,
+         head_dim=128, max_pages=152, positions=(608, 1824),
+         dtype="bfloat16"))
 PAGED_TOL = 2e-2        # max|kernel - XLA| (XLA's products are one bf16 pass)
 LOSS0_BOUND = 1.0       # |step-0 loss - ln(vocab)|
 DP_LOSS_TOL = 0.05      # |dp4 loss - one-chip loss|, every step
@@ -259,26 +268,32 @@ def paged_kernel_check(shapes, ctx, seed=0):
     for lane, pos in enumerate(at):
         held = pos // c["page_size"] + 1
         table[lane, :held] = [free.pop() for _ in range(held)]
+    kv_heads, dtype = c.get("kv_heads", c["heads"]), c.get("dtype", "float32")
     row = (c["lanes"], c["heads"], c["head_dim"])
-    plane = (c["num_pages"], c["page_size"], c["heads"], c["head_dim"])
-    ops = [jax.device_put(rng.randn(*shape).astype(np.float32), dev)
-           for shape in (row, row, row, plane, plane)]
+    new = (c["lanes"], kv_heads, c["head_dim"])
+    # float32 tokens by heads; bfloat16 tokens one row (ops/paged.py)
+    plane = (c["num_pages"], c["page_size"]) + (
+        (kv_heads, c["head_dim"]) if dtype == "float32"
+        else (kv_heads * c["head_dim"],))
+    ops = [jax.device_put(jnp.asarray(rng.randn(*shape), dtype), dev)
+           for shape in (row, new, new, plane, plane)]
     ops += [jax.device_put(table, dev),
             jax.device_put(at.astype(np.int32), dev)]
     scale = 1.0 / math.sqrt(c["head_dim"])
     want = jax.jit(lambda *a: paged._gather_decode(*a, scale))(*ops)
     got = jax.jit(lambda *a: paged._kernel_decode(
         *a, scale, interpret=dev.platform != "tpu"))(*ops)
-    gap = float(jnp.abs(got[0] - want[0]).max())
+    gap = float(jnp.abs(got[0].astype(jnp.float32)
+                        - want[0].astype(jnp.float32)).max())
     check({d for g in got for d in g.devices()} == {dev},
           "paged-attention kernel ran on %s" % dev)
     check(gap <= PAGED_TOL and all(
         bool(jnp.array_equal(g, w)) for g, w in zip(got[1:], want[1:])),
         "paged-attention kernel vs the XLA formulation at %d lanes, %d pages "
-        "of %d, %d x %d, table width %d, positions %d-%d: max|diff| = %.2e "
-        "<= %.0e, written rows equal"
-        % (c["lanes"], c["num_pages"], c["page_size"], c["heads"],
-           c["head_dim"], c["max_pages"], at.min(), at.max(), gap,
+        "of %d, %d over %d x %d %s, table width %d, positions %d-%d: "
+        "max|diff| = %.2e <= %.0e, written rows equal"
+        % (c["lanes"], c["num_pages"], c["page_size"], c["heads"], kv_heads,
+           c["head_dim"], dtype, c["max_pages"], at.min(), at.max(), gap,
            PAGED_TOL))
     return gap
 
@@ -416,6 +431,8 @@ def server_phase(cfg, ctx, seed=0):
     finally:
         srv.stop()
     paged_gap = paged_kernel_check(cfg.get("paged", PAGED), ctx, seed)
+    for shapes in cfg.get("paged_grouped", PAGED_GROUPED):
+        paged_kernel_check(shapes, ctx, seed)
 
     # prefill logits: ctx vs an explicit mx.cpu() bind — a named
     # comparison, not a fallback

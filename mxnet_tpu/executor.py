@@ -338,11 +338,11 @@ class Executor:
                                rng)
 
             fn.__name__ = self._program_name
-            fn = self._bound(fn)
             if self._naive:
-                self._jit_cache[key] = fn
+                self._jit_cache[key] = self._bound(fn)
             else:
                 from . import compile_cache as _cc
+                from .ops.interpret import carrying
 
                 jit_kw, static_key = {}, key
                 if self._carried:
@@ -354,6 +354,9 @@ class Executor:
                     self.carry_donated = bool(donate)
                     jit_kw = {"donate_argnums": donate}
                     static_key = key + (("donate", donate),)
+                    # the kernels that take a plane where it lies read this
+                    fn = carrying(fn, self.carry_donated)
+                fn = self._bound(fn)
                 self._jit_cache[key] = self._first_call(key, _cc.maybe_cached(
                     jax.jit(fn, **jit_kw), kind, static_key, self), kind)
         return self._jit_cache[key]

@@ -1016,6 +1016,18 @@ class DecodeEngine:
                 "pool": self.pool.devices(),
                 "prefill": of(prefill), "decode": of(decode)}
 
+    def _paged_formulation(self):
+        """Which formulation the lane program's ``_contrib_PagedAttention``
+        runs over this engine's K/V planes, where they live (a family that
+        pages no K/V, the latent one, has only the gather of its own op)."""
+        if not self.pool.k_pools:
+            return "xla"
+        plane = self.pool.k_pools[0]
+        return decode_formulation(
+            self._device.platform, self.num_heads, self.head_dim, plane.dtype,
+            kv_heads=self.family.kv_heads, rows=len(plane.shape) == 3,
+            page_size=self.pool.page_size)
+
     def snapshot(self) -> dict:
         # what this process's start was spent on (spans by name, the
         # compile ledger by program): read before the lock is taken
@@ -1038,10 +1050,7 @@ class DecodeEngine:
                          if p._exec.carry_donated is not None), None),
                     # which formulation the lane program's attention runs
                     # where this engine's planes live (ops/paged.py)
-                    "paged_attention": decode_formulation(
-                        self._device.platform, self.num_heads, self.head_dim,
-                        self.pool.paged_planes()[0].dtype,
-                        kv_heads=self.family.kv_heads),
+                    "paged_attention": self._paged_formulation(),
                     "kv": self.pool.snapshot(), "startup": startup}
             if self.pool.num_slots:
                 snap["state_slots"] = snap["kv"]["state_slots"]

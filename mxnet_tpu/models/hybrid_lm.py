@@ -15,9 +15,12 @@ assembles its own:
 * :func:`get_hybrid_lm_prefill`: a prompt bucket with the prompts' true
   lengths; beside the logits it returns what every layer carries into
   decode, in :meth:`HybridLM.planes`' order: an attention layer its K and
-  V ``(b, L, kv_heads, head_dim)``, a state-space layer its recurrent
-  state ``(b, heads, head_dim, state)`` and convolution tail ``(b, K-1,
-  conv_dim)`` as they stand after each prompt's LAST REAL token;
+  V ``(b, L, kv_heads * head_dim)`` (a token one row, as the planes hold it:
+  whole tiles in any dtype, so a page is one contiguous piece that the
+  paged-decode kernel reads where it lies, ops/paged.py), a state-space
+  layer its recurrent state ``(b, heads, head_dim, state)`` and convolution
+  tail ``(b, K-1, conv_dim)`` as they stand after each prompt's LAST REAL
+  token;
 * :func:`get_hybrid_lm_decode`: the lane program, one token a lane: the
   attention layers read and write K/V pages through ``page_table``, the
   state-space layers read and write their lane's slot of the state planes
@@ -221,8 +224,9 @@ class HybridLM:
         out = []
         for i, kind in enumerate(self.layer_types):
             if kind == ATTENTION:
+                # a token is ONE row over the K/V heads (module docstring)
                 out += [("layer%d_%s_pool" % (i, kv), "paged",
-                         (self.kv_heads, self.head_dim), self.dtype)
+                         (self.kv_heads * self.head_dim,), self.dtype)
                         for kv in "kv"]
             elif kind == LATENT:
                 out += [("layer%d_latent_pool" % i, "paged",
@@ -510,9 +514,14 @@ def _table(m):
 
 def _sequence_graph(m, seq_len, length):
     def dense(q, k, v, name):
+        def row(x, kv):  # as the planes hold a token
+            return sym.Reshape(x, shape=(-1, seq_len,
+                                         m.kv_heads * m.head_dim),
+                               name="%s_%s_rows" % (name, kv))
+
         return sym._contrib_DenseAttention(
             q, k, v, causal=True, scale=m.attention_multiplier,
-            name=name), [k, v]
+            name=name), [row(k, "k"), row(v, "v")]
 
     def latent(q_n, q_r, rows, w_kvb, name):
         return sym._contrib_LatentAttention(

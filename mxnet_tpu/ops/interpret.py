@@ -17,6 +17,12 @@ The platform is read, in order, from
 A kernel on a ``tpu`` device is compiled by Mosaic; anywhere else it runs
 in Pallas interpret mode and says so once (``logging.info``).
 
+An Executor also says, around a program that carries planes from call to
+call, whether it donates them (:func:`carrying`, read by
+:func:`carried_in_place`): a kernel that takes such a plane where it lies may
+then hold it to the device's main memory, which the TPU compiler refuses
+(aborts) for a plane it has to copy first.
+
 The same scope carries the device mesh of a data-parallel executor: GSPMD
 cannot partition a Mosaic kernel ("Mosaic kernels cannot be automatically
 partitioned"), so a kernel traced under a mesh runs per batch shard through
@@ -30,7 +36,7 @@ import logging
 import threading
 
 __all__ = ["bound_to", "bind", "platform_of", "interpret_for",
-           "over_batch_shards"]
+           "over_batch_shards", "carrying", "carried_in_place"]
 
 _scope = threading.local()
 _said = set()
@@ -57,6 +63,27 @@ def bind(fn, platform: str, mesh=None, batch_axis=None):
             return fn(*args, **kwargs)
 
     return bound
+
+
+def carrying(fn, donated: bool):
+    """``fn``, a program that carries planes, wrapped so its body — traced
+    lazily — can ask :func:`carried_in_place` whether they are donated."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        old = getattr(_scope, "donated", False)
+        _scope.donated = bool(donated)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _scope.donated = old
+
+    return traced
+
+
+def carried_in_place() -> bool:
+    """True inside a program whose carried planes are donated: an output
+    aliased to one is the plane's own buffer, never a copy."""
+    return getattr(_scope, "donated", False)
 
 
 def over_batch_shards(fn):

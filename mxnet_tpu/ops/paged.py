@@ -20,11 +20,20 @@ Three ops:
   this step's own K/V (taken from the projections, never read back), and
   writes that K/V at ``positions[lane]`` into the pool: ``lanes`` rows.
   Two formulations, one op (:func:`decode_formulation` picks by where the
-  operands live): on a TPU one Pallas kernel walks each lane's live pages
-  where they lie in the plane — ``positions[lane] // page_size`` page
-  reads a lane, not the table's whole width; anywhere else (every CPU
-  test, ``mx.cpu()`` serving) XLA gathers the whole table and masks,
-  which is also the kernel's oracle (tests/test_paged_kernel.py).  Because
+  operands live and what they are): on a TPU one Pallas kernel
+  (``paged_decode``) walks each lane's live pages where they lie in the
+  plane — ``positions[lane] // page_size`` page reads a lane, not the
+  table's whole width — in either of two forms of plane: float32 tokens of
+  ``(heads, head_dim)`` whole tiles, a K/V head a query head (the
+  transformer family), and bfloat16 tokens held as ONE row of ``kv_heads *
+  head_dim`` lanes, whose query heads may share a K/V head (grouped-query:
+  a page is fetched once for its group; the hybrid family's attention
+  layers).  Either way a page is one contiguous block of whole tiles, the
+  planes go through the call in place, and the products' sums, the running
+  maximum, sum and weighted values are float32.  Anywhere else (every CPU
+  test, ``mx.cpu()`` serving, tokens that are no whole tiles) XLA gathers
+  the whole table and masks, which is also the kernel's oracle
+  (tests/test_paged_kernel.py).  Because
   pools, page tables, and lane vectors are all fixed-shape, the whole
   decode step is ONE static XLA program per lane-count bucket — no
   per-sequence-length recompiles, which is the entire point
@@ -213,19 +222,43 @@ def _paged_attention_window(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
             flat_v.reshape(num_pages, ps, heads, hd))
 
 
-def decode_formulation(platform, heads, head_dim, dtype, kv_heads=None):
+def decode_formulation(platform, heads, head_dim, dtype, kv_heads=None,
+                       rows=False, page_size=16):
     """Which formulation ``_contrib_PagedAttention`` runs: ``"pallas"`` —
     the kernel that reads the live pages where they lie — where the
-    operands live on a TPU, a token's K (or V) is whole float32 tiles
-    (a page is then one contiguous block of the plane, the DMA's unit) and
-    every query head has a K/V head of its own (the kernel knows no
-    groups); ``"xla"`` — the gather over the whole table — anywhere else.  An
-    observation of the operands, as ``interpret.interpret_for`` is for the
-    flash kernels: no attribute, environment variable or autotune entry
-    chooses."""
-    tiled = (np.dtype(dtype) == np.float32 and head_dim % 128 == 0
-             and heads % 8 == 0 and kv_heads in (None, heads))
+    operands live on a TPU and a page is one contiguous block of whole tiles
+    of the plane (the DMA's unit), in one of the two forms the kernel takes:
+
+    * a token of ``(kv_heads, head_dim)`` float32 values that are whole
+      tiles themselves, every query head with a K/V head of its own
+      (``kv_heads`` None or ``heads``);
+    * ``rows``: a token held as ONE row of ``kv_heads * head_dim`` bfloat16
+      lanes (``kv_heads`` may divide ``heads``: the query heads of a group
+      share the K/V head's pages), the row whole lane tiles and the page's
+      ``page_size`` rows whole sublane tiles.
+
+    ``"xla"`` — the gather over the whole table — anywhere else.  ``dtype``
+    is what the products' operands are held in (the query's and the planes'
+    common type).  An observation of the operands, as
+    ``interpret.interpret_for`` is for the flash kernels: no attribute,
+    environment variable or autotune entry chooses."""
+    kv_heads = heads if kv_heads is None else kv_heads
+    if rows:
+        tiled = (np.dtype(dtype) == np.dtype("bfloat16")
+                 and heads % kv_heads == 0 and heads % 8 == 0
+                 and (kv_heads * head_dim) % 128 == 0 and page_size % 16 == 0)
+    else:
+        tiled = (np.dtype(dtype) == np.float32 and head_dim % 128 == 0
+                 and heads % 8 == 0 and kv_heads == heads)
     return "pallas" if platform == "tpu" and tiled else "xla"
+
+
+def _by_heads(plane, kv_heads):
+    """A plane whose token is one row of ``kv_heads * head_dim`` values, by
+    K/V heads; one that is by heads already, as it is."""
+    if plane.ndim == 4:
+        return plane
+    return plane.reshape(plane.shape[:2] + (kv_heads, -1))
 
 
 def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
@@ -233,10 +266,14 @@ def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
     whole table (``max_pages * page_size`` slots), put this step's own K/V
     into the gathered copy at its position, masked softmax; the pool's
     update is a scatter of ``lanes`` rows.  Grouped-query where ``q`` holds
-    more heads than the planes: the planes are by K/V heads, and query head
+    more heads than the planes: the planes are by K/V heads (a token
+    ``(kv_heads, head_dim)``, or one row of their product), and query head
     ``i`` reads K/V head ``i // group``."""
     import jax.numpy as jnp
 
+    heads = k_new.shape[1]
+    pools = k_pool.shape, v_pool.shape
+    k_pool, v_pool = _by_heads(k_pool, heads), _by_heads(v_pool, heads)
     num_pages, ps, heads, hd = k_pool.shape
     lanes, max_pages = pt.shape
     group = _group(q.shape[1], heads)
@@ -279,45 +316,87 @@ def _gather_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale):
         out = jnp.einsum("lht,lthd->lhd", p, vals)
     else:
         out = jnp.einsum("lhgt,lthd->lhgd", p, vals).reshape(q.shape)
-    return (out.astype(q.dtype), flat_k.reshape(k_pool.shape),
-            flat_v.reshape(v_pool.shape))
+    return (out.astype(q.dtype), flat_k.reshape(pools[0]),
+            flat_v.reshape(pools[1]))
 
 
-# page slots per plane in VMEM; one less is in flight.  At the cell's shapes
-# (a page of 131 KB) 7 x 2 pages cover the DMA's latency at the HBM's rate.
-_RING = 8
+# Bytes of each plane in flight towards the ring of VMEM slots, and the
+# tokens a slot holds at the least.  A slot is one fetch's worth of a lane's
+# pages: one float32 page of the cgpt13b cell's (16 tokens of 16 x 128: 128
+# KiB, 7 in flight cover the DMA's latency at the HBM's rate: PR 29), or as
+# many bfloat16 pages of 16-32 KiB as hold 128 tokens (the scores' lane tile).
+# The ring's depth follows from the slot's bytes.
+_IN_FLIGHT_BYTES = 7 * (128 << 10)
+_SLOT_TOKENS = 128
+
+
+def _ring(page_shape, dtype):
+    """(pages a slot, slots) of the kernel's ring for pages of this shape
+    (``(page_size,) + token``) and dtype."""
+    if len(page_shape) == 2:  # a token is a row: pages are fetched together
+        chunk = max(1, _SLOT_TOKENS // page_shape[0])
+    else:
+        chunk = 1
+    slot = chunk * int(np.prod(page_shape)) * np.dtype(dtype).itemsize
+    return chunk, 1 + max(1, -(-_IN_FLIGHT_BYTES // slot))
 
 
 def _decode_kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, k_in, v_in,
-                   o_ref, k_out, v_out, work, qs, m_ref, l_ref, acc, kbuf,
-                   vbuf, sems, row_sems, *, scale, max_pages):
+                   o_ref, k_out, v_out, work, m_ref, l_ref, acc, kbuf, vbuf,
+                   sems, row_sems, aside, *, scale, max_pages, chunk):
     """One call, all lanes.  Every lane's live pages, in lane then table
-    order, stream from the planes where they lie through a ring of VMEM page slots
-    while an online softmax (float32 on the VPU: exact products) folds
-    each page into its lane's running max, sum and weighted values.  The
-    planes come in and go out as the same buffers (``k_out`` aliases
-    ``k_in``): the kernel's only write to them is ``lanes`` rows."""
+    order, stream from the planes where they lie through a ring of VMEM
+    slots (``chunk`` pages of one lane a slot) while an online softmax folds
+    each slot into its lane's running max, sum and weighted values, all
+    float32.  The planes come in and go out as the same buffers (``k_out``
+    aliases ``k_in``): the kernel's only write to them is this step's
+    ``lanes`` rows.  Two forms of plane (:func:`decode_formulation`):
+
+    * float32 tokens of ``(heads, head_dim)``: the products on the VPU
+      (exact); a token is whole tiles, so this step's rows go to their slots
+      as they are (``aside``: the query times the scale);
+    * ``rows``, bfloat16 tokens of one row: a slot's scores are ONE product
+      on the MXU of the lane's query heads, each laid out over its K/V
+      head's lanes of the row (zeros elsewhere: ``q_ref``), with the slot's
+      keys; the weighted values one product with the probabilities in two
+      bfloat16 halves (float32 sums of exact products: nothing is rounded
+      that the gather rounds not).  A row is no DMA's unit (two rows share a
+      sublane): each lane's current page comes into ``aside``, takes the
+      row and goes back whole."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    rows_form = len(k_in.shape) == 3
+    qs = held = aside  # one scratch, by the planes' form (docstring)
     lanes = q_ref.shape[0]
-    ring, ps = kbuf.shape[0], kbuf.shape[1]
+    ring, ps = kbuf.shape[0], k_in.shape[1]
+    chunks = -(-max_pages // chunk)  # slots a lane's whole table makes
+    f32 = jnp.float32
+
+    def live_pages(lane):
+        return (pos_ref[lane] + ps - 1) // ps
 
     # this step's K/V rows into each lane's current slot (inactive lanes
     # hit the scratch page).  No page read below uses that slot — it holds
     # the token AT the lane's position, the reads stop before it — so the
-    # rows fly while the pages stream and are waited for at the end.
-    def rows(lane):
-        at = pos_ref[lane]
-        page = pt_ref[lane * max_pages + at // ps]
-        return (pltpu.make_async_copy(kn_ref.at[lane],
-                                      k_out.at[page, at % ps],
-                                      row_sems.at[0, lane]),
-                pltpu.make_async_copy(vn_ref.at[lane],
-                                      v_out.at[page, at % ps],
-                                      row_sems.at[1, lane]))
+    # rows fly while the pages stream and are waited for at the end.  (A
+    # page that goes back whole is the page as it was but for that slot.)
+    def rows(to_plane):
+        def copies(lane):
+            at = pos_ref[lane]
+            page = pt_ref[lane * max_pages + at // ps]
+            if rows_form:  # (in the plane, here): the page that holds it
+                ends = [(k_out.at[page], held.at[0, lane]),
+                        (v_out.at[page], held.at[1, lane])]
+            else:          # the token's row itself
+                ends = [(k_out.at[page, at % ps], kn_ref.at[lane]),
+                        (v_out.at[page, at % ps], vn_ref.at[lane])]
+            return [pltpu.make_async_copy(*(pair[::-1] if to_plane else pair),
+                                          row_sems.at[i, lane])
+                    for i, pair in enumerate(ends)]
+        return copies
 
     def each(copies, method):
         def run(n, carry):
@@ -326,108 +405,203 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, k_in, v_in,
             return carry
         return run
 
-    lax.fori_loop(0, lanes, each(rows, "start"), None)
+    lax.fori_loop(0, lanes, each(rows(not rows_form), "start"), None)
 
-    # the work list: lane * max_pages + table column, once per page that
-    # holds a token before the lane's position.  A lane at position 0 (an
-    # inactive lane among them) has none: it attends its own token alone.
-    def lane_pages(lane, n):
-        def page(col, n):
-            work[n] = lane * max_pages + col
+    # the work list: lane * chunks + the slot's number in the lane's table,
+    # once per ``chunk`` pages that hold a token before the lane's position.
+    # A lane at position 0 (an inactive lane among them) has none: it
+    # attends its own token alone.
+    def lane_slots(lane, n):
+        def one(c, n):
+            work[n] = lane * chunks + c
             return n + 1
-        return lax.fori_loop(0, (pos_ref[lane] + ps - 1) // ps, page, n)
+        return lax.fori_loop(0, (live_pages(lane) + chunk - 1) // chunk, one,
+                             n)
 
-    total = lax.fori_loop(0, lanes, lane_pages, 0)
+    total = lax.fori_loop(0, lanes, lane_slots, 0)
 
-    def pages(n):
-        slot, page = n % ring, pt_ref[work[n]]
-        return (pltpu.make_async_copy(k_in.at[page], kbuf.at[slot],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_in.at[page], vbuf.at[slot],
-                                      sems.at[1, slot]))
+    def fetch(method):
+        """Start, or wait for, the pages of work item ``n``: the lane's
+        live ones among the slot's ``chunk``."""
+        def run(n, carry):
+            slot = n % ring
+            lane, c = work[n] // chunks, work[n] % chunks
 
-    lax.fori_loop(0, jnp.minimum(ring - 1, total), each(pages, "start"),
-                  None)
+            def pages(j):
+                page = pt_ref[lane * max_pages + c * chunk + j]
+                dst = (slot, j) if rows_form else (slot,)
+                return (pltpu.make_async_copy(k_in.at[page], kbuf.at[dst],
+                                              sems.at[0, slot]),
+                        pltpu.make_async_copy(v_in.at[page], vbuf.at[dst],
+                                              sems.at[1, slot]))
+
+            if chunk == 1:
+                return each(pages, method)(0, carry)
+            return lax.fori_loop(
+                0, jnp.minimum(chunk, live_pages(lane) - c * chunk),
+                each(pages, method), carry)
+        return run
+
+    if rows_form:
+        # a slot's rows past the lane's live pages are an earlier fetch's,
+        # or nobody's: masked below, but 0 x NaN is NaN in the values' product
+        vbuf[...] = jnp.zeros_like(vbuf)
+    lax.fori_loop(0, jnp.minimum(ring - 1, total), fetch("start"), None)
 
     # this step's own token opens every lane's softmax: its K/V come from
     # the projections, not from the pool
-    qs[...] = q_ref[...] * scale
-    m_ref[...] = jnp.sum(qs[...] * kn_ref[...], axis=-1, keepdims=True)
+    if rows_form:
+        m_ref[...] = jnp.sum(q_ref[...].astype(f32) * kn_ref[...], axis=-1,
+                             keepdims=True) * scale
+        acc[...] = jnp.broadcast_to(vn_ref[...], acc.shape)
+
+        def put(lane, carry):
+            each(rows(False), "wait")(lane, None)
+            here = lax.broadcasted_iota(jnp.int32, held.shape[2:], 0) \
+                == pos_ref[lane] % ps
+            for i, new in enumerate((kn_ref, vn_ref)):
+                held[i, lane] = jnp.where(here, new[lane],
+                                          held[i, lane].astype(f32)
+                                          ).astype(held.dtype)
+            return each(rows(True), "start")(lane, carry)
+
+        lax.fori_loop(0, lanes, put, None)
+    else:
+        qs[...] = q_ref[...] * scale
+        m_ref[...] = jnp.sum(qs[...] * kn_ref[...], axis=-1, keepdims=True)
+        acc[...] = vn_ref[...]
     l_ref[...] = jnp.ones_like(l_ref)
-    acc[...] = vn_ref[...]
 
     def fold(n, carry):
         @pl.when(n + ring - 1 < total)
         def _():
-            each(pages, "start")(n + ring - 1, None)
+            fetch("start")(n + ring - 1, None)
 
-        lane, col = work[n] // max_pages, work[n] % max_pages
+        lane, c = work[n] // chunks, work[n] % chunks
         slot = n % ring
-        each(pages, "wait")(n, None)
-        s = jnp.sum(kbuf[slot] * qs[lane][None], axis=-1,
-                    keepdims=True)                        # (ps, heads, 1)
-        token = col * ps + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(token < pos_ref[lane], s, _NEG)
+        fetch("wait")(n, None)
         m_old = m_ref[lane]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+        if rows_form:
+            width = kbuf.shape[-1]
+            keys = kbuf[slot].reshape(chunk * ps, width)
+            s = lax.dot_general(q_ref[lane], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32) * scale
+            token = c * chunk * ps + lax.broadcasted_iota(jnp.int32, s.shape,
+                                                          1)
+            s = jnp.where(token < pos_ref[lane], s, _NEG)  # (heads, tokens)
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)  # a masked slot underflows to 0.0
+            high = p.astype(keys.dtype)
+            low = (p - high.astype(f32)).astype(keys.dtype)
+            pv = jnp.dot(jnp.concatenate([high, low], axis=0),
+                         vbuf[slot].reshape(chunk * ps, width),
+                         preferred_element_type=f32)
+            p_sum = jnp.sum(p, axis=1, keepdims=True)
+            pv = pv[:p.shape[0]] + pv[p.shape[0]:]
+        else:
+            s = jnp.sum(kbuf[slot] * qs[lane][None], axis=-1,
+                        keepdims=True)                    # (ps, heads, 1)
+            token = c * ps + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            s = jnp.where(token < pos_ref[lane], s, _NEG)
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])  # a masked slot underflows to 0.0
+            p_sum, pv = jnp.sum(p, axis=0), jnp.sum(p * vbuf[slot], axis=0)
         alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new[None])  # a masked slot underflows to 0.0
         m_ref[lane] = m_new
-        l_ref[lane] = alpha * l_ref[lane] + jnp.sum(p, axis=0)
-        acc[lane] = alpha * acc[lane] + jnp.sum(p * vbuf[slot], axis=0)
+        l_ref[lane] = alpha * l_ref[lane] + p_sum
+        acc[lane] = alpha * acc[lane] + pv
         return carry
 
     lax.fori_loop(0, total, fold, None)
     o_ref[...] = acc[...] / l_ref[...]
-    lax.fori_loop(0, lanes, each(rows, "wait"), None)
+    lax.fori_loop(0, lanes, each(rows(True), "wait"), None)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                              "in_place"))
 def _kernel_decode(q, k_new, v_new, k_pool, v_pool, pt, pos, scale,
-                   interpret=False):
+                   interpret=False, in_place=False):
     """The kernel's call: page table and positions as scalar-prefetch
     operands (a page id is a DMA's source index), the planes left where
     they lie, in the layout they have, and aliased to their outputs — a
     donated plane then goes through the call in place, uncopied and
     unstaged (an undonated one is copied once, as for the scatter) — and
-    everything else whole in VMEM.  The planes are NOT pinned to HBM
-    (``pltpu.HBM``): an undonated pool small enough for fast memory then
-    aborts the TPU compiler's memory-space assignment.  Jitted on its own
-    so that a lane program's 24 call sites trace and lower the kernel
-    once, not 24 times, at every start (the persistent cache's key needs
-    the lowered program)."""
+    everything else whole in VMEM.  ``in_place`` says the planes are
+    donated (``interpret.carried_in_place``): planes of row tokens are then
+    held to the HBM (``pltpu.HBM``).  Left free, XLA stages a plane small
+    enough for its fast memory there and back around the call, the whole
+    plane each way (the 704-token cells' 11.5 MB planes); held, a plane that
+    XLA must copy first (an undonated one) aborts the TPU compiler's
+    memory-space assignment.  The float32 planes stay free as they were
+    (PR 29): XLA leaves the transformer cell's 23 MB planes where they lie,
+    and held, the chip's compiler refuses them ("Different aliasing
+    shapes": my chip run, PR 44).  Jitted on its own so that a lane
+    program's 24 call sites trace and lower the kernel once, not 24 times,
+    at every start (the persistent cache's key needs the lowered
+    program)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     lanes, heads, hd = q.shape
-    ps = k_pool.shape[1]
+    ps, token = k_pool.shape[1], k_pool.shape[2:]
     max_pages = pt.shape[1]
     f32 = jnp.float32
-    whole = pl.BlockSpec((lanes, heads, hd), lambda i, *_: (0, 0, 0))
-    in_place = pl.BlockSpec(memory_space=pl.ANY)
-    plane = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, max_pages=max_pages),
+    chunk, ring = _ring(k_pool.shape[1:], k_pool.dtype)
+    if len(token) == 2:
+        width, slot = hd, (ps,) + token
+        operands = (q, k_new, v_new)
+        aside = pltpu.VMEM((lanes, heads, hd), f32)  # the scaled query
+    else:
+        # a token is one row of ``kv_heads`` heads: query head ``i`` lies
+        # over the lanes of K/V head ``i // group`` and is zero over the
+        # others', so ONE product of a lane's (heads, width) with a slot's
+        # (tokens, width) is every head's scores, and the product of the
+        # probabilities with the slot's values holds every head's weighted
+        # values over its own lanes
+        kv_heads, (width,) = k_new.shape[1], token
+        slot = (chunk, ps, width)
+        mine = (jnp.arange(heads)[:, None] // _group(heads, kv_heads)
+                == jnp.arange(kv_heads)[None, :])[None, :, :, None]
+        operands = (
+            jnp.where(mine, q[:, :, None, :], 0).reshape(lanes, heads, width),
+            k_new.astype(f32).reshape(lanes, 1, width),
+            v_new.astype(f32).reshape(lanes, 1, width))
+        # each lane's current page of K and of V
+        aside = pltpu.VMEM((2, lanes, ps, width), k_pool.dtype)
+    whole = [pl.BlockSpec(x.shape, lambda i, *_: (0, 0, 0)) for x in operands]
+    plane = pltpu.HBM if in_place and len(token) == 1 else \
+        jax.ShapeDtypeStruct
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    out, k_pool, v_pool = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, max_pages=max_pages,
+                          chunk=chunk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(1,),
-            in_specs=[whole, whole, whole, in_place, in_place],
-            out_specs=[whole, in_place, in_place],
+            in_specs=whole + [where_it_lies, where_it_lies],
+            out_specs=[whole[0], where_it_lies, where_it_lies],
             scratch_shapes=[
-                pltpu.SMEM((lanes * max_pages,), jnp.int32),  # work list
-                pltpu.VMEM((lanes, heads, hd), f32),          # scaled q
-                pltpu.VMEM((lanes, heads, 1), f32),           # running max
-                pltpu.VMEM((lanes, heads, 1), f32),           # running sum
-                pltpu.VMEM((lanes, heads, hd), f32),          # weighted V
-                pltpu.VMEM((_RING, ps, heads, hd), f32),
-                pltpu.VMEM((_RING, ps, heads, hd), f32),
-                pltpu.SemaphoreType.DMA((2, _RING)),
-                pltpu.SemaphoreType.DMA((2, lanes))]),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), plane, plane],
+                pltpu.SMEM((lanes * -(-max_pages // chunk),),
+                           jnp.int32),                      # work list
+                pltpu.VMEM((lanes, heads, 1), f32),         # running max
+                pltpu.VMEM((lanes, heads, 1), f32),         # running sum
+                pltpu.VMEM((lanes, heads, width), f32),     # weighted V
+                pltpu.VMEM((ring,) + slot, k_pool.dtype),
+                pltpu.VMEM((ring,) + slot, v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, ring)),
+                pltpu.SemaphoreType.DMA((2, lanes)), aside]),
+        out_shape=[jax.ShapeDtypeStruct(operands[0].shape,
+                                        q.dtype if len(token) == 2 else f32),
+                   plane(k_pool.shape, k_pool.dtype),
+                   plane(v_pool.shape, v_pool.dtype)],
         # operands count the two scalar-prefetch ones: the planes are 5, 6
         input_output_aliases={5: 1, 6: 2},
         name="paged_decode", interpret=interpret,
-    )(pt.reshape(-1), pos, q, k_new, v_new, k_pool, v_pool)
+    )(pt.reshape(-1), pos, *operands, k_pool, v_pool)
+    if len(token) == 1:
+        out = jnp.where(mine, out.reshape(lanes, heads, kv_heads, hd),
+                        0).sum(2).astype(q.dtype)
+    return out, k_pool, v_pool
 
 
 @register("_contrib_PagedAttention",
@@ -448,35 +622,46 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
       q               : (lanes, heads, head_dim) — this step's projections
       k_new, v_new    : (lanes, kv_heads, head_dim); ``kv_heads`` divides
                         ``heads`` (grouped-query), or is ``heads``
-      k_pool, v_pool  : (num_pages, page_size, kv_heads, head_dim)
+      k_pool, v_pool  : (num_pages, page_size, kv_heads, head_dim), or with
+                        a token held as one row (num_pages, page_size,
+                        kv_heads * head_dim)
       page_table      : (lanes, max_pages) pool-page ids per lane, in
                         sequence order (float carrier, cast to int32 —
                         Predictor feeds every input as its bind dtype)
       positions       : (lanes,) this token's absolute position per lane
     Returns (att_out, k_pool_out, v_pool_out).  The attention reads the
     pool as it came and takes this step's own K/V from the projections; on
-    a TPU through the kernel, elsewhere through the gather
-    (:func:`decode_formulation`).  The engine carries the pools through
-    the step donated (``Executor.set_carried``), so the write of
-    ``lanes`` rows updates them in place; undonated it copies each pool
-    once.
+    a TPU through the kernel where it takes the planes' form (float32
+    tokens of whole tiles a head, or bfloat16 tokens of one row, grouped or
+    not), elsewhere through the gather (:func:`decode_formulation`).  The
+    engine carries the pools through the step donated
+    (``Executor.set_carried``), so the write of ``lanes`` rows updates them
+    in place; undonated it copies each pool once.
     """
     import jax.numpy as jnp
 
-    from .interpret import platform_of
+    from .interpret import carried_in_place, platform_of
 
     heads, hd = q.shape[-2:]
-    if k_new.shape[-2] != k_pool.shape[2]:
-        raise ValueError("this step's K holds %d heads, the pool's planes %d"
-                         % (k_new.shape[-2], k_pool.shape[2]))
+    kv_heads = k_new.shape[-2]
+    rows = k_pool.ndim == 3
+    if k_pool.shape[2:] != ((kv_heads * hd,) if rows else (kv_heads, hd)):
+        raise ValueError("this step's K holds %d heads of %d, the pool's "
+                         "planes a token of %s"
+                         % (kv_heads, hd, k_pool.shape[2:]))
     if int(attrs["page_size"]) != k_pool.shape[1]:
         raise ValueError("page_size %s, but the pool's pages hold %d slots"
                          % (attrs["page_size"], k_pool.shape[1]))
     scale = attrs.get("scale")
     scale = (1.0 / np.sqrt(hd)) if scale is None else float(scale)
-    decode = {"pallas": _kernel_decode, "xla": _gather_decode}[
+    decode = {"pallas": functools.partial(_kernel_decode,
+                                          in_place=carried_in_place()),
+              "xla": _gather_decode}[
         decode_formulation(platform_of(q, k_pool, v_pool), heads, hd,
-                           k_pool.dtype, kv_heads=k_pool.shape[2])]
+                           jnp.result_type(q.dtype, k_pool.dtype)
+                           if rows else k_pool.dtype,
+                           kv_heads=kv_heads, rows=rows,
+                           page_size=k_pool.shape[1])]
     return decode(q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
                   k_pool, v_pool, page_table.astype(jnp.int32),
                   positions.astype(jnp.int32), scale)

@@ -310,6 +310,19 @@ def test_lane_program_moves_no_plane(one_chip, monkeypatch, donated):
     assert not copied if donated else len(copied) >= 2 * layers
 
 
+def _no_plane_is_copied(text, model, pages):
+    """No line of the compiled module copies (plain or staged) an array of a
+    K/V plane's shape: donated, the planes go through in place."""
+    import re
+
+    for shape in {"bf16[%d,16,%d]" % ((pages,) + shape)
+                  for name, kind, shape, _ in model.planes()
+                  if name.endswith(("_k_pool", "_v_pool"))}:
+        copied = [line for line in text.splitlines() if shape in line
+                  and re.search(r" copy(-start)?\(", line)]
+        assert not copied, copied[:2]
+
+
 # the hybrid family's lane program: reduced widths with the cell's head,
 # state and convolution sizes twice over, and the cell's own widths
 # (perfbench: g4hmicro-decode-closed16) over one period of its pattern
@@ -333,8 +346,9 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
     memory space ``S(1)``: XLA's own pass over the whole plane had 4 of the
     cell case's 5 planes staged so), gathers, scatters or slices a state
     plane; no loop over lanes either.
-    Grouped-query attention takes the XLA formulation: no paged-decode
-    kernel in the program."""
+    Each grouped-query attention layer's pages (bfloat16, a token one row of
+    ``kv_heads x 64``) go through ONE ``paged_decode`` call that reads them
+    where they lie: no copy of a K/V plane, staged or plain."""
     import re
 
     import jax
@@ -388,10 +402,11 @@ def test_hybrid_lane_program_steps_its_state_in_one_pass(one_chip,
     assert len(outs) == 2 + len(planes)
     text = compiled.as_text()
     assert " while(" not in text
-    # one kernel a state-space layer, and no other
+    # one kernel a state-space layer, one an attention layer, and no other
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
-    assert len(names) == mamba and all(n.startswith("ssm_step")
-                                       for n in names), names
+    assert sorted(n.split(".")[0] for n in names) == \
+        ["paged_decode"] * w["periods"] + ["ssm_step"] * mamba, names
+    _no_plane_is_copied(text, model, pages)
     # every plane goes through the module in place: argument i is output i
     # (the logits are output 0, the planes follow in the carried order)
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
@@ -486,7 +501,8 @@ def _leaves_go_whole_into(text, kernel, calls,
         assert leaf.replace("bf16", "f32") not in text
 
 
-def _compile_for_the_chip(symbol, shapes, types, planes, one_chip):
+def _compile_for_the_chip(symbol, shapes, types, planes, one_chip,
+                          donated=True):
     import jax
     import jax.numpy as jnp
 
@@ -505,7 +521,7 @@ def _compile_for_the_chip(symbol, shapes, types, planes, one_chip):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         ex._forward_args(None))
     fwd = ex._get_fwd(False)
-    assert ex.carry_donated or not planes
+    assert ex.carry_donated == donated or not planes
     return getattr(fwd, "_fn", fwd).lower(*call).compile()
 
 
@@ -519,7 +535,9 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     copy, slice, convert or gather of it), so a step streams an expert's
     weights at most once.  The outputs end with ``next_ids`` and
     ``expert_load`` (3 layers x 32 experts, int32); every plane is aliased
-    in and out."""
+    in and out.  The attention layer's pages (32 query heads over 8 K/V
+    heads of 64, bfloat16) go through ONE ``paged_decode`` call, no K/V
+    plane copied."""
     import re
 
     import jax
@@ -557,7 +575,9 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     assert " while(" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
     kinds = sorted(n.split(".")[0] for n in names)
-    assert kinds == ["moe_grouped"] * 3 and "ragged-dot" not in text
+    assert kinds == ["moe_grouped"] * 3 + ["paged_decode"] \
+        and "ragged-dot" not in text
+    _no_plane_is_copied(text, model, pages)
     _leaves_go_whole_into(text, "moe_grouped", calls=3)
     scoped = re.findall(r"%moe_grouped[.\d]* = [^\n]*op_name=\"([^\"]*)\"",
                         text)
@@ -631,6 +651,94 @@ def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
 
 
+# the three cells whose attention layers page grouped bfloat16 K/V
+# (perfbench: g4hmicro-, lfm2moe-, g4hsmall-decode-closed16): one period of
+# each pattern at the cell's widths, and the cell's OWN pool (pages, table
+# width): what XLA does with a plane depends on its size
+PAGED_CELLS = {
+    "g4hmicro": (705, 44, dict(
+        hidden=2048, layer_types=["mamba"] * 5 + ["attention"], num_heads=32,
+        kv_heads=8, head_dim=64, intermediate=8192, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, conv_kernel=4,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=1 / 64.0, logits_scaling=8.0)),
+    "lfm2moe": (705, 44, dict(
+        hidden=2048, layer_types=["conv", "conv", "attention", "conv"],
+        num_heads=32, kv_heads=8, head_dim=64, intermediate=7168,
+        conv_kernel=3, rotary_theta=1e6, qk_norm=True, num_experts=32,
+        experts_per_token=4, expert_width=1792, num_dense_layers=1)),
+    "g4hsmall": (2433, 152, None),  # _granite_small
+}
+
+
+@pytest.mark.parametrize("cell,donated", [(c, True) for c in
+                                          sorted(PAGED_CELLS)]
+                         + [("g4hmicro", False)])
+def test_hybrid_lane_program_reads_its_pages_where_they_lie(one_chip,
+                                                            monkeypatch,
+                                                            cell, donated):
+    """The attention layer of each hybrid cell's lane program, planes carried
+    and donated, compiled for the chip at the cell's own pool: ONE
+    ``paged_decode`` call under the layer's ``paged_attention`` scope, which
+    takes both planes where they lie (a token one row of ``kv_heads x
+    head_dim`` bfloat16 lanes, so a page is whole tiles) and returns them in
+    place.  No line of the module copies a K/V plane, whole or in slices,
+    plain or staged into the fast memory ``S(1)`` (left free, XLA staged the
+    704-token cells' 11.5 MB planes there and back around the call, and with
+    the gather it relaid every plane out, 0.65 ms a step: PERF.md, PR 44),
+    and nothing gathers from one.  Undonated (the rule under the
+    framework's compile cache) XLA copies each plane once and the program
+    still compiles: a plane held to the HBM that XLA must copy first aborts
+    its memory-space assignment, so the kernel holds only donated ones
+    (``interpret.carried_in_place``)."""
+    import re
+
+    import jax.numpy as jnp
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: not donated)
+    from mxnet_tpu.models import HybridLM
+
+    pages, max_pages, sizes = PAGED_CELLS[cell]
+    model = _granite_small(["mamba", "attention"]) if sizes is None else \
+        HybridLM(vocab_size=512, **sizes)
+    lanes, slots = 16, 17
+    symbol = model.decode_symbol(max_pages * 16, 16)
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids", "state_slot")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    text = _compile_for_the_chip(symbol, shapes, types, planes, one_chip,
+                                 donated).as_text()
+    calls = re.findall(r"%(paged_decode[.\d]*) = [^\n]*"
+                       r"\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"", text)
+    assert len(calls) == 1 and re.search(
+        r"layer\d+_attn/paged_attention/", calls[0][1]), calls
+    width = model.kv_heads * model.head_dim
+    plane = r"bf16\[(%d,16|%d),%d\]" % (pages, pages * 16, width)
+    uses = [line for line in text.splitlines()
+            if re.search(plane, line) and re.match(r"\s*(ROOT )?%[\w.\-]+ = ",
+                                                    line)]
+    moved = [line for line in uses if re.search(
+        r" (copy|copy-start|copy-done|slice-start|slice-done|gather|scatter|"
+        r"dynamic-update-slice|fusion)\(", line)]
+    if not donated:
+        assert moved and not any(" gather(" in line for line in moved)
+        return
+    assert not moved, moved[:2]
+    staged = [line for line in uses
+              if re.search(plane + r"\{[^}]*S\(1\)", line)]
+    assert not staged, staged[:2]
+    # both planes aliased in and out of the module
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
 def _granite_small(layer_types, vocab=512):
     """Granite 4.0-H Small's block at its published widths, 36 of its 72
     experts held (perfbench/configs/granite-4.0-h-small.json)."""
@@ -656,7 +764,9 @@ def test_granite_moe_lane_program_steps_state_and_streams_experts(
     (160 pairs a step: its tiles fit), whole and in bfloat16; the router
     (the softmax over the picked logits) under ``moe_router``, the shared
     MLP the dense MLP's three ops; ``expert_load`` (2 layers x 72) the last
-    output; every plane aliased in and out."""
+    output; every plane aliased in and out; the attention layer's pages (32
+    query heads over 8 K/V heads of 128, bfloat16: the ``g4hsmall`` cell's)
+    through ONE ``paged_decode`` call, no K/V plane copied."""
     import re
 
     import jax
@@ -686,7 +796,8 @@ def test_granite_moe_lane_program_steps_state_and_streams_experts(
     assert " while(" not in text and "ragged-dot" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
     assert sorted(n.split(".")[0] for n in names) == \
-        ["moe_grouped", "moe_grouped", "ssm_step"]
+        ["moe_grouped", "moe_grouped", "paged_decode", "ssm_step"]
+    _no_plane_is_copied(text, model, pages)
     _leaves_go_whole_into(text, "moe_grouped", calls=2,
                           leaves=("bf16[36,4096,1536]", "bf16[36,768,4096]"))
     ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
@@ -728,8 +839,8 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
         [], one_chip)
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert [o.shape for o in outs] == [
-        (1, L, vocab), (1, 128, 64, 128), (1, 3, 8448), (1, L, 8, 128),
-        (1, L, 8, 128)]
+        (1, L, vocab), (1, 128, 64, 128), (1, 3, 8448), (1, L, 8 * 128),
+        (1, L, 8 * 128)]
     text = compiled.as_text()
     assert "ragged-dot" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)

@@ -77,7 +77,10 @@ def test_server_phase_toy_width_on_cpu():
         dict(TOY_LM, max_seq=64, lanes=4, page_size=8,
              prompt_lens=(8, 16, 32), new_tokens=6,
              paged=dict(lanes=2, num_pages=9, page_size=4, heads=2,
-                        head_dim=8, max_pages=4, positions=(3, 14))),
+                        head_dim=8, max_pages=4, positions=(3, 14)),
+             paged_grouped=(dict(lanes=3, num_pages=13, page_size=16, heads=8,
+                                 kv_heads=2, head_dim=64, max_pages=4,
+                                 positions=(3, 60), dtype="bfloat16"),)),
         mx.tpu(0))
     assert out["paged_kernel_gap"] <= 1e-5  # interpreted: float32 both
     assert len(out["transcripts"]) == 4

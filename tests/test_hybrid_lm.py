@@ -430,12 +430,15 @@ def test_spans_and_counters_of_the_state(tmp_path):
 # kinds: a convolution mixer, rotary / QK-norm, routed experts).  The JSON is
 # the compile-cache fingerprint and fixes every named scope of the device
 # trace; a PR that changes a graph on purpose replaces its digest here and
-# says so in CHANGES.md.
+# says so in CHANGES.md.  (PR 44: the prefill graph returns an attention
+# layer's K and V slabs with a token as ONE row of kv_heads * head_dim, two
+# Reshape nodes a layer, as the planes hold it now; the lane graph takes its
+# planes as unshaped Variables and did not move.)
 _DIGESTS = {
     "score":
         "709b3c0a98e8c33203bc78e65d41a9b59a1ce2be1e6aaaf73892eea570ba1e1c",
     "prefill":
-        "fa8b9fc814ebe01ad9b21ab63e0451ca04c7a27738f2c60279196281ec15b514",
+        "54f0e77983ea61f083ef09cc2f8dd42de543e8c11ca12335324cbe9016b8a449",
     "decode":
         "b3e417a9826d79e72bb7c6f948657bb79a0b16aafb91c6e8ef23ac60d664fc5e",
 }

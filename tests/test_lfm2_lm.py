@@ -311,7 +311,7 @@ def test_the_family_says_which_planes_and_outputs_it_carries():
     spec = builder.family_spec(_cfg())
     fam = HybridLM(**spec)
     kinds = [(kind, shape) for _, kind, shape, _ in fam.planes()]
-    assert kinds == ([("slot", (2, 32))] * 2 + [("paged", (2, 8))] * 2
+    assert kinds == ([("slot", (2, 32))] * 2 + [("paged", (2 * 8,))] * 2
                      + [("slot", (2, 32))]) * 2
     assert fam.lane_extras == ("expert_load",)
     assert fam.expert_layers == tuple(range(2, 8))

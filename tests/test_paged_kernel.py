@@ -17,8 +17,21 @@ from mxnet_tpu.ops.interpret import bound_to
 
 HEADS, HD = 2, 8
 
+# the two forms of plane the kernel takes, name -> (query heads, K/V heads,
+# head_dim, dtype, a token held as one row of kv_heads * head_dim): float32
+# tokens by heads (toy widths), and the bfloat16 row tokens of the three
+# hybrid cells' attention layers (their own head shapes) and of a family
+# whose every query head has a K/V head of its own
+FORMS = {
+    "float32": (HEADS, HEADS, HD, jnp.float32, False),
+    "bf16_32_over_8_of_64": (32, 8, 64, jnp.bfloat16, True),
+    "bf16_32_over_8_of_128": (32, 8, 128, jnp.bfloat16, True),
+    "bf16_16_of_128_group_1": (16, 16, 128, jnp.bfloat16, True),
+}
+
 # name -> (page_size, max_pages, positions; None is an inactive lane,
-#          page ids handed out: "shuffled", "descending" or "ascending")
+#          page ids handed out: "shuffled", "descending" or "ascending"
+#          [, the planes' form: "float32" unless given])
 CASES = {
     "page_size_4": (4, 8, [5, 31, 12, 18], "shuffled"),
     "page_size_16": (16, 4, [40, 7, 63, 22], "shuffled"),
@@ -34,17 +47,36 @@ CASES = {
     "lane_bucket_4": (16, 4, [33, 1, 17, 60], "shuffled"),
     "lane_bucket_8": (4, 8, [3, 30, 11, None, 16, 23, 0, 8], "shuffled"),
     "more_pages_than_the_ring": (4, 16, [63, 50, 37], "shuffled"),
+    # grouped bfloat16 pages: an inactive lane, a live lane at position 0,
+    # lanes of one page and less, and tables of more slots than the ring
+    "grouped_heads_of_64": (16, 12, [40, None, 0, 191, 130, 16, 7],
+                            "shuffled", "bf16_32_over_8_of_64"),
+    "grouped_heads_of_128": (16, 12, [40, None, 0, 191, 130, 16, 7],
+                             "shuffled", "bf16_32_over_8_of_128"),
+    "bfloat16_group_of_1": (16, 12, [40, None, 0, 191, 130], "shuffled",
+                            "bf16_16_of_128_group_1"),
+    "grouped_more_slots_than_the_ring": (16, 96, [1535, 700, None, 1100],
+                                         "descending",
+                                         "bf16_32_over_8_of_64"),
+    "grouped_all_lanes_inactive": (16, 4, [None, None], "ascending",
+                                   "bf16_32_over_8_of_64"),
+    "grouped_page_size_4": (4, 8, [5, 31, 12, 0], "shuffled",
+                            "bf16_32_over_8_of_64"),
 }
 
 
-def _operands(page_size, max_pages, positions, order, seed=0):
+def _operands(page_size, max_pages, positions, order, form="float32",
+              seed=0):
+    heads, kv_heads, hd, dtype, rows = FORMS[form]
     rng = np.random.RandomState(seed)
     lanes = len(positions)
     num_pages = 1 + lanes * max_pages  # page 0 is the scratch page
-    q, k_new, v_new = (jnp.asarray(rng.randn(lanes, HEADS, HD), jnp.float32)
-                       for _ in range(3))
-    k_pool, v_pool = (jnp.asarray(rng.randn(num_pages, page_size, HEADS, HD),
-                                  jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.randn(lanes, heads, hd), dtype)
+    k_new, v_new = (jnp.asarray(rng.randn(lanes, kv_heads, hd), dtype)
+                    for _ in range(2))
+    token = (kv_heads * hd,) if rows else (kv_heads, hd)
+    k_pool, v_pool = (jnp.asarray(rng.randn(num_pages, page_size, *token),
+                                  dtype) for _ in range(2))
     free = list(range(1, num_pages))  # popped from the end
     if order == "shuffled":
         rng.shuffle(free)
@@ -59,14 +91,31 @@ def _operands(page_size, max_pages, positions, order, seed=0):
     return q, k_new, v_new, k_pool, v_pool, jnp.asarray(table), at
 
 
+def _in_float32(ops):
+    """The same values, held in float32: the gather over them rounds
+    nothing, so it is the oracle of a kernel whose sums are float32."""
+    return [x.astype(jnp.float32) for x in ops[:5]] + list(ops[5:])
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_matches_the_gather(name):
-    page_size, max_pages, positions, order = CASES[name]
-    ops = _operands(page_size, max_pages, positions, order)
-    scale = 1.0 / np.sqrt(HD)
+    page_size, max_pages, positions, order = CASES[name][:4]
+    ops = _operands(*CASES[name])
+    scale = 1.0 / np.sqrt(ops[0].shape[-1])
     want, want_k, want_v = paged._gather_decode(*ops, scale)
     got, got_k, got_v = paged._kernel_decode(*ops, scale, interpret=True)
-    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    if got.dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    else:
+        # bfloat16 pages: the kernel's products and sums are float32 (never
+        # less exact than the gather's, which rounds its scores), so it is
+        # held to the float32 gather over the same values, to the ONE
+        # rounding of its output (8 bits of mantissa)
+        exact = paged._gather_decode(*_in_float32(ops), scale)[0]
+        np.testing.assert_allclose(got.astype(jnp.float32), exact,
+                                   rtol=2.0 ** -8, atol=1e-4)
+        assert got.dtype == want.dtype == ops[0].dtype
+        assert got_k.dtype == got_v.dtype == ops[3].dtype
     # the pool: nothing but the lanes' rows changed, and those hold this
     # step's K/V.  (Every inactive lane writes the scratch page's first
     # slot: which of them lands last is nobody's business.)
@@ -79,27 +128,36 @@ def test_kernel_matches_the_gather(name):
             if pos is not None:
                 page = table[lane, pos // page_size]
                 np.testing.assert_array_equal(
-                    got_p[page, pos % page_size], new[lane])
+                    got_p[page, pos % page_size],
+                    new[lane].reshape(before.shape[2:]))
     if sum(p is None for p in positions) == 1:
         np.testing.assert_array_equal(got_k[0], want_k[0])
 
 
-def test_history_beyond_the_position_is_never_read():
+@pytest.mark.parametrize("form,page_size,positions", [
+    ("float32", 4, [9, 14]),
+    ("bf16_32_over_8_of_64", 16, [37, 58]),
+    ("bf16_32_over_8_of_128", 16, [37, 58]),
+    ("bf16_16_of_128_group_1", 16, [37, 58]),
+])
+def test_history_beyond_the_position_is_never_read(form, page_size,
+                                                   positions):
     """Slots at and after a lane's position may hold anything (a retired
     sequence's tokens, the slot this step writes): neither formulation lets
     them into the softmax, and the kernel does not even fetch the pages
-    after the position's."""
-    page_size, max_pages, positions = 4, 8, [9, 14]
-    ops = list(_operands(page_size, max_pages, positions, "shuffled"))
-    scale = 1.0 / np.sqrt(HD)
+    after the position's (a slot of the ring holds several bfloat16 pages of
+    a lane: the rows past its live pages are masked too)."""
+    max_pages = 8
+    ops = list(_operands(page_size, max_pages, positions, "shuffled", form))
+    scale = 1.0 / np.sqrt(ops[0].shape[-1])
     want = paged._kernel_decode(*ops, scale, interpret=True)[0]
     table = np.asarray(ops[5])
     for plane in (3, 4):
-        pool = np.asarray(ops[plane]).copy()
+        pool = np.asarray(ops[plane].astype(jnp.float32)).copy()
         for lane, pos in enumerate(positions):
             page = table[lane, pos // page_size]
             pool[page, pos % page_size:] = 1e4
-        ops[plane] = jnp.asarray(pool)
+        ops[plane] = jnp.asarray(pool, ops[plane].dtype)
     # a dead page id in the table's tail: must not be fetched (NaN poisons)
     poisoned = table.copy()
     poisoned[0, positions[0] // page_size + 1:] = table[1, 0]
@@ -114,28 +172,59 @@ def test_history_beyond_the_position_is_never_read():
     ("gpu", 16, 128, np.float32, "xla"),
     ("tpu", 2, 8, np.float32, "xla"),        # a token's K is no whole tile
     ("tpu", 4, 128, np.float32, "xla"),
-    ("tpu", 16, 128, jnp.bfloat16, "xla"),   # the kernel is float32's
+    # bfloat16 tokens by heads are no whole tiles: only as ONE row
+    ("tpu", 16, 128, jnp.bfloat16, "xla"),
 ])
 def test_formulation_follows_where_the_operands_live(platform, heads,
                                                      head_dim, dtype, want):
     assert paged.decode_formulation(platform, heads, head_dim, dtype) == want
 
 
-def _pallas_calls(jaxpr):
-    names = []
+@pytest.mark.parametrize("platform,heads,kv_heads,head_dim,dtype,more,want", [
+    # the three hybrid cells' attention layers, and a group of 1
+    ("tpu", 32, 8, 64, jnp.bfloat16, {}, "pallas"),
+    ("tpu", 32, 8, 128, jnp.bfloat16, {}, "pallas"),
+    ("tpu", 16, 16, 128, jnp.bfloat16, {}, "pallas"),
+    ("cpu", 32, 8, 64, jnp.bfloat16, {}, "xla"),
+    ("gpu", 32, 8, 128, jnp.bfloat16, {}, "xla"),
+    # float32 rows, or a float32 query over bfloat16 rows: the gather
+    ("tpu", 32, 8, 64, np.float32, {}, "xla"),
+    ("tpu", 4, 2, 8, jnp.bfloat16, {}, "xla"),     # a row of 16 lanes
+    ("tpu", 32, 8, 24, jnp.bfloat16, {}, "xla"),   # 192: off a lane tile
+    ("tpu", 12, 5, 128, jnp.bfloat16, {}, "xla"),  # no whole groups
+    ("tpu", 32, 8, 64, jnp.bfloat16, {"page_size": 4}, "xla"),
+    ("tpu", 32, 8, 64, jnp.bfloat16, {"page_size": 32}, "pallas"),
+])
+def test_formulation_takes_a_token_held_as_one_row(platform, heads, kv_heads,
+                                                   head_dim, dtype, more,
+                                                   want):
+    """``rows``: the plane holds a token as one row of ``kv_heads x
+    head_dim`` lanes.  The kernel takes such pages in bfloat16 where the row
+    is whole lane tiles and the page whole sublane tiles, grouped or not."""
+    assert paged.decode_formulation(platform, heads, head_dim, dtype,
+                                    kv_heads=kv_heads, rows=True,
+                                    **more) == want
+
+
+def _pallas_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            names.append(eqn.params["name"])
+            yield eqn
         for v in eqn.params.values():
             inner = getattr(v, "jaxpr", v)
             if hasattr(inner, "eqns"):
-                names += _pallas_calls(inner)
-    return names
+                yield from _pallas_eqns(inner)
 
 
-@pytest.mark.parametrize("platform,want", [("cpu", []),
-                                           ("tpu", ["paged_decode"])])
-def test_op_picks_by_the_executors_scope(platform, want):
+def _pallas_calls(jaxpr):
+    return [eqn.params["name"] for eqn in _pallas_eqns(jaxpr)]
+
+
+@pytest.mark.parametrize("platform,form,want", [
+    ("cpu", "float32", []), ("tpu", "float32", ["paged_decode"]),
+    ("cpu", "bfloat16 rows", []), ("tpu", "bfloat16 rows", ["paged_decode"]),
+    ("tpu", "float32 query, bfloat16 rows", [])])
+def test_op_picks_by_the_executors_scope(platform, form, want):
     """Traced under ``bound_to`` (what an Executor enters around every
     program it traces) the op runs the kernel on a tpu context only: no
     attribute, no environment variable.  Traced, not run."""
@@ -145,6 +234,13 @@ def test_op_picks_by_the_executors_scope(platform, want):
     shapes = [(2, heads, hd)] * 3 + [(5, page_size, heads, hd)] * 2 + \
         [(2, 4), (2,)]
     args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    if form != "float32":  # 8 query heads over 2 K/V heads of 128, a row
+        page_size, bf16 = 16, jnp.bfloat16
+        args = [jax.ShapeDtypeStruct(
+                    (2, heads, hd), jnp.float32 if "query" in form else bf16)
+                ] + [jax.ShapeDtypeStruct((2, 2, hd), bf16)] * 2 + \
+            [jax.ShapeDtypeStruct((5, page_size, 2 * hd), bf16)] * 2 + \
+            args[5:]
     op = get_op("_contrib_PagedAttention")
 
     def step(*a):
@@ -152,6 +248,35 @@ def test_op_picks_by_the_executors_scope(platform, want):
             return op.fn(None, {"page_size": page_size}, *a)
 
     assert _pallas_calls(jax.make_jaxpr(step)(*args).jaxpr) == want
+
+
+@pytest.mark.parametrize("donated", [True, False])
+def test_the_kernel_holds_only_donated_planes_to_the_hbm(donated):
+    """Inside a program whose carried planes are donated (what an Executor
+    says around it: ``interpret.carrying``) the kernel's aliased plane
+    outputs are held to the HBM, so that XLA cannot stage a whole plane
+    through its fast memory around the call; a plane XLA must copy first is
+    left free, because held it aborts the TPU compiler (tests/
+    test_chip_compile.py compiles both).  Traced, not run."""
+    from mxnet_tpu.ops.interpret import carrying
+    from mxnet_tpu.ops.registry import get_op
+
+    bf16 = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct((2, 8, 128), bf16)] + \
+        [jax.ShapeDtypeStruct((2, 2, 128), bf16)] * 2 + \
+        [jax.ShapeDtypeStruct((5, 16, 256), bf16)] * 2 + \
+        [jax.ShapeDtypeStruct((2, 4), jnp.float32),
+         jax.ShapeDtypeStruct((2,), jnp.float32)]
+    op = get_op("_contrib_PagedAttention")
+
+    def step(*a):
+        with bound_to("tpu"):
+            return op.fn(None, {"page_size": 16}, *a)
+
+    (eqn,) = _pallas_eqns(jax.make_jaxpr(carrying(step, donated))(*args).jaxpr)
+    spaces = [str(getattr(aval, "memory_space", None))
+              for aval in eqn.params["out_avals"]]
+    assert [s == "hbm" for s in spaces] == [False, donated, donated], spaces
 
 
 V, LAYERS, S, PAGE = 64, 2, 32, 4
@@ -195,3 +320,47 @@ def test_engine_says_which_formulation_and_how_many_pages(monkeypatch):
     assert [s["lanes"] for s in steps] == [1] * (new - 1)
     assert [s["pages"] for s in steps] == \
         [(len(prompt) + k) // PAGE + 1 for k in range(new - 1)]
+
+
+def test_engine_says_pallas_for_grouped_bfloat16_pages_on_a_tpu(monkeypatch):
+    """A family whose attention layers page grouped bfloat16 K/V (the hybrid
+    one: 8 query heads over 2 K/V heads of 64, a token ONE row of 128 lanes,
+    pages of 16) says ``pallas`` where its planes live on a TPU and ``xla`` on
+    the host platform; a float32 pool of the same family, and pages that are
+    no whole tiles, say ``xla`` on both."""
+    import types
+
+    from perfbench.builders import hybrid_lm as builder
+    from perfbench.models import hybrid_lm as ref
+
+    cfg = dict(vocab_size=V, hidden_size=512, layer_types=["mamba",
+                                                           "attention"],
+               num_attention_heads=8, num_key_value_heads=2,
+               intermediate_size=64, shared_intermediate_size=64,
+               mamba_n_heads=16, mamba_d_head=64, mamba_d_state=8,
+               mamba_d_conv=4, mamba_expand=2, mamba_n_groups=1,
+               mamba_chunk_size=4, mamba_conv_bias=True, rms_norm_eps=1e-5,
+               embedding_multiplier=1.0, residual_multiplier=1.0,
+               attention_multiplier=0.125, logits_scaling=1.0,
+               position_embedding_type="nope", tie_word_embeddings=True)
+    said = {}
+    for dtype, page_size in (("bfloat16", 16), ("float32", 16),
+                             ("bfloat16", 4)):
+        cfg["weights_dtype"] = dtype
+        params = {k: mx.nd.NDArray(v, mx.cpu())
+                  for k, v in ref.make_weights(cfg, 3).items()}
+        eng = DecodeEngine(params, family=builder.family_spec(cfg),
+                           ctx=mx.cpu(), max_seq_len=64, lane_buckets=(2,),
+                           page_size=page_size, num_pages=9,
+                           prefill_len_buckets=(16,), start=False,
+                           warmup=False)
+        plane = eng.pool.k_pools[0]
+        assert plane.shape == (9, page_size, 2 * 64) and \
+            str(plane.dtype) == dtype
+        on_host = eng.snapshot()["paged_attention"]
+        monkeypatch.setattr(eng, "_device",
+                            types.SimpleNamespace(platform="tpu"))
+        said[dtype, page_size] = (on_host, eng.snapshot()["paged_attention"])
+    assert said == {("bfloat16", 16): ("xla", "pallas"),
+                    ("float32", 16): ("xla", "xla"),
+                    ("bfloat16", 4): ("xla", "xla")}

@@ -298,8 +298,10 @@ def test_gather_decode_groups_are_repeated_kv_heads(heads, kv_heads):
 
 
 def test_grouped_planes_take_the_gather_on_every_platform():
-    """The Pallas kernel knows no groups: planes with fewer heads than the
-    query go through the XLA formulation even where it would run."""
+    """Planes whose token is BY HEADS, with fewer heads than the query, go
+    through the XLA formulation even where the kernel would run: the kernel
+    takes groups over a token held as one row (bfloat16; the hybrid
+    family's planes since PR 44: tests/test_paged_kernel.py)."""
     assert paged.decode_formulation("tpu", 16, 128, np.float32) == "pallas"
     assert paged.decode_formulation("tpu", 16, 128, np.float32,
                                     kv_heads=16) == "pallas"
@@ -307,6 +309,8 @@ def test_grouped_planes_take_the_gather_on_every_platform():
                                     kv_heads=8) == "xla"
     assert paged.decode_formulation("tpu", 32, 64, jnp.bfloat16,
                                     kv_heads=8) == "xla"
+    assert paged.decode_formulation("tpu", 32, 64, jnp.bfloat16,
+                                    kv_heads=8, rows=True) == "pallas"
 
 
 def test_paged_attention_refuses_planes_of_other_heads():
