@@ -108,7 +108,7 @@ from ..base import MXNetError, env, register_env
 from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
-from ..ops.moe import experts_formulation
+from ..ops.moe import experts_formulation, experts_path
 from ..ops.paged import LATENT_FORMULATIONS, decode_formulation
 from ..ops.ssm import step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
@@ -1209,6 +1209,9 @@ class DecodeEngine:
         if "expert_load" in self._lane_extras:
             # every prompt token routes in every expert layer
             args["expert_pairs"] = self.family.expert_pairs(args["tokens"])
+            if misses:
+                args.update(self._experts_moved(
+                    self._prefill[L].bucket_for(len(misses)) * L))
         with _span("gen:prefill", "gen", args):
             start = time.monotonic()
             for seq in admitted:
@@ -1268,6 +1271,20 @@ class DecodeEngine:
                                 if not s.stream.done)
             self.metrics.admitted.inc(len(admitted))
             self.metrics.g_active.set(len(self._active))
+
+    def _experts_moved(self, rows):
+        """What an expert layer of a program over ``rows`` rows moves at the
+        width of a row, as span arguments: ``experts_path`` (``ops/moe.py``
+        ``experts_path``: ``"held"`` or ``"all"``) and ``expert_pairs_moved``,
+        the share of the layer's (row, pick) pairs that path moves, by the
+        static share of the experts held here (1.0 for ``"all"``)."""
+        held = self.family.experts_held
+        path = experts_path(
+            experts_formulation(self._device.platform, *self._moe_experts),
+            held, self.family.num_experts,
+            rows * self.family.experts_per_token)
+        share = held / self.family.num_experts if path == "held" else 1.0
+        return {"experts_path": path, "expert_pairs_moved": round(share, 4)}
 
     def _catchup_group(self, seqs: List[_Seq]):
         """Batch-walk the KNOWN suffix of prefix hits through the

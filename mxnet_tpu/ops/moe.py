@@ -42,6 +42,31 @@ next tile in flight while this one multiplies; an expert no row picked costs
 no fetch); for other operands ``jax.lax.ragged_dot`` twice, which on a TPU is
 XLA's own grouped kernel and anywhere else XLA's dense expansion, the oracle
 of both (tests/test_moe_kernel.py).
+
+What is moved around the products, and for which pairs, is a second
+observation (:func:`experts_path`, from the formulation, the held count
+against the router's width, and the pair count).  The index work -- the
+sort key, the stable sort, the groups' sizes: ``n x k`` int32 values -- is
+over all pairs on either path; the rows (``H`` features each) are not:
+
+``"held"``  where the kernel runs, the chip holds a SHARE of the experts and
+    the pairs fill more than one row tile (a prefill's thousands): the held
+    pairs are the sorted order's prefix, and only the row tiles they fill
+    are gathered from the layer's rows, once, in the layout the kernel reads
+    (a loop whose trip count is the live tiles, into a buffer nobody
+    filled); the kernel's float32 output is neither sliced nor gathered: the
+    kernel ``moe_combine`` streams its live tiles, where they lie, and adds
+    each held pair's row, weighted, into its row's float32 sum, all the
+    layer's sums resident in VMEM a block of columns at a time.  A pair of
+    another share costs an int32 in the sort and nothing else.  Nothing is
+    bounded: with every pick held every tile is live, and the answer is the
+    same, only slower.
+``"all"``   a lane step's one row tile, a layer that holds every expert and
+    the XLA formulations: every pair's row is gathered into sorted order,
+    the products' output is gathered back to (row, pick) order and summed
+    over the picks.  Also what a gradient goes through, on either path
+    (over ``ragged_dot``: the kernels are forward passes, and a loop with a
+    dynamic trip count has no reverse mode).
 """
 from __future__ import annotations
 
@@ -133,12 +158,12 @@ _TILE_BYTES = 4 << 20
 _ROW_TILE, _CHUNK = 512, 64
 
 
-def _depth_tile(depth, width, itemsize):
+def _depth_tile(depth, width, itemsize, budget=_TILE_BYTES):
     """Rows of a weight tile ``(rows, width)``: the most that divide
     ``depth``, are whole lane tiles (they are the last axis of the rows'
-    block) and fit ``_TILE_BYTES``; ``depth`` where none does."""
+    block) and fit ``budget``; ``depth`` where none does."""
     fits = [t for t in range(128, depth + 1, 128)
-            if depth % t == 0 and t * width * itemsize <= _TILE_BYTES]
+            if depth % t == 0 and t * width * itemsize <= budget]
     return max(fits, default=depth)
 
 
@@ -238,33 +263,18 @@ def _grouped_kernel(offs, ve, vt, nv, x_ref, w13_ref, w2_ref, o_ref, h_ref,
     lax.fori_loop(first, last, multiply, None)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "depths", "interpret"))
-def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
-                    interpret=False):
-    """The Pallas formulation: one call a layer, both products and the gate
-    (``h`` never leaves VMEM, and the second leaf's first tile is in flight
-    while the first leaf's last one multiplies: two calls would drain and
-    fill the pipeline between them, and write and read ``a``).  The grid is
-    (visit, weight tile); :func:`_visits`' lists are the scalar-prefetch
-    operands and every index map reads them, so the call's own pipeline
-    fetches the next tile of this expert, or the first tile of the NEXT
-    expert with a group, while this one multiplies, and an expert without a
-    group is never named: no byte of it moves.  The leaves are read where
-    they lie: a tile is ``depths[i]`` whole rows of ``w13[e]`` / ``w2[e]``,
-    one contiguous piece.  ``w2``'s map stays on the previous visit's last
-    tile until this visit's second product starts, so that every step
-    starts exactly one tile's fetch.  Jitted on its own so that a program's
-    14 call sites trace and lower it once (as ``ops/paged.py``
-    ``_kernel_decode``); ``rows`` (row tile, chunk) and ``depths`` (of a
-    tile of each leaf) are for tests (:func:`_row_tiles`,
-    :func:`_depth_tile`)."""
+def _grouped_call(pairs, held, hidden, width, dtype, rows, depths, interpret):
+    """The ``pallas_call`` of ``moe_grouped`` for that many sorted pairs and
+    what it was cut to: ``(call, row tile, row tiles, k13, d13)``.  ``call``
+    takes :func:`_visits`' four lists, the pairs' rows as ``(k13, tiles x row
+    tile, d13)`` (weight tile, row, feature of the tile: a step indexes the
+    leading axis) and both leaves, and returns ``(tiles x row tile, hidden)``
+    float32 of which only the row tiles a visit names are written."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    pairs, hidden = x.shape
-    held, width = w2.shape[:2]
     row_tile, chunk = rows or _row_tiles(pairs)
-    item = w13.dtype.itemsize
+    item = jnp.dtype(dtype).itemsize
     d13, d2 = depths or (_depth_tile(hidden, 2 * width, item),
                          _depth_tile(width, hidden, item))
     k13, k2 = hidden // d13, width // d2
@@ -296,7 +306,7 @@ def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
                       pl.BlockSpec((None, d2, hidden), w2_map)],
             out_specs=pl.BlockSpec((row_tile, hidden), o_map),
             scratch_shapes=[pltpu.VMEM((row_tile, 2 * width), _F32),
-                            pltpu.VMEM((k2, row_tile, d2), x.dtype)]),
+                            pltpu.VMEM((k2, row_tile, d2), dtype)]),
         out_shape=jax.ShapeDtypeStruct((tiles * row_tile, hidden), _F32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -308,11 +318,37 @@ def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
             bytes_accessed=(min(held, pairs) * 3 * hidden * width * item
                             + pairs * hidden * (item + 4))),
         name="moe_grouped", interpret=interpret)
+    return call, row_tile, tiles, k13, d13
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "depths", "interpret"))
+def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
+                    interpret=False):
+    """The Pallas formulation: one call a layer, both products and the gate
+    (``h`` never leaves VMEM, and the second leaf's first tile is in flight
+    while the first leaf's last one multiplies: two calls would drain and
+    fill the pipeline between them, and write and read ``a``).  The grid is
+    (visit, weight tile); :func:`_visits`' lists are the scalar-prefetch
+    operands and every index map reads them, so the call's own pipeline
+    fetches the next tile of this expert, or the first tile of the NEXT
+    expert with a group, while this one multiplies, and an expert without a
+    group is never named: no byte of it moves.  The leaves are read where
+    they lie: a tile is ``depths[i]`` whole rows of ``w13[e]`` / ``w2[e]``,
+    one contiguous piece.  ``w2``'s map stays on the previous visit's last
+    tile until this visit's second product starts, so that every step
+    starts exactly one tile's fetch.  Jitted on its own so that a program's
+    14 call sites trace and lower it once (as ``ops/paged.py``
+    ``_kernel_decode``); ``rows`` (row tile, chunk) and ``depths`` (of a
+    tile of each leaf) are for tests (:func:`_row_tiles`,
+    :func:`_depth_tile`)."""
+    pairs, hidden = x.shape
+    call, row_tile, tiles, k13, d13 = _grouped_call(
+        pairs, w2.shape[0], hidden, w2.shape[1], x.dtype, rows, depths,
+        interpret)
 
     @jax.custom_vjp
     def grouped(x, sizes, w13, w2):
-        # rows past the pairs belong to no group; the rows' block is (weight
-        # tile, row, feature of the tile): a step indexes its leading axis
+        # rows past the pairs belong to no group
         x = jnp.pad(x, ((0, tiles * row_tile - pairs), (0, 0)))
         x = x.reshape(tiles * row_tile, k13, d13).swapaxes(0, 1)
         return call(*_visits(sizes, tiles, row_tile), x, w13, w2)[:pairs]
@@ -330,6 +366,132 @@ def _kernel_grouped(x, sizes, w13, w2, rows=None, depths=None,
     grouped.defvjp(lambda *operands: (grouped(*operands), operands),
                    backward)
     return grouped(x, sizes, w13, w2)
+
+
+# bytes of the combine's float32 sums in VMEM (all rows of the layer, one
+# block of columns)
+_SUMS_BYTES = 16 << 20
+
+
+def _combine_kernel(total, token, weight, y_ref, o_ref, acc):
+    """One row tile of the grouped kernel's output (the sorted pairs'
+    results, where they lie), one block of columns: each held pair's row,
+    times its weight, is added to its token's row of ``acc``, the float32
+    sums of ALL the layer's rows for this block of columns, which stay in
+    VMEM while the live tiles stream by (a row of the sums is reached by a
+    dynamic sublane offset; a DMA could not fetch one row of a tiled
+    plane).  A pair past the held ones is not looked at, a tile past the
+    live ones is never fetched (the index map stays on the last live one).
+    The last tile's step casts the sums into the output's block."""
+    from jax.experimental import pallas as pl
+
+    t, tiles = pl.program_id(1), pl.num_programs(1)
+    tile = y_ref.shape[0]
+
+    @pl.when(t == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    def add(r, carry):
+        at = t * tile + r
+        row = pl.ds(token[at], 1)
+        acc[row, :] += y_ref[pl.ds(r, 1), :] * weight[at]
+        return carry
+
+    lax.fori_loop(0, jnp.clip(total[0] - t * tile, 0, tile), add, None)
+
+    @pl.when(t == tiles - 1)
+    def _():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+def _combine(y, token, weight, total, n, dtype, row_tile, cols, interpret):
+    """``y`` (tiles x row tile, H) float32 the grouped kernel's output,
+    ``token`` / ``weight`` (tiles x row tile,) the row and the float32
+    weight of each sorted pair, ``total`` (1,) how many of them are held
+    (the first ones): the ``n`` rows' weighted sums (n, H) in ``dtype``,
+    made from the live tiles of ``y`` alone (kernel ``moe_combine``: each
+    read once, nothing of ``y`` copied, sliced or gathered)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hidden = y.shape[1]
+    tiles = y.shape[0] // row_tile
+    cols = cols or _depth_tile(hidden, n, 4, _SUMS_BYTES)
+
+    def y_map(c, t, total, token, weight):
+        live = (total[0] + row_tile - 1) // row_tile
+        return jnp.minimum(t, jnp.maximum(live - 1, 0)), c
+
+    vmem = n * cols * (4 + 2 * jnp.dtype(dtype).itemsize) \
+        + 2 * row_tile * cols * 4
+    return pl.pallas_call(
+        _combine_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(hidden // cols, tiles),
+            in_specs=[pl.BlockSpec((row_tile, cols), y_map)],
+            out_specs=pl.BlockSpec((n, cols), lambda c, t, *_: (0, c)),
+            scratch_shapes=[pltpu.VMEM((n, cols), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((n, hidden), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(2 * vmem + (8 << 20), 100 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * y.shape[0] * hidden, transcendentals=0,
+            bytes_accessed=y.shape[0] * hidden * 4 + n * hidden * 2),
+        name="moe_combine", interpret=interpret)(total, token, weight, y)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "depths", "cols",
+                                             "interpret"))
+def _kernel_held(x, token, weight, sizes, w13, w2, rows=None, depths=None,
+                 cols=None, interpret=False):
+    """The layer's output for the pairs whose expert is held, moving no row
+    of any other pair.  ``x`` (n, H) the layer's rows, ``token`` / ``weight``
+    (n x k,) the row and the float32 weight of each pair in sorted order (the
+    held pairs are its first ``sum(sizes)``), ``sizes`` (held,).
+
+    Into the kernel: the row tiles that hold a held pair, and no other, are
+    gathered from ``x`` straight into the layout ``moe_grouped`` reads (a
+    loop whose trip count is the live tiles: what lies past them is never
+    written, and no visit names it).  Out of it: ``moe_combine`` adds the
+    live tiles of the kernel's output, where they lie, into the rows' sums
+    (float32, in the sorted pairs' order); the output is neither sliced nor
+    gathered, and its tiles past the live ones are never read.  Nothing is
+    bounded: with every pick held every tile is live.  Jitted on its own,
+    and the tiles a loop, for the start's sake (:func:`_kernel_grouped`);
+    ``rows``, ``depths`` and ``cols`` (the combine's block of columns) are
+    for tests."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    n, hidden = x.shape
+    call, row_tile, tiles, k13, d13 = _grouped_call(
+        token.shape[0], w2.shape[0], hidden, w2.shape[1], x.dtype, rows,
+        depths, interpret)
+    total = jnp.sum(sizes, dtype=jnp.int32).reshape(1)
+    past = tiles * row_tile - token.shape[0]
+    token, weight = jnp.pad(token, (0, past)), jnp.pad(weight, (0, past))
+    # one copy of the rows by weight tile (n rows, not n x k)
+    tiled = x.reshape(n, k13, d13).swapaxes(0, 1)
+
+    def gather(t, into):
+        rows_of = lax.dynamic_slice(token, (t * row_tile,), (row_tile,))
+        return lax.dynamic_update_slice(
+            into, jnp.take(tiled, rows_of, axis=1, mode="clip"),
+            (0, t * row_tile, 0))
+
+    # a buffer nobody wrote (on a TPU XLA's ``AllocateBuffer``: no pass over
+    # all of it, as ``jnp.zeros`` would be), held to the order the kernel
+    # reads (left free, XLA lays the loop's buffer out pair by pair, as the
+    # gather makes it, and copies ALL of it for the kernel)
+    into = lax.fori_loop(
+        0, (total[0] + row_tile - 1) // row_tile, gather,
+        with_layout_constraint(
+            lax.empty((k13, tiles * row_tile, d13), x.dtype),
+            Layout(major_to_minor=(0, 1, 2))))
+    y = call(*_visits(sizes, tiles, row_tile), into, w13, w2)
+    return _combine(y, token, weight, total, n, x.dtype, row_tile, cols,
+                    interpret)
 
 
 def experts_formulation(platform, dtype, hidden, width):
@@ -351,30 +513,47 @@ def experts_formulation(platform, dtype, hidden, width):
     return "pallas" if tiled else "ragged"
 
 
+def experts_path(formulation, held, num_experts, pairs):
+    """Which pairs ``_contrib_RoutedExperts`` moves at the width of a row,
+    read off what the op can see (as :func:`experts_formulation`: no
+    attribute, environment variable or model name chooses): ``"held"`` --
+    only the pairs whose expert this chip holds (:func:`_kernel_held`) --
+    where the kernel runs, the chip holds a share of the router's experts
+    (``held`` of ``num_experts``, wherever the share starts) and the sorted
+    pairs fill more than one row tile, so that there are tiles to leave out
+    (a prefill's thousands of pairs); ``"all"`` -- every pair sorted,
+    gathered and gathered back around the products -- for a lane step's one
+    row tile, for a layer that holds every expert (every pair is its own)
+    and for the XLA formulations."""
+    share = 0 < held < num_experts
+    return "held" if (formulation == "pallas" and share
+                      and pairs > _ROW_TILE) else "all"
+
+
 _GROUPED = {"pallas": _kernel_grouped, "ragged": _ragged_grouped,
             "ragged-dense": _ragged_grouped}
 
 
-@functools.partial(jax.jit, static_argnames=("first_expert", "grouped"))
-def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0,
-                   grouped=_ragged_grouped):
-    """``rows`` (n, H), ``ids`` / ``weights`` (n, k), ``w13`` (held, H, 2F)
-    ``[W1 | W3]`` and ``w2`` (held, F, H), the experts ``first_expert ..
-    first_expert + held - 1``.  ``grouped`` makes the two products of the
-    pairs sorted by expert: :func:`_ragged_grouped` (here, and wherever
-    :func:`experts_formulation` says so) or :func:`_kernel_grouped`.
-    Returns (n, H) in ``rows``' dtype: the held experts' part of the
-    layer's output."""
-    n, k = ids.shape
-    held = w13.shape[0]
+def _sorted_pairs(ids, first_expert, held):
+    """The index work, over all ``n x k`` pairs (int32s, never rows):
+    ``mine`` (n x k,) whether a pair's expert is held, ``order`` the pairs
+    sorted by held expert (pairs of other shares, and of rows that are not
+    live, sort behind every held group and belong to none) and ``sizes``
+    (held,) the groups."""
     local = ids.reshape(-1) - int(first_expert)
     mine = (local >= 0) & (local < held)
-    # pairs of other shares (and of rows that are not live) sort behind
-    # every held group and belong to none: the grouped product leaves them
     key = jnp.where(mine, local, held)
     order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)
+    return mine, order, sizes
+
+
+def _all_pairs(rows, ids, weights, w13, w2, first_expert, grouped):
+    """Every pair's row gathered into sorted order, through ``grouped``, and
+    gathered back (the ``"all"`` path, and what a gradient goes through)."""
+    n, k = ids.shape
+    mine, order, sizes = _sorted_pairs(ids, first_expert, w13.shape[0])
     y = grouped(jnp.take(rows, order // k, axis=0), sizes, w13, w2)
     # back to (row, pick) order; a select, not a product with a zero weight:
     # what the grouped product leaves in a row of no group is not looked at
@@ -384,6 +563,50 @@ def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0,
     w = weights.astype(_F32)[:, :, None]
     y = jnp.where(mine.reshape(n, k, 1), y * w, 0.0)
     return jnp.sum(y, axis=1).astype(rows.dtype)
+
+
+def _held_pairs(rows, ids, weights, w13, w2, first_expert, held):
+    """The ``"held"`` path: the index work here, the rows in ``held``
+    (:func:`_kernel_held`).  A loop with a dynamic trip count has no reverse
+    mode, so a gradient goes through :func:`_all_pairs` over the XLA
+    formulation, as the kernel's own does."""
+    @jax.custom_vjp
+    def forward(rows, ids, weights, w13, w2):
+        _, order, sizes = _sorted_pairs(ids, first_expert, w13.shape[0])
+        return held(rows, (order // ids.shape[1]).astype(jnp.int32),
+                    jnp.take(weights.astype(_F32).reshape(-1), order),
+                    sizes, w13, w2)
+
+    def backward(operands, dy):
+        rows, ids, weights, w13, w2 = operands
+        drows, dweights, dw13, dw2 = jax.vjp(
+            lambda rows, weights, w13, w2: _all_pairs(
+                rows, ids, weights, w13, w2, first_expert, _ragged_grouped),
+            rows, weights, w13, w2)[1](dy)
+        return drows, None, dweights, dw13, dw2
+
+    forward.defvjp(lambda *operands: (forward(*operands), operands),
+                   backward)
+    return forward(rows, ids, weights, w13, w2)
+
+
+@functools.partial(jax.jit, static_argnames=("first_expert", "grouped",
+                                             "held"))
+def routed_experts(rows, ids, weights, w13, w2, *, first_expert=0,
+                   grouped=_ragged_grouped, held=None):
+    """``rows`` (n, H), ``ids`` / ``weights`` (n, k), ``w13`` (held, H, 2F)
+    ``[W1 | W3]`` and ``w2`` (held, F, H), the experts ``first_expert ..
+    first_expert + held - 1``.  ``grouped`` makes the two products of the
+    pairs sorted by expert: :func:`_ragged_grouped` (here, and wherever
+    :func:`experts_formulation` says so) or :func:`_kernel_grouped`.
+    ``held`` is None where every pair's row is moved around ``grouped``,
+    or :func:`_kernel_held`, which moves the held pairs' alone and runs the
+    kernel itself (:func:`experts_path` says which).
+    Returns (n, H) in ``rows``' dtype: the held experts' part of the
+    layer's output."""
+    if held is not None:
+        return _held_pairs(rows, ids, weights, w13, w2, first_expert, held)
+    return _all_pairs(rows, ids, weights, w13, w2, first_expert, grouped)
 
 
 def _router_inputs(attrs):
@@ -438,11 +661,14 @@ def _routed_experts(opctx, attrs, data, ids, weights, w13, w2):
         raise ValueError("experts %d..%d are not among the router's %d"
                          % (first, first + held - 1,
                             int(attrs["num_experts"])))
-    grouped = _GROUPED[experts_formulation(
+    formulation = experts_formulation(
         platform_of(data, w13, w2), jnp.result_type(data, w13, w2),
-        data.shape[1], w2.shape[1])]
+        data.shape[1], w2.shape[1])
+    path = experts_path(formulation, held, int(attrs["num_experts"]),
+                        ids.shape[0] * ids.shape[1])
     return routed_experts(data, ids, weights, w13, w2, first_expert=first,
-                          grouped=grouped)
+                          grouped=_GROUPED[formulation],
+                          held=_kernel_held if path == "held" else None)
 
 
 # ---------------------------------------------------------------------------

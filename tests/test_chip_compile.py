@@ -482,6 +482,75 @@ def test_routed_experts_cost_their_pairs_on_v5e(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("rows,k,held,experts,hidden,width", [
+    (2048, 10, 36, 72, 4096, 768),     # g4hsmall-decode-closed16
+    (2048, 8, 16, 256, 7680, 2048)],   # pangu718b-decode-closed16
+    ids=["36_of_72", "16_of_256"])
+def test_a_share_moves_its_own_pairs_rows_on_v5e(one_chip, rows, k, held,
+                                                 experts, hidden, width):
+    """A 2,048-token prefill's expert layer of the two long-prompt cells on
+    the path ``experts_path`` picks for them (``"held"``), compiled for the
+    chip: two Mosaic calls (``moe_grouped``, ``moe_combine``) and ONE loop,
+    which gathers a row tile of the sorted pairs at a time into the layout
+    the kernel reads, in a buffer that is allocated and not filled
+    (``AllocateBuffer``: no pass over all the pairs' rows); nothing else in the
+    module is as wide as all the pairs' rows (no gather, copy, pad, slice or
+    transpose of ``pairs x hidden`` values in either dtype: the kernel's
+    output goes whole into the combine), the leaves go whole into the
+    kernel, and the scratch is the kernel's float32 output and twice the
+    rows in the kernel's layout (8 bytes a pair's feature; moving every pair
+    held 10.4 at 4,096 features)."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(g, ids, w, w13, w2):
+        return moe.routed_experts(g, ids, w, w13, w2,
+                                  grouped=moe._kernel_grouped,
+                                  held=moe._kernel_held)
+
+    pairs = rows * k
+    assert moe.experts_path(
+        moe.experts_formulation("tpu", jnp.bfloat16, hidden, width), held,
+        experts, pairs) == "held"
+    leaves = ("bf16[%d,%d,%d]" % (held, hidden, 2 * width),
+              "bf16[%d,%d,%d]" % (held, width, hidden))
+    compiled = jax.jit(layer).lower(
+        S((rows, hidden), jnp.bfloat16), S((rows, k), jnp.int32),
+        S((rows, k), jnp.float32), S((held, hidden, 2 * width), jnp.bfloat16),
+        S((held, width, hidden), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert [n.split(".")[0] for n in names] == ["moe_grouped",
+                                                "moe_combine"], names
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r'custom_call_target="AllocateBuffer"', text)) == 1
+    _leaves_go_whole_into(text, "moe_grouped", calls=1, leaves=leaves)
+    # values as wide as every pair's row: the loop's buffer (carried, and
+    # updated a tile at a time) and the kernel's output, nothing else
+    made = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (?:bf16|f32)\[([\d,]+)\]"
+                      r"\S* ([\w\-]+)\(")
+    passes_on = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+                 "dynamic-update-slice"}
+    for name, dims, opcode in (m.groups() for m in map(made.match,
+                                                      text.splitlines()) if m):
+        if math.prod(int(d) for d in dims.split(",")) < pairs * hidden:
+            continue
+        assert opcode in passes_on \
+            or opcode == "fusion" and "dynamic-update-slice" in name \
+            or opcode == "custom-call" and name.split(".")[0] in (
+                "empty", "moe_grouped"), (name, dims, opcode)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        1.01 * pairs * hidden * 8
+
+
 def _leaves_go_whole_into(text, kernel, calls,
                           leaves=("bf16[32,2048,3584]", "bf16[32,1792,2048]")):
     """Each stacked leaf of the cell's experts (the LFM2 cell's unless
@@ -821,7 +890,10 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     """The same two layers' prefill of the cell's longest bucket (one prompt
     of 2,048: 8 chunks of 256 through the scan at 128 heads, 20,480 (row,
     pick) pairs a layer, about half of them on held experts), compiled for
-    the chip: the routed products ``moe_grouped`` (no ``ragged-dot``), the
+    the chip: the routed products ``moe_grouped`` (no ``ragged-dot``) and,
+    since the graph holds 36 of the router's 72 experts and a prefill's
+    pairs fill 40 row tiles, the path that moves the held pairs' rows alone
+    (``experts_path``: ``moe_combine`` after each ``moe_grouped``), the
     scan under ``ssm_scan``, the scratch memory under 2 GB (the whole ten
     layers' program takes 1.22 GB: my compile, PR 43), and the slabs the
     layers carry into decode beside the logits."""
@@ -844,8 +916,11 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     text = compiled.as_text()
     assert "ragged-dot" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
-    assert [n.split(".")[0] for n in names] == ["moe_grouped"] * 2
+    assert [n.split(".")[0] for n in names] == ["moe_grouped",
+                                                "moe_combine"] * 2
     ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
+    assert any("layer1_experts/moe_experts/" in o and "moe_combine" in o
+               for o in ops)
     assert any("layer0_ssm/ssm_scan/" in o for o in ops)
     assert any("layer1_router/moe_router/" in o for o in ops)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -458,6 +458,10 @@ def test_a_step_carries_state_and_expert_arguments_at_once(tmp_path):
     prefills = [dict(e.stats) for e in events if e.name == "gen:prefill"]
     assert prefills and all("state_slot" in s and "expert_pairs" in s
                             for s in prefills)
+    # which pairs the prefill's expert layers move, and what chose it
+    # (ops/moe.py ``experts_path``): on the host every pair
+    assert all(s["experts_path"] == "all"
+               and float(s["expert_pairs_moved"]) == 1.0 for s in prefills)
     for name in ("mxtpu_gen_expert_pairs_held", "mxtpu_gen_experts_hit",
                  "mxtpu_gen_expert_picks"):
         assert name in text

@@ -48,12 +48,21 @@ def _picks(seed, rows, among):
         .astype(np.int32)
 
 
-def _check(a, ids, dtype, first=0, **tiles):
+def _held(**tiles):
+    return functools.partial(moe._kernel_held, interpret=True, **tiles)
+
+
+def _check(a, ids, dtype, first=0, held=False, **tiles):
+    """``routed_experts`` through the kernel (``held``: on the path that
+    moves the held pairs' rows alone, ``_kernel_held``) against the
+    ``"ragged-dense"`` formulation."""
     ids = jnp.asarray(ids)
     want = moe.routed_experts(a["g"], ids, a["w"], a["w13"], a["w2"],
                               first_expert=first)
+    path = dict(held=_held(**tiles)) if held else \
+        dict(grouped=_kernel(**tiles))
     got = moe.routed_experts(a["g"], ids, a["w"], a["w13"], a["w2"],
-                             first_expert=first, grouped=_kernel(**tiles))
+                             first_expert=first, **path)
     assert got.dtype == want.dtype == a["g"].dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -148,6 +157,81 @@ def test_an_idle_experts_weights_reach_no_output(idle):
     np.testing.assert_array_equal(got, clean)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("first,held", [(0, 8), (2, 3), (5, 3), (0, 1)])
+@pytest.mark.parametrize("load", ["even", "one_takes_all",
+                                  "crosses_tile_edges", "idle_middle"])
+def test_the_held_path_is_the_dense_formulation(load, first, held, dtype):
+    """The path that moves the held pairs' rows alone (the gather loop into
+    the kernel's layout, ``moe_grouped``, ``moe_combine``: all three in
+    interpret mode) for the whole layer and for shares of it: the live row
+    tiles are those the held pairs fill, from none of the three 16-pair
+    tiles to all of them."""
+    ids = LOADS[load]().astype(np.int32)
+    a = _layer(26, ids.shape[0], dtype)
+    a["w13"], a["w2"] = (a[k][first:first + held] for k in ("w13", "w2"))
+    got = _check(a, ids, dtype, first=first, held=True, cols=128, **SMALL)
+    here = (ids >= first) & (ids < first + held)
+    assert (np.abs(got).max() > 0.1) == bool(here.any())
+    assert (got[~here.any(1)] == 0).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_held_path_leaves_rows_that_are_not_live(dtype):
+    """As ``test_rows_that_are_not_live_belong_to_no_group``, on the held
+    path: the last live tile holds pairs of rows that are not live (they
+    sort right behind the held ones) and their inf and NaN stay there."""
+    a = _layer(22, 24, dtype)
+    live = np.arange(24) % 3 != 1
+    g = np.array(a["g"], np.float32)
+    g[~live] = np.where(np.arange(H) % 2, np.inf, np.nan)
+    a["g"] = jnp.asarray(g, a["g"].dtype)
+    ids = np.where(live[:, None], _picks(22, 24, E), E).astype(np.int32)
+    a["w"] = jnp.where(live[:, None], a["w"], 0.0)
+    got = _check(a, ids, dtype, held=True, **SMALL)
+    assert (got[~live] == 0).all() and np.isfinite(got[live]).all()
+
+
+@pytest.mark.parametrize("total", [0, 1, 16, 23, 48])
+def test_the_combine_reads_the_live_tiles_alone(total):
+    """``moe_combine`` alone: 48 sorted pairs in three tiles of 16, the
+    first ``total`` held; what lies past them in the kernel's output is NaN
+    throughout (a tile past the live ones is memory nobody wrote; a pair
+    past the held ones in the last live tile is not looked at either); the
+    sums in two blocks of 128 columns."""
+    r = np.random.RandomState(total)
+    y = r.randn(48, H).astype(np.float32)
+    token = r.randint(0, 10, 48).astype(np.int32)
+    weight = r.rand(48).astype(np.float32)
+    want = np.zeros((10, H), np.float32)
+    for at in range(total):
+        want[token[at]] += y[at] * weight[at]
+    y[total:] = np.nan
+    got = moe._combine(jnp.asarray(y), jnp.asarray(token),
+                       jnp.asarray(weight),
+                       jnp.asarray([total], jnp.int32), 10, jnp.float32, 16,
+                       128, True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("formulation,held,experts,pairs,want", [
+    ("pallas", 36, 72, 20480, "held"),   # the Granite-small cell's prefills
+    ("pallas", 36, 72, 5120, "held"),
+    ("pallas", 16, 256, 16384, "held"),  # the latent cell's
+    ("pallas", 16, 256, 19456, "held"),  # and its scoring program
+    ("pallas", 1, 2, 513, "held"),
+    ("pallas", 36, 72, 512, "all"),      # one row tile: nothing to leave out
+    ("pallas", 36, 72, 160, "all"),      # the three cells' lane steps
+    ("pallas", 16, 256, 128, "all"),
+    ("pallas", 32, 32, 64, "all"),
+    ("pallas", 32, 32, 2048, "all"),     # every expert held: LFM2's prefill
+    ("ragged", 36, 72, 20480, "all"),    # the XLA formulations
+    ("ragged-dense", 16, 256, 16384, "all")])
+def test_experts_path_is_read_off_the_share_and_the_pairs(
+        formulation, held, experts, pairs, want):
+    assert moe.experts_path(formulation, held, experts, pairs) == want
+
+
 def test_a_gradient_goes_through_the_xla_formulation():
     """The kernel is a forward pass; differentiated, the op's gradients are
     those of the ``ragged_dot`` formulation of the same products (rows, both
@@ -171,6 +255,17 @@ def test_a_gradient_goes_through_the_xla_formulation():
                                    rtol=2e-5)
     assert float(jnp.abs(got[2][:7]).max()) > 1e-3
     assert float(jnp.abs(got[2][7]).max()) == 0.0
+
+    # the held path's loop has no reverse mode: the same gradients
+    def held(g, w, w13, w2):
+        y = moe.routed_experts(g, ids, w, w13, w2, held=_held(**SMALL))
+        return jnp.sum(jnp.sin(y))
+
+    got = jax.grad(held, argnums=(0, 1, 2, 3))(a["g"], a["w"], a["w13"],
+                                               a["w2"])
+    for x, y in zip(got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5,
+                                   rtol=2e-5)
 
 
 def test_the_work_list_visits_each_group_once_a_row_tile():

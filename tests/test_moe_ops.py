@@ -180,6 +180,72 @@ def test_padded_rows_add_nothing_and_poison_nothing():
         **TOL)
 
 
+# the three expert cells' shares (experts, held, first, picks a row) and one
+# that does not start at expert 0
+SHARES = {"16_of_256_k8": (256, 16, 0, 8), "36_of_72_k10": (72, 36, 0, 10),
+          "32_of_32_k4": (32, 32, 0, 4), "16_of_256_from_48": (256, 16, 48, 8)}
+# row tiles of 16 sorted pairs, so that a few rows' pairs straddle several
+_TILES = dict(rows=(16, 16), interpret=True)
+_ALL = functools.partial(moe._kernel_grouped, **_TILES)
+_HELD = functools.partial(moe._kernel_held, **_TILES)
+
+
+@pytest.mark.parametrize("rows", [3, 13])
+@pytest.mark.parametrize("lands", ["as_routed", "all_here", "none_here"])
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_a_share_moves_its_own_pairs_and_is_the_dense_formulation(
+        share, lands, rows):
+    """``routed_experts`` on the path that moves only the held pairs' rows
+    (``held=_kernel_held``: the gather loop, ``moe_grouped`` and
+    ``moe_combine`` in interpret mode) against every held expert over every
+    row, and against the path that moves every pair: as the router sent them
+    (a third of the rows not live, inf and NaN in them), with EVERY pick on
+    a held expert (nothing is bounded: every row tile is live) and with none
+    (exact zeros, nothing read from memory nobody wrote).  Both paths add a
+    row's picks in float32, the held path in the sorted pairs' order and
+    the other in whatever order XLA reduces: they agree to the bit in the
+    rows with at most one held pick, else to the file's tolerance."""
+    experts, held, first, k = SHARES[share]
+    a = _layer(12, rows=rows, experts=experts)
+    r = np.random.RandomState(rows)
+    live = np.ones(rows, bool)
+    if lands == "as_routed":
+        live = np.arange(rows) % 3 != 1
+        ids, w, _ = moe.route(a["g"], a["router"], None, live.astype("f"),
+                              top_k=k)
+        ids, w = np.array(ids), np.array(w)
+    else:
+        here = np.arange(first, first + held)
+        pool = here if lands == "all_here" else \
+            np.setdiff1d(np.arange(experts), here)
+        if len(pool) < k:  # every expert held: nothing lands elsewhere
+            live[:] = False
+            pool = np.arange(experts)
+        ids = np.stack([r.permutation(pool)[:k] for _ in range(rows)])
+        ids = np.where(live[:, None], ids, experts).astype(np.int32)
+        w = np.where(live[:, None], r.rand(rows, k), 0.0).astype(np.float32)
+    g = a["g"].copy()
+    g[~live] = np.where(np.arange(H) % 2, np.inf, np.nan)
+    w13, w2 = (a[name][first:first + held] for name in ("w13", "w2"))
+    want = _dense_experts(np.where(live[:, None], g, 0.0), ids, w, w13, w2,
+                          first=first)
+    run = functools.partial(moe.routed_experts, jnp.asarray(g),
+                            jnp.asarray(ids), jnp.asarray(w), w13, w2,
+                            first_expert=first)
+    got, every = np.asarray(run(held=_HELD)), np.asarray(run(grouped=_ALL))
+    assert np.isfinite(got).all() and (got[~live] == 0).all()
+    np.testing.assert_allclose(got, want, **TOL)
+    np.testing.assert_allclose(got, every, **TOL)
+    here = (ids >= first) & (ids < first + held)
+    one = here.sum(1) <= 1  # one term: no order, no fused multiply-add
+    np.testing.assert_array_equal(got[one], every[one])
+    if lands == "none_here":
+        assert not here.any() and (got == 0).all()
+    elif lands == "all_here":
+        assert here[live].all()
+    assert (np.abs(got).max() > 0.01) == bool(here.any())
+
+
 @pytest.mark.parametrize("grouped", [
     moe._ragged_grouped,
     functools.partial(moe._kernel_grouped, interpret=True)],
