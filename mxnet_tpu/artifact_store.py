@@ -1,18 +1,15 @@
-"""Shared atomic entry-store helpers for content-addressed artifact
-caches.
+"""Atomic entry-store helpers for a content-addressed artifact cache.
 
-One on-disk grammar for every persistent artifact family the framework
-keeps beside a job (serialized XLA executables in ``compile_cache``,
-tuning winners in ``autotune``):
+The on-disk grammar of the persistent artifacts the framework keeps beside
+a job (serialized XLA executables in ``compile_cache``, its one user):
 
     MAGIC | u64 meta_len | meta json | payload bytes
 
 written atomically (tmp+fsync+rename, the checkpoint discipline) with a
 CRC32 sidecar, read back with CRC + header verification, and
-listed/verified/pruned by one admin implementation.  Each family
-parameterizes an :class:`EntryStore` with its own magic, filename
-suffix, and fault-injection op prefix — the families share THIS code
-instead of copy-pasting the format.
+listed/verified/pruned by one admin implementation.  A user parameterizes
+an :class:`EntryStore` with its own magic, filename suffix, and
+fault-injection op prefix.
 """
 from __future__ import annotations
 
@@ -34,7 +31,7 @@ def digest_of(parts: dict) -> str:
 
 
 class EntryStore:
-    """Format + admin surface for one artifact family.
+    """Format + admin surface for one kind of artifact.
 
     Parameters
     ----------
@@ -87,7 +84,7 @@ class EntryStore:
             f.write(meta_blob)
             f.write(payload_bytes)
 
-        # atomic_write fires the fault layer under the family's dotted op
+        # atomic_write fires the fault layer under the store's dotted op
         # and lands the CRC sidecar after the data — identical discipline
         # to checkpoints
         atomic_write(path, writer, checksum=True,

@@ -97,8 +97,7 @@ _SCHEMA = 1
 ENTRY_SUFFIX = ".mxc"
 MANIFEST_NAME = "manifest.json"
 
-# on-disk grammar + admin shared with the autotune TuningDB via
-# artifact_store (one implementation, two artifact families)
+# on-disk grammar + admin: artifact_store
 _STORE = EntryStore(_MAGIC, ENTRY_SUFFIX, "compile-cache", "compile_cache")
 
 _lock = threading.Lock()
@@ -400,7 +399,7 @@ _digest = digest_of
 
 # ---------------------------------------------------------------------------
 # entry file format:  MAGIC | u64 meta_len | meta json | pickle(payload)
-# with a CRC32 sidecar — the shared artifact_store grammar
+# with a CRC32 sidecar — artifact_store's grammar
 # ---------------------------------------------------------------------------
 
 def _entry_path(d: str, digest: str) -> str:
@@ -601,17 +600,6 @@ class CachedFunction:
             "sig": _signature(args),
             "devices": _arg_devices(args),
         }
-        # tuned and untuned executables must never collide: when the
-        # autotuner is active its DB-state fingerprint joins the key (a
-        # different set of winners is a different program)
-        try:
-            from . import autotune as _at
-
-            at_fp = _at.cache_fingerprint()
-        except Exception:
-            at_fp = None
-        if at_fp is not None:
-            parts["autotune"] = at_fp
         if ex._shard_mesh is not None:
             from .sharding.mesh import mesh_fingerprint
 
@@ -774,17 +762,6 @@ def save_bundle(path: str, entries, warmup: Optional[dict] = None) -> str:
                 "mesh_axes": meta.get("mesh_axes"),
                 "cost": meta.get("cost"),
             })
-    # the tuning DB rides along: a restored replica is tuned-by-
-    # construction, with zero re-tuning (best-effort — a bundle without
-    # tuning entries is still a valid bundle)
-    try:
-        from . import autotune as _at
-
-        n = _at.export_to_bundle(path)
-        if n:
-            manifest["autotune_entries"] = n
-    except Exception:
-        pass
     atomic_write(os.path.join(path, MANIFEST_NAME),
                  lambda f: f.write(json.dumps(manifest, indent=1,
                                               default=str).encode()),
@@ -831,12 +808,6 @@ def attach_bundle(path: str, mesh=None) -> dict:
     with _lock:
         if path not in _bundles:
             _bundles.append(path)
-    try:
-        from . import autotune as _at
-
-        _at.attach_bundle_overlay(path)
-    except Exception:
-        pass
     _log_event("compile_cache_bundle_attached", path=path,
                entries=len(manifest.get("entries", [])))
     return manifest

@@ -470,45 +470,8 @@ class Executor:
         out_s = (None, a, d, s, rep)
         return in_s, out_s
 
-    def _autotune_fused(self, stable_key, abstract_args, make_jit,
-                        donate_allowed, env_remat):
-        """Tuned {remat, donate} for this fused program, or None.  The
-        record-mode loop lowers each remat x donation variant of the
-        EXACT program about to run (same graph, same abstract args) and
-        scores by the XLA-cost-analysis roofline.  Any failure degrades
-        to the env-derived defaults."""
-        if abstract_args is None:
-            return None
-        try:
-            from . import autotune
-
-            if not autotune.enabled():
-                return None
-            import jax
-
-            sig = jax.tree_util.tree_map(
-                lambda x: (tuple(x.shape), str(x.dtype)), abstract_args)
-            key = {"graph": self._plan.fingerprint(),
-                   "static": repr(stable_key),
-                   "compute_dtype": str(self._compute_dtype),
-                   "sig": repr(sig),
-                   "remat_env": int(env_remat),
-                   "donate_allowed": bool(donate_allowed)}
-
-            def build(cand):
-                return (make_jit(bool(cand["remat"]),
-                                 bool(cand["donate"])), abstract_args)
-
-            return autotune.get_or_tune(
-                "fused_step", key,
-                candidates=autotune.spaces.fused_step(donate_allowed),
-                build_fn=build, default=None)
-        except Exception:
-            return None
-
     def _get_fused_step(self, key, update_infos, pure_update, needs_rng,
-                        shardings=None, stable_key=None, abstract_args=None,
-                        guard=False):
+                        shardings=None, stable_key=None, guard=False):
         """Jitted forward+backward+update with donated param/state/aux
         buffers.  This is the whole of the reference's per-batch engine
         traffic (GraphExecutor::Forward/Backward + the kvstore push/pull +
@@ -530,10 +493,10 @@ class Executor:
         if key not in self._jit_cache:
             plan = self._plan
             placement = self._placement
-            env_remat = bool(env("MXNET_BACKWARD_DO_MIRROR", 0, int))
+            remat = bool(env("MXNET_BACKWARD_DO_MIRROR", 0, int))
             cast = self._cast_fn()
 
-            def make_fn(remat):
+            def make_fn():
                 def fused_step(diff_args, states, aux, other_args, rng, sc,
                                opt_rng):
                     lr0, wd0, t = sc
@@ -602,7 +565,7 @@ class Executor:
                 return self._bound(fused_step)
 
             if self._naive:
-                self._jit_cache[key] = make_fn(env_remat)
+                self._jit_cache[key] = make_fn()
             else:
                 from . import compile_cache as _cc
 
@@ -622,35 +585,20 @@ class Executor:
                 options = {} if self._shard_mesh is not None else \
                     collective_compiler_options(self._kernel_mesh[0])
 
-                def make_jit(remat, donate_on):
-                    donate = (0, 1, 2) if (donate_on and donate_allowed) \
-                        else ()
-                    kw = {"compiler_options": options} if options else {}
-                    if shardings is not None:
-                        kw.update(in_shardings=shardings[0],
-                                  out_shardings=shardings[1])
-                    return jax.jit(make_fn(remat), donate_argnums=donate,
-                                   **kw)
-
-                remat, donate_on = env_remat, donate_allowed
-                tuned = self._autotune_fused(stable_key, abstract_args,
-                                             make_jit, donate_allowed,
-                                             env_remat)
-                if tuned is not None:
-                    remat = bool(tuned.get("remat", remat))
-                    donate_on = (bool(tuned.get("donate", donate_on))
-                                 and donate_allowed)
-                    self._fused_autotune = dict(tuned)
-                jfn = make_jit(remat, donate_on)
+                donate = (0, 1, 2) if donate_allowed else ()
+                kw = {"compiler_options": options} if options else {}
+                if shardings is not None:
+                    kw.update(in_shardings=shardings[0],
+                              out_shardings=shardings[1])
+                jfn = jax.jit(make_fn(), donate_argnums=donate, **kw)
                 # the persistent key uses stable_key (no object ids) so a
                 # fresh process — or a fresh optimizer instance with the
                 # same hypers — maps to the same disk entry; donation, remat
                 # and the compiler's options change the compiled program,
                 # so they are part of the key (a step with no options keeps
                 # the key it had)
-                donate = (0, 1, 2) if (donate_on and donate_allowed) else ()
                 if stable_key is not None:
-                    stable_key = stable_key + (("donate", tuple(donate)),
+                    stable_key = stable_key + (("donate", donate),
                                                ("remat", int(remat)))
                     if options:
                         stable_key += (("compiler_options",
@@ -799,8 +747,7 @@ class Executor:
             if self._shard_mesh is not None:
                 shardings = self._fused_shardings(diff_args, states, aux,
                                                   other_args)
-            # abstract arg signature of the fused call: the autotuner
-            # lowers candidate variants against it, and perf_probe reuses
+            # abstract arg signature of the fused call: perf_probe reuses
             # it (via _fused_introspect) to lower the exact same program
             # (committed arrays keep their sharding: on a device mesh the
             # partitioning of the program comes from nothing else)
@@ -812,9 +759,7 @@ class Executor:
                 (diff_args, states, aux, other_args, rng, sc, opt_rng))
         fn = self._get_fused_step(key, tuple(infos), optimizer.pure_update,
                                   optimizer.needs_rng, shardings,
-                                  stable_key=stable_key,
-                                  abstract_args=abstract_args,
-                                  guard=guard)
+                                  stable_key=stable_key, guard=guard)
         if first_build and not self._naive:
             # introspection hook (compile-miss path only — zero per-step
             # cost), so tools/perf_probe.py can lower/compile the exact

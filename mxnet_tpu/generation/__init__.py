@@ -9,7 +9,7 @@ step, zero post-warmup recompiles, streaming :class:`GenStream`
 handles).  Token-path optimizations: cross-request prefix caching
 (content-hashed copy-on-write KV pages, ``MXNET_GEN_PREFIX_CACHE_PAGES``)
 and speculative decoding (draft model + fused verify pass, bit-identical
-greedy acceptance, autotuned draft length).  Serving integration
+greedy acceptance).  Serving integration
 (``generate`` SLO class, ``POST /generate`` token streaming) lives in
 ``mxnet_tpu.serving``.
 """

@@ -30,11 +30,11 @@ Two token-path optimizations ride on top of the paged pool:
   since every feed token is known before the call — scores all K+1
   slots in a single causal forward.  Greedy acceptance keeps every
   token whose draft matched the target argmax, so transcripts match
-  non-speculative greedy (asserted per-K by the spec-parity tests).  A
-  per-stream acceptance-rate EWMA feeds the ``draft_k`` autotune site
-  (objective: accepted tokens per target FLOP), and the winning K is
-  resolved at construction so it travels inside ``spec()`` / AOT
-  bundles — a restored replica speculates with zero re-tuning.
+  non-speculative greedy (asserted per-K by the spec-parity tests).  K is
+  resolved at construction (the draft spec's ``k``, else
+  ``MXNET_GEN_DRAFT_K``) and travels inside ``spec()`` / AOT bundles; the
+  acceptance-rate EWMA is reported by ``snapshot()`` and the
+  ``mxtpu_gen_draft_accept_rate`` gauge.
 
 XLA discipline: every XLA-visible shape here is static.
 
@@ -149,77 +149,6 @@ class StateNotRebuildableError(MXNetError):
     engine has can rebuild it."""
 
 
-def _autotune_engine_config(num_layers, num_heads, head_dim, max_seq_len,
-                            dtype, max_lanes):
-    """Tuned {lane_buckets, page_size} for this model geometry, or None.
-
-    The objective is analytic and deterministic — no lowering: expected
-    padded-lane waste under uniform live-lane demand, KV fragmentation
-    of a half page per sequence, a per-bucket compile-cost term (every
-    lane bucket is one more decode executable to build and keep warm)
-    and a page-table-length term penalizing tiny pages."""
-    try:
-        from .. import autotune
-    except Exception:
-        return None
-    if not autotune.enabled():
-        return None
-    key = {"num_layers": int(num_layers), "num_heads": int(num_heads),
-           "head_dim": int(head_dim), "max_seq_len": int(max_seq_len),
-           "max_lanes": int(max_lanes), "dtype": str(np.dtype(dtype))}
-
-    def score(cand):
-        buckets = sorted(int(b) for b in cand["lane_buckets"])
-        page = int(cand["page_size"])
-        waste = 0.0
-        for n in range(1, max_lanes + 1):
-            b = next((b for b in buckets if b >= n), buckets[-1])
-            waste += (b - n) / float(b)
-        waste /= max_lanes
-        frag = (page - 1) / 2.0 / max(1.0, max_seq_len / 2.0)
-        return (waste + frag + 0.02 * len(buckets)
-                + 0.0005 * (max_seq_len / float(page)))
-
-    return autotune.get_or_tune(
-        "decode_engine", key,
-        candidates=autotune.spaces.decode_engine(max_lanes, max_seq_len),
-        score_fn=score, default=None)
-
-
-def _autotune_draft_k(num_layers, hidden, draft_layers, draft_hidden,
-                      acceptance):
-    """Tuned {k: draft length} for a (target, draft) geometry pair, or
-    None.  Analytic objective, lower is better: expected cost per
-    accepted token.  One iteration costs ``(k+1)`` target-token-FLOPs
-    for the fused verify pass plus ``rho*k`` for the draft rounds
-    (``rho`` = draft/target per-token FLOP ratio, dominated by
-    ``layers*hidden^2``), and yields ``sum(a^i, i=0..k)`` expected
-    tokens under per-token acceptance probability ``a`` — the standard
-    speculative-decoding geometric progress model."""
-    try:
-        from .. import autotune
-    except Exception:
-        return None
-    if not autotune.enabled():
-        return None
-    acceptance = min(0.99, max(0.0, float(acceptance)))
-    key = {"num_layers": int(num_layers), "hidden": int(hidden),
-           "draft_layers": int(draft_layers),
-           "draft_hidden": int(draft_hidden),
-           "acceptance": round(acceptance, 1)}
-    rho = ((int(draft_layers) * float(draft_hidden) ** 2)
-           / (int(num_layers) * float(hidden) ** 2))
-
-    def score(cand):
-        k = int(cand["k"])
-        expected = sum(acceptance ** i for i in range(k + 1))
-        return ((k + 1) + rho * k) / expected
-
-    return autotune.get_or_tune(
-        "draft_k", key, candidates=autotune.spaces.draft_k(),
-        score_fn=score, default=None)
-
-
 register_env("MXNET_GEN_PAGE_SIZE", 16, int,
              "KV-pool page size (tokens per page) for DecodeEngine.")
 register_env("MXNET_GEN_NUM_PAGES", 128, int,
@@ -238,8 +167,7 @@ register_env("MXNET_GEN_PREFIX_CACHE_PAGES", 0, int,
              "retain (LRU-evicted); 0 disables prefix caching.")
 register_env("MXNET_GEN_DRAFT_K", 4, int,
              "Speculative draft length (tokens proposed per iteration) "
-             "when a draft model is configured and no tuned/explicit K "
-             "is available.")
+             "when a draft model is configured without an explicit K.")
 
 _DONE = object()  # GenStream queue sentinel
 
@@ -455,10 +383,9 @@ class DecodeEngine:
     draft : dict, optional
         Speculative-decoding draft model: ``{"params": path-or-dict,
         "num_layers": int, "num_heads": int, "hidden": int,
-        "k": int or None, "acceptance_hint": float}``.  ``k`` None
-        consults the ``draft_k`` autotune site, then
-        ``MXNET_GEN_DRAFT_K``; the RESOLVED value is stored back into
-        :meth:`spec` so bundles/replicas rebuild without re-tuning.
+        "k": int or None}``.  ``k`` None takes ``MXNET_GEN_DRAFT_K``;
+        the RESOLVED value is stored back into :meth:`spec` so
+        bundles/replicas rebuild with the same K.
     """
 
     @_framed("start:engine")
@@ -486,7 +413,7 @@ class DecodeEngine:
         self.family = family = generator_family(
             family, vocab_size, num_layers, num_heads, hidden, self._dtype)
         self.vocab_size = family.vocab_size
-        # the family's geometry (the autotuner's key, spec(), snapshot())
+        # the family's geometry (spec(), snapshot())
         self.num_layers = family.num_layers
         self.num_heads = family.num_heads
         self.hidden = family.hidden
@@ -499,21 +426,6 @@ class DecodeEngine:
 
         self._ctx = ctx = ctx or current_context()
         self._device = ctx.jax_device()
-        # unset knobs consult the autotuner before the env defaults:
-        # explicit constructor args always pin, tuned winners beat the
-        # built-in defaults, env vars remain the no-autotune fallback
-        tuned = None
-        if page_size is None or lane_buckets is None:
-            tuned = _autotune_engine_config(
-                self.num_layers, self.num_heads, self.head_dim,
-                self.max_seq_len, self._dtype,
-                max_lanes=(max(int(b) for b in lane_buckets)
-                           if lane_buckets is not None
-                           else env("MXNET_GEN_MAX_LANES", 8, int)))
-        if page_size is None and tuned:
-            page_size = tuned.get("page_size")
-        if lane_buckets is None and tuned:
-            lane_buckets = tuned.get("lane_buckets")
         self.page_size = int(env("MXNET_GEN_PAGE_SIZE", 16, int)
                              if page_size is None else page_size)
         self.num_pages = int(env("MXNET_GEN_NUM_PAGES", 128, int)
@@ -557,18 +469,14 @@ class DecodeEngine:
             d_layers = int(d.get("num_layers", max(1, self.num_layers // 2)))
             d_heads = int(d.get("num_heads", self.num_heads))
             d_hidden = int(d.get("hidden", self.hidden))
-            hint = float(d.get("acceptance_hint", 0.8))
             k = d.get("k")
             if k is None:
-                tuned_k = _autotune_draft_k(self.num_layers, self.hidden,
-                                            d_layers, d_hidden, hint)
-                k = (tuned_k.get("k") if tuned_k
-                     else env("MXNET_GEN_DRAFT_K", 4, int))
+                k = env("MXNET_GEN_DRAFT_K", 4, int)
             k = max(1, min(int(k), self.max_seq_len - 1))
             dparams = d.get("params")
             self._draft = {"params": dparams, "num_layers": d_layers,
                            "num_heads": d_heads, "hidden": d_hidden,
-                           "k": k, "acceptance_hint": hint}
+                           "k": k}
             if isinstance(dparams, str):
                 dparams = nd.load(dparams)
             if dparams is None:
@@ -767,8 +675,7 @@ class DecodeEngine:
     def spec(self) -> Dict:
         """Model/engine geometry needed to rebuild this engine against a
         new checkpoint (hot-swap, AOT warmup manifests, shadow replicas).
-        The draft block carries the RESOLVED speculative K — a replica
-        rebuilt from a bundle speculates with zero re-tuning."""
+        The draft block carries the RESOLVED speculative K."""
         out = {
             **self.family.engine_spec(),
             "max_seq_len": self.max_seq_len,
@@ -891,20 +798,6 @@ class DecodeEngine:
             # drain deadline expired with work outstanding (or fail-fast
             # stop racing the loop): cancel whatever is left
             self._fail_all_locked(ServerClosedError("engine stopped"))
-        # observed-acceptance feedback: when the measured EWMA drifts a
-        # decile from the configured hint, pre-record the draft_k winner
-        # for the observed rate so the NEXT construction (same geometry,
-        # honest hint) resolves without tuning from the stale prior
-        if self._draft is not None and self._accept_ewma is not None:
-            if abs(self._accept_ewma
-                   - self._draft["acceptance_hint"]) >= 0.1:
-                try:
-                    _autotune_draft_k(
-                        self.num_layers, self.hidden,
-                        self._draft["num_layers"], self._draft["hidden"],
-                        self._accept_ewma)
-                except Exception:
-                    pass
 
     def handoff(self) -> int:
         """Preempt every queued and active stream WITHOUT stopping the

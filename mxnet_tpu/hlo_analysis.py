@@ -2,12 +2,9 @@
 
 One home for the flops/bytes-accessed introspection that used to be
 copy-pasted across ``telemetry.step_monitor``, ``compile_cache``,
-``tools/perf_probe.py`` and ``tools/layout_probe.py`` — and that the
-autotuner now uses as its cheap objective: lower a candidate program,
-read XLA's own cost analysis, and score it with a roofline model
-("A Learned Performance Model for TPUs", arxiv 2008.01040, argues the
-compiled program's numbers are the ones that matter).  Everything here
-runs on CPU with no chip — lowering is shape-only.
+``tools/perf_probe.py`` and ``tools/layout_probe.py``: lower a program
+and read XLA's own cost analysis of it.  Everything here runs on CPU with
+no chip — lowering is shape-only.
 """
 from __future__ import annotations
 
@@ -15,35 +12,20 @@ import collections
 import re
 from typing import Optional
 
-from .base import env, register_env
+from .base import env
 
-__all__ = ["peak_flops", "hbm_bytes_per_s", "cost_analysis",
-           "lower_and_analyze", "roofline_ms", "hlo_op_counts",
-           "collective_counts", "op_scopes", "bn_fusion_analysis"]
-
-register_env("MXNET_TELEMETRY_HBM_GBS", 0.0, float,
-             "HBM bandwidth (GB/s) for the roofline bytes term; "
-             "0 looks the attached device's kind up in DEVICE_PEAKS.")
+__all__ = ["peak_flops", "cost_analysis", "lower_and_analyze",
+           "hlo_op_counts", "collective_counts", "op_scopes",
+           "bn_fusion_analysis"]
 
 # Published per-chip peaks keyed by jax ``device_kind`` — the one table
-# every MFU / roofline denominator reads (bench.py included).  Source:
-# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
+# every MFU denominator reads.  Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16.
 # A kind that is not listed has no peak: MFU is None, not the v5e's.
 DEVICE_PEAKS = {
     # what jax reports for a v5e chip (chip_smoke.py run, PR 22)
-    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5 lite": {"flops": 197e12},
 }
-# the chip the autotuner's off-chip roofline proxy models when the
-# attached device is not in the table (roofline_ms ranks, never reports)
-ROOFLINE_PROXY_KIND = "TPU v5 lite"
-
-
-def _peak(field, device_kind):
-    if device_kind is None:
-        import jax
-
-        device_kind = jax.devices()[0].device_kind
-    return DEVICE_PEAKS.get(device_kind, {}).get(field)
 
 
 def peak_flops(device_kind=None) -> Optional[float]:
@@ -51,15 +33,13 @@ def peak_flops(device_kind=None) -> Optional[float]:
     published bf16 peak of ``device_kind`` (default: the attached
     device's); None for a kind DEVICE_PEAKS does not list."""
     v = env("MXNET_TELEMETRY_PEAK_FLOPS", 0.0, float)
-    return float(v) if v else _peak("flops", device_kind)
+    if v:
+        return float(v)
+    if device_kind is None:
+        import jax
 
-
-def hbm_bytes_per_s(device_kind=None) -> Optional[float]:
-    """Roofline bytes denominator: MXNET_TELEMETRY_HBM_GBS override,
-    else the published HBM bandwidth of ``device_kind``; None when
-    unlisted."""
-    v = env("MXNET_TELEMETRY_HBM_GBS", 0.0, float)
-    return float(v) * 1e9 if v else _peak("hbm_bytes_per_s", device_kind)
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind, {}).get("flops")
 
 
 def cost_analysis(compiled) -> Optional[dict]:
@@ -85,23 +65,6 @@ def lower_and_analyze(fn, abstract):
     lowered = fn.lower(*abstract)
     compiled = lowered.compile()
     return compiled, cost_analysis(compiled)
-
-
-def roofline_ms(info) -> Optional[float]:
-    """Roofline lower-bound runtime (ms) of a cost-analysis dict: the
-    slower of the compute term (flops/peak) and the memory term
-    (bytes/HBM-bandwidth).  The autotuner's CPU-side objective — exact
-    runtimes are wrong off-chip, but the RANKING across candidates of
-    the same program tracks the roofline."""
-    if not info:
-        return None
-    flops = float(info.get("flops") or 0.0)
-    nbytes = float(info.get("bytes_accessed") or 0.0)
-    if flops <= 0 and nbytes <= 0:
-        return None
-    peak = peak_flops() or peak_flops(ROOFLINE_PROXY_KIND)
-    bw = hbm_bytes_per_s() or hbm_bytes_per_s(ROOFLINE_PROXY_KIND)
-    return max(flops / peak, nbytes / bw) * 1e3
 
 
 def hlo_op_counts(hlo_text, interesting=None) -> dict:

@@ -502,81 +502,16 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 _DEFAULT_BLOCK = 512
 
 
-def _autotune_blocks(seq_q, seq_k, head_dim, dtype, causal):
-    """Tuning-DB winner for this shape family, or None.  The record-mode
-    tuning loop lowers the forward kernel per candidate at one head /
-    batch 1 (the grid scales linearly in b*h, so the per-candidate
-    RANKING is shape-family-wide) and scores by the XLA-cost-analysis
-    roofline — CPU-runnable, no chip needed."""
-    from .. import autotune
-
-    if not autotune.enabled():
-        return None
-    key = {"seq_q": int(seq_q), "seq_k": int(seq_k),
-           "head_dim": int(head_dim), "dtype": str(dtype),
-           "causal": bool(causal)}
-
-    def build(cand):
-        import jax
-
-        interpret = interpret_for("flash_attention")
-        scale = 1.0 / np.sqrt(head_dim)
-
-        def fwd(q, k, v):
-            return _flash_forward(q, k, v, causal, scale,
-                                  cand["block_q"], cand["block_k"],
-                                  interpret)[0]
-
-        sds = jax.ShapeDtypeStruct
-        abstract = (sds((1, seq_q, 1, head_dim), dtype),
-                    sds((1, seq_k, 1, head_dim), dtype),
-                    sds((1, seq_k, 1, head_dim), dtype))
-        return jax.jit(fwd), abstract
-
-    def measure(cand):
-        import time
-
-        import jax
-        import jax.numpy as jnp
-
-        fn, abstract = build(cand)
-        args = [jnp.zeros(a.shape, a.dtype) for a in abstract]
-        compiled = fn.lower(*args).compile()
-        jax.block_until_ready(compiled(*args))
-        t0 = time.perf_counter()
-        for _ in range(3):
-            out = compiled(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / 3 * 1e3
-
-    return autotune.get_or_tune(
-        "flash_attention", key,
-        candidates=autotune.spaces.flash_blocks(seq_q, seq_k),
-        build_fn=build, measure_fn=measure, default=None)
-
-
 def _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype, causal):
     """((forward block_q, block_k), (backward block_q, block_k)) of a call.
-    Explicit ints are respected as given by all three kernels, and so is
-    the autotuner's winner for this shape family (it measured the forward
-    at those blocks).  What is left None falls back to the measured
-    defaults: 512 for the backward kernels, and for the forward the most
-    rows that keep an operand block within ``_FWD_BLOCK_BYTES`` (it works a
-    fetched block through in ``_FWD_TILE`` tiles, so its block is how much
-    one grid step holds, not how much one product covers).  Each is clamped
-    by ``_pick_block``."""
+    Explicit ints are respected as given by all three kernels.  What is
+    left None takes the measured defaults: 512 for the backward kernels,
+    and for the forward the most rows that keep an operand block within
+    ``_FWD_BLOCK_BYTES`` (it works a fetched block through in ``_FWD_TILE``
+    tiles, so its block is how much one grid step holds, not how much one
+    product covers).  Each is clamped by ``_pick_block``."""
     import jax.numpy as jnp
 
-    if block_q is None or block_k is None:
-        try:
-            tuned = _autotune_blocks(seq_q, seq_k, head_dim, dtype,
-                                     causal) or {}
-        except Exception:
-            tuned = {}
-        if block_q is None:
-            block_q = tuned.get("block_q")
-        if block_k is None:
-            block_k = tuned.get("block_k")
     cap = _FWD_BLOCK_MAX
     while cap > _DEFAULT_BLOCK and \
             cap * head_dim * jnp.dtype(dtype).itemsize > _FWD_BLOCK_BYTES:
@@ -601,11 +536,10 @@ def _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype, causal):
 def resolve_blocks(block_q, block_k, seq_q, seq_k, head_dim=128,
                    dtype="bfloat16", causal=False):
     """The EFFECTIVE (block_q, block_k) a call's backward kernels run with
-    (and its forward, wherever a block size was given or tuned): explicit
-    ints are respected as-is, None consults the autotuner (winner for this
-    shape family when enabled) and falls back to the measured default
-    (512/512 — PERF.md's v5e-validated config); either way the result
-    is clamped by ``_pick_block``."""
+    (and its forward, wherever a block size was given): explicit ints are
+    respected as-is, None takes the measured default (512/512 — PERF.md's
+    v5e-validated config); either way the result is clamped by
+    ``_pick_block``."""
     return _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype,
                     causal)[1]
 
@@ -615,11 +549,10 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     """Exact fused attention, Pallas fwd+bwd. q, k, v: [b, seq, heads, d].
 
     Blocks are clamped to the sequence length for short inputs.  Passing
-    None (the default) consults the autotuner (``MXNET_AUTOTUNE``) for this
-    shape family's winner before falling back to the measured defaults;
-    explicit block sizes are always respected, by all three kernels.  The
-    defaults (PERF.md section 6, PR 40, has the readings): 512 x 512 for
-    the two backward kernels (v5e, d=128, s=8k, measured before PR 24:
+    None (the default) takes the measured defaults; explicit block sizes
+    are always respected, by all three kernels.  The defaults (PERF.md
+    section 6, PR 40, has the readings): 512 x 512 for the two backward
+    kernels (v5e, d=128, s=8k, measured before PR 24:
     512-wide tiles ran ~3x faster than 128; v5e, causal, bfloat16,
     4 x 2048 x 16 x 128, PR 40: dQ 0.975 and dK/dV 1.265 ms a call, at
     1024 x 1024 0.99 and 1.26 by the host's clock where 512 reads 1.14
@@ -664,9 +597,9 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
 
 def _attrs_config(attrs, q, k):
     """(causal, scale, forward blocks, backward blocks) for the registered
-    op.  Attrs without pinned block sizes resolve through the autotuner
-    (falling back to the measured defaults) — the fwd and bwd kernels see
-    the same deterministic resolution for one (attrs, shapes) pair."""
+    op.  Attrs without pinned block sizes take the measured defaults — the
+    fwd and bwd kernels see the same deterministic resolution for one
+    (attrs, shapes) pair."""
     d = q.shape[-1]
     scale = attrs.get("scale")
     if scale is None:
@@ -723,7 +656,7 @@ def _register():
         inputs=("query", "key", "value"),
         params={"causal": Param(bool, False),
                 "scale": Param("float-or-none", None),
-                # None = autotuner winner, else the measured defaults
+                # None = the measured defaults
                 "block_q": Param("int-or-none", None),
                 "block_k": Param("int-or-none", None)},
         infer_shape=lambda attrs, s: (s, [s[0]], []),

@@ -504,8 +504,8 @@ def experts_formulation(platform, dtype, hidden, width):
     ``"ragged-dense"`` -- the same ``ragged_dot`` expanded by XLA into a
     masked dense product, every expert over every pair: the oracle of both,
     fine at test sizes -- anywhere else.  An observation, as ``ops/paged.py``
-    ``decode_formulation`` is: no attribute, environment variable or
-    autotune entry chooses."""
+    ``decode_formulation`` is: no attribute or environment variable
+    chooses."""
     if platform != "tpu":
         return "ragged-dense"
     tiled = (jnp.dtype(dtype) == jnp.bfloat16 and hidden % 128 == 0

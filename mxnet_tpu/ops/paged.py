@@ -240,8 +240,8 @@ def decode_formulation(platform, heads, head_dim, dtype, kv_heads=None,
     ``"xla"`` — the gather over the whole table — anywhere else.  ``dtype``
     is what the products' operands are held in (the query's and the planes'
     common type).  An observation of the operands, as
-    ``interpret.interpret_for`` is for the flash kernels: no attribute,
-    environment variable or autotune entry chooses."""
+    ``interpret.interpret_for`` is for the flash kernels: no attribute or
+    environment variable chooses."""
     kv_heads = heads if kv_heads is None else kv_heads
     if rows:
         tiled = (np.dtype(dtype) == np.dtype("bfloat16")

@@ -447,7 +447,7 @@ def step_formulation(platform, head_dim, state, dtype):
     whole tiles (a block of heads is then one contiguous piece of a slot);
     ``"xla"`` -- one pass over the whole plane -- anywhere else.  An
     observation of the operands, as ``ops/paged.py`` ``decode_formulation``
-    is: no attribute, environment variable or autotune entry chooses."""
+    is: no attribute or environment variable chooses."""
     tiled = (jnp.dtype(dtype) == jnp.float32 and head_dim % 8 == 0
              and state % 128 == 0)
     return "pallas" if platform == "tpu" and tiled else "xla"

@@ -40,38 +40,6 @@ register_env("MXNET_SERVING_DRAIN_TIMEOUT_MS", 30000.0, float,
              "hang retirement forever.")
 
 
-def _autotune_buckets(max_batch):
-    """Tuned micro-batch bucket ladder for ``max_batch``, or None.
-
-    Analytic objective: expected relative padding waste under uniform
-    1..max_batch batch demand, plus a per-bucket penalty — every bucket
-    is one more executable to compile, warm, and keep resident."""
-    try:
-        from .. import autotune
-    except Exception:
-        return None
-    if not autotune.enabled():
-        return None
-    mb = int(max_batch)
-
-    def score(cand):
-        buckets = sorted(int(b) for b in cand["buckets"])
-        waste = 0.0
-        for n in range(1, mb + 1):
-            b = next((b for b in buckets if b >= n), buckets[-1])
-            waste += (b - n) / float(b)
-        return waste / mb + 0.03 * len(buckets)
-
-    try:
-        cfg = autotune.get_or_tune(
-            "serving_buckets", {"max_batch": mb},
-            candidates=autotune.spaces.serving_buckets(mb),
-            score_fn=score, default=None)
-    except Exception:
-        return None
-    return list(cfg["buckets"]) if cfg else None
-
-
 class InferenceServer:
     """Dynamic-batching inference service over a (symbol, params) checkpoint.
 
@@ -114,9 +82,7 @@ class InferenceServer:
                 % shapes)
         max_batch = batch_dims.pop()
         if buckets is None:
-            tuned = _autotune_buckets(max_batch)
-            buckets = (tuned if tuned is not None
-                       else pow2_buckets(max_batch))
+            buckets = pow2_buckets(max_batch)
         self._item_shapes = {k: s[1:] for k, s in shapes.items()}
         self._input_shapes = shapes
         self._dtype = np.dtype(dtype)

@@ -240,8 +240,7 @@ def _reset_for_tests() -> None:
     # would keep writing to the dropped registry
     for modname, attr in (("mxnet_tpu.io", "_PREFETCH_TELEM"),
                           ("mxnet_tpu.kvstore_server", "_TELEM"),
-                          ("mxnet_tpu.compile_cache", "_instruments"),
-                          ("mxnet_tpu.autotune", "_instruments")):
+                          ("mxnet_tpu.compile_cache", "_instruments")):
         m = sys.modules.get(modname)
         if m is not None:
             setattr(m, attr, None)
@@ -327,7 +326,7 @@ def startup_report() -> dict:
 
 
 def summary() -> dict:
-    """Compact run summary for embedding (bench.py BENCH json): non-zero
+    """Compact run summary for embedding in a run's record: non-zero
     counters/gauges from the global registry plus the active StepMonitor
     report, and once the process has built anything what its start was
     spent on (:func:`startup_report`)."""

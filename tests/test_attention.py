@@ -245,3 +245,56 @@ def test_flash_gradients_through_the_schedule(name):
     for a, b in zip(g, g_ref):
         assert_almost_equal(np.asarray(a), np.asarray(b),
                             rtol=1e-4, atol=1e-4)
+
+
+# (block_q, block_k, seq_q, seq_k, head_dim, dtype, causal) ->
+# ((forward block_q, block_k), (backward block_q, block_k))
+RESOLVED = {
+    # the LM cell's attention: forward and backward defaults differ
+    "lm_cell": ((None, None, 2048, 2048, 128, "bfloat16", True),
+                ((2048, 2048), (512, 512))),
+    # the shape the backward's 512 x 512 was measured at (PERF.md)
+    "benched_8192": ((None, None, 8192, 8192, 128, "bfloat16", True),
+                     ((2048, 2048), (512, 512))),
+    "noncausal": ((None, None, 2048, 2048, 128, "bfloat16", False),
+                  ((2048, 2048), (512, 512))),
+    # twice the bytes a row: half the rows a forward block
+    "float32": ((None, None, 2048, 2048, 128, "float32", True),
+                ((1024, 1024), (512, 512))),
+    "float32_head_256": ((None, None, 2048, 2048, 256, "float32", True),
+                         ((512, 512), (512, 512))),
+    # whole tiles only
+    "no_wide_block_divides": ((None, None, 1536, 1536, 128, "bfloat16", True),
+                              ((512, 512), (512, 512))),
+    "no_512_divides": ((None, None, 640, 640, 128, "bfloat16", True),
+                       ((128, 128), (128, 128))),
+    "under_512": ((None, None, 384, 384, 128, "bfloat16", True),
+                  ((384, 384), (384, 384))),
+    "more_keys_than_rows": ((None, None, 512, 4096, 128, "bfloat16", False),
+                            ((512, 2048), (512, 512))),
+    # explicit ints are respected by all three kernels
+    "both_explicit": ((256, 128, 2048, 2048, 128, "bfloat16", True),
+                      ((256, 128), (256, 128))),
+    "q_explicit": ((256, None, 2048, 2048, 128, "bfloat16", True),
+                   ((256, 2048), (256, 512))),
+    "k_explicit": ((None, 1024, 2048, 2048, 128, "bfloat16", True),
+                   ((2048, 1024), (512, 1024))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RESOLVED))
+def test_resolved_blocks(name):
+    """Explicit ints are respected; what is left None takes the measured
+    defaults: nothing else decides."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att
+
+    (bq, bk, sq, sk, d, dtype, causal), want = RESOLVED[name]
+    assert att._resolve(bq, bk, sq, sk, d, jnp.dtype(dtype), causal) == want
+    dq, dk = att.resolve_blocks(bq, bk, sq, sk, head_dim=d, dtype=dtype,
+                                causal=causal)
+    assert (dq, dk) == want[1]
+    if bq is None and bk is None:
+        # never bk < bq, which starves the MXU contraction
+        assert dk >= dq

@@ -200,10 +200,14 @@ def test_hot_swap_warm_cache_zero_compiles(cache_dir, tmp_path):
         srv.stop()
 
 
-def test_aot_bundle_roundtrip(cache_dir, tmp_path, monkeypatch):
+@pytest.mark.parametrize("leftovers", [False, True],
+                         ids=["plain", "tuning_leftovers"])
+def test_aot_bundle_roundtrip(leftovers, cache_dir, tmp_path, monkeypatch):
     """save_aot_bundle beside the checkpoint, then restore with NO cache
     dir configured: from_checkpoint auto-attaches the bundle and the
-    whole warmup is deserialize-only."""
+    whole warmup is deserialize-only.  A bundle written before PR 46 may
+    carry a tuning store (an ``autotune/`` directory, ``autotune_entries``
+    in its manifest): both are ignored."""
     net, params = _tiny_model(seed=4)
     prefix = str(tmp_path / "aot")
     mx.model.save_checkpoint(prefix, 1, net, dict(params), {})
@@ -219,6 +223,16 @@ def test_aot_bundle_roundtrip(cache_dir, tmp_path, monkeypatch):
     manifest = cc.read_manifest(bundle)
     assert manifest["entries"], "bundle saved no executables"
     assert manifest["warmup"]["buckets"]
+    assert "autotune_entries" not in manifest
+    assert not os.path.exists(os.path.join(bundle, "autotune"))
+    if leftovers:
+        manifest["autotune_entries"] = 1
+        with open(os.path.join(bundle, cc.MANIFEST_NAME), "w") as f:
+            json.dump(manifest, f)
+        os.makedirs(os.path.join(bundle, "autotune"))
+        with open(os.path.join(bundle, "autotune", "0" * 32 + ".mxt"),
+                  "wb") as f:
+            f.write(b"MXTPUAT1" + b"\0" * 16)
 
     _reset()  # also detaches bundles + drops the memory cache
     monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", "")
@@ -226,11 +240,36 @@ def test_aot_bundle_roundtrip(cache_dir, tmp_path, monkeypatch):
         prefix, 1, {"data": (4, IN_DIM)}, max_wait_us=1000)
     try:
         s = cc.stats()
-        assert s["hits"] >= 1 and s["misses"] == 0, \
+        assert s["hits"] >= 1 and s["misses"] == 0 and s["errors"] == 0, \
             "bundle-attached warmup still compiled: %s" % s
         np.testing.assert_array_equal(srv2.predict(data=X[0])[0], ref)
     finally:
         srv2.stop()
+
+
+def test_one_program_one_key(cache_dir, monkeypatch):
+    """What a key is made of is the program, its operands and the
+    devices: two builds of one program share one entry, and nothing else
+    joins the key."""
+    seen = []
+    key_parts = cc.CachedFunction._key_parts
+
+    def spy(self, args):
+        seen.append(key_parts(self, args))
+        return seen[-1]
+
+    monkeypatch.setattr(cc.CachedFunction, "_key_parts", spy)
+    net, params = _tiny_model()
+    X = np.zeros((2, IN_DIM), np.float32)
+    _forward(net, params, X)
+    _reset()
+    _forward(net, params, X)
+    assert len(seen) == 2 and seen[0] == seen[1]
+    assert sorted(seen[0]) == [
+        "cast_exclude", "compute_dtype", "devices", "graph", "group2ctx",
+        "kind", "remat", "schema", "sig", "static"]
+    assert len(cc.ls_entries(cache_dir)) == 1
+    assert cc.stats()["hits"] == 1 and cc.stats()["misses"] == 0
 
 
 def test_aot_bundle_topology_mismatch_refused(cache_dir, tmp_path):

@@ -2,8 +2,11 @@
 actually reads (or registers) must have a row — or at least a mention —
 in docs/how_to/env_var.md.  Catches the recurring drift where a new knob
 ships without documentation."""
+import importlib
 import os
 import re
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOC = os.path.join(REPO, "docs", "how_to", "env_var.md")
@@ -53,3 +56,17 @@ def test_lint_catches_known_vars():
     referenced = _referenced_vars()
     assert "MXNET_TELEMETRY" in referenced           # register_env(...)
     assert "MXNET_KVSTORE_SYNC" in referenced        # os.environ.get(...)
+
+
+def test_the_tuner_is_gone():
+    """PR 46 took ``mxnet_tpu.autotune`` out with no shim: the import
+    fails, and no variable of its family is read, registered or
+    documented."""
+    from mxnet_tpu.base import list_env
+
+    with pytest.raises(ImportError):
+        importlib.import_module("mxnet_tpu.autotune")
+    with open(DOC) as f:
+        names = set(_VAR.findall(f.read()))
+    names |= set(_referenced_vars()) | set(list_env())
+    assert not [v for v in names if "AUTOTUNE" in v]

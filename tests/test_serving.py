@@ -44,6 +44,24 @@ def test_pow2_buckets():
         serving.pow2_buckets(0)
 
 
+@pytest.mark.parametrize("max_batch,buckets,want", [
+    (8, None, (1, 2, 4, 8)),      # a power of two: the pow2 ladder
+    (6, None, (1, 2, 4, 6)),      # any other: the ladder, capped by it
+    (8, (8, 2, 2), (2, 8)),       # an explicit list, as given
+])
+def test_server_bucket_ladder(max_batch, buckets, want):
+    """The server's padded batch sizes: the list it is given, else the
+    pow2 ladder up to the leading dim of its inputs."""
+    net, params = _tiny_model()
+    srv = serving.InferenceServer(net, dict(params),
+                                  {"data": (max_batch, IN_DIM)},
+                                  buckets=buckets, warmup=False, start=False)
+    try:
+        assert tuple(srv.buckets) == want
+    finally:
+        srv.stop()
+
+
 def test_bucketed_predictor_padding_matches_per_request():
     """Padded bucketed execution is numerically the per-request forward."""
     net, params = _tiny_model()

@@ -209,9 +209,6 @@ def test_peak_flops_table_and_override(monkeypatch):
     # no peak and therefore no MFU — never the v5e's
     assert telemetry.peak_flops("TPU v5 lite") == 197e12
     assert telemetry.peak_flops() is None
-    from mxnet_tpu import hlo_analysis
-    assert hlo_analysis.hbm_bytes_per_s("TPU v5 lite") == 819e9
-    assert hlo_analysis.hbm_bytes_per_s() is None
     monkeypatch.setenv("MXNET_TELEMETRY_PEAK_FLOPS", "1e12")
     assert telemetry.peak_flops() == 1e12
 

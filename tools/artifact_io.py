@@ -1,6 +1,6 @@
 """Artifact routing for the perf tools.
 
-The probes print their records to stdout (that contract stays — bench.py
+The probes print their records to stdout (that contract stays — scripts
 and humans parse it), but the on-disk copy that used to come from shell
 redirection into the repo root (``capture_r05.jsonl`` & friends) now
 lands in the telemetry artifacts directory instead: set

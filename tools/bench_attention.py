@@ -19,9 +19,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def run_bench(batch=1, heads=8, head_dim=128, seq=16384, steps=10,
               block_q=512, block_k=1024):
-    """Time the causal flash-attention train step; returns the record dict.
-    Importable so bench.py can measure in-process (the TPU is held by one
-    process — a subprocess could not claim it)."""
+    """Time the causal flash-attention train step; returns the record
+    dict."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -38,8 +37,6 @@ def run_bench(batch=1, heads=8, head_dim=128, seq=16384, steps=10,
     v = jax.random.normal(key, (b, s, h, d), dt) * 0.1
 
     def loss(q, k, v):
-        # block_q/block_k None defers to resolve_blocks (autotuned when
-        # MXNET_AUTOTUNE is on, built-in defaults otherwise)
         o = flash_attention(q, k, v, causal=True, block_q=block_q,
                             block_k=block_k)
         return jnp.mean(o.astype(jnp.float32) ** 2)
@@ -125,42 +122,15 @@ def main():
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--block-q", type=int, default=None,
-                    help="pin the q block (default: 512, or the tuned "
-                         "winner under --autotune)")
+                    help="pin the q block (default: 512)")
     ap.add_argument("--block-k", type=int, default=None,
-                    help="pin the k block (default: 1024, or the tuned "
-                         "winner under --autotune)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="bench the pinned/default blocks AND the "
-                         "autotuned resolution side by side (sets "
-                         "MXNET_AUTOTUNE=record unless already set)")
+                    help="pin the k block (default: 1024)")
     ap.add_argument("--oracle", action="store_true",
                     help="also time upstream splash attention (the "
                          "ceiling reference)")
     cli = ap.parse_args()
     bq = 512 if cli.block_q is None else cli.block_q
     bk = 1024 if cli.block_k is None else cli.block_k
-    if cli.autotune:
-        os.environ.setdefault("MXNET_AUTOTUNE", "record")
-        from mxnet_tpu import autotune
-
-        base = run_bench(batch=cli.batch, heads=cli.heads,
-                         head_dim=cli.head_dim, seq=cli.seq,
-                         steps=cli.steps, block_q=bq, block_k=bk)
-        base["config"] = "pinned %dx%d" % (bq, bk)
-        print(json.dumps(base))
-        tuned = run_bench(batch=cli.batch, heads=cli.heads,
-                          head_dim=cli.head_dim, seq=cli.seq,
-                          steps=cli.steps, block_q=None, block_k=None)
-        tuned["config"] = "autotuned"
-        tuned["autotune"] = autotune.stats()
-        print(json.dumps(tuned))
-        delta = base["step_ms"] - tuned["step_ms"]
-        print(json.dumps({
-            "metric": "flash_autotune_delta_ms", "value": round(delta, 2),
-            "speedup": round(base["step_ms"] / tuned["step_ms"], 3)
-            if tuned["step_ms"] else None}))
-        return
     print(json.dumps(run_bench(
         batch=cli.batch, heads=cli.heads, head_dim=cli.head_dim,
         seq=cli.seq, steps=cli.steps, block_q=bq, block_k=bk)))

@@ -4,7 +4,7 @@ One process = one replica lifecycle: build an :class:`InferenceServer`
 (a deep-enough MLP that XLA compilation dominates cold start, several
 batch buckets so warmup compiles more than one program), then measure
 wall time from construction start to the first prediction result.  The
-parent (``bench.py`` cold-start phase) runs this twice against one
+parent runs this twice against one
 ``MXNET_COMPILE_CACHE_DIR``: the first run compiles and populates the
 cache, the second must start warm — hits>0, zero compiles — which is the
 PR-10 acceptance measurement.

@@ -365,8 +365,7 @@ def run_draft(num_requests=16, vocab=128, layers=2, heads=4, hidden=64,
                              budgets=(32, 40, 48))
     plain = _tokens_per_sec(target_params, spec, workload, None)
     draft = {"params": draft_params, "num_layers": draft_layers,
-             "num_heads": heads, "hidden": hidden,
-             "acceptance_hint": 0.8}
+             "num_heads": heads, "hidden": hidden}
     if draft_k is not None:
         draft["k"] = draft_k
     spec_run = _tokens_per_sec(target_params, spec, workload, draft)

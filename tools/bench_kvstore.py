@@ -12,8 +12,7 @@ same in-process dist_async server (kvstore_server.py):
 * ``async_bucket`` — bucketing on: small keys coalesce into fused
                      multi-key RPCs (MXNET_KVSTORE_BUCKET_BYTES)
 
-Emits ONE JSON line (the bench.py record shape) as the last stdout line;
-wired into bench.py as a fast CPU-only phase (needs no chip).
+Emits ONE JSON line as the last stdout line; CPU-only (needs no chip).
 """
 import argparse
 import json

@@ -10,8 +10,7 @@ Two questions the row-sparse plane exists to answer:
 
 Runs entirely on CPU against in-process KVStoreServers (the payloads are
 host numpy; claiming a TPU would measure nothing extra).  Emits ONE JSON
-line (the bench.py record shape) as the last stdout line; wired into
-bench.py as a CPU-only phase like bench_kvstore.py.
+line as the last stdout line, like bench_kvstore.py.
 """
 import argparse
 import json

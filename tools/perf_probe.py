@@ -1,6 +1,6 @@
 """Performance probe for the fused ResNet-50 train step.
 
-Builds the exact benchmark Module (bench.py path), runs one step, then lowers
+Builds the ResNet-50 Module, runs one step, then lowers
 the SAME fused program and reports XLA cost analysis (flops, bytes), HLO op
 histogram (how many transposes/copies survived), and measured step time.
 Optionally dumps full HLO text and a jax.profiler trace.
@@ -54,8 +54,8 @@ def build_module(batch):
     return mod, train
 
 
-# re-exported for back-compat: the analysis now lives in the shared
-# mxnet_tpu.hlo_analysis module (the autotuner uses it too)
+# re-exported for back-compat: the analysis lives in the shared
+# mxnet_tpu.hlo_analysis module
 from mxnet_tpu.hlo_analysis import bn_fusion_analysis  # noqa: E402,F401
 from mxnet_tpu.hlo_analysis import hlo_op_counts, op_scopes  # noqa: E402
 

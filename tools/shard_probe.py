@@ -9,8 +9,7 @@ reports:
     (all-reduce / all-gather / reduce-scatter / collective-permute) — the
     compiled truth of what the layout costs in comms.
 
-The last stdout line is a single JSON record (bench.py smoke phase parses
-it).  CPU-friendly: run with JAX_PLATFORMS=cpu and
+The last stdout line is a single JSON record.  CPU-friendly: run with JAX_PLATFORMS=cpu and
 XLA_FLAGS=--xla_force_host_platform_device_count=8 for a simulated mesh.
 
 Usage:
