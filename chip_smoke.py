@@ -50,6 +50,11 @@ PAGED_GROUPED = (
     dict(lanes=16, num_pages=2433, page_size=16, heads=32, kv_heads=8,
          head_dim=128, max_pages=152, positions=(608, 1824),
          dtype="bfloat16"))
+# the latent cell's attention layers (perfbench: pangu718b-decode-closed16):
+# 128 heads over ONE plane of rows [c | k_r], 512 + 64 values in 640 columns
+PAGED_LATENT = dict(lanes=16, num_pages=2433, page_size=16, heads=128,
+                    nope=128, rope=64, rank=512, v=128, row=640,
+                    max_pages=152, positions=(608, 1824))
 PAGED_TOL = 2e-2        # max|kernel - XLA| (XLA's products are one bf16 pass)
 LOSS0_BOUND = 1.0       # |step-0 loss - ln(vocab)|
 DP_LOSS_TOL = 0.05      # |dp4 loss - one-chip loss|, every step
@@ -248,6 +253,21 @@ def _post_generate(host, port, prompt, max_new):
     return tokens, stamps, done
 
 
+def _lanes_and_table(c, rng):
+    """Positions within ``c["positions"]`` for ``c["lanes"]`` lanes, and a
+    page table that hands each the pages its tokens need, out of order."""
+    import numpy as np
+
+    at = rng.randint(c["positions"][0], c["positions"][1] + 1,
+                     size=c["lanes"])
+    free = list(rng.permutation(np.arange(1, c["num_pages"])))
+    table = np.zeros((c["lanes"], c["max_pages"]), np.int32)
+    for lane, pos in enumerate(at):
+        held = pos // c["page_size"] + 1
+        table[lane, :held] = [free.pop() for _ in range(held)]
+    return at, table
+
+
 def paged_kernel_check(shapes, ctx, seed=0):
     """``_contrib_PagedAttention``'s kernel (compiled on a chip, interpreted
     elsewhere) against the XLA formulation from the same pool: largest gap
@@ -261,13 +281,7 @@ def paged_kernel_check(shapes, ctx, seed=0):
     dev = ctx.jax_device()
     c = dict(shapes)
     rng = np.random.RandomState(seed + 2)
-    at = rng.randint(c["positions"][0], c["positions"][1] + 1,
-                     size=c["lanes"])
-    free = list(rng.permutation(np.arange(1, c["num_pages"])))
-    table = np.zeros((c["lanes"], c["max_pages"]), np.int32)
-    for lane, pos in enumerate(at):
-        held = pos // c["page_size"] + 1
-        table[lane, :held] = [free.pop() for _ in range(held)]
+    at, table = _lanes_and_table(c, rng)
     kv_heads, dtype = c.get("kv_heads", c["heads"]), c.get("dtype", "float32")
     row = (c["lanes"], c["heads"], c["head_dim"])
     new = (c["lanes"], kv_heads, c["head_dim"])
@@ -295,6 +309,52 @@ def paged_kernel_check(shapes, ctx, seed=0):
         % (c["lanes"], c["num_pages"], c["page_size"], c["heads"], kv_heads,
            c["head_dim"], dtype, c["max_pages"], at.min(), at.max(), gap,
            PAGED_TOL))
+    return gap
+
+
+def latent_kernel_check(shapes, ctx, seed=0):
+    """``_contrib_PagedLatentAttention``'s kernel (compiled on a chip,
+    interpreted elsewhere) against the XLA formulation from the same plane:
+    largest gap of the attended rows over their largest value, and the rows
+    written."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import paged
+
+    dev = ctx.jax_device()
+    c = dict(shapes)
+    rng = np.random.RandomState(seed + 3)
+    at, table = _lanes_and_table(c, rng)
+    width = c["rank"] + c["rope"]
+    plane = rng.randn(c["num_pages"], c["page_size"], c["row"])
+    plane[..., width:] = 0  # as the pool holds a row (HybridLM.latent_row)
+    ops = [jax.device_put(jnp.asarray(x, jnp.bfloat16), dev) for x in (
+        rng.randn(c["lanes"], c["heads"], c["nope"]),
+        rng.randn(c["lanes"], c["heads"], c["rope"]),
+        rng.randn(c["lanes"], width),
+        rng.randn(c["heads"] * (c["nope"] + c["v"]), c["rank"])
+        / math.sqrt(c["rank"]), plane)]
+    ops += [jax.device_put(table, dev),
+            jax.device_put(at.astype(np.int32), dev)]
+    scale = 1.0 / math.sqrt(c["nope"] + c["rope"]) / math.sqrt(c["nope"])
+    want = paged.paged_latent_attention(*ops, scale=scale)
+    got = paged._kernel_latent_decode(*ops, scale=scale,
+                                      interpret=dev.platform != "tpu")
+    gap = float(jnp.abs(got[0].astype(jnp.float32)
+                        - want[0].astype(jnp.float32)).max()
+                / jnp.abs(want[0].astype(jnp.float32)).max())
+    check({d for g in got for d in g.devices()} == {dev},
+          "latent-attention kernel ran on %s" % dev)
+    check(gap <= PAGED_TOL and bool(jnp.array_equal(got[1], want[1])),
+          "latent-attention kernel vs the XLA formulation at %d lanes, %d "
+          "pages of %d, %d heads over rows of %d + %d in %d bfloat16, table "
+          "width %d, positions %d-%d: max|diff| / max|XLA| = %.2e <= %.0e, "
+          "written rows equal"
+          % (c["lanes"], c["num_pages"], c["page_size"], c["heads"],
+             c["rank"], c["rope"], c["row"], c["max_pages"], at.min(),
+             at.max(), gap, PAGED_TOL))
     return gap
 
 
@@ -433,6 +493,8 @@ def server_phase(cfg, ctx, seed=0):
     paged_gap = paged_kernel_check(cfg.get("paged", PAGED), ctx, seed)
     for shapes in cfg.get("paged_grouped", PAGED_GROUPED):
         paged_kernel_check(shapes, ctx, seed)
+    latent_gap = latent_kernel_check(cfg.get("paged_latent", PAGED_LATENT),
+                                     ctx, seed)
 
     # prefill logits: ctx vs an explicit mx.cpu() bind — a named
     # comparison, not a fallback
@@ -458,7 +520,8 @@ def server_phase(cfg, ctx, seed=0):
           "%.2e <= %.0e" % (dev, rel, LOGITS_REL_TOL))
     return {"transcripts": transcripts, "step_ms": step_ms,
             "tokens_per_s": total / wall, "logits_rel_diff": rel,
-            "paged_kernel_gap": paged_gap, "devices": where}
+            "paged_kernel_gap": paged_gap, "latent_kernel_gap": latent_gap,
+            "devices": where}
 
 
 # ---------------------------------------------------------------------------

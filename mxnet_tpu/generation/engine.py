@@ -109,7 +109,8 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
 from ..ops.moe import experts_formulation, experts_path
-from ..ops.paged import LATENT_FORMULATIONS, decode_formulation
+from ..ops.paged import (LATENT_PREFILL, decode_formulation,
+                         latent_formulation)
 from ..ops.ssm import step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
@@ -141,6 +142,21 @@ def _expert_products(symbol, params):
               if n["op"] == "_contrib_RoutedExperts")
     return next(((np.dtype(w13.dtype), w13.shape[1], w13.shape[2] // 2)
                  for w13 in leaves), None)
+
+
+def _latent_rows(symbol, params, pool):
+    """``(rank, the plane's row, plane dtype)`` of a lane graph's
+    ``_contrib_PagedLatentAttention`` nodes (its first: a family's latent
+    layers are of one shape), what ``ops/paged.py`` ``latent_formulation``
+    picks from beside the heads; None for a graph without one."""
+    nodes = json.loads(symbol.tojson())["nodes"]
+    planes = {s.name: s for s in pool.specs}
+    for n in nodes:
+        if n["op"] == "_contrib_PagedLatentAttention":
+            weight, plane = (nodes[n["inputs"][i][0]]["name"] for i in (3, 4))
+            return (params[weight].shape[-1], planes[plane].shape[-1],
+                    planes[plane].dtype)
+    return None
 
 
 class StateNotRebuildableError(MXNetError):
@@ -582,6 +598,8 @@ class DecodeEngine:
         # bytes a token holds over the family's latent planes (0: none)
         self._latent_token_bytes = int(
             getattr(family, "latent_token_bytes", lambda: 0)())
+        self._latent_rows = _latent_rows(
+            self._decode[self.max_lanes]._symbol, self._params, self.pool)
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -912,7 +930,8 @@ class DecodeEngine:
     def _paged_formulation(self):
         """Which formulation the lane program's ``_contrib_PagedAttention``
         runs over this engine's K/V planes, where they live (a family that
-        pages no K/V, the latent one, has only the gather of its own op)."""
+        pages no K/V, the latent one, says what its own op runs under
+        ``latent_attention``)."""
         if not self.pool.k_pools:
             return "xla"
         plane = self.pool.k_pools[0]
@@ -951,9 +970,13 @@ class DecodeEngine:
                 # likewise for the lane program's state step (ops/ssm.py)
                 snap["ssm_step"] = step_formulation(
                     self._device.platform, *self._ssm_step)
-            if self._latent_token_bytes:
+            if self._latent_rows:
                 # likewise for the latent layers' two ops (ops/paged.py)
-                snap["latent_attention"] = dict(LATENT_FORMULATIONS)
+                snap["latent_attention"] = {
+                    "prefill": LATENT_PREFILL,
+                    "decode": latent_formulation(
+                        self._device.platform, self.num_heads,
+                        *self._latent_rows, page_size=self.pool.page_size)}
             if self._lane_extras:
                 # likewise for the routed experts' grouped products
                 # (ops/moe.py), and what the lanes picked so far

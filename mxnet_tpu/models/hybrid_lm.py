@@ -71,8 +71,10 @@ always did, byte for byte: tests/test_hybrid_lm.py pins them):
   rotated, ``[k_n | v] = W_kvb c`` a head (``nope_dim | v_dim``), scores
   ``(q_n . k_n + q_r . k_r) * attention_multiplier``.  It carries ONE paged
   plane a layer, ``layer<i>_latent_pool``, a token's entry the ``kv_rank +
-  rope_dim`` values ``[c | k_r]`` after the norm and the rotation; the
-  sequence graphs run it expanded, the lane graph absorbed over that plane;
+  rope_dim`` values ``[c | k_r]`` after the norm and the rotation (and zeros
+  up to whole lane tiles where the row spans more than one:
+  :meth:`HybridLM.latent_row`); the sequence graphs run it expanded, the
+  lane graph absorbed over that plane;
 * ``shared_expert_width``: an expert layer adds ``Shared(x)``, a SiLU-gated
   MLP every row takes (the dense layers' three ops under the names
   ``layer<i>_shared_in`` / ``_gate`` / ``_out``), to its routed part;
@@ -230,7 +232,7 @@ class HybridLM:
                         for kv in "kv"]
             elif kind == LATENT:
                 out += [("layer%d_latent_pool" % i, "paged",
-                         (self.kv_rank + self.rope_dim,), self.dtype)]
+                         (self.latent_row(),), self.dtype)]
             elif kind == MAMBA:
                 out += [("layer%d_ssm_state" % i, "slot",
                          (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
@@ -252,9 +254,20 @@ class HybridLM:
         return 3 * self.hidden * self.expert_width * \
             np.dtype(self.dtype).itemsize
 
+    def latent_row(self):
+        """Columns of a latent plane's row: the ``kv_rank + rope_dim`` values
+        ``[c | k_r]``, and where they span more than one lane tile (128)
+        without filling the last, zeros up to its end (openPangu's 512 + 64
+        in 640).  A page of such rows is whole tiles and lies in one piece
+        wherever the plane lives, so a kernel can fetch it from there: a
+        chip's own layout of a ``(pages, 16, 576)`` plane puts the PAGES on
+        the lanes (PERF.md section 6, PR 49)."""
+        width = self.kv_rank + self.rope_dim
+        return width if width <= 128 else -(-width // 128) * 128
+
     def latent_token_bytes(self):
-        """Bytes a token holds over the latent planes (0: no latent
-        layer)."""
+        """Bytes of a token's values over the latent planes (0: no latent
+        layer): what attention has to read of it, not the zeros beside."""
         return self.layer_types.count(LATENT) * (
             (self.kv_rank or 0) + (self.rope_dim or 0)) * \
             np.dtype(self.dtype).itemsize
@@ -524,9 +537,13 @@ def _sequence_graph(m, seq_len, length):
             name=name), [row(k, "k"), row(v, "v")]
 
     def latent(q_n, q_r, rows, w_kvb, name):
+        zeros = m.latent_row() - (m.kv_rank + m.rope_dim)
+        # the slab as the plane holds a token (``HybridLM.latent_row``)
+        held = sym.Pad(rows, pad_width=(0, 0, 0, 0, 0, zeros),
+                       name=name + "_rows") if zeros else rows
         return sym._contrib_LatentAttention(
             q_n, q_r, rows, w_kvb, scale=m.attention_multiplier,
-            name=name), [rows]
+            name=name), [held]
 
     table = _table(m)
     x = _embed(sym.Variable("data"), m, table)

@@ -55,13 +55,22 @@ the same two places in a model, under two ops of its own:
 
 * ``_contrib_PagedLatentAttention`` — one decode step over ONE paged plane
   of latent rows, *absorbed*: ``W_kvb``'s key part goes into the query
-  (``q_c = q_n W_k``, ``rank`` wide), every head reads the same gathered
-  rows (``score = q_c . c + q_r . k_r``), the weighted sum of latents leaves
+  (``q_c = q_n W_k``, ``rank`` wide), every head reads the same rows
+  (``score = q_c . c + q_r . k_r``), the weighted sum of latents leaves
   through ``W_kvb``'s value part.  The same mathematics as the expanded
   form (tests/test_latent_lm.py holds them together), at ``rank +
-  rope`` values a token instead of ``heads x (nope + v)``.  An XLA
-  formulation over the gathered table (:data:`LATENT_FORMULATIONS`); a
-  kernel that walks the live pages is a later change's.
+  rope`` values a token instead of ``heads x (nope + v)``.  Two
+  formulations, one op (:func:`latent_formulation` picks, as
+  :func:`decode_formulation` does): on a TPU, over bfloat16 rows, the
+  kernel ``paged_latent_decode`` walks each lane's live pages where they
+  lie in the plane (multi-query attention with one shared key row a token,
+  whose values are the key's first ``rank`` columns: one plane, one fetch a
+  page, products of whole MXU tiles); anywhere else XLA gathers the whole
+  table, which is also the kernel's oracle (tests/test_paged_kernel.py).
+  The plane holds a row in whole lane tiles (``HybridLM.latent_row``:
+  openPangu's 512 + 64 values in 640 columns, zeros after them), because a
+  chip lays a ``(pages, 16, 576)`` plane out with the PAGES on the lanes,
+  where no page is one piece of memory.
 
 Page 0 of the pool is reserved as a scratch page: inactive lanes carry
 an all-zero page-table row and position 0, so their (masked-out) writes
@@ -330,11 +339,12 @@ _IN_FLIGHT_BYTES = 7 * (128 << 10)
 _SLOT_TOKENS = 128
 
 
-def _ring(page_shape, dtype):
+def _ring(page_shape, dtype, tokens=_SLOT_TOKENS):
     """(pages a slot, slots) of the kernel's ring for pages of this shape
-    (``(page_size,) + token``) and dtype."""
+    (``(page_size,) + token``) and dtype, a slot of ``tokens`` at the
+    least."""
     if len(page_shape) == 2:  # a token is a row: pages are fetched together
-        chunk = max(1, _SLOT_TOKENS // page_shape[0])
+        chunk = max(1, tokens // page_shape[0])
     else:
         chunk = 1
     slot = chunk * int(np.prod(page_shape)) * np.dtype(dtype).itemsize
@@ -678,13 +688,10 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
 _LATENT_HEAD_BLOCK, _LATENT_QUERY_BLOCK = 8, 512
 
 
-# what the two latent-attention ops run: XLA wherever the operands live
-# (``prefill``: the expanded form over blocks of heads and queries;
-# ``decode``: the absorbed form over the gathered table).  A kernel that
-# walks the live pages would make this an observation of the operands, as
-# :func:`decode_formulation` is.
-LATENT_FORMULATIONS = {"prefill": "xla-expanded-head-blocks",
-                       "decode": "xla-absorbed-gather"}
+# what ``_contrib_LatentAttention`` runs wherever the operands live: the
+# expanded form over blocks of heads and queries.  (What the decode step's op
+# runs is an observation of its operands: :func:`latent_formulation`.)
+LATENT_PREFILL = "xla-expanded-head-blocks"
 
 
 def _softmax(s):
@@ -760,25 +767,49 @@ def _latent_attention(opctx, attrs, q_n, q_r, latent, weight):
                             scale=float(attrs["scale"]))
 
 
+def _absorbed(q_n, q_r, new, weight, pool):
+    """The absorbed form's operands.  ``W_kvb``'s key part goes into the
+    query: ``[q_c | q_r]`` (lanes, heads, width), the cached row's own
+    layout, and this step's row ``new`` (lanes, width), both in the pool's
+    dtype and as wide as its rows (zeros past ``rank + rope`` where the
+    plane holds whole lane tiles: ``HybridLM.latent_row``); the value part
+    ``w_v`` (heads, v, rank) is for the way out.  A head's rows of ``W_kvb``
+    are ``[k_n | v]``."""
+    import jax.numpy as jnp
+
+    heads, nope = q_n.shape[1:]
+    w = weight.reshape(heads, -1, weight.shape[-1])
+    # (head-major operands: the host's XLA has no bfloat16 product with the
+    # batch axis in the middle)
+    q_c = jnp.einsum("hln,hnr->hlr", q_n.swapaxes(0, 1), w[:, :nope],
+                     preferred_element_type=jnp.float32).swapaxes(0, 1)
+    q = jnp.concatenate([q_c.astype(pool.dtype), q_r.astype(pool.dtype)],
+                        axis=-1)
+    new = new.astype(pool.dtype)
+    zeros = pool.shape[-1] - new.shape[-1]
+    if zeros:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, zeros)))
+        new = jnp.pad(new, ((0, 0), (0, zeros)))
+    return q, new, w[:, nope:]
+
+
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_latent_attention(q_n, q_r, new, weight, pool, pt, pos, *, scale):
     """The absorbed form, one token a lane: ``q_n`` (lanes, heads, nope),
     ``q_r`` (lanes, heads, rope) rotated, ``new`` (lanes, rank + rope) this
     step's own ``[c | k_r]``, ``weight`` ``W_kvb``, ``pool`` (num_pages,
-    page_size, rank + rope), ``pt`` (lanes, max_pages) and ``pos`` (lanes,)
-    int32.  Gathers every lane's table (as :func:`_gather_decode`), puts
-    this step's row into the copy at its position and writes it to the pool:
-    ``lanes`` rows.  Returns (lanes, heads, v) and the pool."""
+    page_size, row) whose rows hold those values and zeros after them, ``pt``
+    (lanes, max_pages) and ``pos`` (lanes,) int32.  The XLA formulation, and the kernel's oracle: gathers every
+    lane's table (as :func:`_gather_decode`), puts this step's row into the
+    copy at its position and writes it to the pool: ``lanes`` rows.  Returns
+    (lanes, heads, v) and the pool."""
     import jax.numpy as jnp
 
-    lanes, heads, nope = q_n.shape
+    lanes = q_n.shape[0]
     num_pages, ps, width = pool.shape
     rank = weight.shape[-1]
     f32 = jnp.float32
-    # a head's rows of W_kvb are [k_n | v]
-    w = weight.reshape(heads, -1, rank)
-    w_k, w_v = w[:, :nope], w[:, nope:]
-    new = new.astype(pool.dtype)
+    q, new, w_v = _absorbed(q_n, q_r, new, weight, pool)
     flat = pool.reshape(num_pages * ps, width)
     idx = (pt[:, :, None] * ps
            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(lanes, -1)
@@ -787,14 +818,6 @@ def paged_latent_attention(q_n, q_r, new, weight, pool, pt, pos, *, scale):
     cur = jnp.take_along_axis(pt, (pos // ps)[:, None], axis=1)[:, 0]
     flat = flat.at[cur * ps + pos % ps].set(new)     # inactive: page 0
 
-    # the key part of W_kvb goes into the query: rank wide, beside q_r the
-    # cached row's own layout
-    # (head-major operands: the host's XLA has no bfloat16 product with the
-    # batch axis in the middle)
-    q_c = jnp.einsum("hln,hnr->hlr", q_n.swapaxes(0, 1), w_k,
-                     preferred_element_type=f32).swapaxes(0, 1).astype(
-                         pool.dtype)
-    q = jnp.concatenate([q_c, q_r.astype(pool.dtype)], axis=-1)
     s = jnp.einsum("lhw,ltw->lht", q, rows,
                    preferred_element_type=f32) * scale
     valid = jnp.arange(idx.shape[1], dtype=jnp.int32)[None, :] <= pos[:, None]
@@ -803,6 +826,233 @@ def paged_latent_attention(q_n, q_r, new, weight, pool, pt, pos, *, scale):
                      preferred_element_type=f32).astype(pool.dtype)
     out = jnp.einsum("lhr,hvr->lhv", o_c, w_v, preferred_element_type=f32)
     return out.astype(q_n.dtype), flat.reshape(pool.shape)
+
+
+def latent_formulation(platform, heads, rank, row, dtype, page_size=16):
+    """Which formulation ``_contrib_PagedLatentAttention`` runs:
+    ``"pallas-absorbed-live-pages"`` (the kernel ``paged_latent_decode``,
+    which reads each lane's live pages where they lie in the plane) where
+    the operands live on a TPU, query and plane are bfloat16 (``dtype``
+    their common type) and a page is one block of whole tiles: its
+    ``page_size`` rows whole sublane tiles, the ``row`` the plane holds a
+    token in whole lane tiles, and so its first ``rank`` columns, the values
+    (the rotated key after them may be any width: 64 at openPangu's, in a
+    row of 640); ``"xla-absorbed-gather"`` over the whole table anywhere
+    else.  An observation of the operands, as :func:`decode_formulation`
+    is."""
+    tiled = (np.dtype(dtype) == np.dtype("bfloat16") and heads % 8 == 0
+             and rank % 128 == 0 and row % 128 == 0 and page_size % 16 == 0)
+    return "pallas-absorbed-live-pages" if platform == "tpu" and tiled \
+        else "xla-absorbed-gather"
+
+
+# Tokens a slot of the latent kernel's ring holds at the least: 16 pages of
+# 20 KiB.  A slot costs a product, a softmax and a product that wait on each
+# other whatever it holds: at 128 tokens a call took 237 us, at 256 it takes
+# 138, at 512 no less (PERF.md section 6, PR 49).
+_LATENT_SLOT_TOKENS = 256
+
+
+def _latent_decode_kernel(pt_ref, pos_ref, q_ref, new_ref, plane_in, o_ref,
+                          plane_out, work, m_ref, l_ref, acc, buf, sems,
+                          row_sems, aside, *, scale, max_pages, chunk):
+    """One call, all lanes, ONE plane.  As :func:`_decode_kernel`'s ``rows``
+    form walks them, every lane's live pages stream in lane then table order
+    through a ring of VMEM slots (``chunk`` pages of one lane a slot) and an
+    online softmax folds each slot into the lane's running max, sum and
+    weighted rows, all float32.  What differs: every head scores the SAME
+    row (``[q_c | q_r] . [c | k_r]``: one product of the lane's (heads,
+    width) query with the slot's (tokens, width) rows, no head owns lanes of
+    the row), the values are that row's first ``rank`` columns (the buffer
+    that held the keys, no second plane), and the state is ONE lane's: a
+    lane's slots are folded one after another, so its output leaves when
+    its last slot has been folded.  Scores, state and the products' sums are
+    float32, the probabilities go into their product in two bfloat16 halves
+    and the weighted rows leave in float32: the kernel rounds nothing (one
+    that rounded the probabilities once, as the gather does, read the
+    benchmark's gap to its reference higher on eight seeds of eight:
+    PERF.md section 6, PR 49).  This step's own row opens each lane's
+    softmax from the projections; its page comes into ``aside``, takes the
+    row and goes back whole (a bfloat16 row is no DMA's unit)."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, width = q_ref.shape
+    ring, ps = buf.shape[0], plane_in.shape[1]
+    rank = acc.shape[-1]
+    chunks = -(-max_pages // chunk)  # slots a lane's whole table makes
+    f32 = jnp.float32
+
+    def live_pages(lane):
+        return (pos_ref[lane] + ps - 1) // ps
+
+    def slots(lane):
+        return (live_pages(lane) + chunk - 1) // chunk
+
+    def own_page(lane, to_plane):
+        """The copy of the page that holds the lane's position (an inactive
+        lane's: the scratch page) into ``aside``, or back."""
+        page = pt_ref[lane * max_pages + pos_ref[lane] // ps]
+        ends = (plane_out.at[page], aside.at[lane])
+        return pltpu.make_async_copy(*(ends[::-1] if to_plane else ends),
+                                     row_sems.at[lane])
+
+    def each_lane(fn):
+        lax.fori_loop(0, lanes, lambda lane, carry: fn(lane), None)
+
+    each_lane(lambda lane: own_page(lane, False).start())
+
+    # the work list: lane * chunks + the slot's number in the lane's table,
+    # once per ``chunk`` pages that hold a token before the lane's position
+    # (none for a lane at position 0: it attends its own token alone)
+    def lane_slots(lane, n):
+        def one(c, n):
+            work[n] = lane * chunks + c
+            return n + 1
+        return lax.fori_loop(0, slots(lane), one, n)
+
+    total = lax.fori_loop(0, lanes, lane_slots, 0)
+
+    def fetch(method):
+        """Start, or wait for, the pages of work item ``n``: the lane's
+        live ones among the slot's ``chunk`` (all of them, but in a lane's
+        last slot: those without a loop)."""
+        def run(n):
+            lane, c = work[n] // chunks, work[n] % chunks
+            slot, first = n % ring, lane * max_pages + c * chunk
+            live = jnp.minimum(chunk, live_pages(lane) - c * chunk)
+
+            def page(j, carry=None):
+                getattr(pltpu.make_async_copy(
+                    plane_in.at[pt_ref[first + j]], buf.at[slot, j],
+                    sems.at[slot]), method)()
+                return carry
+
+            @pl.when(live == chunk)
+            def _():
+                for j in range(chunk):
+                    page(j)
+
+            @pl.when(live < chunk)
+            def _():
+                lax.fori_loop(0, live, page, None)
+        return run
+
+    # a slot's rows past the lane's live pages are an earlier fetch's, or
+    # nobody's: masked below, but 0 x NaN is NaN in the weighted rows
+    buf[...] = jnp.zeros_like(buf)
+    lax.fori_loop(0, jnp.minimum(ring - 1, total),
+                  lambda n, carry: fetch("start")(n), None)
+
+    def put(lane):
+        own_page(lane, False).wait()
+        here = lax.broadcasted_iota(jnp.int32, aside.shape[1:], 0) \
+            == pos_ref[lane] % ps
+        aside[lane] = jnp.where(here, new_ref[lane],
+                                aside[lane].astype(f32)).astype(aside.dtype)
+        own_page(lane, True).start()
+
+    each_lane(put)
+
+    def attend(lane, n):
+        q = q_ref[lane]
+        # this step's own token opens the lane's softmax: its row comes
+        # from the projections, not from the pool
+        m_ref[...] = jnp.sum(q.astype(f32) * new_ref[lane], axis=-1,
+                             keepdims=True) * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc[...] = jnp.broadcast_to(new_ref[lane][:, :rank], acc.shape)
+
+        def fold(c, n):
+            @pl.when(n + ring - 1 < total)
+            def _():
+                fetch("start")(n + ring - 1)
+
+            fetch("wait")(n)
+            rows = buf[n % ring].reshape(chunk * ps, width)
+            s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32) * scale
+            token = c * chunk * ps + lax.broadcasted_iota(jnp.int32, s.shape,
+                                                          1)
+            s = jnp.where(token < pos_ref[lane], s, _NEG)  # (heads, tokens)
+            m_old = m_ref[...]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)  # a masked slot underflows to 0.0
+            alpha = jnp.exp(m_old - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            # the probabilities in two bfloat16 halves, as _decode_kernel's
+            high = p.astype(rows.dtype)
+            low = (p - high.astype(f32)).astype(rows.dtype)
+            pv = jnp.dot(jnp.concatenate([high, low], axis=0),
+                         rows[:, :rank], preferred_element_type=f32)
+            acc[...] = alpha * acc[...] + pv[:heads] + pv[heads:]
+            return n + 1
+
+        n = lax.fori_loop(0, slots(lane), fold, n)
+        o_ref[lane] = acc[...] / l_ref[...]
+        return n
+
+    lax.fori_loop(0, lanes, attend, 0)
+    each_lane(lambda lane: own_page(lane, True).wait())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                              "in_place"))
+def _kernel_latent_decode(q_n, q_r, new, weight, pool, pt, pos, *, scale,
+                          interpret=False, in_place=False):
+    """:func:`paged_latent_attention` with the kernel ``paged_latent_decode``
+    in the gather's place: ``q_c = q_n W_k`` before the call and ``o_c W_v``
+    after it stay XLA's, the plane goes through the call where it lies and
+    aliased to its output, held to the HBM where the program donates it
+    (``in_place``: :func:`_kernel_decode` has the rule and its reasons), the
+    page table and positions are scalar-prefetch operands.  Jitted on its
+    own so that a lane program's latent layers trace and lower it once."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads = q_n.shape[:2]
+    ps, width = pool.shape[1:]
+    rank, max_pages = weight.shape[-1], pt.shape[1]
+    f32 = jnp.float32
+    chunk, ring = _ring(pool.shape[1:], pool.dtype, _LATENT_SLOT_TOKENS)
+    q, new, w_v = _absorbed(q_n, q_r, new, weight, pool)
+    operands = (q, new.astype(f32).reshape(lanes, 1, width))
+    whole = [pl.BlockSpec(x.shape, lambda i, *_: (0, 0, 0)) for x in operands]
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    o_c, pool = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale,
+                          max_pages=max_pages, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=whole + [where_it_lies],
+            out_specs=[pl.BlockSpec((lanes, heads, rank),
+                                    lambda i, *_: (0, 0, 0)), where_it_lies],
+            scratch_shapes=[
+                pltpu.SMEM((lanes * -(-max_pages // chunk),),
+                           jnp.int32),                      # work list
+                pltpu.VMEM((heads, 1), f32),                # running max
+                pltpu.VMEM((heads, 1), f32),                # running sum
+                pltpu.VMEM((heads, rank), f32),             # weighted rows
+                pltpu.VMEM((ring, chunk, ps, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((ring,)),
+                pltpu.SemaphoreType.DMA((lanes,)),
+                # each lane's current page
+                pltpu.VMEM((lanes, ps, width), pool.dtype)]),
+        out_shape=[jax.ShapeDtypeStruct((lanes, heads, rank), f32),
+                   (pltpu.HBM if in_place else jax.ShapeDtypeStruct)(
+                       pool.shape, pool.dtype)],
+        # operands count the two scalar-prefetch ones: the plane is 4
+        input_output_aliases={4: 1},
+        name="paged_latent_decode", interpret=interpret,
+    )(pt.reshape(-1), pos, *operands, pool)
+    out = jnp.einsum("lhr,hvr->lhv", o_c.astype(pool.dtype), w_v,
+                     preferred_element_type=f32)
+    return out.astype(q_n.dtype), pool
 
 
 def _paged_latent_infer(attrs, shapes):
@@ -825,17 +1075,28 @@ def _paged_latent_infer(attrs, shapes):
 @jax.named_scope("paged_attention_latent")
 def _paged_latent_attention(opctx, attrs, q_n, q_r, latent, weight, pool,
                             page_table, positions):
-    """:func:`paged_latent_attention` as an op.  Two scopes, one inside the
-    other: ``paged_attention`` for whoever reads the decode step's
+    """One decode step of latent attention, absorbed, as an op: through the
+    kernel where the operands live on a TPU and are what it takes, elsewhere
+    through the gather (:func:`latent_formulation`).  Two scopes, one inside
+    the other: ``paged_attention`` for whoever reads the decode step's
     attention whatever its kind, ``paged_attention_latent`` for this one."""
     import jax.numpy as jnp
+
+    from .interpret import carried_in_place, platform_of
 
     if int(attrs["page_size"]) != pool.shape[1]:
         raise ValueError("page_size %s, but the pool's pages hold %d slots"
                          % (attrs["page_size"], pool.shape[1]))
-    if latent.shape[-1] != pool.shape[2]:
+    if latent.shape[-1] > pool.shape[2]:
         raise ValueError("this step's latent row is %d wide, the pool's %d"
                          % (latent.shape[-1], pool.shape[2]))
-    return paged_latent_attention(
-        q_n, q_r, latent, weight, pool, page_table.astype(jnp.int32),
-        positions.astype(jnp.int32), scale=float(attrs["scale"]))
+    decode = {"pallas-absorbed-live-pages": functools.partial(
+                  _kernel_latent_decode, in_place=carried_in_place()),
+              "xla-absorbed-gather": paged_latent_attention}[
+        latent_formulation(platform_of(q_n, pool), q_n.shape[1],
+                           weight.shape[-1], pool.shape[2],
+                           jnp.result_type(q_n.dtype, pool.dtype),
+                           page_size=pool.shape[1])]
+    return decode(q_n, q_r, latent, weight, pool,
+                  page_table.astype(jnp.int32), positions.astype(jnp.int32),
+                  scale=float(attrs["scale"]))

@@ -657,67 +657,138 @@ def test_lfm2_lane_program_streams_each_expert_once(one_chip, monkeypatch):
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
 
 
-def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch):
-    """The latent-attention family's decode step at openPangu-Ultra-MoE's
-    widths, one dense and one expert layer (16 of 256 experts held, a shared
-    expert beside them, sandwich norms, an untied head), as the engine's
-    Executor builds it, compiled for the chip.  ONE latent plane a layer,
-    aliased in and out; the absorbed attention under ``paged_attention/
-    paged_attention_latent`` (both readers' scopes); the routed products the
-    kernel ``moe_grouped`` at 7680 x 2048 (its tiles fit), the shared expert
-    the dense MLP's three ops under ``layer<i>_shared_*``; no
-    ``state_slot``."""
-    import re
+# the latent cell's pool (perfbench: pangu718b-decode-closed16): what XLA
+# does with a plane depends on its size
+LATENT_POOL = dict(lanes=16, pages=2433, max_pages=152)
 
-    import jax
-    import jax.numpy as jnp
 
-    import mxnet_tpu as mx
-    from mxnet_tpu import compile_cache
+def _pangu(layers):
+    """openPangu-Ultra-MoE's block at its published widths, 16 of its 256
+    experts held, a shared expert beside them, sandwich norms, an untied
+    head: ``layers`` latent layers, the first of them dense."""
     from mxnet_tpu.models import HybridLM
-    from mxnet_tpu.ops.interpret import bind
 
-    monkeypatch.setattr(compile_cache, "active", lambda: False)
-    lanes, pages, max_pages, vocab = 16, 40, 152, 512
-    model = HybridLM(
-        vocab_size=vocab, hidden=7680, layer_types=["latent"] * 2,
+    return HybridLM(
+        vocab_size=512, hidden=7680, layer_types=["latent"] * layers,
         num_heads=128, kv_heads=128, head_dim=192, nope_dim=128, rope_dim=64,
         v_dim=128, q_rank=1536, kv_rank=512, intermediate=18432,
         rotary_theta=25.6e6, num_experts=256, experts_per_token=8,
         expert_width=2048, num_dense_layers=1, experts_held=16,
         routed_scaling=2.5, router_bias=False,
         shared_expert_width=2048, sandwich_norm=True, tied_head=False)
-    symbol = model.decode_symbol(2432, 16)
+
+
+@pytest.mark.parametrize("donated", [True, False])
+def test_latent_lane_program_carries_one_plane_a_layer(one_chip, monkeypatch,
+                                                       donated):
+    """The latent-attention family's decode step at openPangu-Ultra-MoE's
+    widths, one dense and one expert layer, as the engine's Executor builds
+    it, compiled for the chip at the cell's own pool.  ONE latent plane a
+    layer, a token's row the 512 + 64 values in 640 columns (whole lane
+    tiles: ``HybridLM.latent_row``), aliased in and out; the absorbed
+    attention ONE ``paged_latent_decode`` call a layer under
+    ``paged_attention/paged_attention_latent`` (both readers' scopes), which
+    takes the plane where it lies: donated, no line of the module copies,
+    gathers from or scatters into a plane, plain or staged into the fast
+    memory ``S(1)``; undonated (the rule under the framework's compile
+    cache) XLA copies each plane once and the program still compiles.  The
+    routed products are the kernel ``moe_grouped`` at 7680 x 2048 (its tiles
+    fit), the shared expert the dense MLP's three ops under
+    ``layer<i>_shared_*``; no ``state_slot``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: not donated)
+    lanes, pages, max_pages = (LATENT_POOL[k] for k in ("lanes", "pages",
+                                                        "max_pages"))
+    model, vocab = _pangu(2), 512
+    symbol = model.decode_symbol(max_pages * 16, 16)
     assert "state_slot" not in symbol.list_arguments()
     shapes = {name: (lanes,) for name in ("data", "positions", "source",
                                           "prev_ids")}
     shapes["page_table"] = (lanes, max_pages)
     types, planes = {}, []
     for name, kind, shape, dtype in model.planes():
-        assert (kind, shape) == ("paged", (576,))
+        assert (kind, shape) == ("paged", (640,))
         shapes[name] = (pages, 16) + shape
         types[name] = jnp.dtype(dtype)
         planes.append(name)
-    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip)
+    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip,
+                                     donated)
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert [(o.shape, str(o.dtype)) for o in (outs[0], outs[-2], outs[-1])] \
         == [((lanes, vocab), "float32"), ((lanes,), "float32"),
             ((1, 256), "int32")]
     assert len(outs) == 3 + len(planes) == 3 + 2
     text = compiled.as_text()
-    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
-    assert [n.split(".")[0] for n in names] == ["moe_grouped"]
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"[^\n]*"
+                       r"op_name=\"([^\"]*)\"", text)
+    assert sorted(n.split(".")[0] for n, _ in calls) == \
+        ["moe_grouped", "paged_latent_decode", "paged_latent_decode"]
+    assert all(re.search(r"layer\d_attn/paged_attention/"
+                         r"paged_attention_latent/", scope)
+               for n, scope in calls if n.startswith("paged_latent")), calls
     _leaves_go_whole_into(text, "moe_grouped", calls=1,
                           leaves=("bf16[16,7680,4096]", "bf16[16,2048,7680]"))
     ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
     assert any("layer1_experts/moe_experts/" in o for o in ops)
     for part in ("in", "gate", "out"):
         assert any(("layer1_shared_%s/" % part) in o for o in ops), part
-    assert any(re.search(r"layer\d_attn/paged_attention/"
-                         r"paged_attention_latent/", o) for o in ops)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    plane = r"bf16\[(%d,16|%d),640\]" % (pages, pages * 16)
+    uses = [line for line in text.splitlines()
+            if re.search(plane, line) and re.match(r"\s*(ROOT )?%[\w.\-]+ = ",
+                                                    line)]
+    moved = [line for line in uses if re.search(
+        r" (copy|copy-start|copy-done|slice-start|slice-done|gather|scatter|"
+        r"dynamic-update-slice|fusion)\(", line)]
+    if not donated:
+        assert moved and not any(" gather(" in line for line in moved)
+        return
+    assert not moved, moved[:2]
+    assert not [line for line in uses
+                if re.search(plane + r"\{[^}]*S\(1\)", line)]
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+
+
+def test_latent_decode_kernel_compiles_for_v5e(one_chip):
+    """The kernel alone at the latent cell's shapes (16 lanes of 128 heads,
+    rows of 512 + 64 in 640 columns, 2,433 pages, a table 152 wide): ONE
+    custom call between XLA's ``q_c = q_n W_k`` and ``o_c W_v``, inside the
+    default limit of the fast memory, and, donated, no copy of the plane."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import paged
+
+    lanes, pages, max_pages = (LATENT_POOL[k] for k in ("lanes", "pages",
+                                                        "max_pages"))
+    heads, nope, rope, rank, v = 128, 128, 64, 512, 128
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(*operands):
+        return paged._kernel_latent_decode(*operands, scale=0.1,
+                                           in_place=True)
+
+    text = jax.jit(step, donate_argnums=(4,)).lower(
+        arg((lanes, heads, nope)), arg((lanes, heads, rope)),
+        arg((lanes, rank + rope)), arg((heads * (nope + v), rank)),
+        arg((pages, 16, 640)), arg((lanes, max_pages), jnp.int32),
+        arg((lanes,), jnp.int32)).compile().as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert len(names) == 1 and names[0].startswith("paged_latent_decode")
+    assert not [line for line in text.splitlines()
+                if "bf16[%d,16,640]" % pages in line
+                and re.search(r" copy(-start)?\(", line)]
 
 
 # the three cells whose attention layers page grouped bfloat16 K/V

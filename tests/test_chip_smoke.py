@@ -80,9 +80,13 @@ def test_server_phase_toy_width_on_cpu():
                         head_dim=8, max_pages=4, positions=(3, 14)),
              paged_grouped=(dict(lanes=3, num_pages=13, page_size=16, heads=8,
                                  kv_heads=2, head_dim=64, max_pages=4,
-                                 positions=(3, 60), dtype="bfloat16"),)),
+                                 positions=(3, 60), dtype="bfloat16"),),
+             paged_latent=dict(lanes=3, num_pages=13, page_size=16, heads=8,
+                               nope=16, rope=64, rank=128, v=16, row=256,
+                               max_pages=4, positions=(3, 60))),
         mx.tpu(0))
     assert out["paged_kernel_gap"] <= 1e-5  # interpreted: float32 both
+    assert out["latent_kernel_gap"] <= chip_smoke.PAGED_TOL
     assert len(out["transcripts"]) == 4
     assert out["transcripts"][0] == out["transcripts"][-1]
     assert out["logits_rel_diff"] <= chip_smoke.LOGITS_REL_TOL

@@ -2,7 +2,9 @@
 walks each lane's live pages (interpret mode here) against the XLA gather
 over the whole table, which stays as its oracle; which of the two the op
 picks where; and what the engine says about it (``snapshot()``, the
-``gen:step`` span's ``pages``).
+``gen:step`` span's ``pages``).  Then the same for
+``_contrib_PagedLatentAttention``: its kernel over ONE plane of latent rows
+against its gather.
 """
 import jax
 import jax.numpy as jnp
@@ -65,6 +67,23 @@ CASES = {
 }
 
 
+def _table(rng, num_pages, page_size, max_pages, positions, order):
+    """The page table that hands each live lane the pages its tokens need
+    (ids ``order``ed), and the lanes' positions (an inactive lane's: 0)."""
+    free = list(range(1, num_pages))  # popped from the end
+    if order == "shuffled":
+        rng.shuffle(free)
+    elif order == "ascending":
+        free.reverse()
+    table = np.zeros((len(positions), max_pages), np.int32)
+    for lane, at in enumerate(positions):
+        if at is not None:
+            held = at // page_size + 1
+            table[lane, :held] = [free.pop() for _ in range(held)]
+    return jnp.asarray(table), jnp.asarray([p or 0 for p in positions],
+                                           jnp.int32)
+
+
 def _operands(page_size, max_pages, positions, order, form="float32",
               seed=0):
     heads, kv_heads, hd, dtype, rows = FORMS[form]
@@ -77,18 +96,8 @@ def _operands(page_size, max_pages, positions, order, form="float32",
     token = (kv_heads * hd,) if rows else (kv_heads, hd)
     k_pool, v_pool = (jnp.asarray(rng.randn(num_pages, page_size, *token),
                                   dtype) for _ in range(2))
-    free = list(range(1, num_pages))  # popped from the end
-    if order == "shuffled":
-        rng.shuffle(free)
-    elif order == "ascending":
-        free.reverse()
-    table = np.zeros((lanes, max_pages), np.int32)
-    for lane, at in enumerate(positions):
-        if at is not None:
-            held = at // page_size + 1
-            table[lane, :held] = [free.pop() for _ in range(held)]
-    at = jnp.asarray([p or 0 for p in positions], jnp.int32)
-    return q, k_new, v_new, k_pool, v_pool, jnp.asarray(table), at
+    table, at = _table(rng, num_pages, page_size, max_pages, positions, order)
+    return q, k_new, v_new, k_pool, v_pool, table, at
 
 
 def _in_float32(ops):
@@ -279,6 +288,201 @@ def test_the_kernel_holds_only_donated_planes_to_the_hbm(donated):
     assert [s == "hbm" for s in spaces] == [False, donated, donated], spaces
 
 
+# -- latent attention: one plane of rows [c | k_r] ---------------------------
+
+# name -> (heads, nope, rope, rank, v, the plane's row): the latent cell's
+# widths (512 + 64 values in 640 columns), a narrow family, and rows that
+# fill their lane tiles
+LATENT_FORMS = {
+    "cell": (128, 128, 64, 512, 128, 640),
+    "narrow": (8, 16, 64, 128, 16, 256),
+    "whole_tiles": (16, 32, 128, 128, 32, 256),
+}
+
+# name -> (max_pages, positions; None is an inactive lane, page ids handed
+#          out, the form).  Pages of 16.
+LATENT_CASES = {
+    # an inactive lane on the scratch page, a live lane at position 0, at a
+    # page's first and last slot, at the table's end
+    "cell_widths": (12, [40, None, 0, 191, 176, 16, 15], "shuffled", "cell"),
+    "narrow_rows": (12, [40, None, 0, 191, 130, 16, 7], "shuffled",
+                    "narrow"),
+    "rows_of_whole_tiles": (12, [40, None, 0, 191, 130], "shuffled",
+                            "whole_tiles"),
+    "more_slots_than_the_ring": (96, [1535, 700, None, 1100], "descending",
+                                 "narrow"),
+    "all_lanes_inactive": (4, [None, None], "ascending", "narrow"),
+    "first_and_last_slot_of_a_page": (4, [16, 15, 48, 47], "shuffled",
+                                      "narrow"),
+    "one_lane": (8, [77], "shuffled", "narrow"),
+    "table_full": (8, [127, 2], "shuffled", "narrow"),
+}
+
+
+def _latent_operands(max_pages, positions, order, form, seed=0,
+                     dtype=jnp.bfloat16):
+    heads, nope, rope, rank, v, row = LATENT_FORMS[form]
+    rng = np.random.RandomState(seed)
+    lanes, page_size = len(positions), 16
+    num_pages = 1 + lanes * max_pages  # page 0 is the scratch page
+    q_n, q_r, new = (jnp.asarray(rng.randn(*shape), dtype) for shape in (
+        (lanes, heads, nope), (lanes, heads, rope), (lanes, rank + rope)))
+    weight = jnp.asarray(rng.randn(heads * (nope + v), rank)
+                         / np.sqrt(rank), dtype)
+    pool = rng.randn(num_pages, page_size, row)
+    pool[..., rank + rope:] = 0  # as the pool holds a row
+    table, at = _table(rng, num_pages, page_size, max_pages, positions, order)
+    return q_n, q_r, new, weight, jnp.asarray(pool, dtype), table, at
+
+
+def _latent_scale(form):
+    heads, nope, rope = LATENT_FORMS[form][:3]
+    return float(1.0 / np.sqrt(nope + rope) / np.sqrt(nope))
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_CASES))
+def test_latent_kernel_matches_the_gather(name):
+    max_pages, positions, order, form = LATENT_CASES[name]
+    ops = _latent_operands(*LATENT_CASES[name])
+    scale = _latent_scale(form)
+    want, want_pool = paged.paged_latent_attention(*ops, scale=scale)
+    got, got_pool = paged._kernel_latent_decode(*ops, scale=scale,
+                                                interpret=True)
+    assert got.dtype == want.dtype == ops[0].dtype
+    assert got_pool.dtype == ops[4].dtype and got_pool.shape == ops[4].shape
+    # the gather rounds the probabilities, ``o_c`` and the output once each
+    # to 8 bits of mantissa; the kernel's call rounds the last two (the
+    # kernel itself nothing: float32 sums of exact products).  Both are held
+    # to the float32 gather over the same values, to three such roundings of
+    # the largest attended value
+    exact = np.asarray(paged.paged_latent_attention(
+        *[x.astype(jnp.float32) for x in ops[:5]], *ops[5:],
+        scale=scale)[0])
+    tol = 3 * 2.0 ** -8 * np.abs(exact).max()
+    for out in (got, want):
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                   exact, rtol=0, atol=tol)
+    # the plane: nothing but the lanes' rows changed, and those hold this
+    # step's row, zeros after its values.  (Every inactive lane writes the
+    # scratch page's first slot: which of them lands last is nobody's
+    # business.)
+    new, pool, table = ops[2], ops[4], np.asarray(ops[5])
+    np.testing.assert_array_equal(got_pool[1:], want_pool[1:])
+    np.testing.assert_array_equal(got_pool[0, 1:], pool[0, 1:])
+    for lane, pos in enumerate(positions):
+        if pos is not None:
+            row = got_pool[table[lane, pos // 16], pos % 16]
+            np.testing.assert_array_equal(row[:new.shape[1]], new[lane])
+            assert not np.asarray(row[new.shape[1]:]).any()
+
+
+@pytest.mark.parametrize("form,positions", [("cell", [37, 58]),
+                                            ("narrow", [37, 150])])
+def test_latent_history_beyond_the_position_is_never_read(form, positions):
+    """Slots at and after a lane's position may hold anything finite (a
+    retired sequence's rows, the slot this step writes) and the pages after
+    the position's anything at all: the kernel masks the first and never
+    fetches the second (NaN would poison its weighted rows: 0 x NaN)."""
+    max_pages = 12
+    ops = list(_latent_operands(max_pages, positions, "shuffled", form))
+    scale = _latent_scale(form)
+    want = paged._kernel_latent_decode(*ops, scale=scale, interpret=True)[0]
+    table = np.asarray(ops[5]).copy()
+    pool = np.asarray(ops[4].astype(jnp.float32)).copy()
+    poisoned = table.copy()
+    for lane, pos in enumerate(positions):
+        pool[table[lane, pos // 16], pos % 16:] = 1e4
+    # lane 0's table names a page of NaN after its live ones
+    spare = next(p for p in range(1, pool.shape[0]) if p not in table)
+    pool[spare] = np.nan
+    poisoned[0, positions[0] // 16 + 1:] = spare
+    got = paged._kernel_latent_decode(
+        *ops[:4], jnp.asarray(pool, ops[4].dtype), jnp.asarray(poisoned),
+        ops[6], scale=scale, interpret=True)[0]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("platform,heads,rank,row,dtype,more,want", [
+    ("tpu", 128, 512, 640, jnp.bfloat16, {}, "pallas-absorbed-live-pages"),
+    ("cpu", 128, 512, 640, jnp.bfloat16, {}, "xla-absorbed-gather"),
+    ("gpu", 128, 512, 640, jnp.bfloat16, {}, "xla-absorbed-gather"),
+    ("tpu", 8, 128, 256, jnp.bfloat16, {}, "pallas-absorbed-live-pages"),
+    # float32 rows, or a float32 query over bfloat16 rows: the gather
+    ("tpu", 128, 512, 640, np.float32, {}, "xla-absorbed-gather"),
+    # a row as wide as its values, off a lane tile: a page is no block of
+    # whole tiles (and a chip lays such a plane out pages-minor)
+    ("tpu", 128, 512, 576, jnp.bfloat16, {}, "xla-absorbed-gather"),
+    ("tpu", 4, 12, 16, jnp.bfloat16, {}, "xla-absorbed-gather"),  # toy
+    ("tpu", 128, 192, 256, jnp.bfloat16, {}, "xla-absorbed-gather"),
+    ("tpu", 12, 512, 640, jnp.bfloat16, {}, "xla-absorbed-gather"),
+    ("tpu", 128, 512, 640, jnp.bfloat16, {"page_size": 4},
+     "xla-absorbed-gather"),
+    ("tpu", 128, 512, 640, jnp.bfloat16, {"page_size": 32},
+     "pallas-absorbed-live-pages"),
+])
+def test_latent_formulation_follows_where_the_operands_live(
+        platform, heads, rank, row, dtype, more, want):
+    """The kernel takes bfloat16 rows of whole lane tiles whose values (the
+    first ``rank`` columns) are whole lane tiles too, in pages of whole
+    sublane tiles, on a TPU; anything else the gather."""
+    assert paged.latent_formulation(platform, heads, rank, row, dtype,
+                                    **more) == want
+
+
+def _latent_op_args(form, lanes, pages, max_pages, query=jnp.bfloat16,
+                    rows=jnp.bfloat16):
+    heads, nope, rope, rank, v, row = LATENT_FORMS[form]
+    shapes = [((lanes, heads, nope), query), ((lanes, heads, rope), query),
+              ((lanes, rank + rope), query),
+              ((heads * (nope + v), rank), query), ((pages, 16, row), rows),
+              ((lanes, max_pages), jnp.float32), ((lanes,), jnp.float32)]
+    return [jax.ShapeDtypeStruct(*s) for s in shapes]
+
+
+@pytest.mark.parametrize("platform,form,want", [
+    ("cpu", "bfloat16", []), ("tpu", "bfloat16", ["paged_latent_decode"]),
+    ("tpu", "float32", []), ("tpu", "float32 query, bfloat16 rows", [])])
+def test_latent_op_picks_by_the_executors_scope(platform, form, want):
+    """As ``_contrib_PagedAttention``: traced under ``bound_to`` the op runs
+    its kernel on a tpu context only, for bfloat16 query and rows: no
+    attribute, no environment variable.  Traced, not run."""
+    from mxnet_tpu.ops.registry import get_op
+
+    f32 = jnp.float32
+    args = _latent_op_args(
+        "narrow", 2, 5, 4, query=f32 if "float32" in form else jnp.bfloat16,
+        rows=f32 if form == "float32" else jnp.bfloat16)
+    op = get_op("_contrib_PagedLatentAttention")
+
+    def step(*a):
+        with bound_to(platform):
+            return op.fn(None, {"page_size": 16, "scale": 0.1}, *a)
+
+    assert _pallas_calls(jax.make_jaxpr(step)(*args).jaxpr) == want
+
+
+@pytest.mark.parametrize("donated", [True, False])
+def test_the_latent_kernel_holds_only_a_donated_plane_to_the_hbm(donated):
+    """The K/V kernel's rule (above) for the one plane of this op."""
+    from mxnet_tpu.ops.interpret import carrying
+    from mxnet_tpu.ops.registry import get_op
+
+    op = get_op("_contrib_PagedLatentAttention")
+
+    def step(*a):
+        with bound_to("tpu"):
+            return op.fn(None, {"page_size": 16, "scale": 0.1}, *a)
+
+    (eqn,) = _pallas_eqns(jax.make_jaxpr(carrying(step, donated))(
+        *_latent_op_args("narrow", 2, 5, 4)).jaxpr)
+    spaces = [str(getattr(aval, "memory_space", None))
+              for aval in eqn.params["out_avals"]]
+    assert [s == "hbm" for s in spaces] == [False, donated], spaces
+    # the plane goes in and out as one buffer (operand 4 counts the two
+    # scalar-prefetch ones)
+    assert tuple(eqn.params["input_output_aliases"]) == ((4, 1),)
+
+
 V, LAYERS, S, PAGE = 64, 2, 32, 4
 
 
@@ -364,3 +568,86 @@ def test_engine_says_pallas_for_grouped_bfloat16_pages_on_a_tpu(monkeypatch):
     assert said == {("bfloat16", 16): ("xla", "pallas"),
                     ("float32", 16): ("xla", "xla"),
                     ("bfloat16", 4): ("xla", "xla")}
+
+
+# a latent family whose row spans two lane tiles without filling them (128 +
+# 64 values: 256 columns), at toy widths elsewhere
+LATENT_CFG = dict(
+    vocab_size=V, hidden_size=32, intermediate_size=64,
+    moe_intermediate_size=16, num_hidden_layers=2, first_k_dense_replace=1,
+    n_routed_experts=4, n_routed_experts_published=4, first_expert=0,
+    n_shared_experts=1, num_attention_heads=8, num_key_value_heads=8,
+    qk_nope_head_dim=8, qk_rope_head_dim=64, v_head_dim=8, q_lora_rank=16,
+    kv_lora_rank=128, num_experts_per_tok=4, norm_topk_prob=True,
+    routed_scaling_factor=2.5, rope_theta=25600000, rms_norm_eps=1e-5,
+    sandwich_norm=True, tie_word_embeddings=False, attention_bias=False,
+    hidden_act="silu")
+
+
+def _latent_engine(dtype, page_size):
+    from perfbench.builders import latent_moe_lm as builder
+    from perfbench.models import latent_moe_lm as ref
+
+    cfg = dict(LATENT_CFG, weights_dtype=dtype)
+    params = {k: mx.nd.NDArray(v, mx.cpu())
+              for k, v in ref.make_weights(cfg, 3).items()}
+    return DecodeEngine(params, family=builder.family_spec(cfg), ctx=mx.cpu(),
+                        max_seq_len=64, lane_buckets=(2,),
+                        page_size=page_size, num_pages=9,
+                        prefill_len_buckets=(16,), start=False, warmup=False)
+
+
+def test_engine_says_what_the_latent_family_runs_where(monkeypatch):
+    """A family of latent layers (bfloat16 rows of 128 + 64 values, held in
+    256 columns: whole lane tiles) says ``pallas-absorbed-live-pages`` for
+    its decode step where its planes live on a TPU and
+    ``xla-absorbed-gather`` on the host platform; float32 rows, and pages
+    that are no whole sublane tiles, say the gather on both."""
+    import types
+
+    said = {}
+    for dtype, page_size in (("bfloat16", 16), ("float32", 16),
+                             ("bfloat16", 4)):
+        eng = _latent_engine(dtype, page_size)
+        (plane,) = eng.pool.planes()[:1]
+        assert plane.shape == (9, page_size, 256) and str(plane.dtype) == dtype
+        # the counter counts a token's values, not the zeros beside them
+        assert eng._latent_token_bytes == 2 * 192 * plane.dtype.itemsize
+        on_host = eng.snapshot()["latent_attention"]
+        monkeypatch.setattr(eng, "_device",
+                            types.SimpleNamespace(platform="tpu"))
+        on_tpu = eng.snapshot()["latent_attention"]
+        assert on_host["prefill"] == on_tpu["prefill"] == \
+            "xla-expanded-head-blocks"
+        assert eng.snapshot()["paged_attention"] == "xla"  # no K/V planes
+        said[dtype, page_size] = (on_host["decode"], on_tpu["decode"])
+    gather, kernel = "xla-absorbed-gather", "pallas-absorbed-live-pages"
+    assert said == {("bfloat16", 16): (gather, kernel),
+                    ("float32", 16): (gather, gather),
+                    ("bfloat16", 4): (gather, gather)}
+
+
+def test_zeros_beside_a_latent_rows_values_change_no_token(monkeypatch):
+    """The plane holds a row of 128 + 64 values in 256 columns
+    (``HybridLM.latent_row``): the prefill's slabs and the decode step's
+    rows carry zeros there, and the transcript is the one of a plane as wide
+    as the values."""
+    from mxnet_tpu.models import HybridLM
+
+    def transcript():
+        eng = _latent_engine("float32", 16)
+        eng.start()
+        try:
+            widths = {p.shape[-1] for p in eng.pool.planes()}
+            return widths, eng.generate([3, 1, 4, 1, 5, 9, 2, 6], 12), [
+                p.asnumpy() for p in eng.pool.planes()]
+        finally:
+            eng.stop()
+
+    widths, tokens, planes = transcript()
+    assert widths == {256}
+    assert all(not p[..., 192:].any() and p[..., :192].any() for p in planes)
+    monkeypatch.setattr(HybridLM, "latent_row",
+                        lambda self: self.kv_rank + self.rope_dim)
+    narrow_widths, narrow_tokens, _ = transcript()
+    assert narrow_widths == {192} and narrow_tokens == tokens
