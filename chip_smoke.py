@@ -24,6 +24,7 @@ LAST line of standard output is the contracted JSON object, also on failure
 (``"ok": false``, exit code 1); everything else is printed before it.
 """
 import argparse
+import functools
 import gc
 import json
 import math
@@ -55,7 +56,15 @@ PAGED_GROUPED = (
 PAGED_LATENT = dict(lanes=16, num_pages=2433, page_size=16, heads=128,
                     nope=128, rope=64, rank=512, v=128, row=640,
                     max_pages=152, positions=(608, 1824))
+# the two Granite cells' state-space layers in prefill (perfbench: g4hsmall-
+# and g4hmicro-decode-closed16): their longest bucket with prompts that end
+# inside a chunk and on a chunk's edge, and the short mix's one-chunk bucket
+SSM_SCAN = (dict(L=2048, heads=128, lengths=(1300, 2048)),
+            dict(L=512, heads=64, lengths=(200, 512, 256, 1)),
+            dict(L=64, heads=64, lengths=(40,)))
 PAGED_TOL = 2e-2        # max|kernel - XLA| (XLA's products are one bf16 pass)
+SCAN_Y_TOL = 1e-2       # max|kernel - XLA| / max|XLA| of y, rounded to bf16
+SCAN_STATE_TOL = 1e-4   # the same of the final state, float32 in both
 LOSS0_BOUND = 1.0       # |step-0 loss - ln(vocab)|
 DP_LOSS_TOL = 0.05      # |dp4 loss - one-chip loss|, every step
 LOGITS_REL_TOL = 0.05   # max|tpu - cpu| prefill logits / max|cpu logits|
@@ -395,6 +404,54 @@ def lane_pick_check(eng, seed=0):
             eng.pool.free(sid)
 
 
+def scan_kernel_check(shapes, ctx, seed=0):
+    """``_contrib_SSMScan``'s kernel (compiled on a chip, interpreted
+    elsewhere) against the XLA formulation of the same bfloat16 rows:
+    largest gap of ``y`` over the prompts' live rows and of the final
+    state, each over the XLA form's largest value; every row finite."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import ssm
+
+    dev = ctx.jax_device()
+    c = dict(shapes)
+    rng = np.random.RandomState(seed + 4)
+    b, L, heads = len(c["lengths"]), c["L"], c["heads"]
+    sizes = dict(heads=heads, head_dim=64, state=128, chunk=256)
+    silu = lambda v: v / (1 + np.exp(-v))  # noqa: E731
+    ops = [jnp.asarray(silu(rng.randn(b, L, heads * 64 + 256)), jnp.bfloat16),
+           jnp.asarray(rng.randn(b, L, heads) - 1.0, jnp.bfloat16),
+           jnp.asarray(np.log(rng.uniform(1, 16, heads)), jnp.float32),
+           jnp.asarray(rng.randn(heads), jnp.float32),
+           jnp.asarray(rng.randn(heads), jnp.float32),
+           jnp.asarray(c["lengths"], jnp.int32)]
+    ops = [jax.device_put(x, dev) for x in ops]
+    want = jax.jit(lambda *a: ssm.ssm_scan(*a, **sizes))(*ops)
+    got = jax.jit(lambda *a: ssm.ssm_scan(
+        *a, scan=functools.partial(ssm._kernel_scan,
+                                   interpret=dev.platform != "tpu"),
+        **sizes))(*ops)
+    live = np.arange(L)[None, :, None] < np.asarray(c["lengths"])[:, None,
+                                                                  None]
+    y, y_want = (np.asarray(a[0], np.float32) for a in (got, want))
+    y_gap = float(np.abs(np.where(live, y - y_want, 0)).max()
+                  / np.abs(y_want).max())
+    s_gap = float(jnp.abs(got[1] - want[1]).max() / jnp.abs(want[1]).max())
+    check({d for g in got for d in g.devices()} == {dev},
+          "scan kernel ran on %s" % dev)
+    check(y_gap <= SCAN_Y_TOL and s_gap <= SCAN_STATE_TOL
+          and np.isfinite(y).all(),
+          "scan kernel vs the XLA formulation at %d prompts of %s in a "
+          "bucket of %d, %d heads of 64, state 128, chunks of 256, bfloat16: "
+          "max|diff| / max|XLA| of y = %.2e <= %.0e, of the state = %.2e <= "
+          "%.0e, every row finite"
+          % (b, "/".join(map(str, c["lengths"])), L, heads, y_gap,
+             SCAN_Y_TOL, s_gap, SCAN_STATE_TOL))
+    return y_gap, s_gap
+
+
 def server_phase(cfg, ctx, seed=0):
     """InferenceServer + generator on ``ctx`` behind its HTTP endpoint:
     >=4 ``POST /generate`` requests, two in flight at a time; the first
@@ -403,7 +460,7 @@ def server_phase(cfg, ctx, seed=0):
     the paged-attention kernel against the XLA formulation at the decode
     cell's shapes (``cfg["paged"]`` overrides them).
     Returns {"transcripts", "step_ms", "tokens_per_s", "logits_rel_diff",
-    "paged_kernel_gap", "devices"}."""
+    "paged_kernel_gap", "latent_kernel_gap", "scan_kernel_gaps", "devices"}."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -495,6 +552,8 @@ def server_phase(cfg, ctx, seed=0):
         paged_kernel_check(shapes, ctx, seed)
     latent_gap = latent_kernel_check(cfg.get("paged_latent", PAGED_LATENT),
                                      ctx, seed)
+    scan_gaps = [scan_kernel_check(shapes, ctx, seed)
+                 for shapes in cfg.get("ssm_scan", SSM_SCAN)]
 
     # prefill logits: ctx vs an explicit mx.cpu() bind — a named
     # comparison, not a fallback
@@ -521,6 +580,7 @@ def server_phase(cfg, ctx, seed=0):
     return {"transcripts": transcripts, "step_ms": step_ms,
             "tokens_per_s": total / wall, "logits_rel_diff": rel,
             "paged_kernel_gap": paged_gap, "latent_kernel_gap": latent_gap,
+            "scan_kernel_gaps": scan_gaps,
             "devices": where}
 
 

@@ -111,7 +111,7 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
 from ..ops.moe import experts_formulation, experts_path
 from ..ops.paged import (LATENT_PREFILL, decode_formulation,
                          latent_formulation)
-from ..ops.ssm import step_formulation
+from ..ops.ssm import scan_formulation, step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
 __all__ = ["DecodeEngine", "GenStream", "StateNotRebuildableError"]
@@ -130,6 +130,25 @@ def _state_step(symbol, pool):
     return next(((int(n["attr"]["head_dim"]), int(n["attr"]["state"]),
                   dtypes[nodes[n["inputs"][5][0]]["name"]])
                  for n in nodes if n["op"] == "_contrib_SSMStep"), None)
+
+
+def _state_scan(symbol, params):
+    """``heads``, ``head_dim``, ``state``, ``chunk`` and ``dtype`` of a
+    prefill graph's ``_contrib_SSMScan`` nodes (its first: a family's
+    state-space layers are of one shape; the dtype is that of the
+    convolution's weight before it, which writes the rows the scan reads):
+    what ``ops/ssm.py`` ``scan_formulation`` picks from beside the bucket;
+    None for a graph without one."""
+    nodes = json.loads(symbol.tojson())["nodes"]
+    for n in nodes:
+        if n["op"] == "_contrib_SSMScan":
+            conv = nodes[n["inputs"][0][0]]
+            weight = params[nodes[conv["inputs"][1][0]]["name"]]
+            sizes = {k: int(n["attr"][k])
+                     for k in ("heads", "head_dim", "state")}
+            return dict(sizes, chunk=int(n["attr"].get("chunk", 256)),
+                        dtype=np.dtype(weight.dtype))
+    return None
 
 
 def _expert_products(symbol, params):
@@ -588,6 +607,9 @@ class DecodeEngine:
                                 "decode_b%d")
         self._ssm_step = _state_step(
             self._decode[self.max_lanes]._symbol, self.pool)
+        longest = self._prefill[self.prefill_len_buckets[-1]]
+        self._ssm_scan = _state_scan(
+            longest._preds[longest.max_batch_size]._symbol, self._params)
         # the lane program's outputs after the picked ids, and the routed
         # experts' cumulative load (expert layers, experts) where it has one
         self._lane_extras = tuple(getattr(family, "lane_extras", ()))
@@ -970,6 +992,11 @@ class DecodeEngine:
                 # likewise for the lane program's state step (ops/ssm.py)
                 snap["ssm_step"] = step_formulation(
                     self._device.platform, *self._ssm_step)
+            if self._ssm_scan:
+                # and for the prefill programs' scan, at the longest bucket
+                snap["ssm_scan"] = scan_formulation(
+                    self._device.platform, self.prefill_len_buckets[-1],
+                    is_train=False, **self._ssm_scan)
             if self._latent_rows:
                 # likewise for the latent layers' two ops (ops/paged.py)
                 snap["latent_attention"] = {
@@ -1122,6 +1149,13 @@ class DecodeEngine:
         if self.pool.num_slots:
             args["state_slot"] = "|".join(
                 str(self.pool.state_slot(s.sid)) for s in admitted)
+        if self._ssm_scan:
+            # the chunks the bucket holds a prompt, and those with a token
+            # in them: what the scan walks (ops/ssm.py)
+            chunk = self._ssm_scan["chunk"]
+            args["scan_chunks"] = len(misses) * -(-L // chunk)
+            args["scan_chunks_live"] = sum(-(-len(s.tokens) // chunk)
+                                           for s in misses)
         if "expert_load" in self._lane_extras:
             # every prompt token routes in every expert layer
             args["expert_pairs"] = self.family.expert_pairs(args["tokens"])

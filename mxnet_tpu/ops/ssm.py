@@ -13,12 +13,17 @@ with ``[x | B | C]`` the output of a causal depthwise convolution (width
 ``K``, with bias) followed by SiLU.  Two forms of each, one numerics:
 
 * over a prompt (prefill): :func:`causal_conv` and :func:`ssm_scan`, the
-  chunked scan of the Mamba-2 paper in plain ``jax.numpy``.  Both take the
-  prompts' TRUE lengths: a position at or past a prompt's length
-  contributes nothing (its ``dt`` is forced to 0, so it neither decays the
-  state nor adds to it) and the convolution's tail is read at
-  ``length-(K-1) .. length-1``, so a right-padded prompt leaves exactly the
-  state its unpadded self would;
+  chunked scan of the Mamba-2 paper.  Both take the prompts' TRUE lengths:
+  a position at or past a prompt's length contributes nothing (its ``dt``
+  is forced to 0, so it neither decays the state nor adds to it) and the
+  convolution's tail is read at ``length-(K-1) .. length-1``, so a
+  right-padded prompt leaves exactly the state its unpadded self would.
+  The scan has two formulations, one op (:func:`scan_formulation`): on a
+  TPU one Pallas kernel a layer walks each prompt's live chunks one at a
+  time, a block of heads at a time, a head's decay matrix never leaving
+  VMEM; anywhere else, and in a graph that is differentiated, plain
+  ``jax.numpy`` takes the whole bucket at once, which is also the
+  kernel's oracle;
 * one token a lane (decode): :func:`conv_step` and :func:`ssm_step` over
   per-lane *slots* of a state plane, indexed by ``state_slot`` as a page
   table indexes K/V pages; the engine carries the planes through the step
@@ -45,7 +50,7 @@ from jax import lax
 from .param import Param
 from .registry import register
 
-_F32 = jnp.float32
+_F32, _BF16 = jnp.float32, jnp.bfloat16
 _HI = lax.Precision.HIGHEST
 
 
@@ -249,13 +254,20 @@ def _causal_conv1d_step(opctx, attrs, data, weight, *rest):
 # the selective state-space recurrence
 # ---------------------------------------------------------------------------
 
-def _split_xbc(xbc, heads, head_dim, state):
-    """``[x | B | C]`` of the last axis, in float32: x (..., heads,
-    head_dim), B and C (..., state)."""
+def _inner(xbc, heads, head_dim, state):
+    """``heads x head_dim``, the width of ``x`` in ``[x | B | C]``; refuses
+    an ``xbc`` of another width."""
     inner = heads * head_dim
     if xbc.shape[-1] != inner + 2 * state:
         raise ValueError("xbc is %d wide; heads x head_dim + 2 x state is %d"
                          % (xbc.shape[-1], inner + 2 * state))
+    return inner
+
+
+def _split_xbc(xbc, heads, head_dim, state):
+    """``[x | B | C]`` of the last axis, in float32: x (..., heads,
+    head_dim), B and C (..., state)."""
+    inner = _inner(xbc, heads, head_dim, state)
     xbc = xbc.astype(_F32)
     x = xbc[..., :inner].reshape(xbc.shape[:-1] + (heads, head_dim))
     return x, xbc[..., inner:inner + state], xbc[..., inner + state:]
@@ -266,28 +278,15 @@ def _dt_and_a(dt_raw, A_log, dt_bias):
     return dt, -jnp.exp(A_log.astype(_F32))
 
 
-def ssm_scan(xbc, dt_raw, A_log, D, dt_bias, length=None, *, heads,
-             head_dim, state, chunk=256):
-    """Prefill form: the chunked scan.  ``xbc`` (b, L, heads*head_dim +
-    2*state) after the convolution, ``dt_raw`` (b, L, heads), ``A_log``,
-    ``D``, ``dt_bias`` (heads,), ``length`` (b,) or None.  Inside a chunk
-    of ``chunk`` positions the outputs are one masked product of the decay
-    matrix, between chunks the state is carried by a short recurrence.
-    Returns ``y`` (b, L, heads*head_dim) in ``xbc``'s dtype and the final
-    state (b, heads, head_dim, state), float32, as it stands after each
-    prompt's TRUE length."""
+def _chunked_scan(xbc, dt, A, D, length, *, chunk, heads, head_dim, state):
+    """The XLA formulation, and the kernel's oracle: every chunk of the
+    bucket at once.  ``xbc`` (b, L, ...) with ``L`` whole chunks, ``dt`` (b,
+    L, heads) float32 and zero past each prompt's ``length``, which is not
+    needed beside that: a dead chunk's products are zeros.  Differentiable,
+    so it is also a training graph's path."""
     b, L = xbc.shape[:2]
+    Q, nc = chunk, L // chunk
     x, B, C = _split_xbc(xbc, heads, head_dim, state)
-    dt, A = _dt_and_a(dt_raw, A_log, dt_bias)
-    if length is not None:
-        live = jnp.arange(L)[None, :] < length.astype(jnp.int32)[:, None]
-        dt = jnp.where(live[:, :, None], dt, 0.0)
-    Q = min(int(chunk), L)
-    pad = -L % Q
-    if pad:  # positions past the end: dt 0, nothing moves
-        x, B, C, dt = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                       for a in (x, B, C, dt))
-    nc = (L + pad) // Q
     x = x.reshape(b, nc, Q, heads, head_dim)
     B, C = B.reshape(b, nc, Q, state), C.reshape(b, nc, Q, state)
     dt = dt.reshape(b, nc, Q, heads)
@@ -317,9 +316,251 @@ def ssm_scan(xbc, dt_raw, A_log, D, dt_bias, length=None, *, heads,
     before = jnp.stack(before, axis=1)  # the state each chunk starts from
     y = y + jnp.einsum("bctn,bchpn->bcthp", C, before, precision=_HI) \
         * jnp.exp(cs)[..., None]
-    y = y + D.astype(_F32)[:, None] * x
-    y = y.reshape(b, nc * Q, heads * head_dim)[:, :L]
-    return y.astype(xbc.dtype), S
+    y = y + D[:, None] * x
+    return y.reshape(b, L, heads * head_dim), S
+
+
+def _pieces(a):
+    """``a`` as bfloat16 addends whose float32 sum is ``a``: itself where it
+    is bfloat16, else the three a float32 mantissa splits into."""
+    if a.dtype == _BF16:
+        return [a]
+    hi = a.astype(_BF16)
+    rest = a - hi.astype(_F32)
+    mid = rest.astype(_BF16)
+    return [hi, mid, (rest - mid.astype(_F32)).astype(_BF16)]
+
+
+def _dot(a, b, contract):
+    """``a`` x ``b`` over ``contract`` (one axis of each), accumulated in
+    float32 and as exact as ``precision=HIGHEST``, which is these passes for
+    two float32 operands: every pair of :func:`_pieces` but the three
+    smallest.  An operand that IS bfloat16 has one piece, so a float32 matrix
+    times what a bfloat16 convolution wrote costs three passes, not six, for
+    the same sum."""
+    out = None
+    for i, pa in enumerate(_pieces(a)):
+        for j, pb in enumerate(_pieces(b)):
+            if i + j < 3:
+                part = lax.dot_general(pa, pb, ((contract[:1], contract[1:]),
+                                                ((), ())),
+                                       preferred_element_type=_F32)
+                out = part if out is None else out + part
+    return out
+
+
+def _tile_heads(head_dim):
+    """Heads a lane tile of ``x``: those of 64 go two and two."""
+    return max(1, 128 // head_dim)
+
+
+def _scan_kernel(live_ref, d_ref, x_ref, b_ref, c_ref, col_ref, row_ref,
+                 dt_ref, y_ref, s_ref, *, head_dim):
+    """One prompt, one block of its heads, one chunk; the chunk axis is the
+    grid's last, so ``s_ref`` (the block's state, (heads a block x head_dim,
+    state) float32: the output's own VMEM block, written back once, when
+    the block of heads changes) carries from chunk to chunk.  ``x_ref`` (1,
+    Q, heads a block x head_dim), ``b_ref`` / ``c_ref`` (1, Q, state) are
+    blocks of ``xbc`` as the convolution wrote it; ``col_ref`` (1, 1, Q,
+    heads a block) and ``row_ref`` (1, heads a block, Q)
+    the chunk's cumulative log decay with the positions along the sublanes
+    and along the lanes (``seg`` takes one of each), ``dt_ref`` likewise
+    ``dt``, by rows.  A head's ``Q x Q`` decay matrix lives and dies in
+    here.  The block is worked a lane tile of ``x`` at a time, in a loop
+    (one tile's operations are all that shape inference and a program's
+    trace walk): heads narrower than a tile share it, each one's product taking the tile
+    with the other heads' lanes zeroed, which the MXU's 128 columns make no
+    dearer than the head alone, so no value is ever moved across lanes."""
+    from jax.experimental import pallas as pl
+
+    prompt, block, c = (pl.program_id(i) for i in range(3))
+    P, Q, width = head_dim, x_ref.shape[1], x_ref.shape[2]
+    hb = width // P
+    per = _tile_heads(P)
+    W = per * P
+
+    @pl.when(c == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    # past the prompt: dt is 0 there, the state stands, nothing reads y
+    @pl.when(c >= live_ref[prompt])
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(c < live_ref[prompt])
+    def _():
+        B, C = b_ref[0], c_ref[0]
+        G = _dot(C, B, (1, 1))  # (Q, Q): C_t . B_s
+        causal = (lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+                  <= lax.broadcasted_iota(jnp.int32, (Q, Q), 0))
+        lane = lax.broadcasted_iota(jnp.int32, (1, W), 1)
+        # M is lower triangular: rows t in bands of T, each against the
+        # positions s up to its end (3 of the 4 quarters of a 256 chunk)
+        T = 128 if Q % 128 == 0 else Q
+
+        def tile(i, carry):
+            """One lane tile of ``x``: ``per`` heads."""
+            at = pl.ds(pl.multiple_of(i * W, W), W)
+            x, S = x_ref[0, :, at], s_ref[0, at, :]  # (Q, W), (W, state)
+            x32 = x.astype(_F32)
+            y = [jnp.zeros((T, W), _F32)] * (Q // T)
+            scale, skip = jnp.zeros((Q, W), _F32), jnp.zeros((1, W), _F32)
+            keep, to_end = [], []
+            for k in range(per):
+                h = i * per + k
+                col = col_ref[0, 0, :, pl.ds(h, 1)]  # (Q, 1)
+                row, dt = row_ref[0, pl.ds(h, 1), :], dt_ref[0, pl.ds(h, 1), :]
+                mine = (lane >= k * P) & (lane < (k + 1) * P)
+                xh = jnp.where(mine, x32, 0.0).astype(x.dtype)
+                for band in range(Q // T):
+                    t, n = slice(band * T, (band + 1) * T), (band + 1) * T
+                    # M[t, s] = exp(cs_t - cs_s) (C_t . B_s) dt_s for s <= t
+                    M = jnp.where(causal[t, :n],
+                                  jnp.exp(col[t] - row[:, :n]), 0.0)
+                    M = M * G[t, :n] * dt[:, :n]
+                    y[band] = y[band] + _dot(M, xh[:n], (1, 0))
+                scale = jnp.where(mine, jnp.exp(col), scale)
+                skip = jnp.where(mine, d_ref[block * hb + h], skip)
+                end = row[:, Q - 1:Q]  # (1, 1)
+                keep.append(jnp.broadcast_to(jnp.exp(end), (P, 1)))
+                to_end.append(jnp.broadcast_to(dt * jnp.exp(end - row),
+                                               (P, Q)))
+            # C_t S_prev, grown by exp(cs_t), and D x beside the chunk's own
+            y = jnp.concatenate(y, axis=0) + _dot(C, S, (1, 1)) * scale \
+                + skip * x32
+            y_ref[0, :, at] = y.astype(y_ref.dtype)
+            # S = exp(cs_end) S_prev + (x dt to_end)^T B
+            s_ref[0, at, :] = jnp.concatenate(keep, axis=0) * S + _dot(
+                x32.T * jnp.concatenate(to_end, axis=0), B, (1, 0))
+            return carry
+
+        # traced once, unrolled where it is lowered: a loop the compiler
+        # cannot schedule across costs a third more time (my chip runs, PR 50)
+        lax.fori_loop(0, hb // per, tile, 0, unroll=True)
+
+
+# lanes of x a block of the scan's kernel at most: 8 heads at the cells' head
+# size, which keeps a grid step's blocks, the state and the products'
+# intermediates within the chip's default scoped VMEM
+_SCAN_LANES = 512
+
+
+def _scan_heads(heads, head_dim):
+    """Heads a block of the scan's kernel, or None where no block is whole
+    tiles: a divisor of ``heads`` that fills whole lane tiles of ``x`` and
+    whole sublane tiles of the per-head rows (or is every head); the most
+    that fit ``_SCAN_LANES``, else the fewest."""
+    per = _tile_heads(head_dim)
+    fit = [h for h in range(per, heads + 1, per)
+           if heads % h == 0 and (h * head_dim) % 128 == 0
+           and (h % 8 == 0 or h == heads)]
+    small = [h for h in fit if h * head_dim <= _SCAN_LANES]
+    return max(small) if small else min(fit, default=None)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "head_dim",
+                                             "state", "interpret"))
+def _kernel_scan(xbc, dt, A, D, length, *, chunk, heads, head_dim, state,
+                 interpret=False):
+    """The Pallas formulation: grid ``(prompt, block of heads, chunk)``,
+    :func:`_scan_kernel` a step.  The chunks of each prompt that hold a
+    token, (b,) int32 from ``length``, are the scalar-prefetch operand: the
+    index maps of every input stop at a prompt's last live chunk, so a dead
+    step fetches nothing, and the kernel does no arithmetic there.  ``xbc`` goes
+    in three times as it lies (the heads' block of ``x``, ``B``, ``C``: no
+    slice is copied out first).  Jitted on its own so that a prefill
+    program's layers trace and lower the kernel once (as
+    :func:`_kernel_step`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, L = xbc.shape[:2]
+    Q, nc, inner = chunk, L // chunk, _inner(xbc, heads, head_dim, state)
+    hb = _scan_heads(heads, head_dim)
+    blocks, width = heads // hb, hb * head_dim
+    cs = jnp.cumsum((dt * A).reshape(b, nc, Q, heads), axis=2)
+    cs = cs.reshape(b, L, heads)
+    live = (length + Q - 1) // Q
+
+    def upto(i, c, live):  # a prompt's chunk c, or its last live one
+        return jnp.minimum(c, jnp.maximum(live[i] - 1, 0))
+
+    rows = pl.BlockSpec((1, hb, Q), lambda i, j, c, live:
+                        (i, j, upto(i, c, live)))
+    xbc_block = lambda n, at: pl.BlockSpec(  # noqa: E731
+        (1, Q, n), lambda i, j, c, live: (i, upto(i, c, live), at(j)))
+    y, S = pl.pallas_call(
+        functools.partial(_scan_kernel, head_dim=head_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, blocks, nc),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      xbc_block(width, lambda j: j),
+                      xbc_block(state, lambda j: inner // state),
+                      xbc_block(state, lambda j: inner // state + 1),
+                      pl.BlockSpec((1, 1, Q, hb), lambda i, j, c, live:
+                                   (i, j, upto(i, c, live), 0)),
+                      rows, rows],
+            out_specs=[pl.BlockSpec((1, Q, width),
+                                    lambda i, j, c, live: (i, c, j)),
+                       pl.BlockSpec((1, width, state),
+                                    lambda i, j, c, live: (i, j, 0))]),
+        out_shape=[jax.ShapeDtypeStruct((b, L, inner), xbc.dtype),
+                   jax.ShapeDtypeStruct((b, inner, state), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="ssm_scan", interpret=interpret,
+    )(live, D, xbc, xbc, xbc,
+      cs.reshape(b, L, blocks, hb).swapaxes(1, 2), cs.swapaxes(1, 2),
+      dt.swapaxes(1, 2))
+    return y, S.reshape(b, heads, head_dim, state)
+
+
+def scan_formulation(platform, L, heads, head_dim, state, dtype, is_train,
+                     chunk=256):
+    """Which formulation ``_contrib_SSMScan`` runs: ``"pallas"`` -- the
+    kernel that walks a prompt's live chunks one at a time -- where the
+    operands live on a TPU, the op is not being differentiated (the kernel
+    has no backward: a training graph keeps the XLA form and its gradient)
+    and a chunk, a head and a head's state are whole tiles; ``"xla"`` --
+    the whole bucket at once -- anywhere else.  An observation of the
+    operands, as :func:`step_formulation` is."""
+    tiled = (jnp.dtype(dtype) in (jnp.dtype(_BF16), jnp.dtype(_F32))
+             and min(int(chunk), L) % 16 == 0 and state % 128 == 0
+             and (heads * head_dim) % state == 0
+             and _scan_heads(heads, head_dim) is not None)
+    return ("pallas" if platform == "tpu" and tiled and not is_train
+            else "xla")
+
+
+def ssm_scan(xbc, dt_raw, A_log, D, dt_bias, length=None, *, heads,
+             head_dim, state, chunk=256, scan=_chunked_scan):
+    """Prefill form: the chunked scan.  ``xbc`` (b, L, heads*head_dim +
+    2*state) after the convolution, ``dt_raw`` (b, L, heads), ``A_log``,
+    ``D``, ``dt_bias`` (heads,), ``length`` (b,) or None.  Inside a chunk
+    of ``chunk`` positions the outputs are one masked product of the decay
+    matrix, between chunks the state is carried by a short recurrence:
+    ``scan`` is :func:`_chunked_scan` over the whole bucket (here, and
+    wherever :func:`scan_formulation` says ``"xla"``) or
+    :func:`_kernel_scan` over each prompt's live chunks.  Returns ``y`` (b,
+    L, heads*head_dim) in ``xbc``'s dtype and the final state (b, heads,
+    head_dim, state), float32, as it stands after each prompt's TRUE
+    length."""
+    b, L = xbc.shape[:2]
+    dt, A = _dt_and_a(dt_raw, A_log, dt_bias)
+    if length is None:
+        length = jnp.full((b,), L, jnp.int32)
+    else:
+        length = jnp.minimum(length.astype(jnp.int32), L)
+        dt = jnp.where(jnp.arange(L)[None, :, None] < length[:, None, None],
+                       dt, 0.0)
+    Q = min(int(chunk), L)
+    pad = -L % Q
+    if pad:  # positions past the end: dt 0, nothing moves
+        xbc, dt = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (xbc, dt))
+    y, S = scan(xbc, dt, A, D.astype(_F32), length, chunk=Q, heads=heads,
+                head_dim=head_dim, state=state)
+    return y[:, :L].astype(xbc.dtype), S
 
 
 def _routed_step(decay, u, B, C, states, slot):
@@ -495,9 +736,16 @@ def _ssm_scan(opctx, attrs, data, dt, A_log, D, dt_bias, *length):
     ``dt`` (b, L, heads), the per-head ``A_log``, ``D``, ``dt_bias`` and,
     with ``use_length``, ``length`` (b,); writes ``out`` (b, L, inner) and
     ``state`` (b, heads, head_dim, state) float32."""
+    from .interpret import platform_of
+
+    sizes, chunk = _sizes(attrs), int(attrs.get("chunk", 256))
+    scan = {"pallas": _kernel_scan, "xla": _chunked_scan}[scan_formulation(
+        platform_of(data), data.shape[1], sizes["heads"], sizes["head_dim"],
+        sizes["state"], data.dtype, getattr(opctx, "is_train", False),
+        chunk)]
     return ssm_scan(data, dt, A_log, D, dt_bias,
-                    length[0] if length else None,
-                    chunk=int(attrs.get("chunk", 256)), **_sizes(attrs))
+                    length[0] if length else None, chunk=chunk, scan=scan,
+                    **sizes)
 
 
 @register("_contrib_SSMStep",

@@ -879,6 +879,40 @@ def test_hybrid_lane_program_reads_its_pages_where_they_lie(one_chip,
     assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
 
 
+@pytest.mark.parametrize("b,L,heads", [
+    (1, 2048, 128), (4, 1024, 128),      # g4hsmall's longest bucket, a batch
+    (1, 512, 64), (1, 64, 64),           # g4hmicro's longest, and one chunk
+    (1, 2560, 128),                      # the scoring program's, padded
+])
+def test_scan_kernel_compiles_for_v5e(one_chip, b, L, heads):
+    """``_contrib_SSMScan``'s kernel at the two Granite cells' shapes
+    (bfloat16 rows of heads x 64 + 2 x 128, chunks of 256): Mosaic takes the
+    blocks of ``xbc`` as they lie, the transposed ``x`` and a chunk of 64
+    rows, within the default scoped VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import ssm
+
+    sizes = dict(heads=heads, head_dim=64, state=128, chunk=256)
+
+    def scan(xbc, dt, A_log, D, dt_bias, length):
+        return ssm.ssm_scan(xbc, dt, A_log, D, dt_bias, length,
+                            scan=ssm._kernel_scan, **sizes)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    vec = arg((heads,), jnp.float32)
+    compiled = jax.jit(scan).lower(
+        arg((b, L, heads * 64 + 256), jnp.bfloat16),
+        arg((b, L, heads), jnp.bfloat16), vec, vec, vec,
+        arg((b,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert [n.split(".")[0] for n in _custom_call_names(
+        compiled.as_text())] == ["ssm_scan"]
+
+
 def _granite_small(layer_types, vocab=512):
     """Granite 4.0-H Small's block at its published widths, 36 of its 72
     experts held (perfbench/configs/granite-4.0-h-small.json)."""
@@ -965,7 +999,8 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     since the graph holds 36 of the router's 72 experts and a prefill's
     pairs fill 40 row tiles, the path that moves the held pairs' rows alone
     (``experts_path``: ``moe_combine`` after each ``moe_grouped``), the
-    scan under ``ssm_scan``, the scratch memory under 2 GB (the whole ten
+    scan ONE ``ssm_scan`` call a state-space layer, under the scope
+    ``ssm_scan``, the scratch memory under 2 GB (the whole ten
     layers' program takes 1.22 GB: my compile, PR 43), and the slabs the
     layers carry into decode beside the logits."""
     import re
@@ -987,12 +1022,17 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     text = compiled.as_text()
     assert "ragged-dot" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
-    assert [n.split(".")[0] for n in names] == ["moe_grouped",
-                                                "moe_combine"] * 2
+    assert [n.split(".")[0] for n in names] == [
+        "ssm_scan", "moe_grouped", "moe_combine", "moe_grouped",
+        "moe_combine"]
     ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
     assert any("layer1_experts/moe_experts/" in o and "moe_combine" in o
                for o in ops)
-    assert any("layer0_ssm/ssm_scan/" in o for o in ops)
+    # the scan is the kernel, under the scope the per-layer metrics read;
+    # the bucket's decay tensor (268 MB a layer before PR 50) is gone
+    assert any("layer0_ssm/ssm_scan/" in o and o.endswith("pallas_call")
+               for o in ops)
+    assert "f32[1,8,256,256,128]" not in text
     assert any("layer1_router/moe_router/" in o for o in ops)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 

@@ -83,10 +83,15 @@ def test_server_phase_toy_width_on_cpu():
                                  positions=(3, 60), dtype="bfloat16"),),
              paged_latent=dict(lanes=3, num_pages=13, page_size=16, heads=8,
                                nope=16, rope=64, rank=128, v=16, row=256,
-                               max_pages=4, positions=(3, 60))),
+                               max_pages=4, positions=(3, 60)),
+             ssm_scan=(dict(L=512, heads=8, lengths=(200, 512)),
+                       dict(L=64, heads=8, lengths=(40,)))),
         mx.tpu(0))
     assert out["paged_kernel_gap"] <= 1e-5  # interpreted: float32 both
     assert out["latent_kernel_gap"] <= chip_smoke.PAGED_TOL
+    assert len(out["scan_kernel_gaps"]) == 2 and all(
+        y <= chip_smoke.SCAN_Y_TOL and s <= chip_smoke.SCAN_STATE_TOL
+        for y, s in out["scan_kernel_gaps"])
     assert len(out["transcripts"]) == 4
     assert out["transcripts"][0] == out["transcripts"][-1]
     assert out["logits_rel_diff"] <= chip_smoke.LOGITS_REL_TOL

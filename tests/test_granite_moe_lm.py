@@ -253,7 +253,7 @@ def test_prefill_then_decode_logits_are_the_reference(dtype):
     assert pool.num_slots > 0 and snap["state_slots"]["live"] == 0 and \
         snap["state_slots"]["peak"] == 4
     assert snap["moe_experts"] == "ragged-dense"  # the host's formulation
-    assert snap["ssm_step"] == "xla"
+    assert snap["ssm_step"] == "xla" and snap["ssm_scan"] == "xla"
     assert pool.plane_names() == [
         n for i, t in enumerate(TYPES) for n in (
             ["layer%d_k_pool" % i, "layer%d_v_pool" % i] if t == "attention"
@@ -458,6 +458,8 @@ def test_a_step_carries_state_and_expert_arguments_at_once(tmp_path):
     prefills = [dict(e.stats) for e in events if e.name == "gen:prefill"]
     assert prefills and all("state_slot" in s and "expert_pairs" in s
                             for s in prefills)
+    assert all(0 < int(s["scan_chunks_live"]) <= int(s["scan_chunks"])
+               for s in prefills)
     # which pairs the prefill's expert layers move, and what chose it
     # (ops/moe.py ``experts_path``): on the host every pair
     assert all(s["experts_path"] == "all"

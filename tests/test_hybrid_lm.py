@@ -143,6 +143,7 @@ def test_prefill_then_decode_logits_are_the_reference(dtype):
     # on the host platform the state step is the pass over the whole plane
     # (an engine without state planes has no such key: test_paged_kernel.py)
     assert snap["ssm_step"] == "xla" and snap["paged_attention"] == "xla"
+    assert snap["ssm_scan"] == "xla"  # likewise the prefill's scan
     for st in streams:
         assert st.done and st.exception() is None and len(st.tokens) == 9
         seq = st.prompt + st.tokens
@@ -416,6 +417,10 @@ def test_spans_and_counters_of_the_state(tmp_path):
         for s in steps if "lanes" in s)
     prefills = [dict(e.stats) for e in events if e.name == "gen:prefill"]
     assert sorted(str(p["state_slot"]) for p in prefills) == ["1", "2"]
+    # prompts of 5 and 9 in buckets of 8 and 16, chunks of 4: what the
+    # buckets hold, and the chunks with a token in them
+    assert sorted((int(p["scan_chunks"]), int(p["scan_chunks_live"]))
+                  for p in prefills) == [(2, 2), (4, 3)]
     for name in ("mxtpu_gen_state_slots_live", "mxtpu_gen_state_slots_peak",
                  "mxtpu_gen_state_bytes"):
         assert name in text

@@ -472,3 +472,173 @@ def test_the_op_picks_its_formulation_where_the_operands_live():
     there = str(jax.make_jaxpr(bind(step, "tpu"))(*args))
     assert "pallas_call" not in here
     assert "pallas_call" in there and "name=ssm_step" in there
+
+
+# -- the Pallas formulation of the scan, in interpret mode -------------------
+
+SCAN = functools.partial(ssm._kernel_scan, interpret=True)
+CELL = dict(head_dim=64, state=128)  # both Granite cells' head and state
+
+
+def _scan_inputs(b, L, heads, dtype=np.float32, seed=0, head_dim=64,
+                 state=128):
+    r = np.random.RandomState(seed)
+    return dict(
+        xbc=jnp.asarray(0.5 * r.randn(b, L, heads * head_dim + 2 * state),
+                        dtype),
+        dt=jnp.asarray(r.randn(b, L, heads), np.float32),
+        vec=(np.log(r.uniform(1, 16, heads)).astype(np.float32),
+             r.randn(heads).astype(np.float32),
+             r.randn(heads).astype(np.float32)))
+
+
+def _both_scans(a, length, heads, chunk=256, **sizes):
+    sizes = dict(CELL, heads=heads, chunk=chunk, **sizes)
+    n = None if length is None else jnp.asarray(length, jnp.int32)
+    args = (a["xbc"], a["dt"], *a["vec"], n)
+    return ssm.ssm_scan(*args, **sizes), ssm.ssm_scan(*args, scan=SCAN,
+                                                      **sizes)
+
+
+# (b, L, heads, lengths): the cells' buckets at a few heads of the cells'
+# size (a block is 8 heads: 16 and 24 are two and three blocks), ragged
+# lengths on a chunk's edge, inside the first chunk, equal to L, and one
+# bucket that is no multiple of the chunk (padded inside the scan)
+@pytest.mark.parametrize("b,L,heads,lengths", [
+    (1, 64, 8, None), (1, 128, 8, [77]), (1, 256, 16, [256]),
+    (2, 512, 8, [256, 300]), (2, 512, 24, [3, 512]),
+    (1, 2048, 8, [1300]), (2, 640, 8, [640, 257]), (1, 300, 8, [290]),
+])
+def test_kernel_scan_is_the_chunked_scan(b, L, heads, lengths):
+    """``y`` on the live rows and the final state equal the XLA
+    formulation's to float32 rounding (the same sums in another order; the
+    products of three bfloat16 pieces are ``highest``'s own); the rows past
+    a prompt's length are finite (zeros past its last live chunk)."""
+    a = _scan_inputs(b, L, heads, seed=L + heads)
+    (want_y, want_S), (y, S) = _both_scans(a, lengths, heads)
+    assert y.shape == want_y.shape and y.dtype == want_y.dtype
+    assert np.isfinite(np.asarray(y)).all()
+    for i in range(b):
+        n = L if lengths is None else lengths[i]
+        np.testing.assert_allclose(np.asarray(y[i, :n]),
+                                   np.asarray(want_y[i, :n]), rtol=2e-5,
+                                   atol=2e-4)
+        last = -(-n // 256) * 256
+        assert not np.asarray(y[i, last:]).any()
+    np.testing.assert_allclose(np.asarray(S), np.asarray(want_S), rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("heads", [64, 128])
+def test_kernel_scan_at_the_cells_heads(heads):
+    """Every head of Granite-micro (64) and Granite-small (128), bfloat16 as
+    the convolution writes it, two chunks with the second half live: the
+    state to float32 rounding, ``y`` to the one bfloat16 rounding both forms
+    end with."""
+    a = _scan_inputs(1, 512, heads, jnp.bfloat16, seed=heads)
+    (want_y, want_S), (y, S) = _both_scans(a, [384], heads)
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(S), np.asarray(want_S), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y[0, :384], np.float32),
+                               np.asarray(want_y[0, :384], np.float32),
+                               rtol=1e-2, atol=1e-2)
+    assert np.isfinite(np.asarray(y, np.float32)).all()
+
+
+def test_kernel_scan_keeps_a_stale_nan_out_of_a_live_prompt():
+    """A padded row that holds NaN past a prompt's last live chunk reaches
+    nothing: the chunk is neither fetched nor multiplied."""
+    a = _scan_inputs(1, 512, 8, seed=3)
+    a["xbc"] = a["xbc"].at[:, 256:].set(jnp.nan)
+    (_, _), (y, S) = _both_scans(a, [200], 8)
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(S)).all()
+
+
+def test_kernel_scan_is_the_token_by_token_recurrence():
+    """Against the plainest form, not only its sibling: two heads of 64,
+    chunks of 16 (whole sublane tiles), a ragged pair."""
+    sizes = dict(heads=2, head_dim=64, state=128)
+    a = _scan_inputs(2, 48, 2, seed=5)
+    n = [48, 21]
+    y, S = ssm.ssm_scan(a["xbc"], a["dt"], *a["vec"],
+                        jnp.asarray(n, jnp.int32), chunk=16, scan=SCAN,
+                        **sizes)
+    for i in range(2):
+        want_y, want_S = _recurrence(
+            np.asarray(a["xbc"][i, :n[i]]), np.asarray(a["dt"][i, :n[i]]),
+            *a["vec"], H=2, P=64, N=128)
+        np.testing.assert_allclose(np.asarray(y[i, :n[i]]), want_y,
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(S[i]), want_S, rtol=1e-4,
+                                   atol=1e-4)
+
+
+def test_kernel_steps_continued_from_a_kernel_scan_are_the_whole_recurrence():
+    """As ``test_kernel_steps_continued_from_a_scan...``, the prefill through
+    the scan's kernel: 12 tokens right-padded to a bucket of 32 in chunks of
+    16, then 20 steps through the step's kernel."""
+    sizes = dict(heads=2, head_dim=64, state=128)
+    H2, P2, N2, L, split = 2, 64, 128, 32, 12
+    a = _scan_inputs(1, L, H2, seed=11)
+    xbc, dt, vec = np.asarray(a["xbc"]), np.asarray(a["dt"]), a["vec"]
+    want_y, want_S = _recurrence(xbc[0], dt[0], *vec, H=H2, P=P2, N=N2)
+    y, S = ssm.ssm_scan(a["xbc"], a["dt"], *vec, jnp.array([split]),
+                        chunk=16, scan=SCAN, **sizes)
+    np.testing.assert_allclose(np.asarray(y[0, :split]), want_y[:split],
+                               rtol=1e-4, atol=1e-4)
+    states = jnp.zeros((4, H2, P2, N2)).at[3].set(S[0])
+    slot = jnp.array([0, 3], jnp.int32)
+    for t in range(split, L):
+        row = jnp.stack([jnp.ones(xbc.shape[-1]), xbc[0, t]])
+        y, states = ssm.ssm_step(row, jnp.stack([jnp.ones(H2), dt[0, t]]),
+                                 *vec, states, slot, step=KERNEL, **sizes)
+        np.testing.assert_allclose(np.asarray(y[1]), want_y[t], rtol=1e-4,
+                                   atol=1e-4)
+    np.testing.assert_allclose(np.asarray(states[3]), want_S, rtol=1e-4,
+                               atol=1e-4)
+
+
+@pytest.mark.parametrize("platform,L,heads,head_dim,state,dtype,train,want", [
+    ("tpu", 2048, 128, 64, 128, jnp.bfloat16, False, "pallas"),  # g4hsmall
+    ("tpu", 512, 64, 64, 128, jnp.bfloat16, False, "pallas"),    # g4hmicro
+    ("tpu", 64, 64, 64, 128, jnp.bfloat16, False, "pallas"),     # one chunk
+    ("tpu", 2432, 128, 64, 128, jnp.bfloat16, False, "pallas"),  # padded
+    ("tpu", 512, 64, 64, 128, np.float32, False, "pallas"),
+    ("tpu", 512, 8, 128, 128, jnp.bfloat16, False, "pallas"),
+    ("tpu", 2048, 128, 64, 128, jnp.bfloat16, True, "xla"),   # a gradient
+    ("cpu", 2048, 128, 64, 128, jnp.bfloat16, False, "xla"),
+    ("gpu", 2048, 128, 64, 128, jnp.bfloat16, False, "xla"),
+    ("tpu", 512, 64, 64, 128, np.float16, False, "xla"),
+    ("tpu", 512, 64, 64, 64, jnp.bfloat16, False, "xla"),     # half a tile
+    ("tpu", 512, 3, 4, 128, jnp.bfloat16, False, "xla"),      # toy heads
+    ("tpu", 512, 7, 64, 128, jnp.bfloat16, False, "xla"),     # no block
+    ("tpu", 12, 64, 64, 128, jnp.bfloat16, False, "xla"),     # a ragged chunk
+])
+def test_scan_formulation_is_read_off_the_operands(platform, L, heads,
+                                                   head_dim, state, dtype,
+                                                   train, want):
+    assert ssm.scan_formulation(platform, L, heads, head_dim, state, dtype,
+                                train) == want
+
+
+def test_the_scan_op_picks_its_formulation_where_the_operands_live():
+    """``_contrib_SSMScan`` traced for a TPU lowers the kernel, under the
+    scope the XLA formulation carries; on this CPU, and for a graph that is
+    being differentiated, it lowers none."""
+    from mxnet_tpu.ops.interpret import bind
+    from mxnet_tpu.ops.registry import OpContext
+
+    sizes = dict(heads=8, head_dim=64, state=128)
+    a = _scan_inputs(1, 256, 8)
+
+    def scan(opctx):
+        return lambda xbc, dt: ssm._ssm_scan(opctx, sizes, xbc, dt, *a["vec"])
+
+    here = str(jax.make_jaxpr(scan(None))(a["xbc"], a["dt"]))
+    there = str(jax.make_jaxpr(bind(scan(OpContext(False)), "tpu"))(
+        a["xbc"], a["dt"]))
+    train = str(jax.make_jaxpr(bind(scan(OpContext(True)), "tpu"))(
+        a["xbc"], a["dt"]))
+    assert "pallas_call" not in here and "pallas_call" not in train
+    assert "pallas_call" in there and "name=ssm_scan" in there
