@@ -65,8 +65,9 @@ per-prompt inputs, ``data`` and for some ``length``), the decode graph
 name, kind ``paged`` or ``slot``, entry shape, dtype), which one
 :class:`~.kv_pool.PagedKVPool` owns, and the keys it adds to :meth:`spec`
 (``engine_spec()``).  A family with slot planes (recurrent
-state, convolution tails) gets a ``state_slot`` vector beside
-``page_table``.  A family may name small outputs its lane program returns
+state, convolution tails, a sliding-window layer's ring) gets a
+``state_slot`` vector beside ``page_table``; one with rings says what a lane
+holds of them (``ring_bytes()``: ``gen:step``'s ``window_bytes``).  A family may name small outputs its lane program returns
 after the picked ids (``lane_extras``; ``expert_load``, the live lanes'
 picks by expert layer and expert, is the one the engine knows what to do
 with): they are read with the ids, one iteration late.  What a family
@@ -109,7 +110,7 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                QueueFullError, ServerClosedError,
                                pow2_buckets)
 from ..ops.moe import experts_formulation, experts_path
-from ..ops.paged import (LATENT_PREFILL, decode_formulation,
+from ..ops.paged import (LATENT_PREFILL, WINDOW_STEP, decode_formulation,
                          latent_formulation)
 from ..ops.ssm import scan_formulation, step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
@@ -377,6 +378,9 @@ class _GenMetrics:
         # dispatched had to read (live tokens x a token's bytes over the
         # latent planes)
         self.g_latent_bytes = reg.gauge("mxtpu_gen_latent_bytes")
+        # a family with sliding-window layers: the rings the last step
+        # dispatched had to fetch (live lanes x a lane's rings)
+        self.g_window_bytes = reg.gauge("mxtpu_gen_window_bytes")
         _telemetry.register_collector(self)
 
     def render_prometheus(self):
@@ -490,8 +494,9 @@ class DecodeEngine:
                 self.max_seq_len, self.page_size) is None:
             raise MXNetError(
                 "the %s family has no windowed (catch-up / verify) graph: "
-                "%s needs one. A recurrent state cannot be rewound past a "
-                "rejected token, nor rebuilt from cached pages"
+                "%s needs one. A recurrent state (or a sliding window's "
+                "ring) cannot be rewound past a rejected token, nor rebuilt "
+                "from cached pages"
                 % (family.name, "draft=" if draft
                    else "prefix_cache_pages=%d" % self.prefix_cache_pages))
 
@@ -622,6 +627,9 @@ class DecodeEngine:
             getattr(family, "latent_token_bytes", lambda: 0)())
         self._latent_rows = _latent_rows(
             self._decode[self.max_lanes]._symbol, self._params, self.pool)
+        # bytes of a lane's rings over the family's sliding-window layers
+        # (0: none): slots that a step reads whole and writes one row of
+        self._ring_bytes = int(getattr(family, "ring_bytes", lambda: 0)())
 
         # -- speculative rig: draft pool + prefill + decode, target verify
         self._draft_pool: Optional[PagedKVPool] = None
@@ -1004,6 +1012,9 @@ class DecodeEngine:
                     "decode": latent_formulation(
                         self._device.platform, self.num_heads,
                         *self._latent_rows, page_size=self.pool.page_size)}
+            if self._ring_bytes:
+                # and for the sliding-window layers' lane form
+                snap["window_attention"] = WINDOW_STEP
             if self._lane_extras:
                 # likewise for the routed experts' grouped products
                 # (ops/moe.py), and what the lanes picked so far
@@ -1549,10 +1560,16 @@ class DecodeEngine:
                 table[i] = pool.page_table_row(seq.sid, self.max_pages)
                 if slots is not None:
                     slots[i] = pool.state_slot(seq.sid)
-        if slots is not None:
+        if slots is not None and pool.slot_bytes > self._ring_bytes:
             # the recurrent state of the step's lanes: read once, written
             # once
-            more["state_bytes"] = len(lanes) * pool.slot_bytes
+            more["state_bytes"] = len(lanes) * (pool.slot_bytes
+                                                - self._ring_bytes)
+        if self._ring_bytes:
+            # the rings of the step's lanes: fetched whole whatever the
+            # lane's length, one row of each written
+            more["window_bytes"] = len(lanes) * self._ring_bytes
+            self.metrics.g_window_bytes.set(more["window_bytes"])
         if self._latent_token_bytes:
             # the latent rows of the lanes' tokens up to this step's: what
             # the absorbed attention reads

@@ -24,7 +24,8 @@ refcounts, prefix index.
 A pool may carry a second kind of plane beside the paged ones: *slot*
 planes, ``(num_slots,) + shape`` each, that hold one fixed-size state a
 sequence and layer (a state-space layer's recurrent state and convolution
-tail, models/hybrid_lm.py) where a paged plane holds K/V a token.  One
+tail, or a sliding-window layer's ring of its last ``window`` tokens' K and
+V, models/hybrid_lm.py) where a paged plane holds K/V a token.  One
 manager owns both: a sequence takes its slot with its first pages
 (:meth:`alloc_prefix`) and gives it back with them (:meth:`free`), so the
 rule that keeps a page from a new owner until the last step that names it
@@ -197,6 +198,13 @@ class PagedKVPool:
                 for s in self.specs]
             span.set(bytes=self.device_bytes(), pages=self.num_pages,
                      slots=self.num_slots)
+            # a sliding-window layer's rings (``*_ring``: the last
+            # ``window`` tokens a lane, models/hybrid_lm.py), every slot's
+            rings = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                        for s in self.specs
+                        if s.kind == "slot" and s.name.endswith("_ring"))
+            if rings:
+                span.set(ring_bytes=self.num_slots * rings)
         self._paged = [p for p, s in zip(self._planes, self.specs)
                        if s.kind == "paged"]
         # the K/V pairs among them, by the names this pool gives its own

@@ -84,6 +84,27 @@ always did, byte for byte: tests/test_hybrid_lm.py pins them):
 * ``tied_head=False``: the logits go through ``lm_head_weight`` (vocab,
   hidden), not the embedding.
 
+* mixer ``window``, sliding-window attention (ops/paged.py; poolside
+  Laguna, ``laguna``): token ``t`` attends to ``t - window < u <= t``, over
+  ``window_heads`` query heads (None: ``num_heads``; the K/V heads are the
+  ``attention`` kind's) rotated by plain angles of ``window_rotary_theta``
+  over all of a head (None: no positions).  It carries a RING a lane and no
+  pages: two slot planes ``layer<i>_k_ring`` / ``layer<i>_v_ring`` ``(window,
+  kv_heads * head_dim)`` in the weights' dtype, token ``t`` at ``t %
+  window``, taken and given back with the lane's first pages as a recurrent
+  state is; the prefill graph returns each prompt's rings as they stand
+  after its last real token;
+* the ``attention`` kind's rotation may be partial (``rotary_dim``: the
+  first so many features of a head, the rest pass) and scaled
+  (``rotary_scaling``: YaRN's table and the factor on cosine and sine,
+  ``ops.moe.rotary_table``; keys ``factor``, ``original_max``,
+  ``beta_fast``, ``beta_slow``, ``attention_factor``);
+* ``attn_gate``: ONE gate a query head, ``o_i <- sigmoid(W_g h)_i o_i``
+  before ``W_o`` (``W_g`` ``layer<i>_gate_weight`` (heads, hidden), ``h`` the
+  layer's normed input), in the ``attention`` and ``window`` kinds: the
+  nodes ``layer<i>_attn_gate`` (a ``FullyConnected``), ``_attn_gate_sigmoid``
+  and ``_attn_gate_mul`` (a broadcast multiply).
+
 A description whose layers carry no slot plane (all ``attention`` or
 ``latent``) has no ``state_slot``: its lane graph knows a padded lane by its
 page table, whose first page is the scratch page 0.
@@ -98,6 +119,7 @@ from .. import symbol as sym
 from ..ops.moe import SCORES as _ROUTER_SCORES
 
 MAMBA, ATTENTION, CONV, LATENT = "mamba", "attention", "conv", "latent"
+WINDOW = "window"
 # the kinds whose layers page what they cache (the rest hold a slot a lane)
 _PAGED = (ATTENTION, LATENT)
 
@@ -106,12 +128,14 @@ class HybridLM:
     """The model's description, and the family object the generation
     engine asks (generation/engine.py, "The family seam").
 
-    ``layer_types`` is the pattern (``"mamba"``, ``"conv"``, ``"attention"``
-    or ``"latent"`` a layer; for ``latent`` ``head_dim`` is ``nope_dim +
+    ``layer_types`` is the pattern (``"mamba"``, ``"conv"``, ``"attention"``,
+    ``"window"`` or ``"latent"`` a layer; for ``latent`` ``head_dim`` is ``nope_dim +
     rope_dim``, ``kv_heads`` is ``num_heads``, and ``q_rank`` / ``kv_rank`` /
     ``nope_dim`` / ``rope_dim`` / ``v_dim`` are needed); ``num_heads`` / ``kv_heads`` / ``head_dim``
     the attention layers', ``rotary_theta`` (None: no positions) and
-    ``qk_norm`` theirs too; ``intermediate`` the dense gated MLP's inner
+    ``qk_norm`` theirs too, as ``rotary_dim`` / ``rotary_scaling`` and
+    ``attn_gate`` (module docstring); ``window`` / ``window_heads`` /
+    ``window_rotary_theta`` the sliding-window layers'; ``intermediate`` the dense gated MLP's inner
     width; ``ssm_heads`` / ``ssm_head_dim`` / ``ssm_state`` / ``chunk`` the
     state-space layers' (one B/C group; needed only where there is one);
     ``conv_kernel`` the convolutions' width, Mamba's and the short one's;
@@ -138,7 +162,9 @@ class HybridLM:
                    norm_topk=True, routed_scaling=1.0, router_bias=True,
                    shared_expert_width=0, sandwich_norm=False,
                    tied_head=True, router_score="sigmoid", q_rank=None,
-                   kv_rank=None, nope_dim=None, rope_dim=None, v_dim=None)
+                   kv_rank=None, nope_dim=None, rope_dim=None, v_dim=None,
+                   window=0, window_heads=None, window_rotary_theta=None,
+                   rotary_dim=0, rotary_scaling=None, attn_gate=False)
     _REQUIRED = ("vocab_size", "hidden", "layer_types", "num_heads",
                  "kv_heads", "head_dim", "intermediate")
     _SSM = ("ssm_heads", "ssm_head_dim", "ssm_state")
@@ -158,12 +184,31 @@ class HybridLM:
         for k, default in self._FIELDS.items():
             setattr(self, k, sizes.get(k, default))
         self.layer_types = tuple(self.layer_types)
-        bad = set(self.layer_types) - {MAMBA, ATTENTION, CONV, LATENT}
+        bad = set(self.layer_types) - {MAMBA, ATTENTION, CONV, LATENT,
+                                       WINDOW}
         if bad or not set(self.layer_types) & set(_PAGED):
-            raise ValueError("layer_types: every entry %r, %r, %r or %r, at "
-                             "least one attention or latent layer (the "
+            raise ValueError("layer_types: every entry %r, %r, %r, %r or %r, "
+                             "at least one attention or latent layer (the "
                              "engine's pages); got %s"
-                             % (MAMBA, CONV, ATTENTION, LATENT, sorted(bad)))
+                             % (MAMBA, CONV, ATTENTION, WINDOW, LATENT,
+                                sorted(bad)))
+        if WINDOW in self.layer_types:
+            if self.window_heads is None:
+                self.window_heads = self.num_heads
+            if self.window <= 0 or self.window_heads % self.kv_heads:
+                raise ValueError("window: a window of %r tokens, %r query "
+                                 "heads over %d K/V heads"
+                                 % (self.window, self.window_heads,
+                                    self.kv_heads))
+        if self.rotary_scaling is not None:
+            self.rotary_scaling = dict(self.rotary_scaling)
+            want = {"factor", "original_max", "beta_fast", "beta_slow",
+                    "attention_factor"}
+            if set(self.rotary_scaling) != want or self.rotary_theta is None:
+                raise ValueError("rotary_scaling: keys %s beside a "
+                                 "rotary_theta; got %s"
+                                 % (sorted(want),
+                                    sorted(self.rotary_scaling)))
         if LATENT in self.layer_types and (
                 self.head_dim != self.nope_dim + self.rope_dim
                 or self.kv_heads != self.num_heads):
@@ -233,6 +278,11 @@ class HybridLM:
             elif kind == LATENT:
                 out += [("layer%d_latent_pool" % i, "paged",
                          (self.latent_row(),), self.dtype)]
+            elif kind == WINDOW:
+                # the last ``window`` tokens' rows, token t at t % window
+                out += [("layer%d_%s_ring" % (i, kv), "slot",
+                         (self.window, self.kv_heads * self.head_dim),
+                         self.dtype) for kv in "kv"]
             elif kind == MAMBA:
                 out += [("layer%d_ssm_state" % i, "slot",
                          (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
@@ -271,6 +321,13 @@ class HybridLM:
         return self.layer_types.count(LATENT) * (
             (self.kv_rank or 0) + (self.rope_dim or 0)) * \
             np.dtype(self.dtype).itemsize
+
+    def ring_bytes(self):
+        """Bytes of one lane's rings over the sliding-window layers (0:
+        none): what a lane step fetches of them, whatever the lane's
+        length."""
+        return self.layer_types.count(WINDOW) * 2 * self.window \
+            * self.kv_heads * self.head_dim * np.dtype(self.dtype).itemsize
 
     def prefill_symbol(self, seq_len, max_seq_len=None):
         return get_hybrid_lm_prefill(self, seq_len)
@@ -365,17 +422,28 @@ def _conv_mixer(h, m, name, seq_len, carried):
     return _fc(y, m.hidden, name + "_out_proj"), [tail]
 
 
-def _attention_mixer(h, m, name, seq_len, attend, positions=None):
+def _attention_mixer(h, m, name, seq_len, attend, positions=None,
+                     kind=ATTENTION):
     """``attend(q, k, v, name) -> (att, extras)`` over ``(..., heads,
     head_dim)`` / ``(..., kv_heads, head_dim)``; ``positions`` the rows'
-    (the sequence axis' or the lanes'), read where the model rotates."""
+    (the sequence axis' or the lanes'), read where the model rotates.
+    ``kind``: ``attention``, or ``window`` with its own head count and
+    rotation."""
     lead = (-1,) if seq_len is None else (-1, seq_len)
     hd = m.head_dim
+    num_heads = m.window_heads if kind == WINDOW else m.num_heads
+    theta = m.window_rotary_theta if kind == WINDOW else m.rotary_theta
+    # only what a description turns on is written into the graph
+    turn = {}
+    if kind == ATTENTION and m.rotary_dim:
+        turn["rotary_dim"] = m.rotary_dim
+    if kind == ATTENTION and m.rotary_scaling:
+        turn.update(m.rotary_scaling)
 
     def heads(x, n):
         return sym.Reshape(x, shape=lead + (n, hd))
 
-    q = heads(_fc(h, m.num_heads * hd, name + "_q"), m.num_heads)
+    q = heads(_fc(h, num_heads * hd, name + "_q"), num_heads)
     k = heads(_fc(h, m.kv_heads * hd, name + "_k"), m.kv_heads)
     v = heads(_fc(h, m.kv_heads * hd, name + "_v"), m.kv_heads)
     if m.qk_norm:
@@ -383,12 +451,24 @@ def _attention_mixer(h, m, name, seq_len, attend, positions=None):
                                              hd),
                                      eps=m.eps, name="%s_%s_norm" % (name, w))
                 for x, w in ((q, "q"), (k, "k")))
-    if m.rotary_theta is not None:
-        q, k = (sym._contrib_Rotary(x, positions, theta=m.rotary_theta,
-                                    name="%s_%s_rotary" % (name, w))
+    if theta is not None:
+        q, k = (sym._contrib_Rotary(x, positions, theta=theta,
+                                    name="%s_%s_rotary" % (name, w), **turn)
                 for x, w in ((q, "q"), (k, "k")))
     att, extras = attend(q, k, v, name + "_attn")
-    att = sym.Reshape(att, shape=(-1, m.num_heads * hd))
+    if m.attn_gate:
+        gate = sym.Activation(
+            sym.FullyConnected(
+                h, sym.Variable(name + "_gate_weight",
+                                shape=(num_heads, m.hidden)),
+                num_hidden=num_heads, no_bias=True,
+                name=name + "_attn_gate"),
+            act_type="sigmoid", name=name + "_attn_gate_sigmoid")
+        att = sym.broadcast_mul(
+            sym.Reshape(att, shape=(-1, num_heads, hd)),
+            sym.Reshape(gate, shape=(-1, num_heads, 1)),
+            name=name + "_attn_gate_mul")
+    att = sym.Reshape(att, shape=(-1, num_heads * hd))
     return _fc(att, m.hidden, name + "_o"), extras
 
 
@@ -481,8 +561,9 @@ def _block(x, m, i, seq_len, attend, carried, positions=None, live=None):
     layer, its router's load (else None)."""
     name = "layer%d" % i
     h = _norm(x, m, name + "_norm1")
-    if m.layer_types[i] == ATTENTION:
-        h, extras = _attention_mixer(h, m, name, seq_len, attend, positions)
+    if m.layer_types[i] in (ATTENTION, WINDOW):
+        h, extras = _attention_mixer(h, m, name, seq_len, attend, positions,
+                                     m.layer_types[i])
     elif m.layer_types[i] == LATENT:
         h, extras = _latent_mixer(h, m, name, seq_len, attend, positions)
     elif m.layer_types[i] == CONV:
@@ -536,6 +617,13 @@ def _sequence_graph(m, seq_len, length):
             q, k, v, causal=True, scale=m.attention_multiplier,
             name=name), [row(k, "k"), row(v, "v")]
 
+    def window(q, k, v, name):
+        more = [] if length is None else [length]
+        att, k_ring, v_ring = sym._contrib_WindowAttention(
+            q, k, v, *more, window=m.window, scale=m.attention_multiplier,
+            use_length=bool(more), name=name)
+        return att, [k_ring, v_ring]
+
     def latent(q_n, q_r, rows, w_kvb, name):
         zeros = m.latent_row() - (m.kv_rank + m.rope_dim)
         # the slab as the plane holds a token (``HybridLM.latent_row``)
@@ -549,7 +637,8 @@ def _sequence_graph(m, seq_len, length):
     x = _embed(sym.Variable("data"), m, table)
     positions = live = None
     routes = bool(m.expert_layers) and length is not None
-    if m.rotary_theta is not None or routes:
+    if m.rotary_theta is not None or m.window_rotary_theta is not None \
+            or routes:
         positions = sym._arange(start=0, stop=seq_len, name="positions")
     if routes:
         # a position past its prompt's length routes to no expert
@@ -558,7 +647,8 @@ def _sequence_graph(m, seq_len, length):
             sym.Reshape(length, shape=(-1, 1)), name="live"), shape=(-1,))
     carried = []
     for i in range(m.num_layers):
-        attend = latent if m.layer_types[i] == LATENT else dense
+        attend = {LATENT: latent, WINDOW: window}.get(m.layer_types[i],
+                                                      dense)
         x, extras, _ = _block(x, m, i, seq_len, attend, length, positions,
                               live)
         carried.extend(extras)
@@ -590,7 +680,7 @@ def get_hybrid_lm_decode(model, page_size=16):
     """One decode step, every lane one token.  Inputs ``data``,
     ``positions``, ``source``, ``prev_ids``, ``state_slot`` (lanes,),
     ``page_table`` (lanes, max_pages) (``state_slot`` only where a layer
-    carries a slot plane) and the planes of
+    carries a slot plane: a state, a convolution tail, a ring) and the planes of
     :meth:`HybridLM.planes`; outputs the logits (lanes, vocab), the planes
     in that order, then ``next_ids`` (lanes,) and :attr:`HybridLM.
     lane_extras`.  ``positions`` places the attention layers' K/V and, where
@@ -617,6 +707,15 @@ def get_hybrid_lm_decode(model, page_size=16):
                 return att, [pool]
             return attend
 
+        if m.layer_types[i] == WINDOW:
+            def attend(q, k, v, name):
+                att, k_out, v_out = sym._contrib_WindowAttentionStep(
+                    q, k, v, planes["layer%d_k_ring" % i],
+                    planes["layer%d_v_ring" % i], slot, positions,
+                    scale=m.attention_multiplier, name=name)
+                return att, [k_out, v_out]
+            return attend
+
         def attend(q, k, v, name):
             att, k_out, v_out = sym._contrib_PagedAttention(
                 q, k, v, planes["layer%d_k_pool" % i],
@@ -639,7 +738,7 @@ def get_hybrid_lm_decode(model, page_size=16):
             scalar=0, name="live")
     planes_out, loads = [], []
     for i, kind in enumerate(m.layer_types):
-        carried = {ATTENTION: None, LATENT: None,
+        carried = {ATTENTION: None, LATENT: None, WINDOW: None,
                    CONV: (planes.get("layer%d_conv_tail" % i), slot),
                    MAMBA: (planes.get("layer%d_ssm_state" % i),
                            planes.get("layer%d_conv_tail" % i), slot)}[kind]

@@ -71,9 +71,11 @@ over all pairs on either path; the rows (``H`` features each) are not:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .param import Param
@@ -675,20 +677,61 @@ def _routed_experts(opctx, attrs, data, ids, weights, w13, w2):
 # rotary positions
 # ---------------------------------------------------------------------------
 
-def rotary(x, positions, *, theta, rotary_dim=0):
+def rotary_table(theta, d, scaling=None):
+    """The ``d / 2`` frequencies of a rotation over ``d`` features and the
+    factor on its cosines and sines: ``e_m = theta^(-2m / d)`` and 1, or
+    with ``scaling`` (YaRN: ``factor``, ``original_max``, ``beta_fast``,
+    ``beta_slow``, ``attention_factor``) the frequencies that turn more than
+    ``beta_fast`` times over the ``original_max`` positions as they are,
+    those that turn fewer than ``beta_slow`` times divided by ``factor``,
+    and a ramp between::
+
+        c(b) = d ln(original_max / (2 pi b)) / (2 ln theta)
+        lo = max(floor(c(beta_fast)), 0),  hi = min(ceil(c(beta_slow)), d - 1)
+        ramp_m = clip((m - lo) / (hi - lo), 0, 1)     (hi + 0.001 if hi = lo)
+        f_m = (e_m / factor) ramp_m + e_m (1 - ramp_m)
+
+    Computed here in float64 and held in float32: a constant of the graph."""
+    half = d // 2
+    m = np.arange(half, dtype=np.float64)
+    freq = float(theta) ** (-2.0 * m / d)
+    if not scaling:
+        return freq.astype(np.float32), 1.0
+
+    def turns(b):
+        return d * math.log(scaling["original_max"] / (2 * math.pi * b)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(turns(scaling["beta_fast"])), 0)
+    hi = min(math.ceil(turns(scaling["beta_slow"])), d - 1)
+    if hi == lo:
+        hi += 0.001
+    ramp = np.clip((m - lo) / (hi - lo), 0.0, 1.0)
+    freq = freq / scaling["factor"] * ramp + freq * (1.0 - ramp)
+    return freq.astype(np.float32), float(scaling["attention_factor"])
+
+
+def rotary(x, positions, *, theta, rotary_dim=0, scaling=None):
     """``x`` (..., heads, head_dim) rotated by its position: the first
     ``rotary_dim`` features (0: all of them) in two halves, feature ``i``
     paired with ``i + rotary_dim / 2``, by the angle ``position *
-    theta^(-2i / rotary_dim)``; the rest pass.  ``positions`` is the
+    theta^(-2i / rotary_dim)`` (with ``scaling`` the table and the factor
+    on cosine and sine of :func:`rotary_table`); the rest pass.
+    ``positions`` is the
     trailing part of ``x``'s leading axes ((L,) for (b, L, heads, d),
     (lanes,) for (lanes, heads, d)).  Angles, sines and the rotation are
     float32; returned in ``x``'s dtype."""
     d = int(rotary_dim) or x.shape[-1]
     half = d // 2
-    inv = jnp.exp(jnp.arange(half, dtype=_F32) * (-2.0 / d)
-                  * jnp.log(_F32(theta)))
+    if scaling:
+        inv, factor = rotary_table(theta, d, scaling)
+    else:
+        inv, factor = jnp.exp(jnp.arange(half, dtype=_F32) * (-2.0 / d)
+                              * jnp.log(_F32(theta))), 1.0
     ang = positions.astype(_F32)[..., None, None] * inv  # (..., 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * _F32(factor), sin * _F32(factor)
     x32 = x.astype(_F32)
     a, b = x32[..., :half], x32[..., half:d]
     out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
@@ -698,12 +741,29 @@ def rotary(x, positions, *, theta, rotary_dim=0):
 
 @register("_contrib_Rotary", inputs=("data", "positions"),
           params={"theta": Param(float, 10000.0),
-                  "rotary_dim": Param(int, 0)},
+                  "rotary_dim": Param(int, 0),
+                  # YaRN (:func:`rotary_table`): all five or none (None is
+                  # not written into a graph: the graphs without stay as
+                  # they were)
+                  "factor": Param("float-or-none", None),
+                  "original_max": Param("int-or-none", None),
+                  "beta_fast": Param("float-or-none", None),
+                  "beta_slow": Param("float-or-none", None),
+                  "attention_factor": Param("float-or-none", None)},
           no_grad_inputs=("positions",), hint="rotary")
 @jax.named_scope("rotary")
 def _rotary(opctx, attrs, data, positions):
     """:func:`rotary` as an op: reads ``data`` (..., heads, head_dim) and
     ``positions`` (the sequence axis' or the lanes'; float carrier or
     int), writes ``data``'s shape and dtype."""
+    keys = ("factor", "original_max", "beta_fast", "beta_slow",
+            "attention_factor")
+    scaling = {k: attrs.get(k) for k in keys}
+    if all(v is None for v in scaling.values()):
+        scaling = None
+    elif any(v is None for v in scaling.values()):
+        raise ValueError("a scaled rotation needs every one of %s; got %s"
+                         % (keys, scaling))
     return rotary(data, positions, theta=float(attrs.get("theta", 10000.0)),
-                  rotary_dim=int(attrs.get("rotary_dim", 0)))
+                  rotary_dim=int(attrs.get("rotary_dim", 0)),
+                  scaling=scaling)

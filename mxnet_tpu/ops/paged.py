@@ -6,7 +6,10 @@ Three ops:
 * ``_contrib_DenseAttention`` — plain dense softmax attention over
   ``[b, s, h, d]`` (the ``parallel.ring.local_attention`` oracle as a
   symbol op); grouped-query when ``key`` / ``value`` hold fewer heads than
-  ``query`` (query head ``i`` reads K/V head ``i // (h / kv_heads)``).
+  ``query`` (query head ``i`` reads K/V head ``i // (h / kv_heads)``), and
+  then, over more than one block of queries, in query blocks
+  (:func:`blocked_attention`: a block's float32 scores against the keys up
+  to its last row only, so ``heads x s x s`` scores never exist at once).
   The generation prefill path uses it instead of the Pallas flash kernels
   because interpret-mode Pallas is orders of magnitude too slow on CPU,
   and prefill happens once per sequence; on TPU the flash kernels remain
@@ -72,6 +75,25 @@ the same two places in a model, under two ops of its own:
   chip lays a ``(pages, 16, 576)`` plane out with the PAGES on the lanes,
   where no page is one piece of memory.
 
+Sliding-window attention (a layer whose token ``t`` attends to ``t - window
+< u <= t``) caches a *ring* a lane and not pages: ``window`` rows of K and of
+V, token ``t`` at ``t % window``, a slot plane of the pool like a recurrent
+state (models/hybrid_lm.py, kind ``window``).  Two ops, both under the scope
+``window_attention``:
+
+* ``_contrib_WindowAttention`` — a whole sequence, banded, in query blocks
+  (:func:`blocked_attention` with the band: a block reads the keys from
+  ``window - 1`` before its first row); beside the output it returns each
+  prompt's rings ``(b, window, kv_heads * head_dim)`` as they stand after its
+  LAST REAL token, at the ring's own indices (entry ``j`` the latest token
+  ``t < length`` with ``t % window == j``; zeros where there is none).
+
+* ``_contrib_WindowAttentionStep`` — one token a lane over its slot of the
+  ring planes: this step's K/V go to ``positions % window`` and the query
+  attends to the live entries (``j <= position``: after ``window`` tokens all
+  of them; an entry that is not live reaches nothing, whatever it holds).
+  XLA gathers the live lanes' slots (:data:`WINDOW_STEP`).
+
 Page 0 of the pool is reserved as a scratch page: inactive lanes carry
 an all-zero page-table row and position 0, so their (masked-out) writes
 land harmlessly in the scratch page and never corrupt a live sequence.
@@ -115,6 +137,8 @@ def _dense_attention(opctx, attrs, query, key, value):
     group = _group(heads, kv_heads)
     if scale is None:
         scale = 1.0 / np.sqrt(hd)
+    if causal and s > _QUERY_BLOCK:
+        return blocked_attention(query, key, value, scale=scale)
     q = query.reshape(b, s, kv_heads, group, hd)
     sc = jnp.einsum("bqkgd,btkd->bkgqt", q, key).astype(jnp.float32) * scale
     if causal:
@@ -124,6 +148,43 @@ def _dense_attention(opctx, attrs, query, key, value):
     p = p / p.sum(-1, keepdims=True)
     out = jnp.einsum("bkgqt,btkd->bqkgd", p, value).astype(query.dtype)
     return out.reshape(b, s, heads, hd)
+
+
+# queries whose scores exist at once in the sequence forms: at 64 heads x 512
+# a block's float32 scores over a band of 1,023 keys are 134 MB, over 4,096
+# keys 537 MB, where 64 x 4,096 x 4,096 would be 4.3 GB
+_QUERY_BLOCK = 512
+
+
+def blocked_attention(q, k, v, *, scale, window=0):
+    """Causal grouped-query attention in query blocks: ``q`` (b, s, heads,
+    d), ``k`` / ``v`` (b, s, kv_heads, d), query head ``i`` over K/V head ``i
+    // group``.  A block of ``_QUERY_BLOCK`` queries against the keys only
+    as far as the mask lets them matter: up to the block's last row, and
+    with ``window`` (token ``t`` attends to ``t - window < u <= t``) from
+    ``window - 1`` before its first.  Scores, softmax and the products' sums
+    float32, the products' operands in the rows' dtype."""
+    import jax.numpy as jnp
+
+    b, s, heads, hd = q.shape
+    kv_heads = k.shape[2]
+    qg = q.reshape(b, s, kv_heads, _group(heads, kv_heads), hd)
+    f32 = jnp.float32
+    out = []
+    for start in range(0, s, _QUERY_BLOCK):
+        end = min(start + _QUERY_BLOCK, s)
+        first = max(0, start - window + 1) if window else 0
+        sc = jnp.einsum("bqkgd,btkd->bkgqt", qg[:, start:end],
+                        k[:, first:end], preferred_element_type=f32) * scale
+        ahead = (jnp.arange(start, end)[:, None]
+                 - jnp.arange(first, end)[None, :])
+        mask = ahead >= 0
+        if window:
+            mask = mask & (ahead < window)
+        p = _softmax(jnp.where(mask, sc, _NEG)).astype(v.dtype)
+        out.append(jnp.einsum("bkgqt,btkd->bqkgd", p, v[:, first:end],
+                              preferred_element_type=f32))
+    return jnp.concatenate(out, axis=1).astype(q.dtype).reshape(q.shape)
 
 
 def _group(heads, kv_heads):
@@ -675,6 +736,151 @@ def _paged_attention(opctx, attrs, q, k_new, v_new, k_pool, v_pool,
     return decode(q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
                   k_pool, v_pool, page_table.astype(jnp.int32),
                   positions.astype(jnp.int32), scale)
+
+
+# ---------------------------------------------------------------------------
+# sliding-window attention over a ring a lane
+# ---------------------------------------------------------------------------
+
+# what ``_contrib_WindowAttentionStep`` runs wherever the operands live: XLA
+# gathers the live lanes' ring slots, puts this step's row into the copy and
+# scatters it to the plane.  (A kernel that reads each live lane's ring once
+# where it lies is PERF.md section 7's; ``window_attn_roofline_pct_laguna``
+# records where this form stands.)
+WINDOW_STEP = "xla"
+
+
+def window_rings(k, v, length, window):
+    """Each prompt's rings after its last real token: ``k`` / ``v`` (b, s,
+    kv_heads, d), ``length`` (b,) int32 -> two (b, window, kv_heads * d):
+    entry ``j`` the latest token ``t < length`` with ``t % window == j``,
+    zeros where no token has landed."""
+    import jax.numpy as jnp
+
+    b, s = k.shape[:2]
+    j = jnp.arange(window, dtype=jnp.int32)[None, :]
+    last = length.astype(jnp.int32)[:, None] - 1
+    t = (last - j) // window * window + j     # (b, window); < 0: none yet
+    live = (t >= 0)[..., None]
+    t = jnp.clip(t, 0, s - 1)[..., None]
+
+    def ring(x):
+        rows = x.reshape(b, s, -1)
+        return jnp.where(live, jnp.take_along_axis(rows, t, axis=1), 0)
+
+    return ring(k), ring(v)
+
+
+def _window_infer(attrs, shapes):
+    q, k = shapes[0], shapes[1]
+    if q is None or k is None:
+        return shapes, [None, None, None], []
+    ring = (k[0], int(attrs["window"]), k[2] * k[3])
+    return shapes, [q, ring, ring], []
+
+
+@register("_contrib_WindowAttention",
+          inputs=lambda attrs: ["query", "key", "value"] + (
+              ["length"] if attrs.get("use_length") else []),
+          params={"window": Param(int, required=True),
+                  "scale": Param("float-or-none", None),
+                  "use_length": Param(bool, False)},
+          num_outputs=3, infer_shape=_window_infer,
+          no_grad_inputs=("length",),
+          output_names=lambda attrs: ["out", "k_ring", "v_ring"],
+          hint="windowattention")
+@jax.named_scope("window_attention")
+def _window_attention(opctx, attrs, q, k, v, length=None):
+    """A whole sequence under the band ``t - window < u <= t``
+    (:func:`blocked_attention`), and the rings it leaves
+    (:func:`window_rings`; ``length`` (b,) the prompts' true lengths, the
+    whole sequence without it).
+
+    Shapes: q (b, s, heads, d); k, v (b, s, kv_heads, d); returns (out as q,
+    k_ring, v_ring (b, window, kv_heads * d))."""
+    import jax.numpy as jnp
+
+    window = int(attrs["window"])
+    scale = attrs.get("scale")
+    scale = (1.0 / np.sqrt(q.shape[-1])) if scale is None else float(scale)
+    if length is None:
+        length = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
+    return (blocked_attention(q, k, v, scale=scale, window=window),
+            *window_rings(k, v, length, window))
+
+
+def window_step(q, k_new, v_new, k_ring, v_ring, slot, pos, scale):
+    """The lane form: ``q`` (lanes, heads, d), ``k_new`` / ``v_new`` (lanes,
+    kv_heads, d) in the rings' dtype, ``k_ring`` / ``v_ring`` (num_slots,
+    window, kv_heads * d), ``slot`` / ``pos`` (lanes,) int32.  Gathers the
+    lanes' slots, puts this step's rows into the copy at ``pos % window``,
+    attends to the live entries (``j <= pos``), and writes ``lanes`` rows to
+    the planes.  Returns (lanes, heads, d) and the planes."""
+    import jax.numpy as jnp
+
+    lanes, heads, hd = q.shape
+    kv_heads = k_new.shape[1]
+    window = k_ring.shape[1]
+    group = _group(heads, kv_heads)
+    f32 = jnp.float32
+    at = pos % window
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    k_row, v_row = k_new.reshape(lanes, -1), v_new.reshape(lanes, -1)
+    live = jnp.arange(window, dtype=jnp.int32)[None, :] <= pos[:, None]
+    keys = k_ring[slot].at[lane, at].set(k_row)      # (lanes, window, row)
+    # a dead entry may hold anything: zero times it must still be zero
+    vals = jnp.where(live[..., None],
+                     v_ring[slot].at[lane, at].set(v_row), 0)
+    s = jnp.einsum("lkgd,ltkd->lkgt", q.reshape(lanes, kv_heads, group, hd),
+                   keys.reshape(lanes, window, kv_heads, hd),
+                   preferred_element_type=f32) * scale
+    p = _softmax(jnp.where(live[:, None, None, :], s, _NEG)).astype(
+        vals.dtype)
+    out = jnp.einsum("lkgt,ltkd->lkgd", p,
+                     vals.reshape(lanes, window, kv_heads, hd),
+                     preferred_element_type=f32)
+    # padded lanes all land on the scratch slot 0
+    return (out.reshape(q.shape).astype(q.dtype),
+            k_ring.at[slot, at].set(k_row), v_ring.at[slot, at].set(v_row))
+
+
+def _window_step_infer(attrs, shapes):
+    q, k_ring, v_ring = shapes[0], shapes[3], shapes[4]
+    if q is None or k_ring is None:
+        return shapes, [None, None, None], []
+    return shapes, [q, k_ring, v_ring], []
+
+
+@register("_contrib_WindowAttentionStep",
+          inputs=("query", "key", "value", "k_ring", "v_ring", "state_slot",
+                  "positions"),
+          params={"scale": Param("float-or-none", None)},
+          num_outputs=3, infer_shape=_window_step_infer,
+          no_grad_inputs=("state_slot", "positions"),
+          output_names=lambda attrs: ["out", "k_ring_out", "v_ring_out"],
+          hint="windowattentionstep")
+@jax.named_scope("window_attention")
+def _window_attention_step(opctx, attrs, q, k_new, v_new, k_ring, v_ring,
+                           state_slot, positions):
+    """One decode step of a sliding-window layer for ``lanes`` sequences
+    (:func:`window_step`): q (lanes, heads, d), k_new / v_new (lanes,
+    kv_heads, d), the ring planes (num_slots, window, kv_heads * d), a lane's
+    slot and its token's absolute position (float carriers, cast to int32).
+    The engine carries the planes through the step donated, so the write of
+    ``lanes`` rows updates them in place."""
+    import jax.numpy as jnp
+
+    hd = q.shape[-1]
+    if k_ring.shape[2] != k_new.shape[-2] * hd or k_ring.shape != v_ring.shape:
+        raise ValueError("this step's K holds %d heads of %d, the rings rows "
+                         "of %s and %s" % (k_new.shape[-2], hd,
+                                           k_ring.shape[2:], v_ring.shape[2:]))
+    scale = attrs.get("scale")
+    scale = (1.0 / np.sqrt(hd)) if scale is None else float(scale)
+    return window_step(q, k_new.astype(k_ring.dtype),
+                       v_new.astype(v_ring.dtype), k_ring, v_ring,
+                       state_slot.astype(jnp.int32),
+                       positions.astype(jnp.int32), scale)
 
 
 # ---------------------------------------------------------------------------

@@ -1037,6 +1037,155 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+# the sliding-window cell's pool (perfbench: lagunaxs2-decode-closed16)
+LAGUNA_POOL = dict(lanes=16, pages=5121, max_pages=320, slots=17)
+
+
+def _laguna(vocab=512):
+    """Laguna-XS.2's block at its published widths, ONE period of its
+    pattern (a full layer of 48 query heads, YaRN on half a head, then three
+    sliding layers of 64 over a window of 512, plain angles; 8 K/V heads of
+    128 in all; a gate a head; layer 0 dense, then 32 of 256 experts of 512
+    held beside a shared expert; an untied head)."""
+    from mxnet_tpu.models import HybridLM
+
+    return HybridLM(
+        vocab_size=vocab, hidden=2048,
+        layer_types=["attention", "window", "window", "window"],
+        num_heads=48, window_heads=64, kv_heads=8, head_dim=128, window=512,
+        rotary_theta=500000.0, rotary_dim=64,
+        rotary_scaling=dict(factor=64.0, original_max=4096, beta_fast=64.0,
+                            beta_slow=1.0,
+                            attention_factor=1.4158883083359672),
+        window_rotary_theta=10000.0, attn_gate=True, intermediate=8192,
+        eps=1e-6, num_experts=256, experts_per_token=8, expert_width=512,
+        num_dense_layers=1, experts_held=32, routed_scaling=2.5,
+        router_bias=False, shared_expert_width=512, tied_head=False)
+
+
+def test_laguna_lane_program_holds_rings_beside_pages(one_chip, monkeypatch):
+    """The sliding-window family's decode step at Laguna-XS.2's widths, one
+    period, as the engine's Executor builds it, planes carried and donated,
+    compiled for the chip at the cell's own pool (16 lanes, 320 pages a
+    lane, 17 ring slots).  The full layer's attention is ONE ``paged_decode``
+    call at 48 query heads over rows of 8 x 128 bfloat16 lanes, under
+    ``paged_attention``; the three sliding layers run under
+    ``window_attention`` over ring planes ``(17, 512, 1024)`` that are
+    aliased in and out of the module beside the two paged planes: no ring
+    plane is copied whole (the step gathers 16 slots and scatters 16 rows);
+    the gates under their nodes' names; the routed products
+    ``moe_grouped``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    lanes, pages, max_pages, slots = (LAGUNA_POOL[k] for k in (
+        "lanes", "pages", "max_pages", "slots"))
+    model, vocab = _laguna(), 512
+    symbol = model.decode_symbol(max_pages * 16, 16)
+    shapes = {name: (lanes,) for name in ("data", "positions", "source",
+                                          "prev_ids", "state_slot")}
+    shapes["page_table"] = (lanes, max_pages)
+    types, planes = {}, []
+    for name, kind, shape, dtype in model.planes():
+        shapes[name] = ((pages, 16) if kind == "paged" else (slots,)) + shape
+        types[name] = jnp.dtype(dtype)
+        planes.append(name)
+    assert [shapes[n] for n in planes] == [(pages, 16, 1024)] * 2 \
+        + [(slots, 512, 1024)] * 6
+    compiled = _compile_for_the_chip(symbol, shapes, types, planes, one_chip)
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(outs) == 3 + len(planes)
+    assert outs[0].shape == (lanes, vocab) and outs[-1].shape == (3, 256)
+    text = compiled.as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"[^\n]*"
+                       r"op_name=\"([^\"]*)\"", text)
+    assert sorted(n.split(".")[0] for n, _ in calls) == \
+        ["moe_grouped"] * 3 + ["paged_decode"]
+    assert all(re.search(r"layer0_attn/paged_attention/", scope)
+               for n, scope in calls if n.startswith("paged_decode")), calls
+    ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
+    for i in (1, 2, 3):
+        assert any(("layer%d_attn/window_attention/" % i) in o for o in ops)
+    for i in range(4):
+        assert any(("layer%d_attn_gate" % i) in o for o in ops), i
+    # no ring plane is copied whole, and every plane is aliased in and out
+    ring = r"bf16\[%d,512,1024\]" % slots
+    moved = [line for line in text.splitlines()
+             if re.search(ring, line.split(" = ")[-1].split("(")[0])
+             and re.search(r" (copy|copy-start)\(", line)]
+    assert not moved, moved[:2]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert len(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases)) == len(planes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+def test_laguna_prefill_attends_in_blocks_and_returns_rings(one_chip,
+                                                            monkeypatch):
+    """The same period's prefill of the cell's longest bucket (one prompt of
+    4,096) at the cell's slice of the vocabulary, compiled for the chip: the
+    full layer's K and V slabs as the planes hold a token, each sliding
+    layer's rings ``(1, 512, 1024)``, and a scratch memory under 2 GB (the
+    attention in blocks of 512 queries: 48 x 4,096 x 4,096 float32 scores
+    alone would be 3.2 GB)."""
+    import jax
+
+    from mxnet_tpu import compile_cache
+
+    monkeypatch.setattr(compile_cache, "active", lambda: False)
+    L, vocab = 4096, 12544
+    compiled = _compile_for_the_chip(
+        _laguna(vocab).prefill_symbol(L, 5120),
+        {"data": (1, L), "length": (1,)}, {}, [], one_chip)
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.shape for o in outs] == [(1, L, vocab)] \
+        + [(1, L, 1024)] * 2 + [(1, 512, 1024)] * 6
+    text = compiled.as_text()
+    assert "f32[1,8,6,4096,4096]" not in text
+    assert "f32[1,8,8,4096,4096]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_paged_decode_takes_48_heads_over_rows_of_8_by_128(one_chip):
+    """The kernel alone at the sliding-window cell's full layers: 16 lanes
+    of 48 query heads, six to a K/V head, over bfloat16 rows of 8 x 128
+    lanes, 320 pages a lane (``decode_formulation`` says ``pallas`` for
+    it)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import paged
+
+    lanes, pages, max_pages = (LAGUNA_POOL[k] for k in ("lanes", "pages",
+                                                        "max_pages"))
+    assert paged.decode_formulation("tpu", 48, 128, jnp.bfloat16, kv_heads=8,
+                                    rows=True, page_size=16) == "pallas"
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, k_new, v_new, k_pool, v_pool, table, at):
+        return paged._kernel_decode(q, k_new, v_new, k_pool, v_pool, table,
+                                    at, 128 ** -0.5)
+
+    new, plane = arg((lanes, 8, 128)), arg((pages, 16, 1024))
+    text = jax.jit(step, donate_argnums=(3, 4)).lower(
+        arg((lanes, 48, 128)), new, new, plane, plane,
+        arg((lanes, max_pages), jnp.int32), arg((lanes,), jnp.int32)
+    ).compile().as_text()
+    names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
+    assert len(names) == 1 and names[0].startswith("paged_decode"), names
+    assert not [line for line in text.splitlines()
+                if "bf16[5121,16,1024]" in line
+                and re.search(r" copy(-start)?\(", line)]
+
+
 @pytest.mark.parametrize("chips", [4, 1])
 def test_data_parallel_step_reduces_under_its_compute(topo, monkeypatch,
                                                        chips):

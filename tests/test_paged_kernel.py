@@ -651,3 +651,147 @@ def test_zeros_beside_a_latent_rows_values_change_no_token(monkeypatch):
                         lambda self: self.kv_rank + self.rope_dim)
     narrow_widths, narrow_tokens, _ = transcript()
     assert narrow_widths == {192} and narrow_tokens == tokens
+
+
+# ---------------------------------------------------------------------------
+# sliding-window attention: the sequence form and the lane form over a ring
+# ---------------------------------------------------------------------------
+
+def _banded_softmax(q, k, v, scale, window):
+    """The oracle: a dense masked softmax over the whole square, float64."""
+    b, s, heads, hd = q.shape
+    group = heads // k.shape[2]
+    k, v = (np.repeat(x.astype(np.float64), group, axis=2) for x in (k, v))
+    sc = np.einsum("bqhd,bthd->bhqt", q.astype(np.float64), k) * scale
+    ahead = np.arange(s)[:, None] - np.arange(s)[None, :]
+    mask = ahead >= 0
+    if window:
+        mask &= ahead < window
+    sc = np.where(mask, sc, -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhqt,bthd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(6, 2), (8, 2), (4, 4)])
+@pytest.mark.parametrize("window,block", [(8, 4), (8, 16), (5, 8), (0, 8),
+                                          (64, 8)])
+def test_blocked_attention_is_the_dense_masked_softmax(monkeypatch, heads,
+                                                       kv_heads, window,
+                                                       block):
+    """Query blocks against the keys the mask lets matter (a band from
+    ``window - 1`` before a block's first row; with no window everything up
+    to its last row) against the whole square: blocks smaller and larger
+    than the window, a window wider than the sequence, 6 and 8 query heads
+    over 2 K/V heads (48 and 64 over 8 in the cell)."""
+    from mxnet_tpu.ops import paged
+
+    monkeypatch.setattr(paged, "_QUERY_BLOCK", block)
+    rng = np.random.default_rng(0)
+    s, hd = 37, 8
+    q = rng.standard_normal((2, s, heads, hd)).astype(np.float32)
+    k = rng.standard_normal((2, s, kv_heads, hd)).astype(np.float32)
+    v = rng.standard_normal((2, s, kv_heads, hd)).astype(np.float32)
+    got = np.asarray(paged.blocked_attention(q, k, v, scale=0.3,
+                                             window=window))
+    np.testing.assert_allclose(got, _banded_softmax(q, k, v, 0.3, window),
+                               atol=2e-5, rtol=0)
+
+
+def test_dense_attention_op_blocks_a_long_grouped_sequence(monkeypatch):
+    """``_contrib_DenseAttention`` over more than one block of queries
+    (grouped-query, causal) is the blocked form; a short one stays as it
+    was."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import paged
+
+    rng = np.random.default_rng(1)
+    q = rng.standard_normal((1, 40, 6, 8)).astype(np.float32)
+    k = rng.standard_normal((1, 40, 2, 8)).astype(np.float32)
+    v = rng.standard_normal((1, 40, 2, 8)).astype(np.float32)
+    want = _banded_softmax(q, k, v, 0.25, 0)
+    seen = []
+    real = paged.blocked_attention
+    monkeypatch.setattr(paged, "blocked_attention",
+                        lambda *a, **kw: seen.append(1) or real(*a, **kw))
+    for block, blocked in ((512, False), (16, True)):
+        monkeypatch.setattr(paged, "_QUERY_BLOCK", block)
+        del seen[:]
+        got = mx.nd._contrib_DenseAttention(
+            mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), causal=True,
+            scale=0.25).asnumpy()
+        assert bool(seen) is blocked
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("lengths", [(3, 8), (9, 16), (17, 23), (24, 1)])
+def test_window_op_returns_each_prompts_ring_after_its_last_real_token(
+        lengths):
+    """The sequence op over a bucket of 24 with two prompts' true lengths:
+    entry ``j`` of a ring is the latest real token ``t`` with ``t % 8 == j``
+    (zeros where none has landed), whatever the padding holds."""
+    import mxnet_tpu as mx
+
+    rng = np.random.default_rng(2)
+    s, window, kv, hd = 24, 8, 2, 4
+    q = rng.standard_normal((2, s, 6, hd)).astype(np.float32)
+    k = rng.standard_normal((2, s, kv, hd)).astype(np.float32)
+    v = rng.standard_normal((2, s, kv, hd)).astype(np.float32)
+    out, k_ring, v_ring = (x.asnumpy() for x in mx.nd._contrib_WindowAttention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v),
+        mx.nd.array(np.asarray(lengths, np.float32)), window=window,
+        scale=0.5, use_length=True))
+    np.testing.assert_allclose(out, _banded_softmax(q, k, v, 0.5, window),
+                               atol=2e-5, rtol=0)
+    assert k_ring.shape == v_ring.shape == (2, window, kv * hd)
+    for b, n in enumerate(lengths):
+        for j in range(window):
+            live = [t for t in range(n) if t % window == j]
+            for ring, rows in ((k_ring, k), (v_ring, v)):
+                want = rows[b, live[-1]].reshape(-1) if live \
+                    else np.zeros(kv * hd, np.float32)
+                np.testing.assert_array_equal(ring[b, j], want)
+    # without ``length`` the whole sequence is the prompt
+    _, whole, _ = mx.nd._contrib_WindowAttention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), window=window,
+        scale=0.5)
+    np.testing.assert_array_equal(whole.asnumpy()[0],
+                                  k[0, 16:24].reshape(window, -1))
+
+
+@pytest.mark.parametrize("heads", [6, 8])
+def test_window_step_over_a_ring_is_the_sequence_form_token_by_token(heads):
+    """The lane form fed 27 tokens one at a time (a window of 8: the ring
+    wraps three times) against ONE call of the sequence form; a second lane
+    idles on the scratch slot, and the slots hold NaN before a token lands:
+    an entry that is not live reaches nothing."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import paged
+
+    rng = np.random.default_rng(3)
+    n, window, kv, hd = 27, 8, 2, 4
+    q = rng.standard_normal((1, n, heads, hd)).astype(np.float32)
+    k = rng.standard_normal((1, n, kv, hd)).astype(np.float32)
+    v = rng.standard_normal((1, n, kv, hd)).astype(np.float32)
+    want = np.asarray(paged.blocked_attention(q, k, v, scale=0.5,
+                                              window=window))[0]
+    k_ring = jnp.full((3, window, kv * hd), np.nan, jnp.float32)
+    v_ring = jnp.full((3, window, kv * hd), np.nan, jnp.float32)
+    slot = np.array([2, 0], np.int32)   # lane 1 idles on the scratch slot
+    for t in range(n):
+        out, k_ring, v_ring = paged.window_step(
+            np.stack([q[0, t], q[0, 0]]), np.stack([k[0, t], k[0, 0]]),
+            np.stack([v[0, t], v[0, 0]]), k_ring, v_ring, slot,
+            np.array([t, 0], np.int32), 0.5)
+        np.testing.assert_allclose(np.asarray(out)[0], want[t], atol=2e-5,
+                                   rtol=0)
+        # the ring after token t is what the sequence form returns for a
+        # prompt of t + 1 tokens
+        rk, rv = paged.window_rings(k, v, np.array([t + 1], np.int32), window)
+        live = np.arange(window) <= t
+        np.testing.assert_array_equal(np.asarray(k_ring)[2][live],
+                                      np.asarray(rk)[0][live])
+        np.testing.assert_array_equal(np.asarray(v_ring)[2][live],
+                                      np.asarray(rv)[0][live])
+    assert np.isnan(np.asarray(k_ring)[1]).all()  # nobody's slot: untouched
