@@ -67,8 +67,8 @@ name, kind ``paged`` or ``slot``, entry shape, dtype), which one
 (``engine_spec()``).  A family with slot planes (recurrent
 state, convolution tails, a sliding-window layer's ring) gets a
 ``state_slot`` vector beside ``page_table``; one with rings says what a lane
-holds of them (``ring_bytes()``: ``gen:step``'s ``window_bytes``).  A family may name small outputs its lane program returns
-after the picked ids (``lane_extras``; ``expert_load``, the live lanes'
+holds of them (``ring_bytes()``: ``gen:step``'s ``window_bytes``).  A family
+may name small outputs its lane program returns after the picked ids (``lane_extras``; ``expert_load``, the live lanes'
 picks by expert layer and expert, is the one the engine knows what to do
 with): they are read with the ids, one iteration late.  What a family
 without a catch-up graph cannot do is refused by name, never done wrongly:

@@ -129,14 +129,15 @@ class HybridLM:
     engine asks (generation/engine.py, "The family seam").
 
     ``layer_types`` is the pattern (``"mamba"``, ``"conv"``, ``"attention"``,
-    ``"window"`` or ``"latent"`` a layer; for ``latent`` ``head_dim`` is ``nope_dim +
-    rope_dim``, ``kv_heads`` is ``num_heads``, and ``q_rank`` / ``kv_rank`` /
+    ``"window"`` or ``"latent"`` a layer; for ``latent`` ``head_dim`` is
+    ``nope_dim + rope_dim``, ``kv_heads`` is ``num_heads``, and ``q_rank`` /
+    ``kv_rank`` /
     ``nope_dim`` / ``rope_dim`` / ``v_dim`` are needed); ``num_heads`` / ``kv_heads`` / ``head_dim``
     the attention layers', ``rotary_theta`` (None: no positions) and
     ``qk_norm`` theirs too, as ``rotary_dim`` / ``rotary_scaling`` and
     ``attn_gate`` (module docstring); ``window`` / ``window_heads`` /
-    ``window_rotary_theta`` the sliding-window layers'; ``intermediate`` the dense gated MLP's inner
-    width; ``ssm_heads`` / ``ssm_head_dim`` / ``ssm_state`` / ``chunk`` the
+    ``window_rotary_theta`` the sliding-window layers'; ``intermediate`` the
+    dense gated MLP's inner width; ``ssm_heads`` / ``ssm_head_dim`` / ``ssm_state`` / ``chunk`` the
     state-space layers' (one B/C group; needed only where there is one);
     ``conv_kernel`` the convolutions' width, Mamba's and the short one's;
     ``num_experts`` (0: every layer dense) / ``experts_per_token`` /
@@ -680,8 +681,8 @@ def get_hybrid_lm_decode(model, page_size=16):
     """One decode step, every lane one token.  Inputs ``data``,
     ``positions``, ``source``, ``prev_ids``, ``state_slot`` (lanes,),
     ``page_table`` (lanes, max_pages) (``state_slot`` only where a layer
-    carries a slot plane: a state, a convolution tail, a ring) and the planes of
-    :meth:`HybridLM.planes`; outputs the logits (lanes, vocab), the planes
+    carries a slot plane: a state, a convolution tail, a ring) and the planes
+    of :meth:`HybridLM.planes`; outputs the logits (lanes, vocab), the planes
     in that order, then ``next_ids`` (lanes,) and :attr:`HybridLM.
     lane_extras`.  ``positions`` places the attention layers' K/V and, where
     the model rotates, turns their queries and keys."""

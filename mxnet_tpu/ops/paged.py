@@ -771,22 +771,13 @@ def window_rings(k, v, length, window):
     return ring(k), ring(v)
 
 
-def _window_infer(attrs, shapes):
-    q, k = shapes[0], shapes[1]
-    if q is None or k is None:
-        return shapes, [None, None, None], []
-    ring = (k[0], int(attrs["window"]), k[2] * k[3])
-    return shapes, [q, ring, ring], []
-
-
 @register("_contrib_WindowAttention",
           inputs=lambda attrs: ["query", "key", "value"] + (
               ["length"] if attrs.get("use_length") else []),
           params={"window": Param(int, required=True),
                   "scale": Param("float-or-none", None),
                   "use_length": Param(bool, False)},
-          num_outputs=3, infer_shape=_window_infer,
-          no_grad_inputs=("length",),
+          num_outputs=3, no_grad_inputs=("length",),
           output_names=lambda attrs: ["out", "k_ring", "v_ring"],
           hint="windowattention")
 @jax.named_scope("window_attention")
@@ -844,19 +835,11 @@ def window_step(q, k_new, v_new, k_ring, v_ring, slot, pos, scale):
             k_ring.at[slot, at].set(k_row), v_ring.at[slot, at].set(v_row))
 
 
-def _window_step_infer(attrs, shapes):
-    q, k_ring, v_ring = shapes[0], shapes[3], shapes[4]
-    if q is None or k_ring is None:
-        return shapes, [None, None, None], []
-    return shapes, [q, k_ring, v_ring], []
-
-
 @register("_contrib_WindowAttentionStep",
           inputs=("query", "key", "value", "k_ring", "v_ring", "state_slot",
                   "positions"),
           params={"scale": Param("float-or-none", None)},
-          num_outputs=3, infer_shape=_window_step_infer,
-          no_grad_inputs=("state_slot", "positions"),
+          num_outputs=3, no_grad_inputs=("state_slot", "positions"),
           output_names=lambda attrs: ["out", "k_ring_out", "v_ring_out"],
           hint="windowattentionstep")
 @jax.named_scope("window_attention")

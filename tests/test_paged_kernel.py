@@ -29,6 +29,8 @@ FORMS = {
     "bf16_32_over_8_of_64": (32, 8, 64, jnp.bfloat16, True),
     "bf16_32_over_8_of_128": (32, 8, 128, jnp.bfloat16, True),
     "bf16_16_of_128_group_1": (16, 16, 128, jnp.bfloat16, True),
+    # the sliding-window cell's full layers: six query heads to a K/V head
+    "bf16_48_over_8_of_128": (48, 8, 128, jnp.bfloat16, True),
 }
 
 # name -> (page_size, max_pages, positions; None is an inactive lane,
@@ -57,6 +59,10 @@ CASES = {
                              "shuffled", "bf16_32_over_8_of_128"),
     "bfloat16_group_of_1": (16, 12, [40, None, 0, 191, 130], "shuffled",
                             "bf16_16_of_128_group_1"),
+    "grouped_six_to_a_head": (16, 12, [40, None, 0, 191, 130, 16, 7],
+                              "shuffled", "bf16_48_over_8_of_128"),
+    "grouped_six_to_a_head_long": (16, 96, [1535, 700, None, 1100],
+                                   "descending", "bf16_48_over_8_of_128"),
     "grouped_more_slots_than_the_ring": (16, 96, [1535, 700, None, 1100],
                                          "descending",
                                          "bf16_32_over_8_of_64"),
