@@ -111,7 +111,7 @@ from ..serving.batcher import (BucketedPredictor, DeadlineExceededError,
                                pow2_buckets)
 from ..ops.moe import experts_formulation, experts_path
 from ..ops.paged import (LATENT_PREFILL, WINDOW_STEP, decode_formulation,
-                         latent_formulation)
+                         latent_formulation, sequence_formulation)
 from ..ops.ssm import scan_formulation, step_formulation
 from .kv_pool import KVPoolExhaustedError, PagedKVPool
 
@@ -149,6 +149,21 @@ def _state_scan(symbol, params):
                      for k in ("heads", "head_dim", "state")}
             return dict(sizes, chunk=int(n["attr"].get("chunk", 256)),
                         dtype=np.dtype(weight.dtype))
+    return None
+
+
+def _sequence_heads(symbol, family):
+    """Query heads of a prefill graph's grouped-query sequence attention
+    (its ``_contrib_WindowAttention`` nodes' where it has them, else its
+    ``_contrib_DenseAttention`` nodes' over fewer K/V heads), what
+    ``ops/paged.py`` ``sequence_formulation`` picks from beside the bucket
+    and the family's K/V heads; None for a graph without one."""
+    ops = {n["op"] for n in json.loads(symbol.tojson())["nodes"]}
+    if "_contrib_WindowAttention" in ops:
+        return family.window_heads
+    if "_contrib_DenseAttention" in ops \
+            and family.kv_heads != family.num_heads:
+        return family.num_heads
     return None
 
 
@@ -615,6 +630,8 @@ class DecodeEngine:
         longest = self._prefill[self.prefill_len_buckets[-1]]
         self._ssm_scan = _state_scan(
             longest._preds[longest.max_batch_size]._symbol, self._params)
+        self._sequence_heads = _sequence_heads(
+            longest._preds[longest.max_batch_size]._symbol, family)
         # the lane program's outputs after the picked ids, and the routed
         # experts' cumulative load (expert layers, experts) where it has one
         self._lane_extras = tuple(getattr(family, "lane_extras", ()))
@@ -1005,6 +1022,13 @@ class DecodeEngine:
                 snap["ssm_scan"] = scan_formulation(
                     self._device.platform, self.prefill_len_buckets[-1],
                     is_train=False, **self._ssm_scan)
+            if self._sequence_heads:
+                # and for their grouped-query attention over the sequence
+                # (ops/paged.py), at the longest bucket too
+                snap["sequence_attention"] = sequence_formulation(
+                    self._device.platform, self.prefill_len_buckets[-1],
+                    self._sequence_heads, self.family.kv_heads,
+                    self.head_dim, self.family.dtype, is_train=False)
             if self._latent_rows:
                 # likewise for the latent layers' two ops (ops/paged.py)
                 snap["latent_attention"] = {

@@ -24,6 +24,22 @@ Measured on v5e (my chip runs, PR 40; device trace, causal, bfloat16,
 with 512 x 512 blocks, 0.755 with 1024, 0.620 with the default 2048 (ten
 live tiles a (batch, head) pair, 0.97 us each, 70 % of the MXU's peak on
 the FLOPs executed); non-causal 2.893 -> 0.879.
+Since PR 52 the same kernel takes, as static parameters of ``_fwd_call``:
+  * a group: query head ``h`` reads K/V head ``h // group`` through the K/V
+    index map, K and V never repeated in memory;
+  * a band (``window`` > 0: token ``t`` attends to ``t - window < u <= t``):
+    the grid's innermost dimension is the most blocks a q tile's band
+    touches and its steps start at the tile's first live block, the mask is
+    the diagonal's test, the band's or both by which kind of block a step
+    holds;
+  * operands and result by rows (``heads``: a token one row of its heads,
+    head ``h`` column block ``h``), with no transpose around the call.
+``ops/paged.py`` runs the hybrid block's sequence attention through it
+(forward only, ``sequence_formulation``).  Measured on v5e (my chip runs,
+PR 52; host clock, one prompt of 4,096, 64 heads over 8, a window of 512):
+1.50 ms a call in 512 x 512 blocks (two steps a q tile, 1.56 us a masked
+tile) where XLA's query blocks take 4.88; 1,024-row blocks 1.76, 256 2.43.
+With no group, window or rows the traced program is PR 40's.
 
 Backward: two Pallas kernels in the flash-v2 style, recomputing P per block
 from (Q, K, logsumexp):
@@ -130,8 +146,15 @@ def _last_live_block(i, bq, bk):
     return (i * bq + bq - 1) // bk
 
 
+def _first_live_block(i, bq, bk, window):
+    """Index of the first K/V block q tile ``i`` (traced, or an array of
+    tiles) reads under a band of ``window`` keys: the one that holds key ``i
+    * bq - window + 1``.  The grid's steps of a tile start there."""
+    return (i * bq - window + 1).clip(0) // bk
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, bq, bk, nq, nk, tq, tk, scale, causal):
+                *, bq, bk, nq, nk, tq, tk, scale, causal, window=0):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -139,6 +162,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     qi = pl.program_id(1)
     j = pl.program_id(2)
     d = q_ref.shape[-1]
+    # the K/V block this step holds: a band's steps count from the q tile's
+    # first live block (``nk`` is then the most blocks a tile's band touches)
+    kb = j + _first_live_block(qi, bq, bk, window) if window else j
 
     @pl.when(j == 0)
     def _init():
@@ -146,10 +172,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def tile(a, c, masked):
+    def tile(a, c, kind):
         """Online-softmax update of row group ``a`` with key chunk ``c``
         (m and l as [rows, 128], every lane of a row the same: see the
-        module's note)."""
+        module's note).  ``kind``: ``open``, or which tests mask it:
+        ``masked`` (the diagonal's), ``banded`` (the band's far side: a row
+        that it leaves no key of the tile weighs the tile's keys equally
+        until a later tile's maximum, which its own position always gives
+        it, scales that away) or ``both``."""
         rows = slice(a * tq, (a + 1) * tq)
         keys = slice(c * tk, (c + 1) * tk)
         # dots stay in the input dtype (bf16 on TPU -> MXU) with f32
@@ -160,12 +190,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
             * (scale * _LOG2E)
-        if masked:
+        if kind != "open":
             q_pos = qi * bq + a * tq \
                 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-            k_pos = j * bk + c * tk \
+            k_pos = kb * bk + c * tk \
                 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG)
+            keep = q_pos - k_pos < window if kind == "banded" \
+                else q_pos >= k_pos
+            if kind == "both":
+                keep = jnp.logical_and(keep, q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG)
         m = m_scr[rows, :]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp2(s - _lanes(m_new, tk))
@@ -182,7 +216,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             for a in range(bq // tq):
                 kind = status(a, c)
                 if kind != "dead":
-                    tile(a, c, kind == "masked")
+                    tile(a, c, kind)
 
     def on_diagonal(a, c):
         """Tile of a block whose first row and first key are the same
@@ -193,8 +227,54 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             return "dead"
         return "masked"
 
+    def in_band(status):
+        """``status`` of a tile on the diagonal block, with the band's test
+        where the tile's last row reaches ``window`` or more past its first
+        key."""
+        def banded(a, c):
+            kind = status(a, c)
+            if a * tq - (c + 1) * tk + 1 >= window:
+                return "dead"
+            if kind == "dead" or (a + 1) * tq - 1 - c * tk < window:
+                return kind
+            return {"open": "banded", "masked": "both"}[kind]
+
+        return banded
+
     if not causal:
         block(lambda a, c: "open")
+    elif window:
+        # A band's blocks, by two tests each: wholly under the diagonal or
+        # crossed by it, wholly inside the band or crossed by its far side.
+        # Which of the four kinds the grid holds is static (at 512 x 512
+        # blocks under a window of 512: the block before the diagonal,
+        # crossed by the band, then the diagonal's, inside it); a dead
+        # step (a first tile's second) runs nothing and fetched nothing.
+        def place(i, b):
+            """Whether K/V block ``b`` holds a key q tile ``i`` reads, lies
+            wholly under its diagonal, wholly inside its band (traced, or
+            of Python numbers)."""
+            return (b * bk <= i * bq + bq - 1, (b + 1) * bk - 1 <= i * bq,
+                    i * bq + bq - 1 - b * bk < window)
+
+        first = _first_live_block(np.arange(nq), bq, bk, window).tolist()
+        kinds = sorted({place(i, b)[1:] for i in range(nq)
+                        for b in range(first[i], first[i] + nk)
+                        if place(i, b)[0]})
+        def tiles_of(below, within):
+            """``status`` of the tiles of a block of that kind."""
+            if below:
+                return lambda a, c: "open" if within else "banded"
+            if bq != bk:
+                return lambda a, c: "masked" if within else "both"
+            return on_diagonal if within else in_band(on_diagonal)
+
+        live, under, inside = place(qi, kb)
+        for below, within in kinds:
+            pl.when(functools.reduce(jnp.logical_and, (
+                live, under if below else jnp.logical_not(under),
+                inside if within else jnp.logical_not(inside))))(
+                    functools.partial(block, tiles_of(below, within)))
     else:
         # Three kinds of block: wholly under the diagonal (no mask is
         # built), crossed by it (masked; where the block is square, its
@@ -220,28 +300,57 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(causal, scale, bq, bk, interpret):
+def _fwd_call(causal, scale, bq, bk, interpret, group=1, window=0, heads=0):
     """The forward ``pallas_call`` on flattened [b*h, s, d] operands, jitted
     on its own: a graph of N attention layers traces and lowers the
-    kernel's unrolled body once, not N times."""
+    kernel's unrolled body once, not N times.  ``group`` query heads read
+    one K/V head (``kt`` / ``vt`` [b*h/group, s, d], through the index map:
+    never repeated in memory); ``window`` > 0 is a band under the diagonal
+    (token ``t`` attends to ``t - window < u <= t``), whose q tiles step
+    through the blocks their band touches and no other.  With ``heads`` the
+    operands and the result hold a token as ONE row, [b, s, heads * d] and
+    [b, s, heads / group * d], as a projection leaves it and the paged
+    planes keep it: head ``h`` is column block ``h`` of the row, and nothing
+    is transposed around the call."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     def call(qt, kt, vt):
-        bh, sq, d = qt.shape
+        # where (batch, head) pair ``n``'s block of rows lies, and its K/V
+        # head's (``group`` 1 adds nothing to the maps PR 40 measured)
+        shared = (lambda h: h) if group == 1 else (lambda h: h // group)
+        if heads:
+            bh, sq, d = qt.shape[0] * heads, qt.shape[1], \
+                qt.shape[2] // heads
+            q_at = lambda n, block: (n // heads, block, n % heads)
+            kv_at = lambda n, block: (n // heads, block, shared(n % heads))
+        else:
+            bh, sq, d = qt.shape
+            q_at = lambda n, block: (n, block, 0)
+            kv_at = lambda n, block: (shared(n), block, 0)
         nq, nk = sq // bq, kt.shape[1] // bk
+        if window:
+            tiles = np.arange(nq)
+            nk = int((_last_live_block(tiles, bq, bk)
+                      - _first_live_block(tiles, bq, bk, window)).max()) + 1
         kernel = functools.partial(
             _fwd_kernel, bq=bq, bk=bk, nq=nq, nk=nk,
             tq=_FWD_TILE if bq % _FWD_TILE == 0 else bq,
             tk=_FWD_TILE if bk % _FWD_TILE == 0 else bk,
-            scale=scale, causal=causal)
-        if causal:
-            kv_map = lambda bh, i, j: (
-                bh, jnp.minimum(j, _last_live_block(i, bq, bk)), 0)
+            scale=scale, causal=causal, window=window)
+        if window:
+            kv_block = lambda i, j: jnp.minimum(
+                j + _first_live_block(i, bq, bk, window),
+                _last_live_block(i, bq, bk))
+        elif causal:
+            kv_block = lambda i, j: jnp.minimum(
+                j, _last_live_block(i, bq, bk))
         else:
-            kv_map = lambda bh, i, j: (bh, j, 0)
+            kv_block = lambda i, j: j
+        q_map = lambda n, i, j: q_at(n, i)
+        kv_map = lambda n, i, j: kv_at(n, kv_block(i, j))
         # lse carries a singleton middle dim so its block's trailing dims
         # (1, bq) satisfy the Mosaic tiling rule (second-to-last equals the
         # array dim, last divisible by 128); squeezed before returning
@@ -250,16 +359,16 @@ def _fwd_call(causal, scale, bq, bk, interpret):
             kernel,
             grid=(bh, nq, nk),
             in_specs=[
-                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, bk, d), kv_map),
                 pl.BlockSpec((1, bk, d), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh, 0, i)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, sq, d), qt.dtype, vma=vma),
+                jax.ShapeDtypeStruct(qt.shape, qt.dtype, vma=vma),
                 jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32, vma=vma)],
             scratch_shapes=[
                 pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -274,17 +383,32 @@ def _fwd_call(causal, scale, bq, bk, interpret):
     return jax.jit(call)
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Returns (o, lse) with o: [b, s, h, d], lse: [b*h, s] (f32)."""
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window=0, rows=False):
+    """Returns (o, lse) with o: [b, s, h, d], lse: [b*h, s] (f32).  ``k`` /
+    ``v`` may hold fewer heads than ``q`` (grouped-query: query head ``i``
+    reads K/V head ``i // (h / kv_heads)``); ``window`` > 0 bands the causal
+    mask; ``rows`` hands the kernel the operands as they are, a token one
+    row of its heads (on a TPU for heads of whole lane tiles), where the
+    default is by heads, [b*h, s, d], a transpose each way."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, kv_heads = k.shape[1:3]
+    if h % kv_heads or (window and not causal):
+        raise ValueError("flash_attention: %d query heads over %d K/V heads,"
+                         " window %d, causal %s" % (h, kv_heads, window,
+                                                    causal))
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
+    call = functools.partial(_fwd_call, bool(causal), float(scale), bq, bk,
+                             bool(interpret), h // kv_heads, int(window))
+    if rows:
+        o, lse = call(h)(*(x.reshape(x.shape[:2] + (-1,))
+                           for x in (q, k, v)))
+        return o.reshape(q.shape), lse.reshape(b * h, sq)
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    o, lse = _fwd_call(bool(causal), float(scale), bq, bk,
-                       bool(interpret))(qt, kt, vt)
+    kt = k.transpose(0, 2, 1, 3).reshape(b * kv_heads, sk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * kv_heads, sk, d)
+    o, lse = call(0)(qt, kt, vt)
     return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse.reshape(b * h, sq)
 
 
@@ -502,17 +626,19 @@ def _flash_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 _DEFAULT_BLOCK = 512
 
 
-def _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype, causal):
+def _resolve(block_q, block_k, seq_q, seq_k, head_dim, dtype, causal,
+             window=0):
     """((forward block_q, block_k), (backward block_q, block_k)) of a call.
     Explicit ints are respected as given by all three kernels.  What is
     left None takes the measured defaults: 512 for the backward kernels,
     and for the forward the most rows that keep an operand block within
     ``_FWD_BLOCK_BYTES`` (it works a fetched block through in ``_FWD_TILE``
     tiles, so its block is how much one grid step holds, not how much one
-    product covers).  Each is clamped by ``_pick_block``."""
+    product covers), under a band (``window`` > 0) one tile: a larger block
+    fetches keys the band leaves out.  Each is clamped by ``_pick_block``."""
     import jax.numpy as jnp
 
-    cap = _FWD_BLOCK_MAX
+    cap = _FWD_TILE if window else _FWD_BLOCK_MAX
     while cap > _DEFAULT_BLOCK and \
             cap * head_dim * jnp.dtype(dtype).itemsize > _FWD_BLOCK_BYTES:
         cap //= 2

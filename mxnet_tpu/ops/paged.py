@@ -7,13 +7,18 @@ Three ops:
   ``[b, s, h, d]`` (the ``parallel.ring.local_attention`` oracle as a
   symbol op); grouped-query when ``key`` / ``value`` hold fewer heads than
   ``query`` (query head ``i`` reads K/V head ``i // (h / kv_heads)``), and
-  then, over more than one block of queries, in query blocks
+  then, over more than one block of queries, in one of two formulations
+  (:func:`sequence_formulation` picks by where the operands live and what
+  they are): on a TPU, for bfloat16 heads of whole lane tiles, the flash
+  forward kernel of ``ops/attention.py`` (a K/V head read by its group of
+  query heads through the index map, online softmax in VMEM: no score
+  reaches the HBM); anywhere else in query blocks
   (:func:`blocked_attention`: a block's float32 scores against the keys up
-  to its last row only, so ``heads x s x s`` scores never exist at once).
-  The generation prefill path uses it instead of the Pallas flash kernels
-  because interpret-mode Pallas is orders of magnitude too slow on CPU,
-  and prefill happens once per sequence; on TPU the flash kernels remain
-  the training/high-MFU choice (models/transformer.py).
+  to its last row only, so ``heads x s x s`` scores never exist at once),
+  which is also the kernel's oracle.  A sequence of one block, and heads
+  with a K/V head each, keep the plain softmax: interpret-mode Pallas is
+  orders of magnitude too slow on CPU, and the training graphs have
+  ``_contrib_FlashAttention`` (models/transformer.py).
 
 * ``_contrib_PagedAttention`` — one autoregressive decode step over a
   paged KV pool (the vLLM PagedAttention layout): each decode *lane*
@@ -81,8 +86,9 @@ V, token ``t`` at ``t % window``, a slot plane of the pool like a recurrent
 state (models/hybrid_lm.py, kind ``window``).  Two ops, both under the scope
 ``window_attention``:
 
-* ``_contrib_WindowAttention`` — a whole sequence, banded, in query blocks
-  (:func:`blocked_attention` with the band: a block reads the keys from
+* ``_contrib_WindowAttention`` — a whole sequence, banded, in the same two
+  formulations (the kernel's grid walks only the blocks the band touches;
+  :func:`blocked_attention` with the band: a block reads the keys from
   ``window - 1`` before its first row); beside the output it returns each
   prompt's rings ``(b, window, kv_heads * head_dim)`` as they stand after its
   LAST REAL token, at the ring's own indices (entry ``j`` the latest token
@@ -138,7 +144,7 @@ def _dense_attention(opctx, attrs, query, key, value):
     if scale is None:
         scale = 1.0 / np.sqrt(hd)
     if causal and s > _QUERY_BLOCK:
-        return blocked_attention(query, key, value, scale=scale)
+        return _sequence_attention(opctx, query, key, value, scale=scale)
     q = query.reshape(b, s, kv_heads, group, hd)
     sc = jnp.einsum("bqkgd,btkd->bkgqt", q, key).astype(jnp.float32) * scale
     if causal:
@@ -185,6 +191,67 @@ def blocked_attention(q, k, v, *, scale, window=0):
         out.append(jnp.einsum("bkgqt,btkd->bqkgd", p, v[:, first:end],
                               preferred_element_type=f32))
     return jnp.concatenate(out, axis=1).astype(q.dtype).reshape(q.shape)
+
+
+def sequence_formulation(platform, L, heads, kv_heads, head_dim, dtype,
+                         is_train):
+    """Which formulation a grouped-query sequence of more than one query
+    block runs (``_contrib_DenseAttention``'s causal path, and
+    ``_contrib_WindowAttention``): ``"pallas"`` -- the flash forward kernel
+    of ``ops/attention.py`` (online softmax in VMEM: no score reaches the
+    HBM), a K/V head read by its group through the index map and, under a
+    window, only the blocks the band touches walked -- where the operands
+    live on a TPU in bfloat16, a head is whole lane tiles, ``L`` whole tiles
+    of the kernel and the op is not being differentiated (the call is the
+    forward alone: a training graph keeps the XLA form and its gradient);
+    ``"xla"`` -- :func:`blocked_attention`, also the kernel's oracle --
+    anywhere else.  An observation of the operands, as
+    :func:`decode_formulation` is."""
+    from .attention import _FWD_TILE
+
+    tiled = (np.dtype(dtype) == np.dtype("bfloat16") and head_dim % 128 == 0
+             and heads % kv_heads == 0 and L > _QUERY_BLOCK
+             and L % _FWD_TILE == 0)
+    return ("pallas" if platform == "tpu" and tiled and not is_train
+            else "xla")
+
+
+def _kernel_sequence(q, k, v, *, scale, window=0, interpret=None):
+    """:func:`blocked_attention`'s operands and result through the flash
+    forward kernel: the band in blocks of one tile, a causal sequence in the
+    forward's own default block.  The band's operands go in as they are, a
+    token one row of its heads (on the v5e, in the sliding-window cell's
+    4,096-token prefill: 125.9 ms where by heads reads 133.2: XLA's copy of
+    64 heads' output into its consumer's layout and the transposing write of
+    the rotation before it, 0.5 ms a layer, against 0.13 in the kernel's
+    strided fetches; my chip runs, PR 52); a causal sequence's by heads,
+    whose 2,048-row blocks by rows pass the kernel's fast memory (16.9 MB
+    of 16)."""
+    from . import attention
+    from .interpret import interpret_for
+
+    s, hd = q.shape[1], q.shape[3]
+    blocks, _ = attention._resolve(None, None, s, s, hd, q.dtype, True,
+                                   window)
+    return attention._flash_forward(
+        q, k, v, True, scale, *blocks,
+        interpret_for("sequence_attention", (q, k, v), interpret),
+        window=window, rows=bool(window))[0]
+
+
+def _sequence_attention(opctx, q, k, v, *, scale, window=0):
+    """A grouped-query causal (or banded) sequence of more than one query
+    block, in the formulation its operands allow."""
+    import jax.numpy as jnp
+
+    from .interpret import platform_of
+
+    attend = {"pallas": _kernel_sequence, "xla": blocked_attention}[
+        sequence_formulation(platform_of(q, k, v), q.shape[1], q.shape[2],
+                             k.shape[2], q.shape[3],
+                             jnp.result_type(q.dtype, k.dtype, v.dtype),
+                             getattr(opctx, "is_train", False))]
+    return attend(q, k, v, scale=scale, window=window)
 
 
 def _group(heads, kv_heads):
@@ -796,7 +863,7 @@ def _window_attention(opctx, attrs, q, k, v, length=None):
     scale = (1.0 / np.sqrt(q.shape[-1])) if scale is None else float(scale)
     if length is None:
         length = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
-    return (blocked_attention(q, k, v, scale=scale, window=window),
+    return (_sequence_attention(opctx, q, k, v, scale=scale, window=window),
             *window_rings(k, v, length, window))
 
 

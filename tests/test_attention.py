@@ -217,6 +217,58 @@ def test_flash_forward_schedule_matches_dense(name):
         rtol=2e-5, atol=2e-5)
 
 
+# (seq, heads, kv_heads, block_q, block_k, window): a group and a band (PR 52)
+BANDS = {
+    # the sliding-window cell's geometry in small: square blocks of a window
+    "window_is_the_block": (128, 4, 1, 32, 32, 32),
+    # a band inside one block: the diagonal block takes both tests
+    "window_under_a_block": (128, 2, 2, 32, 32, 9),
+    # blocks under the diagonal AND inside the band: no mask at all
+    "window_of_three_blocks": (160, 6, 2, 32, 32, 96),
+    "window_over_the_sequence": (64, 8, 8, 32, 32, 1000),
+    # 512 x 512 tiles inside 1024-row blocks, a band of one tile and a half
+    "tiles_in_a_banded_block": (2048, 2, 1, 1024, 1024, 768),
+    "group_without_a_window": (128, 6, 2, 64, 16, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BANDS))
+def test_flash_forward_with_a_group_and_a_band_matches_dense(name):
+    """Output AND logsumexp where query heads share a K/V head through the
+    index map and a window bands the causal mask: every kind of block the
+    band's schedule knows (under the diagonal or on it, inside the band or
+    crossed by its far side), and the by-rows operands beside the by-heads
+    ones, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att
+
+    s, h, kv, bq, bk, window = BANDS[name]
+    rng = np.random.RandomState(len(name))
+    q = jnp.asarray(rng.randn(2, s, h, 8).astype(np.float32))
+    k = jnp.asarray(rng.randn(2, s, kv, 8).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, s, kv, 8).astype(np.float32))
+    o, lse = att._flash_forward(q, k, v, True, 0.3, bq, bk, True,
+                                window=window)
+    kk, vv = (jnp.repeat(x, h // kv, axis=2) for x in (k, v))
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.3
+    ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    keep = (ahead >= 0) & ((ahead < window) if window else True)
+    sc = jnp.where(keep, sc, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, axis=-1), vv)
+    assert_almost_equal(np.asarray(o), np.asarray(want), rtol=2e-5,
+                        atol=2e-5)
+    assert_almost_equal(
+        np.asarray(lse),
+        np.asarray(jax.nn.logsumexp(sc, axis=-1).reshape(2 * h, s)),
+        rtol=2e-5, atol=2e-5)
+    o_rows, lse_rows = att._flash_forward(q, k, v, True, 0.3, bq, bk, True,
+                                          window=window, rows=True)
+    np.testing.assert_array_equal(np.asarray(o_rows), np.asarray(o))
+    np.testing.assert_array_equal(np.asarray(lse_rows), np.asarray(lse))
+
+
 @pytest.mark.parametrize("name", [
     "causal_bq_over_bk", "causal_bq_under_bk", "causal_more_keys_than_rows",
     "causal_more_rows_than_keys", "noncausal_rectangular",
@@ -279,6 +331,15 @@ RESOLVED = {
                    ((256, 2048), (256, 512))),
     "k_explicit": ((None, 1024, 2048, 2048, 128, "bfloat16", True),
                    ((2048, 1024), (512, 1024))),
+    # under a band the forward fetches one tile a step (PR 52: the
+    # sliding-window cell's sequence attention, a window of 512)
+    "banded": ((None, None, 4096, 4096, 128, "bfloat16", True, 512),
+               ((512, 512), (512, 512))),
+    "banded_explicit": ((256, 256, 4096, 4096, 128, "bfloat16", True, 512),
+                        ((256, 256), (256, 256))),
+    # the same cell's 5,120-token scoring program, its causal layers
+    "scoring_5120": ((None, None, 5120, 5120, 128, "bfloat16", True),
+                     ((1024, 1024), (512, 512))),
 }
 
 
@@ -290,8 +351,9 @@ def test_resolved_blocks(name):
 
     from mxnet_tpu.ops import attention as att
 
-    (bq, bk, sq, sk, d, dtype, causal), want = RESOLVED[name]
-    assert att._resolve(bq, bk, sq, sk, d, jnp.dtype(dtype), causal) == want
+    (bq, bk, sq, sk, d, dtype, causal, *window), want = RESOLVED[name]
+    assert att._resolve(bq, bk, sq, sk, d, jnp.dtype(dtype), causal,
+                        *window) == want
     dq, dk = att.resolve_blocks(bq, bk, sq, sk, head_dim=d, dtype=dtype,
                                 causal=causal)
     assert (dq, dk) == want[1]

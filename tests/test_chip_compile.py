@@ -1000,7 +1000,9 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     pairs fill 40 row tiles, the path that moves the held pairs' rows alone
     (``experts_path``: ``moe_combine`` after each ``moe_grouped``), the
     scan ONE ``ssm_scan`` call a state-space layer, under the scope
-    ``ssm_scan``, the scratch memory under 2 GB (the whole ten
+    ``ssm_scan``, the attention layer's four blocks of queries ONE
+    ``flash_fwd`` call under its node (32 heads over 8:
+    ``sequence_formulation``), the scratch memory under 2 GB (the whole ten
     layers' program takes 1.22 GB: my compile, PR 43), and the slabs the
     layers carry into decode beside the logits."""
     import re
@@ -1023,11 +1025,14 @@ def test_granite_moe_prefill_scans_128_heads_and_groups_its_pairs(
     assert "ragged-dot" not in text
     names = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"", text)
     assert [n.split(".")[0] for n in names] == [
-        "ssm_scan", "moe_grouped", "moe_combine", "moe_grouped",
+        "ssm_scan", "moe_grouped", "moe_combine", "flash_fwd", "moe_grouped",
         "moe_combine"]
     ops = set(re.findall(r"op_name=\"([^\"]*)\"", text))
     assert any("layer1_experts/moe_experts/" in o and "moe_combine" in o
                for o in ops)
+    assert any("layer1_attn/" in scope for scope in re.findall(
+        r"%flash_fwd[\w.\-]* = [^\n]*op_name=\"([^\"]*)\"", text))
+    assert "f32[1,8,4,512," not in text   # a block's scores
     # the scan is the kernel, under the scope the per-layer metrics read;
     # the bucket's decay tensor (268 MB a layer before PR 50) is gone
     assert any("layer0_ssm/ssm_scan/" in o and o.endswith("pallas_call")
@@ -1129,9 +1134,14 @@ def test_laguna_prefill_attends_in_blocks_and_returns_rings(one_chip,
     """The same period's prefill of the cell's longest bucket (one prompt of
     4,096) at the cell's slice of the vocabulary, compiled for the chip: the
     full layer's K and V slabs as the planes hold a token, each sliding
-    layer's rings ``(1, 512, 1024)``, and a scratch memory under 2 GB (the
-    attention in blocks of 512 queries: 48 x 4,096 x 4,096 float32 scores
-    alone would be 3.2 GB)."""
+    layer's rings ``(1, 512, 1024)``, and the attention ONE ``flash_fwd``
+    call a layer (the cell's 20 layers are five such periods: 20 calls),
+    the full layer's under its node and by heads, the sliding layers' under
+    ``window_attention`` and by rows: no block's float32 scores are left in the text (48
+    x 512 x 4,096 and 64 x 512 x 1,023 of them a block before PR 52), and
+    the scratch memory is under 1 GB."""
+    import re
+
     import jax
 
     from mxnet_tpu import compile_cache
@@ -1145,9 +1155,59 @@ def test_laguna_prefill_attends_in_blocks_and_returns_rings(one_chip,
     assert [o.shape for o in outs] == [(1, L, vocab)] \
         + [(1, L, 1024)] * 2 + [(1, 512, 1024)] * 6
     text = compiled.as_text()
-    assert "f32[1,8,6,4096,4096]" not in text
-    assert "f32[1,8,8,4096,4096]" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*\"tpu_custom_call\"[^\n]*"
+                       r"op_name=\"([^\"]*)\"", text)
+    flash = sorted(scope for n, scope in calls if n.startswith("flash_fwd"))
+    assert len(flash) == 4 and "layer0_attn/" in flash[0] \
+        and "window_attention" not in flash[0], flash
+    for i in (1, 2, 3):
+        assert ("layer%d_attn/window_attention/" % i) in flash[i], flash
+    # the band's calls take and leave a token as one row of 64 x 128 lanes
+    # (no transpose around them); the full layer's go by heads
+    by_rows = [line for line in text.splitlines() if re.match(
+        r"\s*%flash_fwd[\w.\-]* = \(bf16\[1,4096,8192\]", line)]
+    assert len(by_rows) == 3 and all("window_attention" in x
+                                     for x in by_rows), by_rows
+    for scores in ("f32[1,8,6,512,", "f32[1,8,8,512,", "f32[1,8,6,4096,4096]",
+                   "f32[1,8,8,4096,4096]"):
+        assert scores not in text, scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("L,heads,window,grid", [
+    (4096, 64, 512, (64, 8, 2)), (4096, 48, 0, (48, 2, 2)),
+    (5120, 48, 0, (48, 5, 5))])
+def test_sequence_kernel_compiles_for_v5e(one_chip, L, heads, window, grid):
+    """The flash forward alone at the sliding-window cell's longest prefill:
+    one prompt of 4,096, 64 query heads over 8 K/V heads under a band of 512
+    (two 512 x 512 steps a q tile: the grid walks the band's blocks and no
+    other) and 48 over 8 under the causal mask (the forward's default block
+    of 2,048; 1,024 in the cell's 5,120-token scoring program, which 2,048
+    does not divide); ``sequence_formulation`` says ``pallas`` for all."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import paged
+
+    kv, hd = 8, 128
+    assert paged.sequence_formulation("tpu", L, heads, kv, hd, jnp.bfloat16,
+                                      False) == "pallas"
+
+    def arg(n):
+        return jax.ShapeDtypeStruct((1, L, n, hd), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def attend(q, k, v):
+        return paged._kernel_sequence(q, k, v, scale=hd ** -0.5,
+                                      window=window, interpret=False)
+
+    args = arg(heads), arg(kv), arg(kv)
+    (call,) = _pallas_calls(jax.make_jaxpr(attend)(*args).jaxpr)
+    assert call.params["grid_mapping"].grid == grid
+    compiled = jax.jit(attend).lower(*args).compile()
+    names = _custom_call_names(compiled.as_text())
+    assert len(names) == 1 and names[0].startswith("flash_fwd"), names
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 def test_paged_decode_takes_48_heads_over_rows_of_8_by_128(one_chip):

@@ -180,6 +180,7 @@ def test_prefill_then_decode_logits_are_the_reference(dtype, monkeypatch):
     assert eng.pool.slot_bytes == eng._ring_bytes
     assert eng.pool.num_slots == 5 and snap["state_slots"]["capacity"] == 4
     assert snap["window_attention"] == "xla"
+    assert snap["sequence_attention"] == "xla"   # the host platform
     assert snap["paged_attention"] == "xla"
     assert snap["moe_experts"] == "ragged-dense"  # the host's formulation
     control_misses = 0

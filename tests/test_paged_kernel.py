@@ -521,6 +521,7 @@ def test_engine_says_which_formulation_and_how_many_pages(monkeypatch):
         # the choice function's business (above)
         assert eng.snapshot()["paged_attention"] == "xla"
         assert "ssm_step" not in eng.snapshot()  # no state planes, no step
+        assert "sequence_attention" not in eng.snapshot()  # no group
         prompt, new = [3, 1, 4, 1, 5, 9], 7
         assert len(eng.generate(prompt, new)) == new
     finally:
@@ -702,6 +703,197 @@ def test_blocked_attention_is_the_dense_masked_softmax(monkeypatch, heads,
                                              window=window))
     np.testing.assert_allclose(got, _banded_softmax(q, k, v, 0.3, window),
                                atol=2e-5, rtol=0)
+
+
+# (sequence, block): a sequence of 2 and of 5 blocks
+@pytest.mark.parametrize("s,block", [(64, 32), (160, 32)])
+# no window, one block, less than a block, more than the sequence
+@pytest.mark.parametrize("window", [0, 32, 20, 500])
+@pytest.mark.parametrize("heads,kv_heads", [(6, 1), (8, 1), (2, 2)])
+def test_sequence_kernel_is_the_blocked_attention(monkeypatch, heads,
+                                                  kv_heads, window, s, block):
+    """The flash forward (interpret mode) with a group and a band against
+    ``blocked_attention``, its oracle, and the whole square: groups of 6, 8
+    and 1, with no window, one of a block, of less than a block and of more
+    than the sequence, over 2 and 5 blocks.  Under a band the grid's
+    innermost dimension is the most blocks a q tile's band touches, not the
+    sequence's."""
+    from mxnet_tpu.ops import attention as att
+
+    monkeypatch.setattr(paged, "_QUERY_BLOCK", block)
+    rng = np.random.default_rng(4)
+    q = rng.standard_normal((2, s, heads, HD)).astype(np.float32)
+    k = rng.standard_normal((2, s, kv_heads, HD)).astype(np.float32)
+    v = rng.standard_normal((2, s, kv_heads, HD)).astype(np.float32)
+
+    def kernel(q, k, v):
+        return att._flash_forward(q, k, v, True, 0.3, block, block, True,
+                                  window=window)[0]
+
+    got = np.asarray(kernel(q, k, v))
+    np.testing.assert_allclose(got, np.asarray(paged.blocked_attention(
+        q, k, v, scale=0.3, window=window)), atol=2e-5, rtol=0)
+    np.testing.assert_allclose(got, _banded_softmax(q, k, v, 0.3, window),
+                               atol=2e-5, rtol=0)
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in eqn.params.values():
+                if hasattr(sub, "jaxpr"):
+                    yield from pallas_calls(getattr(sub.jaxpr, "jaxpr",
+                                                    sub.jaxpr))
+
+    (call,) = pallas_calls(jax.make_jaxpr(kernel)(q, k, v).jaxpr)
+    blocks = s // block
+    steps = {0: blocks, 32: 2, 20: 2, 500: blocks}[window]
+    assert call.params["grid_mapping"].grid == (2 * heads, blocks, steps)
+
+
+@pytest.mark.parametrize("lengths", [(700, 1024), (1, 513)])
+def test_window_op_through_the_kernel_with_padded_prompts(monkeypatch,
+                                                          lengths):
+    """``_contrib_WindowAttention`` over a right-padded batch of two, a
+    bucket of 1,024 under a window of 512, with the kernel chosen (as on a
+    TPU; interpret mode here): the real rows are the banded softmax's,
+    whatever the padding holds, and the rings are bit-equal to the XLA
+    form's (they come from ``window_rings`` by ``length``, untouched)."""
+    rng = np.random.default_rng(5)
+    s, window, kv, hd = 1024, 512, 1, 8
+    q = rng.standard_normal((2, s, 6, hd)).astype(np.float32)
+    k = rng.standard_normal((2, s, kv, hd)).astype(np.float32)
+    v = rng.standard_normal((2, s, kv, hd)).astype(np.float32)
+    args = [mx.nd.array(x) for x in (q, k, v, np.asarray(lengths,
+                                                         np.float32))]
+
+    def run():
+        return [x.asnumpy() for x in mx.nd._contrib_WindowAttention(
+            *args, window=window, scale=0.5, use_length=True)]
+
+    want = run()
+    seen = []
+    real = paged._kernel_sequence
+    monkeypatch.setattr(paged, "sequence_formulation",
+                        lambda *a, **kw: "pallas")
+    monkeypatch.setattr(paged, "_kernel_sequence",
+                        lambda *a, **kw: seen.append(kw) or real(*a, **kw))
+    out, k_ring, v_ring = run()
+    assert seen == [dict(scale=0.5, window=window)]
+    np.testing.assert_array_equal(k_ring, want[1])
+    np.testing.assert_array_equal(v_ring, want[2])
+    dense = _banded_softmax(q, k, v, 0.5, window)
+    for b, n in enumerate(lengths):
+        np.testing.assert_allclose(out[b, :n], dense[b, :n], atol=2e-5,
+                                   rtol=0)
+        np.testing.assert_allclose(out[b, :n], want[0][b, :n], atol=2e-5,
+                                   rtol=0)
+
+
+@pytest.mark.parametrize("platform,L,heads,kv_heads,head_dim,dtype,train,"
+                         "want", [
+    ("tpu", 4096, 64, 8, 128, jnp.bfloat16, False, "pallas"),  # sliding
+    ("tpu", 4096, 48, 8, 128, jnp.bfloat16, False, "pallas"),  # full
+    ("tpu", 1024, 48, 8, 128, jnp.bfloat16, False, "pallas"),  # two blocks
+    ("tpu", 2048, 32, 8, 128, jnp.bfloat16, False, "pallas"),  # g4hsmall
+    ("tpu", 1024, 16, 16, 256, jnp.bfloat16, False, "pallas"),
+    ("cpu", 4096, 64, 8, 128, jnp.bfloat16, False, "xla"),   # the platform
+    ("gpu", 4096, 64, 8, 128, jnp.bfloat16, False, "xla"),
+    ("tpu", 4096, 64, 8, 128, np.float32, False, "xla"),     # the dtype
+    ("tpu", 4096, 64, 8, 64, jnp.bfloat16, False, "xla"),    # half a tile
+    ("tpu", 704, 64, 8, 128, jnp.bfloat16, False, "xla"),    # no whole tiles
+    ("tpu", 5120, 48, 8, 128, jnp.bfloat16, False, "pallas"),
+    ("tpu", 512, 64, 8, 128, jnp.bfloat16, False, "xla"),    # one block
+    ("tpu", 4096, 64, 8, 128, jnp.bfloat16, True, "xla"),    # a gradient
+])
+def test_sequence_formulation_is_read_off_the_operands(platform, L, heads,
+                                                       kv_heads, head_dim,
+                                                       dtype, train, want):
+    assert paged.sequence_formulation(platform, L, heads, kv_heads, head_dim,
+                                      dtype, train) == want
+
+
+def test_the_sequence_ops_pick_their_formulation_where_the_operands_live():
+    """``_contrib_DenseAttention`` (grouped, causal, two blocks) and
+    ``_contrib_WindowAttention`` traced for a TPU lower ONE ``flash_fwd``
+    kernel each; on this CPU, and for a graph that is being differentiated,
+    they lower none, and that graph still gets its gradient (the XLA
+    form's)."""
+    from mxnet_tpu.ops.interpret import bind
+    from mxnet_tpu.ops.registry import OpContext
+
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.standard_normal((1, 1024, 2, 128)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((1, 1024, 1, 128)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((1, 1024, 1, 128)), jnp.bfloat16)
+    ops = {
+        "dense": lambda opctx: lambda q, k, v: paged._dense_attention(
+            opctx, dict(causal=True, scale=0.1), q, k, v),
+        "window": lambda opctx: lambda q, k, v: paged._window_attention(
+            opctx, dict(window=512, scale=0.1), q, k, v)[0]}
+    for name, op in ops.items():
+        here = str(jax.make_jaxpr(op(None))(q, k, v))
+        there = str(jax.make_jaxpr(bind(op(OpContext(False)), "tpu"))(
+            q, k, v))
+        train = bind(op(OpContext(True)), "tpu")
+        assert "pallas_call" not in here, name
+        assert there.count("pallas_call") == 1 and "flash_fwd" in there, name
+        assert "pallas_call" not in str(jax.make_jaxpr(train)(q, k, v)), name
+        f32 = jnp.float32
+        got = jax.grad(lambda q: train(q, k, v).astype(f32).sum())(q)
+        want = jax.grad(lambda q: jnp.asarray(paged.blocked_attention(
+            q, k, v, scale=0.1, window=512 if name == "window" else 0),
+            f32).sum())(q)
+        assert float(jnp.abs(got.astype(f32)).max()) > 0
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_engine_says_what_its_sequence_attention_runs_where(monkeypatch):
+    """A hybrid family with grouped-query attention (4 query heads of 128
+    over 2) says ``pallas`` for its prefills' sequence attention where it
+    lives on a TPU, in bfloat16, with a longest bucket of two blocks, and
+    ``xla`` on the host platform, for float32 weights and for a bucket of
+    one block; a family whose every query head has a K/V head of its own
+    says nothing."""
+    import types
+
+    from perfbench.builders import hybrid_lm as builder
+    from perfbench.models import hybrid_lm as ref
+
+    cfg = dict(vocab_size=V, hidden_size=512, layer_types=["mamba",
+                                                           "attention"],
+               num_attention_heads=4, num_key_value_heads=2,
+               intermediate_size=64, shared_intermediate_size=64,
+               mamba_n_heads=16, mamba_d_head=64, mamba_d_state=8,
+               mamba_d_conv=4, mamba_expand=2, mamba_n_groups=1,
+               mamba_chunk_size=4, mamba_conv_bias=True, rms_norm_eps=1e-5,
+               embedding_multiplier=1.0, residual_multiplier=1.0,
+               attention_multiplier=0.125, logits_scaling=1.0,
+               position_embedding_type="nope", tie_word_embeddings=True)
+    said = {}
+    for dtype, bucket in (("bfloat16", 1024), ("float32", 1024),
+                          ("bfloat16", 512)):
+        cfg["weights_dtype"] = dtype
+        params = {k: mx.nd.NDArray(v, mx.cpu())
+                  for k, v in ref.make_weights(cfg, 3).items()}
+        eng = DecodeEngine(params, family=builder.family_spec(cfg),
+                           ctx=mx.cpu(), max_seq_len=1040, lane_buckets=(2,),
+                           page_size=16, num_pages=9,
+                           prefill_len_buckets=(bucket,),
+                           prefill_batch_buckets=(1,), start=False,
+                           warmup=False)
+        on_host = eng.snapshot()["sequence_attention"]
+        monkeypatch.setattr(eng, "_device",
+                            types.SimpleNamespace(platform="tpu"))
+        said[dtype, bucket] = (on_host, eng.snapshot()["sequence_attention"])
+    assert said == {("bfloat16", 1024): ("xla", "pallas"),
+                    ("float32", 1024): ("xla", "xla"),
+                    ("bfloat16", 512): ("xla", "xla")}
+    eng = DecodeEngine(_lm_params(), vocab_size=V, num_layers=LAYERS,
+                       num_heads=HEADS, hidden=HEADS * HD, max_seq_len=S,
+                       lane_buckets=(1,), page_size=PAGE, num_pages=24,
+                       prefill_len_buckets=(8,), start=False, warmup=False)
+    assert "sequence_attention" not in eng.snapshot()
 
 
 def test_dense_attention_op_blocks_a_long_grouped_sequence(monkeypatch):
